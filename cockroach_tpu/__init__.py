@@ -53,3 +53,12 @@ __version__ = "0.4.0"
 import jax as _jax  # noqa: E402
 
 _jax.config.update("jax_enable_x64", True)
+
+# Host-side DML predicates and the OLTP lane evaluate on the cpu
+# backend (exec/dml.py _host_eval), so it has to initialise beside the
+# accelerator: a platform list that names only the accelerator
+# (JAX_PLATFORMS=tpu) gains cpu behind it. The first entry stays the
+# default backend, and still fails at start-up if it cannot come up.
+_platforms = _jax.config.jax_platforms
+if _platforms and "cpu" not in _platforms.split(","):
+    _jax.config.update("jax_platforms", _platforms + ",cpu")

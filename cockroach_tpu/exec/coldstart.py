@@ -6,14 +6,17 @@ its compiled programs in memory only. This module wires three pieces
 of cross-process warm-start state (ROADMAP item 5, the Tailwind-style
 accelerator-management frame in PAPERS.md):
 
-1. **Persistent XLA compile cache** — `init_compile_cache` points
-   `jax.experimental.compilation_cache` at an on-disk directory
-   (cluster setting `sql.exec.compile_cache.dir`), under a
-   per-backend / per-jax-version / per-schema subdirectory so stale
-   artifacts from another backend or an upgraded toolchain can never
-   be loaded — the invalidation story is "a new subdir", never a
-   cache flush. Hit/miss/compile-seconds counters come from JAX's
-   monitoring events and surface as `exec.compile.*` metrics.
+1. **Persistent XLA compile cache** — where the launcher set
+   `JAX_COMPILATION_CACHE_DIR`, JAX already reads that directory and
+   `init_compile_cache` leaves `jax_compilation_cache_dir` alone;
+   where it did not, the cache goes to the fixed `<checkout>/.jax_cache`
+   (the path is part of JAX's cache key, so a directory that moves
+   never hits). JAX's key covers backend and compiler version, so one
+   flat directory serves them all. Cluster setting
+   `sql.exec.compile_cache.dir = off` disables it. Hit/miss/
+   compile-seconds counters come from JAX's monitoring events and
+   surface as `exec.compile.*` metrics. The autotune table, parity
+   table and shapes journal are sidecar files in the same directory.
 
 2. **Shape bucket ladder** — `ShapeLadder` generalizes the historical
    "pad row counts to the next power of two" rule into an explicit
@@ -46,17 +49,14 @@ import os
 import threading
 from dataclasses import dataclass
 
-# Bump when the on-disk layout (cache subdir contract, journal or
-# autotune-table format) changes incompatibly: old state is simply
-# never looked at again.
-SCHEMA_VERSION = 1
+_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 _JOURNAL_NAME = "shapes_journal.jsonl"
 _JOURNAL_MAX_BYTES = 8 << 20  # stop appending past this; bounded state
 
 _LOCK = threading.Lock()
-_ACTIVE_DIR: str | None = None
 _LISTENERS = False
+_ERROR: str | None = None  # why the cache is off, when it should be on
 
 # process-wide tallies, bumped by the JAX monitoring listeners
 _HITS = 0
@@ -145,85 +145,70 @@ def _install_listeners() -> None:
         if _LISTENERS:
             return
         _LISTENERS = True
-    try:
-        from jax._src import monitoring
-        monitoring.register_event_listener(_on_event)
-        monitoring.register_event_duration_secs_listener(_on_duration)
-    except Exception:
-        # older/newer jax without the monitoring module: the cache
-        # still works, only the counters stay at zero
-        pass
+    from jax._src import monitoring
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
 
 
-def default_cache_root() -> str:
-    return os.path.join(os.path.expanduser("~"), ".cache",
-                        "cockroach_tpu")
+def checkout_cache_dir() -> str:
+    """`<checkout>/.jax_cache`: fixed beside the package, so every
+    process started from this checkout computes the same cache keys."""
+    pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(pkg), ".jax_cache")
 
 
-def resolve_cache_root(settings=None) -> str | None:
-    """Setting > environment > user default; "off" disables."""
-    configured = ""
-    if settings is not None:
-        try:
-            configured = str(settings.get("sql.exec.compile_cache.dir"))
-        except Exception:
-            configured = ""
-    if configured.lower() in ("off", "none", "disabled"):
+def resolve_cache_dir(settings=None) -> str | None:
+    """$JAX_COMPILATION_CACHE_DIR, else `<checkout>/.jax_cache`; None
+    when `sql.exec.compile_cache.dir` is "off"."""
+    if settings is not None and str(settings.get(
+            "sql.exec.compile_cache.dir")).lower() == "off":
         return None
-    if configured:
-        return configured
-    env = os.environ.get("COCKROACH_TPU_COMPILE_CACHE_DIR", "")
-    if env.lower() in ("off", "none", "disabled"):
-        return None
-    return env or default_cache_root()
+    return os.environ.get(_CACHE_ENV) or checkout_cache_dir()
 
 
-def cache_dir(root: str) -> str:
-    """Per-backend / per-jax-version / per-schema subdirectory: XLA
-    serialized executables are not portable across backends or
-    compiler versions, so stale artifacts are isolated by path instead
-    of trusted-then-validated."""
-    import jax
-    backend = jax.default_backend()
-    return os.path.join(root, f"{backend}-jax{jax.__version__}"
-                              f"-v{SCHEMA_VERSION}")
+def cache_error() -> str | None:
+    """Why the last init_compile_cache left the cache off although it
+    was not disabled (unwritable directory), else None."""
+    return _ERROR
 
 
 def init_compile_cache(settings=None) -> str | None:
-    """Point the JAX persistent compilation cache at the configured
-    directory (idempotent; re-targets on a changed setting). Returns
-    the active per-backend cache dir, or None when disabled/broken —
-    the engine runs fine either way, just cold."""
-    global _ACTIVE_DIR
-    root = resolve_cache_root(settings)
-    if root is None:
+    """Arm the JAX persistent compilation cache (idempotent). Returns
+    the directory in effect, or None when disabled or unwritable —
+    the engine then runs cold, and `cache_error()` says why."""
+    global _ERROR
+    d = resolve_cache_dir(settings)
+    if d is None:
         return None
+    import jax
     try:
-        import jax
-        d = cache_dir(root)
+        os.makedirs(d, exist_ok=True)  # sidecar tables live here too
+    except OSError as e:
         with _LOCK:
-            changed = d != _ACTIVE_DIR
-        if changed:
-            os.makedirs(d, exist_ok=True)
-            # every trace is worth persisting for an interactive
-            # engine: the default 1s/min-size gates exist for training
-            # jobs whose tiny programs aren't worth the disk
-            jax.config.update("jax_compilation_cache_dir", d)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.0)
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", -1)
-            from jax.experimental import compilation_cache as cc
-            # drop the in-memory handle to any previously-targeted
-            # dir so the new path takes effect immediately
-            cc.compilation_cache.reset_cache()
-            with _LOCK:
-                _ACTIVE_DIR = d
-        _install_listeners()
-        with _LOCK:
-            return _ACTIVE_DIR
-    except Exception:
+            _ERROR = f"{d}: {e}"
         return None
+    if os.environ.get(_CACHE_ENV):
+        # placed from outside: JAX read the variable at import
+        if jax.config.jax_compilation_cache_dir != d:
+            raise RuntimeError(
+                f"{_CACHE_ENV}={d!r} was set after jax was imported "
+                f"(jax has {jax.config.jax_compilation_cache_dir!r}); "
+                "set it in the environment the process starts with")
+    elif jax.config.jax_compilation_cache_dir != d:
+        jax.config.update("jax_compilation_cache_dir", d)
+        from jax.experimental import compilation_cache as cc
+        # a program compiled before the first Engine initialised the
+        # cache with no directory; drop that so the path takes effect
+        cc.compilation_cache.reset_cache()
+    # every trace is worth persisting for an interactive engine: the
+    # default 1s/min-size gates exist for training jobs whose tiny
+    # programs aren't worth the disk
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    _install_listeners()
+    with _LOCK:
+        _ERROR = None
+    return d
 
 
 def register_metrics(metrics) -> None:

@@ -403,10 +403,12 @@ def _agg_partials(a: BoundAgg, argf, batch, ctx, gid, num_groups,
         return jax.lax.psum(x, axis_name) if axis_name else x
 
     def pmin(x):
-        return jax.lax.pmin(x, axis_name) if axis_name else x
+        return (aggops.shard_extreme(x, axis_name, "min")
+                if axis_name else x)
 
     def pmax(x):
-        return jax.lax.pmax(x, axis_name) if axis_name else x
+        return (aggops.shard_extreme(x, axis_name, "max")
+                if axis_name else x)
 
     if a.func == "count_rows":
         mask = batch.sel
@@ -811,9 +813,9 @@ def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
                 # shards that saw the group agree on the value (FD);
                 # empty shards contribute the max-identity (the
                 # smallest value), so pmax picks any real one
-                d = jax.lax.pmax(
+                d = aggops.shard_extreme(
                     jnp.where(v, d, aggops._maxident(d.dtype)),
-                    axis_name)
+                    axis_name, "max")
                 v = jax.lax.psum(v.astype(jnp.int32), axis_name) > 0
             aggs_out.append((d, v))
             continue
@@ -822,8 +824,7 @@ def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
         if a.func in ("min", "max"):
             d = acc_f[mmrow[("mm", i)], :]
             if axis_name:
-                d = (jax.lax.pmin if a.func == "min"
-                     else jax.lax.pmax)(d, axis_name)
+                d = aggops.shard_extreme(d, axis_name, a.func)
             if a.arg.type.family in (Family.INT, Family.DECIMAL):
                 # refine the (globally merged) winning hi limb to the
                 # full-width value with the dtype-preserving XLA fold
@@ -842,8 +843,8 @@ def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
                     else aggops.group_max
                 dref = fold(d0, gid, refine, num_groups)
                 if axis_name:
-                    dref = (jax.lax.pmin if a.func == "min"
-                            else jax.lax.pmax)(dref, axis_name)
+                    dref = aggops.shard_extreme(dref, axis_name,
+                                                a.func)
                 aggs_out.append((dref, nonempty))
                 continue
             aggs_out.append((d.astype(jnp.float64), nonempty))

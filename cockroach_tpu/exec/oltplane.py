@@ -221,20 +221,12 @@ class TableMirror:
         vals = np.empty(max(n, 1) * self.ncols, dtype=np.int64)
         vld = np.empty(max(n, 1) * self.ncols, dtype=np.uint8)
         fnd = np.zeros(max(n, 1), dtype=np.uint8)
-        if hasattr(self.lib, "oltp_multiread"):
-            i64p = ctypes.POINTER(ctypes.c_int64)
-            u8p = ctypes.POINTER(ctypes.c_uint8)
-            self.lib.oltp_multiread(
-                self.h, n, karr.ctypes.data_as(i64p), int(read_ts),
-                vals.ctypes.data_as(i64p), vld.ctypes.data_as(u8p),
-                fnd.ctypes.data_as(u8p))
-        else:  # pragma: no cover - stale cached .so without the symbol
-            for i in range(n):
-                got = self.read(int(karr[i]), read_ts)
-                if got is not None:
-                    fnd[i] = 1
-                    vals[i * self.ncols:(i + 1) * self.ncols] = got[0]
-                    vld[i * self.ncols:(i + 1) * self.ncols] = got[1]
+        i64p = ctypes.POINTER(ctypes.c_int64)
+        u8p = ctypes.POINTER(ctypes.c_uint8)
+        self.lib.oltp_multiread(
+            self.h, n, karr.ctypes.data_as(i64p), int(read_ts),
+            vals.ctypes.data_as(i64p), vld.ctypes.data_as(u8p),
+            fnd.ctypes.data_as(u8p))
         return vals.tolist(), vld.tolist(), fnd.tolist()
 
     def scan(self, lo, lo_strict, hi, hi_strict, read_ts: int,

@@ -1,48 +1,75 @@
 """Native (C++) components: build-on-first-import, ctypes ABI.
 
 The reference carries its native axis in c-deps/ built by Bazel; here
-the single native hotspot so far is bulk key encoding (keyenc.cpp).
-The shared library compiles lazily with g++ (cached next to the
-source, keyed on mtime) and loads via ctypes — pybind11 isn't in the
-image, and the ABI is 4 flat functions. Everything degrades to the
-pure-Python codec if a toolchain is missing, so the package never
-hard-depends on a compiler.
+the native hotspots are bulk key encoding (keyenc.cpp) and the OLTP
+row plane (oltp.cpp). Each shared library compiles lazily with g++
+and loads via ctypes — pybind11 isn't in the image, and the ABI is a
+few flat functions. The cached `.so` is named by the SHA-256 of its
+source and compile flags, so an edited source can never load a stale
+library (and a library that loads has every symbol the source
+declares). Without a toolchain the callers run their pure-Python
+codecs; `status()` says which plane each component is on and keeps
+the compiler's message.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
-_SRC = os.path.join(_HERE, "keyenc.cpp")
-_SO = os.path.join(_HERE, "_keyenc.so")
+_CXX = ("g++", "-O3", "-shared", "-fPIC", "-std=c++17")
 
 _lock = threading.Lock()
 _lib = None
 _tried = False
+# component -> {"plane": "native"|"python", "so": path|None,
+#               "built": compiled by this process (False = reused a
+#               cached library whose name matches the source hash),
+#               "error": compiler/loader message|None}
+_STATUS: dict[str, dict] = {}
 
 
-def _compile(src: str, so: str) -> bool:
+def status() -> dict:
+    """Per-component plane report (empty until first use)."""
+    with _lock:
+        return {k: dict(v) for k, v in _STATUS.items()}
+
+
+def _load(stem: str):
+    """Build (unless a library for exactly this source is cached) and
+    dlopen `<stem>.cpp`; None when there is no toolchain. Caller holds
+    _lock."""
+    src = os.path.join(_HERE, stem + ".cpp")
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(
+            " ".join(_CXX).encode() + b"\0" + f.read()).hexdigest()[:16]
+    so = os.path.join(_HERE, f"_{stem}-{digest}.so")
+    st = _STATUS[stem] = {"plane": "python", "so": None,
+                          "built": False, "error": None}
     try:
-        if (os.path.exists(so)
-                and os.path.getmtime(so) >= os.path.getmtime(src)):
-            return True
-        tmp = so + f".tmp{os.getpid()}"
-        subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-             "-o", tmp, src],
-            check=True, capture_output=True, timeout=120)
-        os.replace(tmp, so)
-        return True
-    except (OSError, subprocess.SubprocessError):
-        return False
-
-
-def _build() -> bool:
-    return _compile(_SRC, _SO)
+        if not os.path.exists(so):
+            tmp = so + f".tmp{os.getpid()}"
+            subprocess.run([*_CXX, "-o", tmp, src], check=True,
+                           capture_output=True, timeout=120)
+            os.replace(tmp, so)
+            st["built"] = True
+            for old in glob.glob(os.path.join(_HERE, f"_{stem}*.so")):
+                if old != so:
+                    os.unlink(old)  # libraries of earlier sources
+        lib = ctypes.CDLL(so)
+    except subprocess.CalledProcessError as e:
+        st["error"] = e.stderr.decode(errors="replace")[-2000:]
+        return None
+    except (OSError, subprocess.SubprocessError) as e:
+        st["error"] = f"{type(e).__name__}: {e}"
+        return None
+    st.update(plane="native", so=so)
+    return lib
 
 
 def get_lib():
@@ -52,11 +79,8 @@ def get_lib():
         if _tried:
             return _lib
         _tried = True
-        if not _build():
-            return None
-        try:
-            lib = ctypes.CDLL(_SO)
-        except OSError:
+        lib = _load("keyenc")
+        if lib is None:
             return None
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i64p = ctypes.POINTER(ctypes.c_int64)
@@ -74,8 +98,6 @@ def get_lib():
         return _lib
 
 
-_OLTP_SRC = os.path.join(_HERE, "oltp.cpp")
-_OLTP_SO = os.path.join(_HERE, "_oltp.so")
 _oltp_lib = None
 _oltp_tried = False
 
@@ -88,11 +110,8 @@ def get_oltp():
         if _oltp_tried:
             return _oltp_lib
         _oltp_tried = True
-        if not _compile(_OLTP_SRC, _OLTP_SO):
-            return None
-        try:
-            lib = ctypes.CDLL(_OLTP_SO)
-        except OSError:
+        lib = _load("oltp")
+        if lib is None:
             return None
         i64 = ctypes.c_int64
         i64p = ctypes.POINTER(i64)
@@ -114,15 +133,10 @@ def get_oltp():
         lib.oltp_live.restype = ctypes.c_int
         lib.oltp_read.argtypes = [vp, i64, i64, i64p, u8p]
         lib.oltp_read.restype = ctypes.c_int
-        try:
-            # batch-window gather (may be absent from a stale cached
-            # .so built before the symbol existed; callers hasattr-gate
-            # and fall back to per-key oltp_read)
-            lib.oltp_multiread.argtypes = [vp, i64, i64p, i64, i64p,
-                                           u8p, u8p]
-            lib.oltp_multiread.restype = i64
-        except AttributeError:
-            pass
+        # batch-window gather
+        lib.oltp_multiread.argtypes = [vp, i64, i64p, i64, i64p,
+                                       u8p, u8p]
+        lib.oltp_multiread.restype = i64
         lib.oltp_scan.argtypes = [vp, i64, ctypes.c_int, ctypes.c_int,
                                   i64, ctypes.c_int, ctypes.c_int,
                                   i64, i64, i64p, i64p, u8p]

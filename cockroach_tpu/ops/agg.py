@@ -205,6 +205,21 @@ def group_max(data, group_ids, mask, num_groups: int):
     return jax.ops.segment_max(d, gid, num_segments=num_groups)
 
 
+def shard_extreme(x, axis_name, op: str):
+    """Merge per-shard MIN/MAX partials across the mesh (op: "min" |
+    "max"). XLA:TPU lowers only SUM all-reduces for 64-bit element
+    types, which it emulates on 32-bit lanes ("Supported lowering only
+    of Sum all reduce"), so 64-bit partials gather to every shard and
+    fold locally; narrower ones take the native collective. One rule
+    on every backend, so the CPU mesh tests run the program the chips
+    run."""
+    if jnp.dtype(x.dtype).itemsize < 8:
+        return (jax.lax.pmin if op == "min"
+                else jax.lax.pmax)(x, axis_name)
+    gathered = jax.lax.all_gather(x, axis_name)  # [shards, ...]
+    return (jnp.min if op == "min" else jnp.max)(gathered, axis=0)
+
+
 def group_any(data, group_ids, mask, num_groups: int):
     """Arbitrary per-group representative — ONLY valid when the value
     is constant within each group (the planner's FD-reduced group

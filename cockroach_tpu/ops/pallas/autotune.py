@@ -60,6 +60,11 @@ SECONDS = [0.0]           # wall seconds spent timing candidates
 
 _LOCK = threading.Lock()
 _MEM: dict = {}           # (root, backend) -> (group_tile, block_rows, cap)
+# candidates the backend refused in a sweep: "GTxBRwCAP" -> the
+# compiler's message. A skipped non-default candidate costs nothing,
+# but the text is kept so a refused kernel does not read as "default
+# tile, nothing promoted" (Engine.runtime_status, chip_smoke.py)
+REJECTED: dict[str, str] = {}
 
 
 def register_metrics(metrics) -> None:
@@ -183,12 +188,15 @@ def autotune(backend: str, root: str | None, interpret: bool,
     for gt, br, cap in candidates:
         if br > n:
             continue
+        name = f"{gt}x{br}w{cap}"
         try:
             dt = _time_candidate(gt, br, cap, n, num_groups, interpret)
-        except Exception:
-            continue  # a candidate the backend rejects is just skipped
+        except Exception as e:  # the backend's compiler refused it
+            with _LOCK:
+                REJECTED[name] = f"{type(e).__name__}: {e}"
+            continue
         RUNS.bump("candidate")
-        timings[f"{gt}x{br}w{cap}"] = dt
+        timings[name] = dt
         if dt < best_t:
             best, best_t = (gt, br, cap), dt
     # two sessions autotuning different backends sweep concurrently;
@@ -204,8 +212,8 @@ def autotune(backend: str, root: str | None, interpret: bool,
 def params_for(backend: str, root: str | None, mode: str = "auto",
                interpret: bool = True) -> tuple[int, int, int]:
     """The (group_tile, block_rows, limb_cap) the engine should
-    compile with. Never raises, never blocks beyond the one-time
-    sweep; see module docstring for the mode contract."""
+    compile with. Never blocks beyond the one-time sweep; see module
+    docstring for the mode contract."""
     if mode == "off" or not root:
         if mode != "off":
             TABLE.bump("miss")
@@ -223,10 +231,7 @@ def params_for(backend: str, root: str | None, mode: str = "auto",
         TABLE.bump("hit")
         return entry
     if mode == "on" or (mode == "auto" and not interpret):
-        try:
-            tile = autotune(backend, root, interpret)
-        except Exception:
-            tile = DEFAULT
+        tile = autotune(backend, root, interpret)
         with _LOCK:
             _MEM[key] = tile
         return tile

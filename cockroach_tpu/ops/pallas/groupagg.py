@@ -37,7 +37,6 @@ import threading
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64 as _enable_x64
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -185,10 +184,8 @@ def dense_group_aggregate(gid, sel, values: tuple, masks: tuple,
     GA = (num_groups, len(ops))
     # the engine runs with jax_enable_x64; Mosaic requires i32 index
     # maps and block indices, so trace the kernel in an x64-off scope
-    # (all operands already carry explicit 32-bit dtypes). NB
-    # jax.enable_x64 was removed in 0.4.x; the experimental context
-    # manager takes the same bool.
-    with _enable_x64(False):
+    # (all operands already carry explicit 32-bit dtypes)
+    with jax.enable_x64(False):
         acc, cnt = pl.pallas_call(
             kernel,
             out_shape=(jax.ShapeDtypeStruct(GA, jnp.float32),

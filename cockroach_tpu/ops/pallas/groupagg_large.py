@@ -9,7 +9,9 @@ segment sum into MXU matmuls: for each row block,
     one_hot(gid)[blk, G_tile].T @ values[blk, A]  ->  [G_tile, A]
 
 folds the whole block into a VMEM accumulator tile with no scatters
-anywhere. The grid is sequential on TPU — (group_tiles, row_blocks)
+anywhere (in the kernel the operands are laid out transposed, rows
+along the lanes — see large_group_aggregate). The grid is sequential
+on TPU — (group_tiles, row_blocks)
 with the row-block dimension innermost, so each output tile is
 revisited across consecutive steps (the standard Pallas reduction
 pattern; the accumulator is initialised under `pl.when(i == 0)`).
@@ -47,7 +49,6 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64 as _enable_x64
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -89,7 +90,10 @@ def _kernel(gid_ref, sel_ref, mat_ref, *refs, n_mat_f: int, n_mat: int,
             mm_ops: tuple, want_rep: bool, group_tile: int, blk: int,
             n: int, nf: int, ni: int):
     mm_refs = refs[:len(mm_ops)]
-    acc_f_ref, acc_i_ref = refs[len(mm_ops):]
+    outs = list(refs[len(mm_ops):])
+    acc_f_ref, acc_i_ref = outs[:2]
+    acc_mm_ref = outs[2] if mm_ops else None
+    acc_rep_ref = outs[-1] if want_rep else None
     j = pl.program_id(0)   # group tile (outer)
     i = pl.program_id(1)   # row block (inner: output tile revisited)
     n_mat_i = n_mat - n_mat_f
@@ -97,23 +101,31 @@ def _kernel(gid_ref, sel_ref, mat_ref, *refs, n_mat_f: int, n_mat: int,
     @pl.when(i == 0)
     def _init():
         acc_f_ref[:, :] = jnp.zeros((nf, group_tile), jnp.float32)
+        acc_i_ref[:, :] = jnp.zeros((ni, group_tile), jnp.int32)
         for r, op in enumerate(mm_ops):
             ident = np.float32(np.inf if op == MIN else -np.inf)
-            acc_f_ref[n_mat_f + r:n_mat_f + r + 1, :] = jnp.full(
-                (1, group_tile), ident, jnp.float32)
-        acc_i_ref[:, :] = jnp.zeros((ni, group_tile), jnp.int32)
+            acc_mm_ref[:, r:r + 1] = jnp.full(
+                (group_tile, 1), ident, jnp.float32)
         if want_rep:
-            acc_i_ref[n_mat_i:n_mat_i + 1, :] = jnp.full(
-                (1, group_tile), np.int32(n), jnp.int32)
+            acc_rep_ref[:, :] = jnp.full(
+                (group_tile, 1), np.int32(n), jnp.int32)
 
+    # rows ride the LANE axis: every per-row input is a (1, blk) or
+    # (n_mat, blk) block of a lane-dense array, and the one-hot is
+    # built transposed, [GT, blk] (group ids down the sublanes)
     ids = j * group_tile + jax.lax.broadcasted_iota(
-        jnp.int32, (blk, group_tile), 1)
-    onehot = gid_ref[:, :] == ids  # (blk, 1) == (blk, GT) -> broadcast
+        jnp.int32, (group_tile, blk), 0)
+    onehot = gid_ref[:, :] == ids  # (1, blk) == (GT, blk) -> broadcast
 
     # the whole block's segment partial as ONE [n_mat, GT] MXU matmul
+    # (contracting the row axis of both operands). HIGHEST: the
+    # exactness argument in the module docstring needs the f32
+    # contraction at full precision — a bf16 pass would round any limb
+    # wider than 8 bits.
     part = jax.lax.dot_general(
         mat_ref[:, :], onehot.astype(jnp.float32),
-        (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
     if n_mat_f:
         acc_f_ref[0:n_mat_f, :] += part[0:n_mat_f, :]
     if n_mat_i:
@@ -122,25 +134,23 @@ def _kernel(gid_ref, sel_ref, mat_ref, *refs, n_mat_f: int, n_mat: int,
         # is lossless
         acc_i_ref[0:n_mat_i, :] += part[n_mat_f:n_mat, :].astype(jnp.int32)
 
+    # MIN/MAX and REPMIN reduce along the lanes, so their accumulators
+    # are [GT, 1] columns of outputs laid out [G, slots]
     for r, op in enumerate(mm_ops):
         ident = np.float32(np.inf if op == MIN else -np.inf)
         v = jnp.where(onehot, mm_refs[r][:, :], ident)
         fold = jnp.min if op == MIN else jnp.max
-        red = fold(v, axis=0, keepdims=True)
-        row = n_mat_f + r
-        cur = acc_f_ref[row:row + 1, :]
+        red = fold(v, axis=1, keepdims=True)
         comb = jnp.minimum if op == MIN else jnp.maximum
-        acc_f_ref[row:row + 1, :] = comb(cur, red)
+        acc_mm_ref[:, r:r + 1] = comb(acc_mm_ref[:, r:r + 1], red)
 
     if want_rep:
         sel = sel_ref[:, :] != 0
         rid = i * blk + jax.lax.broadcasted_iota(
-            jnp.int32, (blk, group_tile), 0)
+            jnp.int32, (group_tile, blk), 1)
         rv = jnp.where(jnp.logical_and(onehot, sel), rid, np.int32(n))
-        red = jnp.min(rv, axis=0, keepdims=True)
-        row = n_mat_i
-        acc_i_ref[row:row + 1, :] = jnp.minimum(
-            acc_i_ref[row:row + 1, :], red)
+        acc_rep_ref[:, :] = jnp.minimum(
+            acc_rep_ref[:, :], jnp.min(rv, axis=1, keepdims=True))
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -166,6 +176,12 @@ def large_group_aggregate(gid, sel, mat_values: tuple, mm_values: tuple,
     NF = max(1, n_f + len(mm_ops)) (f sums first, then MIN/MAX rows)
     and NI = max(1, n_i + want_rep) (i sums first, then the rep row:
     min selected row id, n when the group is empty).
+
+    Every operand reaches the kernel lane-dense — per-row vectors as
+    [1, n], the matmul columns stacked [n_mat, n]. A per-row [n, 1]
+    operand is tiled (8, 128) in HBM, 128x its size: at n = 2^23 one
+    such column is 4 GB, and XLA refused TPC-H Q1's 61 of them at SF1
+    ("Used 208.14G of 15.75G hbm").
     """
     n = gid.shape[0]
     BUILDS.bump("large")
@@ -180,8 +196,9 @@ def large_group_aggregate(gid, sel, mat_values: tuple, mm_values: tuple,
     blk = row_block(n, block_rows)
     gtiles = -(-num_groups // group_tile)
     gp = gtiles * group_tile
-    nf = max(1, n_mat_f + len(mm_ops))
-    ni = max(1, n_mat_i + (1 if want_rep else 0))
+    nf = max(1, n_mat_f)
+    ni = max(1, n_mat_i)
+    n_mm = len(mm_ops)
 
     def kernel(gid_ref, sel_ref, mat_ref, *refs):
         _kernel(gid_ref, sel_ref, mat_ref, *refs, n_mat_f=n_mat_f,
@@ -190,29 +207,49 @@ def large_group_aggregate(gid, sel, mat_values: tuple, mm_values: tuple,
 
     # i32 index-map coordinates: under the engine's jax_enable_x64 a
     # literal 0 traces as i64, which Mosaic rejects
-    row1 = pl.BlockSpec((blk, 1), lambda j, i: (i, jnp.int32(0)),
+    row1 = pl.BlockSpec((1, blk), lambda j, i: (jnp.int32(0), i),
                         memory_space=pltpu.VMEM)
-    matspec = pl.BlockSpec((blk, n_mat), lambda j, i: (i, jnp.int32(0)),
+    matspec = pl.BlockSpec((n_mat, blk), lambda j, i: (jnp.int32(0), i),
                            memory_space=pltpu.VMEM)
-    accf_spec = pl.BlockSpec((nf, group_tile),
-                             lambda j, i: (jnp.int32(0), j),
-                             memory_space=pltpu.VMEM)
-    acci_spec = pl.BlockSpec((ni, group_tile),
-                             lambda j, i: (jnp.int32(0), j),
-                             memory_space=pltpu.VMEM)
 
-    args = (gid.astype(jnp.int32).reshape(n, 1),
-            sel.astype(jnp.int8).reshape(n, 1),
-            jnp.stack([v.astype(jnp.float32) for v in mat_values], axis=1),
-            *[v.astype(jnp.float32).reshape(n, 1) for v in mm_values])
-    with _enable_x64(False):
-        acc_f, acc_i = pl.pallas_call(
+    def by_group(rows):   # [rows, G] sums: group ids along the lanes
+        return pl.BlockSpec((rows, group_tile),
+                            lambda j, i: (jnp.int32(0), j),
+                            memory_space=pltpu.VMEM)
+
+    def by_slot(slots):   # [G, slots] lane-reduced MIN/MAX/REPMIN
+        return pl.BlockSpec((group_tile, slots),
+                            lambda j, i: (j, jnp.int32(0)),
+                            memory_space=pltpu.VMEM)
+
+    out_shape = [jax.ShapeDtypeStruct((nf, gp), jnp.float32),
+                 jax.ShapeDtypeStruct((ni, gp), jnp.int32)]
+    out_specs = [by_group(nf), by_group(ni)]
+    if n_mm:
+        out_shape.append(jax.ShapeDtypeStruct((gp, n_mm), jnp.float32))
+        out_specs.append(by_slot(n_mm))
+    if want_rep:
+        out_shape.append(jax.ShapeDtypeStruct((gp, 1), jnp.int32))
+        out_specs.append(by_slot(1))
+
+    args = (gid.astype(jnp.int32).reshape(1, n),
+            sel.astype(jnp.int32).reshape(1, n),
+            jnp.stack([v.astype(jnp.float32) for v in mat_values], axis=0),
+            *[v.astype(jnp.float32).reshape(1, n) for v in mm_values])
+    with jax.enable_x64(False):
+        outs = pl.pallas_call(
             kernel,
-            out_shape=(jax.ShapeDtypeStruct((nf, gp), jnp.float32),
-                       jax.ShapeDtypeStruct((ni, gp), jnp.int32)),
+            out_shape=tuple(out_shape),
             grid=(gtiles, n // blk),
-            in_specs=[row1, row1, matspec] + [row1] * len(mm_values),
-            out_specs=(accf_spec, acci_spec),
+            in_specs=[row1, row1, matspec] + [row1] * n_mm,
+            out_specs=tuple(out_specs),
             interpret=interpret,
         )(*args)
-    return acc_f[:, :num_groups], acc_i[:, :num_groups]
+    acc_f, acc_i = outs[0][:, :num_groups], outs[1][:, :num_groups]
+    if n_mm:
+        mm = outs[2][:num_groups, :].T
+        acc_f = jnp.concatenate([acc_f[:n_mat_f], mm], axis=0)
+    if want_rep:
+        rep = outs[-1][:num_groups, :].T
+        acc_i = jnp.concatenate([acc_i[:n_mat_i], rep], axis=0)
+    return acc_f, acc_i

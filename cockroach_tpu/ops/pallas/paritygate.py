@@ -54,6 +54,11 @@ SECONDS = [0.0]           # wall seconds spent fuzzing
 
 _LOCK = threading.Lock()
 _MEM: dict = {}           # (root, backend) -> tuple of exact paths
+# paths whose fuzz RAISED (as opposed to returning "inexact"): path ->
+# the exception text. The path stays unpromoted either way; the text
+# tells a kernel the backend refused from one that rounds
+# (Engine.runtime_status, chip_smoke.py)
+ERRORS: dict[str, str] = {}
 
 
 def register_metrics(metrics) -> None:
@@ -191,7 +196,9 @@ def fuzz(backend: str, root: str | None,
     for path in PATHS:
         try:
             ok = _FUZZERS[path](interpret)
-        except Exception:
+        except Exception as e:
+            with _LOCK:
+                ERRORS[path] = f"{type(e).__name__}: {e}"
             ok = False
         CHECKS.bump("exact" if ok else "approx")
         if ok:
@@ -209,8 +216,8 @@ def fuzz(backend: str, root: str | None,
 def promoted(backend: str, root: str | None,
              interpret: bool) -> tuple[str, ...]:
     """The kernel paths `auto` may route through on this backend —
-    persisted verdicts, or one fuzz sweep on first use. Never raises;
-    with no persistence root the sweep still runs (cached in-process)
+    persisted verdicts, or one fuzz sweep on first use. With no
+    persistence root the sweep still runs (cached in-process)
     so a cacheless engine gets the same routing, just re-measured per
     process."""
     key = (root, backend)
@@ -230,10 +237,7 @@ def promoted(backend: str, root: str | None,
             TABLE.bump("hit")
             return out
     TABLE.bump("miss")
-    try:
-        out = fuzz(backend, root, interpret)
-    except Exception:
-        out = ()
+    out = fuzz(backend, root, interpret)
     with _LOCK:
         _MEM[key] = out
     return out

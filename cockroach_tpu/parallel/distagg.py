@@ -35,14 +35,7 @@ import threading
 from dataclasses import dataclass
 
 import jax
-
-try:                                     # jax >= 0.5 exports it top-level
-    from jax import shard_map
-    _SM_CHECK_KW = "check_vma"
-except ImportError:                      # older jax: experimental path,
-    # where the replication-check kwarg is still called check_rep
-    from jax.experimental.shard_map import shard_map
-    _SM_CHECK_KW = "check_rep"
+from jax import shard_map
 
 from ..sql import plan as P
 from . import mesh as meshmod
@@ -484,6 +477,6 @@ def make_distributed_fn(runf, mesh, scan_aliases: dict, decision: DistDecision):
                     tuple(repl_leaf for _ in lits))
         return shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=repl_leaf,
-                         **{_SM_CHECK_KW: False})(scans, read_ts,
-                                                  nparts, pid, lits)
+                         check_vma=False)(scans, read_ts, nparts,
+                                          pid, lits)
     return wrapped

@@ -194,8 +194,8 @@ def shutdown_distributed() -> None:
                 pass
             _ELASTIC_SERVER = None
         if _INITIALIZED_JAX:
+            import jax
             try:
-                import jax
                 jax.distributed.shutdown()
             except Exception:
                 pass

@@ -370,6 +370,12 @@ class Node:
                         "peers": peers,
                     }).encode()
                     ctype = "application/json"
+                elif path == "/_status/runtime":
+                    # device, compile cache and native plane this
+                    # node is on, with what it skipped and why
+                    body = json.dumps(
+                        node.engine.runtime_status()).encode()
+                    ctype = "application/json"
                 elif path == "/_status/membership":
                     # elastic-pod membership + shard leases as this
                     # host sees them (epoch'd view, suspects,

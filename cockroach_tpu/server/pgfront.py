@@ -207,6 +207,10 @@ class ReactorServer:
         return self
 
     def stop(self):
+        if self._stopping:
+            # a second os.close of the wake pipe would hit whatever
+            # descriptor reused those numbers
+            return
         self._stopping = True
         self._wakeup()
         if self._thread:

@@ -53,8 +53,8 @@ class DeviceStats:
         accounting stands in)."""
         if self._mem_stats_ok is False:
             return None
+        import jax
         try:
-            import jax
             total = 0
             seen = False
             for d in jax.devices():

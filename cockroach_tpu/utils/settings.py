@@ -91,6 +91,13 @@ def _pow2(v):
         raise SettingError("must be a power of two")
 
 
+def _cache_dir_switch(v):
+    if v.lower() not in ("", "off"):
+        raise SettingError(
+            "must be '' or 'off'; place the cache directory with "
+            "JAX_COMPILATION_CACHE_DIR")
+
+
 def _submesh_size(v):
     if v in ("auto", "off"):
         return
@@ -135,11 +142,11 @@ def _register_builtins(s: Settings):
     # cold-start elimination (exec/coldstart.py): persistent XLA
     # compile cache + shape bucket ladder + Pallas tile autotune
     s.register("sql.exec.compile_cache.dir", "", str,
-               "root of the persistent XLA compile cache ('' = "
-               "$COCKROACH_TPU_COMPILE_CACHE_DIR or "
-               "~/.cache/cockroach_tpu; 'off' disables). Artifacts "
-               "live in a per-backend/per-jax-version subdir, so "
-               "upgrades invalidate by path, never by flush")
+               "'off' disables the persistent XLA compile cache; '' "
+               "(default) uses $JAX_COMPILATION_CACHE_DIR, else "
+               "<checkout>/.jax_cache. The directory itself is placed "
+               "only through that environment variable",
+               _cache_dir_switch)
     s.register("sql.exec.compile_cache.prewarm", 0, int,
                "top-K statement texts from the previous run's shapes "
                "journal that Engine.prewarm() re-prepares at startup "

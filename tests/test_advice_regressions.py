@@ -339,7 +339,7 @@ class TestHiddenSortKeyOrderability:
 
 class TestDatumCompareBindError:
     """ADVICE low: WHERE a = 'not-an-array' must surface a BindError
-    (the engine's SQL error taxonomy), not a raw DatumError."""
+    (the engine's SQL error classes), not a raw DatumError."""
 
     def test_invalid_array_text_is_bind_error(self, eng):
         from cockroach_tpu.sql.binder import BindError

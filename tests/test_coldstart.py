@@ -70,22 +70,60 @@ class TestShapeLadder:
 # ------------------------------------------------------- cache plumbing
 
 class TestCompileCachePlumbing:
-    def test_cache_dir_routed_under_test_tmpdir(self):
-        eng = Engine()
-        root = os.environ["COCKROACH_TPU_COMPILE_CACHE_DIR"]
-        assert eng._compile_cache_dir is not None
-        assert eng._compile_cache_dir.startswith(root)
-        # per-backend / per-version isolation is the invalidation story
+    def test_cache_placed_by_env_is_left_alone(self):
         import jax
-        assert jax.default_backend() in \
-            os.path.basename(eng._compile_cache_dir)
+        placed = os.environ["JAX_COMPILATION_CACHE_DIR"]
+        eng = Engine()
+        # exactly the directory the launcher named: no subdirectory,
+        # and jax's own setting untouched by the engine
+        assert eng._compile_cache_dir == placed
+        assert jax.config.jax_compilation_cache_dir == placed
+        assert coldstart.cache_error() is None
+
+    def test_env_set_after_import_is_refused(self, tmp_path,
+                                             monkeypatch):
+        # jax read the variable at import; an engine that re-pointed
+        # jax at a late value would be setting the directory in code
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / "late"))
+        with pytest.raises(RuntimeError, match="after jax was imported"):
+            Engine()
+
+    def test_unset_env_uses_checkout_dir(self):
+        default = coldstart.checkout_cache_dir()
+        assert default == str(REPO / ".jax_cache")
+        existed = os.path.exists(default)
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        code = ("import jax\n"
+                "from cockroach_tpu.exec.engine import Engine\n"
+                "eng = Engine()\n"
+                "assert eng._compile_cache_dir == "
+                "jax.config.jax_compilation_cache_dir\n"
+                "print(eng._compile_cache_dir)\n")
+        try:
+            out = subprocess.run(
+                [sys.executable, "-c", code], env=env, cwd=str(REPO),
+                capture_output=True, text=True, timeout=300)
+            assert out.returncode == 0, out.stderr[-2000:]
+            assert out.stdout.strip().splitlines()[-1] == default
+        finally:
+            if not existed:
+                import shutil
+                shutil.rmtree(default, ignore_errors=True)
+
+    def test_setting_keeps_only_off(self):
+        from cockroach_tpu.utils.settings import SettingError
+        eng = Engine()
+        with pytest.raises(SettingError):
+            eng.settings.set("sql.exec.compile_cache.dir", "/some/dir")
+        eng.settings.set("sql.exec.compile_cache.dir", "off")
+        assert coldstart.init_compile_cache(eng.settings) is None
 
     def test_compile_metrics_move_on_first_compile(
-            self, tmp_path, monkeypatch):
+            self, private_compile_cache):
         # needs a genuinely cold cache (the suite-shared dir may
         # already hold this statement's programs)
-        monkeypatch.setenv("COCKROACH_TPU_COMPILE_CACHE_DIR",
-                           str(tmp_path / "cold"))
         eng = Engine()
         eng.execute("CREATE TABLE cm (v INT)")
         eng.execute("INSERT INTO cm VALUES (1), (2), (3)")
@@ -145,11 +183,9 @@ class TestCompileCachePlumbing:
                      "mean_exec_s"):
             assert isinstance(getattr(s, attr), float)
 
-    def test_journal_and_prewarm(self, tmp_path, monkeypatch):
+    def test_journal_and_prewarm(self, private_compile_cache):
         # private cache: the suite-shared journal holds other tests'
         # statements, which would crowd out this one's top-k slot
-        monkeypatch.setenv("COCKROACH_TPU_COMPILE_CACHE_DIR",
-                           str(tmp_path / "jw"))
         eng = Engine()
         eng.execute("CREATE TABLE jw (k INT, v INT)")
         eng.execute("INSERT INTO jw VALUES (1, 10), (2, 20), (3, 30)")
@@ -170,13 +206,11 @@ class TestCompileCachePlumbing:
         eng = Engine()
         assert eng.prewarm() == 0  # setting defaults to 0
 
-    def test_journal_replays_session_vars(self, tmp_path, monkeypatch):
+    def test_journal_replays_session_vars(self, private_compile_cache):
         # a statement that compiled under non-default plan-key vars
         # journals them; prewarm re-prepares under the SAME vars, so
         # the session that set them gets a plan-cache hit after the
         # simulated restart instead of a recompile at defaults
-        monkeypatch.setenv("COCKROACH_TPU_COMPILE_CACHE_DIR",
-                           str(tmp_path / "jv"))
         eng = Engine()
         eng.execute("CREATE TABLE jv (k INT, v INT)")
         eng.execute("INSERT INTO jv VALUES (1, 10), (2, 20), (3, 30)")
@@ -413,7 +447,6 @@ import json, os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("JAX_ENABLE_X64", "1")
 import jax
-jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_x64", True)
 from cockroach_tpu.exec.engine import Engine
 
@@ -441,7 +474,7 @@ class TestCrossProcessWarmStart:
         script = tmp_path / "child.py"
         script.write_text(_CHILD)
         env = dict(os.environ)
-        env["COCKROACH_TPU_COMPILE_CACHE_DIR"] = cache
+        env["JAX_COMPILATION_CACHE_DIR"] = cache
         env["PYTHONPATH"] = str(REPO)
         env.pop("XLA_FLAGS", None)  # single device is enough
 
@@ -454,7 +487,7 @@ class TestCrossProcessWarmStart:
 
         cold = run()
         warm = run()
-        assert cold["dir"].startswith(cache)
+        assert cold["dir"] == cache
         assert cold["miss"] > 0, "cold process must compile"
         assert warm["hit"] > 0, \
             "warm process must deserialize from the persistent cache"

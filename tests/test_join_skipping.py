@@ -508,12 +508,10 @@ class TestPrewarmStreamed:
         # back-compat: journal_top still returns bare texts
         assert "SELECT 1" in coldstart.journal_top(d, 10)
 
-    def test_prewarm_compiles_streamed_join(self, tmp_path, monkeypatch):
+    def test_prewarm_compiles_streamed_join(self, private_compile_cache):
         """A streamed join lands in the shapes journal with its page
         bucket; a fresh prewarm must re-prepare it and exercise the
         page/combine/final executables without touching results."""
-        monkeypatch.setenv("COCKROACH_TPU_COMPILE_CACHE_DIR",
-                           str(tmp_path / "pw"))
         eng = _fact_engine()
         want = eng.execute(JOIN_Q, _jsession(eng)).rows
         eng._exec_cache.clear()
@@ -523,9 +521,7 @@ class TestPrewarmStreamed:
         assert got == want
 
     @pytest.mark.slow
-    def test_prewarm_compiles_spill_join(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("COCKROACH_TPU_COMPILE_CACHE_DIR",
-                           str(tmp_path / "pw"))
+    def test_prewarm_compiles_spill_join(self, private_compile_cache):
         eng = _fact_engine()
         want = eng.execute(JOIN_Q, _jsession(eng, spill="on")).rows
         eng._exec_cache.clear()

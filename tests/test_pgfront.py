@@ -276,9 +276,13 @@ def test_idle_session_soak_flat_memory_and_threads(node):
         threads_at_1000 = threading.active_count()
         rss_at_1000 = _rss_kb()
         assert len(impl._sessions) >= base_sessions + 1000
-        # zero threads per parked session: the pool is saturated by
-        # 200 startups, so 800 MORE sessions add no thread at all
-        assert threads_at_1000 <= threads_at_200 + 2, (
+        # zero threads per parked session: workers belong to the
+        # executor pool, which spawns one only when a startup finds
+        # none idle — under a loaded CPU it may still be growing
+        # after 200 startups, but never past its fixed cap, however
+        # many sessions park
+        assert threads_at_1000 - threads_at_200 <= \
+            impl._pool._max_workers, (
             f"threads grew {threads_at_200} -> {threads_at_1000} "
             f"over 800 idle sessions")
         # O(1) memory per parked session (a _Session + a _Conn + an

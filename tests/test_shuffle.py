@@ -7,14 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from cockroach_tpu.parallel.distagg import _SM_CHECK_KW
-from cockroach_tpu.parallel.distagg import shard_map as _sm
 from cockroach_tpu.parallel import shuffle
-
-
-def shard_map(*a, **kw):        # version shim (parallel/distagg.py)
-    kw[_SM_CHECK_KW] = kw.pop("check_vma", False)
-    return _sm(*a, **kw)
 from cockroach_tpu.parallel.mesh import (SHARD_AXIS, make_mesh,
                                          replicated_spec, shard_spec)
 
@@ -38,8 +31,8 @@ def _run_exchange(mesh, keys, vals, valid, cap):
                 jnp.asarray(ovf)[None])
 
     sh = shard_spec()
-    f = shard_map(body, mesh=mesh, in_specs=(sh, sh, sh),
-                  out_specs=(sh, sh, sh, sh), check_vma=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=(sh, sh, sh),
+                      out_specs=(sh, sh, sh, sh), check_vma=False)
     return f(keys, vals, valid)
 
 
@@ -142,8 +135,8 @@ class TestShardedShardedJoin:
                     jnp.asarray(jnp.logical_or(o1, o2))[None])
 
         sh = shard_spec()
-        f = shard_map(body, mesh=mesh, in_specs=(sh, sh, sh, sh),
-                      out_specs=(sh, sh, sh), check_vma=False)
+        f = jax.shard_map(body, mesh=mesh, in_specs=(sh, sh, sh, sh),
+                          out_specs=(sh, sh, sh), check_vma=False)
         tot, cnt, ovf = f(jnp.asarray(lk.reshape(D, -1)),
                           jnp.asarray(lv.reshape(D, -1)),
                           jnp.asarray(rk.reshape(D, -1)),
