@@ -205,6 +205,14 @@ def init_compile_cache(settings=None) -> str | None:
     # programs aren't worth the disk
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # an op's metadata is read now: a device profile folds time by the
+    # plan-operator scope on each op's path (exec/compile.compile_plan).
+    # JAX's key leaves metadata out by default, and an executable
+    # cached by a tree with other scope names (or none) would be
+    # served with that tree's names on its ops. The price: an edit
+    # that moves the lines of a traced closure compiles that plan anew.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      True)
     _install_listeners()
     with _LOCK:
         _ERROR = None

@@ -18,6 +18,7 @@ caches the jitted callable keyed by plan fingerprint + input shapes
 
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass
 from typing import Callable
 
@@ -158,9 +159,35 @@ def _batch_nbytes(b: ColumnBatch) -> int:
         return 0
 
 
+# where compile_plan stands in the plan it is compiling, per thread: a
+# node's ordinal is its pre-order position (the root is 0), never a
+# process-wide count, so the same plan names its scopes alike on
+# every prepare and parameter sets of one shape share one program
+_position = threading.local()
+
+
 def compile_plan(node: P.PlanNode, params: ExecParams,
                  meta: P.OutputMeta | None = None) -> CompiledNode:
-    fn = _compile_plan(node, params, meta)
+    """Compile `node` (and, through the _compile_* helpers, its
+    children). The node's closure runs under
+    jax.named_scope("<kind>.<ordinal>"), so every HLO op it traces
+    carries its plan operator in its metadata (what a device profile
+    folds time by); scopes nest as the closures do, the innermost one
+    owning an op. Metadata only: nothing runs for it on the device."""
+    depth = getattr(_position, "depth", 0)
+    if depth == 0:
+        _position.next = 0
+    scope = f"{type(node).__name__.lower()}.{_position.next}"
+    _position.next += 1
+    _position.depth = depth + 1
+    try:
+        inner = _compile_plan(node, params, meta)
+    finally:
+        _position.depth = depth
+
+    def fn(rc):
+        with jax.named_scope(scope):
+            return inner(rc)
     hook = params.row_hook
     if hook is None and params.profile is None:
         return fn
@@ -400,7 +427,7 @@ def _agg_partials(a: BoundAgg, argf, batch, ctx, gid, num_groups,
     grouped = gid is not None
 
     def psum(x):
-        return jax.lax.psum(x, axis_name) if axis_name else x
+        return aggops.shard_sum(x, axis_name) if axis_name else x
 
     def pmin(x):
         return (aggops.shard_extreme(x, axis_name, "min")
@@ -578,17 +605,18 @@ def _pallas_dense_partials(slots, aggfs, b, ctx, gid, num_groups: int,
         num_groups=num_groups, ops=tuple(ops), interpret=interpret)
     if axis_name:
         # cross-shard merge, column-by-column with the op's collective
-        cnt = jax.lax.psum(cnt, axis_name)
-        cols = []
-        for j, op in enumerate(ops):
-            c = acc[:, j]
-            if op == pg.MIN:
-                cols.append(jax.lax.pmin(c, axis_name))
-            elif op == pg.MAX:
-                cols.append(jax.lax.pmax(c, axis_name))
-            else:
-                cols.append(jax.lax.psum(c, axis_name))
-        acc = jnp.stack(cols, axis=1)
+        with jax.named_scope("shard_merge"):
+            cnt = jax.lax.psum(cnt, axis_name)
+            cols = []
+            for j, op in enumerate(ops):
+                c = acc[:, j]
+                if op == pg.MIN:
+                    cols.append(jax.lax.pmin(c, axis_name))
+                elif op == pg.MAX:
+                    cols.append(jax.lax.pmax(c, axis_name))
+                else:
+                    cols.append(jax.lax.psum(c, axis_name))
+            acc = jnp.stack(cols, axis=1)
     col_of = {(i, role): j for j, (op, i, role) in enumerate(slots)}
     aggs_out = []
     for i, (a, argf) in enumerate(aggfs):
@@ -698,195 +726,201 @@ def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
     from ..ops.pallas import paritygate as _pgate
     n = b.n
     sel = b.sel
-    argdata = {i: argf(ctx) for i, (a, argf) in enumerate(aggfs)
-               if argf is not None}
-    for i, (a, _) in enumerate(aggfs):
-        if a.func in ("sum", "sum_int", "avg", "min", "max") \
-                and a.arg is not None \
-                and a.arg.type.family in (Family.INT, Family.DECIMAL):
-            # the static check ran on SQL types; re-check the traced
-            # dtype (a cast upstream could hand us floats) — limb
-            # sums and the MIN/MAX hi-limb both need real ints
-            if argdata[i][0].dtype not in (jnp.int64, jnp.int32):
-                return None
-    f_cols, f_tags = [], []     # f32-accumulated matmul columns
-    i_cols, i_tags = [], []     # i32-accumulated (limb/count) columns
-    mm_cols, mm_ops_l, mm_tags = [], [], []
-    want_rep = False
-    exact = {}  # agg index -> (limb width w, limb count k)
-    for i, (a, _) in enumerate(aggfs):
-        if a.func == "count_rows":
-            i_cols.append(sel.astype(jnp.float32))
+    # the kernel's operands: arguments evaluated, sums split into limbs
+    with jax.named_scope("operands"):
+        argdata = {i: argf(ctx) for i, (a, argf) in enumerate(aggfs)
+                   if argf is not None}
+        for i, (a, _) in enumerate(aggfs):
+            if a.func in ("sum", "sum_int", "avg", "min", "max") \
+                    and a.arg is not None \
+                    and a.arg.type.family in (Family.INT, Family.DECIMAL):
+                # the static check ran on SQL types; re-check the traced
+                # dtype (a cast upstream could hand us floats) — limb
+                # sums and the MIN/MAX hi-limb both need real ints
+                if argdata[i][0].dtype not in (jnp.int64, jnp.int32):
+                    return None
+        f_cols, f_tags = [], []     # f32-accumulated matmul columns
+        i_cols, i_tags = [], []     # i32-accumulated (limb/count) columns
+        mm_cols, mm_ops_l, mm_tags = [], [], []
+        want_rep = False
+        exact = {}  # agg index -> (limb width w, limb count k)
+        for i, (a, _) in enumerate(aggfs):
+            if a.func == "count_rows":
+                i_cols.append(sel.astype(jnp.float32))
+                i_tags.append(("cnt", i))
+                continue
+            if a.func == "any":
+                want_rep = True  # rides the REPMIN slot + a host gather
+                continue
+            d0, v0 = argdata[i]
+            m = jnp.logical_and(sel, v0)
+            i_cols.append(m.astype(jnp.float32))  # validity + avg divisor
             i_tags.append(("cnt", i))
-            continue
-        if a.func == "any":
-            want_rep = True  # rides the REPMIN slot + a host gather
-            continue
-        d0, v0 = argdata[i]
-        m = jnp.logical_and(sel, v0)
-        i_cols.append(m.astype(jnp.float32))  # validity + avg divisor
-        i_tags.append(("cnt", i))
-        if a.func == "count":
-            continue
-        if a.func in ("min", "max"):
-            ident = np.float32(np.inf if a.func == "min" else -np.inf)
-            if a.arg.type.family in (Family.INT, Family.DECIMAL):
-                # exact ordered-int path (paritygate "int_minmax"):
-                # the kernel reduces the ARITHMETIC high limb — order-
-                # preserving, |limb| <= 2^23 so f32-exact — and the
-                # full-width winner is refined on XLA in the output
-                # loop below over just the rows holding that limb
-                hi = jnp.right_shift(d0.astype(jnp.int64),
-                                     jnp.int64(_pgate.MM_HI_SHIFT))
-                mm_cols.append(
-                    jnp.where(m, hi.astype(jnp.float32), ident))
-            else:
-                mm_cols.append(
-                    jnp.where(m, d0.astype(jnp.float32), ident))
-            mm_ops_l.append(pg.MIN if a.func == "min" else pg.MAX)
-            mm_tags.append(("mm", i))
-            continue
-        if a.arg.type.family == Family.FLOAT:
-            f_cols.append(jnp.where(m, d0, 0).astype(jnp.float32))
-            f_tags.append(("fsum", i))
-            continue
-        # exact int64 sum as w-bit i32 limbs, split OUTSIDE the
-        # kernel (no 64-bit lanes in Mosaic) and recombined below —
-        # the same decomposition as agg._group_sum_i64_limbs. The
-        # width tracks the plan's (possibly autotuned) block_rows so
-        # the f32 block-partial exactness bound holds at that block
-        w = pgl.limb_width(n, max_group_rows,
-                           block_rows=params.pallas_block_rows,
-                           cap=params.pallas_limb_cap)
-        bits = 64
-        if a.arg_nonneg and a.arg_max_abs:
-            bits = max(1, int(a.arg_max_abs).bit_length())
-        k = -(-bits // w)
-        exact[i] = (w, k)
-        d64 = d0.astype(jnp.int64)
-        dz = jnp.where(m, d64, jnp.zeros_like(d64))
-        lmask = jnp.int64((1 << w) - 1)
-        for jl in range(k):
-            limb = jax.lax.shift_right_logical(
-                dz, jnp.int64(jl * w)) & lmask
-            i_cols.append(limb.astype(jnp.int32).astype(jnp.float32))
-            i_tags.append(("limb", i, jl))
-        # f32 shadow sum feeds the overflow sentinel
-        f_cols.append(jnp.where(m, d64, 0).astype(jnp.float32))
-        f_tags.append(("shadow", i))
-    i_cols.append(sel.astype(jnp.float32))  # group liveness
-    i_tags.append(("live",))
+            if a.func == "count":
+                continue
+            if a.func in ("min", "max"):
+                ident = np.float32(np.inf if a.func == "min" else -np.inf)
+                if a.arg.type.family in (Family.INT, Family.DECIMAL):
+                    # exact ordered-int path (paritygate "int_minmax"):
+                    # the kernel reduces the ARITHMETIC high limb — order-
+                    # preserving, |limb| <= 2^23 so f32-exact — and the
+                    # full-width winner is refined on XLA in the output
+                    # loop below over just the rows holding that limb
+                    hi = jnp.right_shift(d0.astype(jnp.int64),
+                                         jnp.int64(_pgate.MM_HI_SHIFT))
+                    mm_cols.append(
+                        jnp.where(m, hi.astype(jnp.float32), ident))
+                else:
+                    mm_cols.append(
+                        jnp.where(m, d0.astype(jnp.float32), ident))
+                mm_ops_l.append(pg.MIN if a.func == "min" else pg.MAX)
+                mm_tags.append(("mm", i))
+                continue
+            if a.arg.type.family == Family.FLOAT:
+                f_cols.append(jnp.where(m, d0, 0).astype(jnp.float32))
+                f_tags.append(("fsum", i))
+                continue
+            # exact int64 sum as w-bit i32 limbs, split OUTSIDE the
+            # kernel (no 64-bit lanes in Mosaic) and recombined below —
+            # the same decomposition as agg._group_sum_i64_limbs. The
+            # width tracks the plan's (possibly autotuned) block_rows so
+            # the f32 block-partial exactness bound holds at that block
+            w = pgl.limb_width(n, max_group_rows,
+                               block_rows=params.pallas_block_rows,
+                               cap=params.pallas_limb_cap)
+            bits = 64
+            if a.arg_nonneg and a.arg_max_abs:
+                bits = max(1, int(a.arg_max_abs).bit_length())
+            k = -(-bits // w)
+            exact[i] = (w, k)
+            d64 = d0.astype(jnp.int64)
+            dz = jnp.where(m, d64, jnp.zeros_like(d64))
+            lmask = jnp.int64((1 << w) - 1)
+            for jl in range(k):
+                limb = jax.lax.shift_right_logical(
+                    dz, jnp.int64(jl * w)) & lmask
+                i_cols.append(limb.astype(jnp.int32).astype(jnp.float32))
+                i_tags.append(("limb", i, jl))
+            # f32 shadow sum feeds the overflow sentinel
+            f_cols.append(jnp.where(m, d64, 0).astype(jnp.float32))
+            f_tags.append(("shadow", i))
+        i_cols.append(sel.astype(jnp.float32))  # group liveness
+        i_tags.append(("live",))
 
     mat = tuple(f_cols) + tuple(i_cols)
     mat_int = (False,) * len(f_cols) + (True,) * len(i_cols)
-    acc_f, acc_i = pgl.large_group_aggregate(
-        gid, sel, mat, tuple(mm_cols), num_groups=num_groups,
-        mat_int=mat_int, mm_ops=tuple(mm_ops_l), want_rep=want_rep,
-        group_tile=params.pallas_group_tile,
-        block_rows=params.pallas_block_rows,
-        interpret=params.pallas_interpret)
+    with jax.named_scope("kernel"):
+        acc_f, acc_i = pgl.large_group_aggregate(
+            gid, sel, mat, tuple(mm_cols), num_groups=num_groups,
+            mat_int=mat_int, mm_ops=tuple(mm_ops_l), want_rep=want_rep,
+            group_tile=params.pallas_group_tile,
+            block_rows=params.pallas_block_rows,
+            interpret=params.pallas_interpret)
 
     def ps(x):
-        return jax.lax.psum(x, axis_name) if axis_name else x
+        return aggops.shard_sum(x, axis_name) if axis_name else x
 
-    frow = {t: r for r, t in enumerate(f_tags)}
-    irow = {t: r for r, t in enumerate(i_tags)}
-    mmrow = {t: len(f_cols) + r for r, t in enumerate(mm_tags)}
-    live = ps(acc_i[irow[("live",)], :]) > 0
-    rep = rep_live = None
-    if want_rep:
-        racc = acc_i[len(i_cols), :]  # REPMIN row (n = empty group)
-        rep_live = racc < n           # shard-LOCAL: rep ids are local
-        rep = jnp.minimum(racc, n - 1)
+    # after the kernel: limbs recombined, shards merged, sentinels
+    with jax.named_scope("finalize"):
+        frow = {t: r for r, t in enumerate(f_tags)}
+        irow = {t: r for r, t in enumerate(i_tags)}
+        mmrow = {t: len(f_cols) + r for r, t in enumerate(mm_tags)}
+        live = ps(acc_i[irow[("live",)], :]) > 0
+        rep = rep_live = None
+        if want_rep:
+            racc = acc_i[len(i_cols), :]  # REPMIN row (n = empty group)
+            rep_live = racc < n           # shard-LOCAL: rep ids are local
+            rep = jnp.minimum(racc, n - 1)
 
-    overflow = jnp.bool_(False)
-    aggs_out = []
-    for i, (a, _) in enumerate(aggfs):
-        if a.func in ("count_rows", "count"):
-            d = ps(acc_i[irow[("cnt", i)], :]).astype(jnp.int64)
-            aggs_out.append((d, jnp.ones_like(d, dtype=jnp.bool_)))
-            continue
-        if a.func == "any":
-            d0, v0 = argdata[i]
-            d, v = aggops.group_any_via_rep(d0, v0, rep, rep_live)
-            if axis_name:
-                # shards that saw the group agree on the value (FD);
-                # empty shards contribute the max-identity (the
-                # smallest value), so pmax picks any real one
-                d = aggops.shard_extreme(
-                    jnp.where(v, d, aggops._maxident(d.dtype)),
-                    axis_name, "max")
-                v = jax.lax.psum(v.astype(jnp.int32), axis_name) > 0
-            aggs_out.append((d, v))
-            continue
-        cnt = ps(acc_i[irow[("cnt", i)], :])
-        nonempty = cnt > 0
-        if a.func in ("min", "max"):
-            d = acc_f[mmrow[("mm", i)], :]
-            if axis_name:
-                d = aggops.shard_extreme(d, axis_name, a.func)
-            if a.arg.type.family in (Family.INT, Family.DECIMAL):
-                # refine the (globally merged) winning hi limb to the
-                # full-width value with the dtype-preserving XLA fold
-                # over only the rows that hold it — every survivor is
-                # an actual input value, so the result is bit-equal to
-                # the pure-XLA path (shards without the winning limb
-                # refine an empty mask, whose fold identity loses the
-                # second pmin/pmax just like an empty-shard group)
-                d0, v0 = argdata[i]
-                m = jnp.logical_and(sel, v0)
-                rowhi = jnp.right_shift(d0.astype(jnp.int64),
-                                        jnp.int64(_pgate.MM_HI_SHIFT))
-                refine = jnp.logical_and(
-                    m, rowhi == d.astype(jnp.int64)[gid])
-                fold = aggops.group_min if a.func == "min" \
-                    else aggops.group_max
-                dref = fold(d0, gid, refine, num_groups)
-                if axis_name:
-                    dref = aggops.shard_extreme(dref, axis_name,
-                                                a.func)
-                aggs_out.append((dref, nonempty))
+        overflow = jnp.bool_(False)
+        aggs_out = []
+        for i, (a, _) in enumerate(aggfs):
+            if a.func in ("count_rows", "count"):
+                d = ps(acc_i[irow[("cnt", i)], :]).astype(jnp.int64)
+                aggs_out.append((d, jnp.ones_like(d, dtype=jnp.bool_)))
                 continue
-            aggs_out.append((d.astype(jnp.float64), nonempty))
-            continue
-        if i not in exact:  # float sum/avg ("on" or promoted)
-            d = ps(acc_f[frow[("fsum", i)], :]).astype(jnp.float64)
+            if a.func == "any":
+                d0, v0 = argdata[i]
+                d, v = aggops.group_any_via_rep(d0, v0, rep, rep_live)
+                if axis_name:
+                    # shards that saw the group agree on the value (FD);
+                    # empty shards contribute the max-identity (the
+                    # smallest value), so pmax picks any real one
+                    d = aggops.shard_extreme(
+                        jnp.where(v, d, aggops._maxident(d.dtype)),
+                        axis_name, "max")
+                    v = aggops.shard_sum(v.astype(jnp.int32),
+                                         axis_name) > 0
+                aggs_out.append((d, v))
+                continue
+            cnt = ps(acc_i[irow[("cnt", i)], :])
+            nonempty = cnt > 0
+            if a.func in ("min", "max"):
+                d = acc_f[mmrow[("mm", i)], :]
+                if axis_name:
+                    d = aggops.shard_extreme(d, axis_name, a.func)
+                if a.arg.type.family in (Family.INT, Family.DECIMAL):
+                    # refine the (globally merged) winning hi limb to the
+                    # full-width value with the dtype-preserving XLA fold
+                    # over only the rows that hold it — every survivor is
+                    # an actual input value, so the result is bit-equal to
+                    # the pure-XLA path (shards without the winning limb
+                    # refine an empty mask, whose fold identity loses the
+                    # second pmin/pmax just like an empty-shard group)
+                    d0, v0 = argdata[i]
+                    m = jnp.logical_and(sel, v0)
+                    rowhi = jnp.right_shift(d0.astype(jnp.int64),
+                                            jnp.int64(_pgate.MM_HI_SHIFT))
+                    refine = jnp.logical_and(
+                        m, rowhi == d.astype(jnp.int64)[gid])
+                    fold = aggops.group_min if a.func == "min" \
+                        else aggops.group_max
+                    dref = fold(d0, gid, refine, num_groups)
+                    if axis_name:
+                        dref = aggops.shard_extreme(dref, axis_name,
+                                                    a.func)
+                    aggs_out.append((dref, nonempty))
+                    continue
+                aggs_out.append((d.astype(jnp.float64), nonempty))
+                continue
+            if i not in exact:  # float sum/avg ("on" or promoted)
+                d = ps(acc_f[frow[("fsum", i)], :]).astype(jnp.float64)
+                if a.func == "avg":
+                    d = d / jnp.maximum(cnt, 1).astype(jnp.float64)
+                aggs_out.append((d, nonempty))
+                continue
+            w, k = exact[i]
+            total = jnp.zeros(cnt.shape, jnp.int64)
+            for jl in range(k):
+                s = ps(acc_i[irow[("limb", i, jl)], :])
+                # wrapping IS int64 modular arithmetic — bit-identical to
+                # _group_sum_i64_limbs' recombination
+                total = total + (s.astype(jnp.int64) << jnp.int64(jl * w))
+            # overflow sentinel, same shape as the XLA path's: a cheap
+            # global bound proves most scans cannot wrap, else compare
+            # the f32 shadow. Tolerance 1e-2 (vs the f64 shadow's 1e-3)
+            # absorbs block-sequential f32 accumulation noise; a real
+            # int64 wrap is ~2^64 off, far beyond either.
+            d0, v0 = argdata[i]
+            m = jnp.logical_and(sel, v0)
+            dz64 = jnp.where(m, d0, jnp.zeros_like(d0)).astype(jnp.float64)
+            # psum makes the bound global: every shard agrees
+            cannot = ps(jnp.float64(n) * jnp.max(jnp.abs(dz64))) \
+                < jnp.float64(2 ** 62)
+            sh = ps(acc_f[frow[("shadow", i)], :]).astype(jnp.float64)
+            err = jnp.abs(total.astype(jnp.float64) - sh)
+            tol = jnp.maximum(jnp.abs(sh) * 1e-2, 1e12)
+            overflow = jnp.logical_or(
+                overflow,
+                jnp.logical_and(jnp.logical_not(cannot), jnp.any(err > tol)))
             if a.func == "avg":
-                d = d / jnp.maximum(cnt, 1).astype(jnp.float64)
-            aggs_out.append((d, nonempty))
-            continue
-        w, k = exact[i]
-        total = jnp.zeros(cnt.shape, jnp.int64)
-        for jl in range(k):
-            s = ps(acc_i[irow[("limb", i, jl)], :])
-            # wrapping IS int64 modular arithmetic — bit-identical to
-            # _group_sum_i64_limbs' recombination
-            total = total + (s.astype(jnp.int64) << jnp.int64(jl * w))
-        # overflow sentinel, same shape as the XLA path's: a cheap
-        # global bound proves most scans cannot wrap, else compare
-        # the f32 shadow. Tolerance 1e-2 (vs the f64 shadow's 1e-3)
-        # absorbs block-sequential f32 accumulation noise; a real
-        # int64 wrap is ~2^64 off, far beyond either.
-        d0, v0 = argdata[i]
-        m = jnp.logical_and(sel, v0)
-        dz64 = jnp.where(m, d0, jnp.zeros_like(d0)).astype(jnp.float64)
-        # psum makes the bound global: every shard agrees
-        cannot = ps(jnp.float64(n) * jnp.max(jnp.abs(dz64))) \
-            < jnp.float64(2 ** 62)
-        sh = ps(acc_f[frow[("shadow", i)], :]).astype(jnp.float64)
-        err = jnp.abs(total.astype(jnp.float64) - sh)
-        tol = jnp.maximum(jnp.abs(sh) * 1e-2, 1e12)
-        overflow = jnp.logical_or(
-            overflow,
-            jnp.logical_and(jnp.logical_not(cannot), jnp.any(err > tol)))
-        if a.func == "avg":
-            scale = (10.0 ** a.arg.type.scale
-                     if a.arg.type.family == Family.DECIMAL else 1.0)
-            d = total.astype(jnp.float64) / scale \
-                / jnp.maximum(cnt, 1).astype(jnp.float64)
-            aggs_out.append((d, nonempty))
-        else:
-            aggs_out.append((total, nonempty))
+                scale = (10.0 ** a.arg.type.scale
+                         if a.arg.type.family == Family.DECIMAL else 1.0)
+                d = total.astype(jnp.float64) / scale \
+                    / jnp.maximum(cnt, 1).astype(jnp.float64)
+                aggs_out.append((d, nonempty))
+            else:
+                aggs_out.append((total, nonempty))
     return aggs_out, live, overflow
 
 
@@ -978,8 +1012,17 @@ def _compile_aggregate(node: P.Aggregate, params: ExecParams) -> CompiledNode:
 
     def run_agg(rc: RunContext) -> ColumnBatch:
         b = childf(rc)
+        # the phases a profile treats apart, under this node's scope:
+        # keys (group ids), operands + kernel (the partials; the
+        # large-G path names its own two inside ops/pallas), finalize
+        with jax.named_scope("keys"):
+            ctx, gid, num_groups, ng, group_cols, b = _group_keys(rc, b)
+        return _aggregate(rc, b, ctx, gid, num_groups, ng, group_cols)
+
+    def _group_keys(rc, b):
         ctx = _ctx_of(b)
         group_cols = {}  # name -> ([G] data, [G] valid)
+        ng = None
 
         if not groupfs:
             gid, num_groups = None, 1
@@ -1030,7 +1073,9 @@ def _compile_aggregate(node: P.Aggregate, params: ExecParams) -> CompiledNode:
             for name, gf in groupfs:
                 d, v = gf(ctx)
                 group_cols[name] = (d[rep], v[rep])
+        return ctx, gid, num_groups, ng, group_cols, b
 
+    def _aggregate(rc, b, ctx, gid, num_groups, ng, group_cols):
         mode = params.pallas_groupagg
         pslots = None
         large = False
@@ -1063,9 +1108,10 @@ def _compile_aggregate(node: P.Aggregate, params: ExecParams) -> CompiledNode:
         if pslots is not None:
             pgid = (gid if gid is not None
                     else jnp.zeros((b.n,), dtype=jnp.int32))
-            aggs_out = _pallas_dense_partials(
-                pslots, aggfs, b, ctx, pgid, num_groups, axis,
-                params.pallas_interpret)
+            with jax.named_scope("kernel"):
+                aggs_out = _pallas_dense_partials(
+                    pslots, aggfs, b, ctx, pgid, num_groups, axis,
+                    params.pallas_interpret)
         elif large:
             res = _pallas_large_partials(
                 aggfs, b, ctx, gid, num_groups, node.max_group_rows,
@@ -1089,47 +1135,49 @@ def _compile_aggregate(node: P.Aggregate, params: ExecParams) -> CompiledNode:
                                                    num_groups)
             aggs_out = []
             for a, argf in aggfs:
-                d, v, ovf = _agg_partials(a, argf, b, ctx, gid,
-                                          num_groups, axis,
-                                          node.max_group_rows,
-                                          rep_state,
-                                          params.sort_normalized)
+                with jax.named_scope("kernel"):   # the XLA reduction
+                    d, v, ovf = _agg_partials(a, argf, b, ctx, gid,
+                                              num_groups, axis,
+                                              node.max_group_rows,
+                                              rep_state,
+                                              params.sort_normalized)
                 aggs_out.append((d, v))
                 if ovf is not None:
                     overflow = jnp.logical_or(overflow, ovf)
 
-        # group liveness
-        if not groupfs:
-            live = jnp.ones((1,), dtype=jnp.bool_)
-        elif dense:
-            if large_live is not None:
-                # the kernel's always-on live column (count of
-                # selected rows per group)
-                live = large_live
-            elif rep_state is not None:
-                # the shared representative scatter already knows
-                # which groups have live rows
-                live = rep_state[1]
+        with jax.named_scope("finalize"):
+            # group liveness
+            if not groupfs:
+                live = jnp.ones((1,), dtype=jnp.bool_)
+            elif dense:
+                if large_live is not None:
+                    # the kernel's always-on live column (count of
+                    # selected rows per group)
+                    live = large_live
+                elif rep_state is not None:
+                    # the shared representative scatter already knows
+                    # which groups have live rows
+                    live = rep_state[1]
+                else:
+                    cnt = aggops.group_count(gid, b.sel, num_groups)
+                    if axis:
+                        cnt = aggops.shard_sum(cnt, axis)
+                    live = cnt > 0
             else:
-                cnt = aggops.group_count(gid, b.sel, num_groups)
-                if axis:
-                    cnt = jax.lax.psum(cnt, axis)
-                live = cnt > 0
-        else:
-            garange = jnp.arange(num_groups, dtype=jnp.int32)
-            live = garange < ng
+                garange = jnp.arange(num_groups, dtype=jnp.int32)
+                live = garange < ng
 
-        out = _agg_output(group_cols, aggs_out, live, itemfs, havingf,
-                          num_groups, overflow,
-                          ht_ovf=(None if (not groupfs or dense)
-                                  else ng < 0))
-        if b.has("__compact_overflow"):
-            # bubble a child Compact's capacity sentinel through the
-            # fresh output batch (aggregation drops child columns)
-            out = out.with_column(
-                "__compact_overflow",
-                jnp.broadcast_to(jnp.any(b.col("__compact_overflow")),
-                                 (out.n,)))
+            out = _agg_output(group_cols, aggs_out, live, itemfs, havingf,
+                              num_groups, overflow,
+                              ht_ovf=(None if (not groupfs or dense)
+                                      else ng < 0))
+            if b.has("__compact_overflow"):
+                # bubble a child Compact's capacity sentinel through the
+                # fresh output batch (aggregation drops child columns)
+                out = out.with_column(
+                    "__compact_overflow",
+                    jnp.broadcast_to(jnp.any(b.col("__compact_overflow")),
+                                     (out.n,)))
         return out
     return run_agg
 
@@ -1768,7 +1816,8 @@ def _compile_hash_dist_aggregate(node: P.Aggregate, params: ExecParams,
         # all_gather of each shard's first xcap dense slots
         # concatenates them — no second re-group
         def gather(x):
-            return jax.lax.all_gather(x[:xcap], axis, tiled=True)
+            with jax.named_scope("shard_merge"):
+                return jax.lax.all_gather(x[:xcap], axis, tiled=True)
 
         n_out = n_shards * xcap
         group_cols = {}
@@ -1778,7 +1827,7 @@ def _compile_hash_dist_aggregate(node: P.Aggregate, params: ExecParams,
         aggs_out = [(gather(d), gather(v)) for d, v in aggs_out]
         my_live = jnp.arange(cap, dtype=jnp.int32) < jnp.maximum(ng2, 0)
         live = gather(my_live)
-        sum_ovf = jax.lax.psum(sum_ovf.astype(jnp.int32), axis) > 0
+        sum_ovf = aggops.shard_sum(sum_ovf.astype(jnp.int32), axis) > 0
         # overflow if: a local table spilled, the merge table spilled,
         # the exchange send budget spilled, or a shard owns more than
         # xcap merged groups (output budget)
@@ -1786,7 +1835,7 @@ def _compile_hash_dist_aggregate(node: P.Aggregate, params: ExecParams,
             + (ng2 < 0).astype(jnp.int32) \
             + (ng2 > xcap).astype(jnp.int32)
         ht_ovf = jnp.logical_or(
-            jax.lax.psum(any_ovf, axis) > 0, x_ovf)
+            aggops.shard_sum(any_ovf, axis) > 0, x_ovf)
         return _agg_output(group_cols, aggs_out, live, itemfs, havingf,
                            n_out, sum_ovf, ht_ovf=ht_ovf)
     return run

@@ -357,6 +357,19 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             "exec.movement.exchange.traced.bytes",
             lambda: _shuf.EXCHANGE_TRACED.value(),
             "all_to_all exchange buffer bytes, tallied at trace time")
+        # what a statement sends across the host/device boundary,
+        # counted at the call sites (exec/session.py, ops/batch.py)
+        from ..ops import batch as _ob
+        for tally, help_ in (
+                (_ob.PROGRAMS, "programs dispatched to the device: a "
+                 "plan's executable, a sentinel-flag reduction, a "
+                 "result pack, an eager gather"),
+                (_ob.H2D_CALLS, "host values handed to the device "
+                 "with a dispatch (scalars, gather indices)"),
+                (_ob.H2D_BYTES, "bytes of those host values"),
+                (_ob.D2H_CALLS, "device-to-host result transfers"),
+                (_ob.D2H_BYTES, "bytes those transfers moved")):
+            self.metrics.func_counter(tally.name, tally.value, help_)
         # TPU-plane visibility: Pallas kernel tallies are trace-time
         # module counters (ops/pallas/groupagg.py); read live at
         # scrape. All of them count at TRACE time — executions run
@@ -608,31 +621,36 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
 
     def _parse_cached(self, sql: str):
         import copy
-        hit = self._parse_cache.get(sql)
-        if hit is not None:
-            # plain SELECTs (no CTEs/derived tables) execute without
-            # mutating the AST — view expansion copies before editing,
-            # subquery-free decorrelation is identity, the planner
-            # builds a separate plan tree — so hits share the cached
-            # object (deepcopy cost exceeded the parse it saved).
-            # Shapes whose executors DO rewrite in place (CTE bodies,
-            # DML coercions) hand out a deep copy.
-            if isinstance(hit, ast.Select) and not hit.ctes \
-                    and not self._has_derived(hit):
-                return hit
-            return copy.deepcopy(hit)
-        stmt = parser.parse(sql)
-        # insertion delegates eviction to the TenantLRU: a tenant past
-        # its sql.exec.plan_cache.tenant_budget evicts its own oldest
-        # entries; at the global cap the oldest half goes (a full
-        # clear made every hot statement reparse at once — a stampede
-        # exactly when the cache was earning its keep). The on_evict
-        # hook keeps _plain_memo in sync.
-        self._parse_cache.max_entries = self._PARSE_CACHE_MAX
-        self._parse_cache.put(sql, stmt, self._current_tenant())
-        return copy.deepcopy(stmt) if not (
-            isinstance(stmt, ast.Select) and not stmt.ctes
-            and not self._has_derived(stmt)) else stmt
+        from ..utils import tracing as _trc
+        with _trc.span("parse") as sp:
+            hit = self._parse_cache.get(sql)
+            if sp is not None:
+                sp.tags["cache"] = "hit" if hit is not None else "miss"
+            if hit is not None:
+                # plain SELECTs (no CTEs/derived tables) execute
+                # without mutating the AST — view expansion copies
+                # before editing, subquery-free decorrelation is
+                # identity, the planner builds a separate plan tree —
+                # so hits share the cached object (deepcopy cost
+                # exceeded the parse it saved). Shapes whose executors
+                # DO rewrite in place (CTE bodies, DML coercions) hand
+                # out a deep copy.
+                if isinstance(hit, ast.Select) and not hit.ctes \
+                        and not self._has_derived(hit):
+                    return hit
+                return copy.deepcopy(hit)
+            stmt = parser.parse(sql)
+            # insertion delegates eviction to the TenantLRU: a tenant
+            # past its sql.exec.plan_cache.tenant_budget evicts its
+            # own oldest entries; at the global cap the oldest half
+            # goes (a full clear made every hot statement reparse at
+            # once — a stampede exactly when the cache was earning its
+            # keep). The on_evict hook keeps _plain_memo in sync.
+            self._parse_cache.max_entries = self._PARSE_CACHE_MAX
+            self._parse_cache.put(sql, stmt, self._current_tenant())
+            return copy.deepcopy(stmt) if not (
+                isinstance(stmt, ast.Select) and not stmt.ctes
+                and not self._has_derived(stmt)) else stmt
 
     # executable cache: same bounded-growth policy as the parse cache
     # (long-lived multi-tenant sessions must not grow it without
@@ -994,10 +1012,13 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         except Exception:
             psink = _prof.ProfileSink()
         # slow-statement sampling records even untraced statements —
-        # but never nested ones (an active span means some outer
-        # statement already owns the recording on this thread)
+        # but never nested ones (an active span that is not the
+        # served statement's own root means some outer statement
+        # already owns the recording on this thread)
+        outer = _trc.current_span()
+        served = outer is not None and outer.tags.get("served") is True
         capture = tracing or diag_req is not None or (
-            slow_thresh > 0 and _trc.current_span() is None
+            slow_thresh > 0 and (outer is None or served)
             and not isinstance(stmt, ast.ShowTrace))
         shared = self._stmt_read_only(stmt, session, sql_text)
         # per-statement compile-vs-execute split: XLA backend
@@ -1021,23 +1042,36 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 self.tracer.tag(compile_s=round(compile_s, 6))
             return r
         try:
-            rec = None
-            if capture:
-                # session tracing "on" keeps the recording gateway-
-                # local (remote nodes stay dark); "cluster" and the
-                # implicit captures (slow sampling) request remote
-                # recordings too
-                rec_req = tmode == "cluster" if tracing else True
-                with self.tracer.capture(
-                        sql_text or type(stmt).__name__,
-                        record_request=rec_req) as rec:
-                    res = _run()
-                if tracing:
-                    session.trace.append(rec)
+            # session tracing "on" keeps the recording gateway-local
+            # (remote nodes stay dark); "cluster" and the implicit
+            # captures (slow sampling) request remote recordings too;
+            # a recording nobody asked for here (the collector's, an
+            # outer statement's) asks for none
+            rec_req = (tmode == "cluster" if tracing else True) \
+                if capture else None
+            name = sql_text or type(stmt).__name__
+            if outer is not None and (served or not capture):
+                # under the served statement's root (pgwire opened it
+                # at the frame) or nested in an outer statement: one
+                # tree, this statement a child of it
+                scope = _trc.span(name, record_request=rec_req)
+            elif capture or _trc.collecting():
+                scope = _trc.capture(name, record_request=bool(rec_req),
+                                     collect=True)
             else:
-                with self.tracer.span(
-                        f"stmt:{type(stmt).__name__.lower()}"):
-                    res = _run()
+                # nothing records: no Span exists, current_span()
+                # stays None and no RPC carries a recording request
+                scope = _trc.NO_SPAN
+            with scope as rec:
+                if rec is not None:
+                    # on the tree's root: the wire's when it opened one
+                    (outer if served else rec).tags.setdefault(
+                        "fingerprint", fp)
+                res = _run()
+            if not capture:
+                rec = None      # read by no sink below
+            elif tracing:
+                session.trace.append(rec)
             self.metrics.counter(
                 f"sql.{type(stmt).__name__.lower()}.count",
                 "statements executed, by type").inc()
@@ -1127,14 +1161,21 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
 
     def _dispatch_locked(self, stmt, session, sql_text: str,
                          shared: bool) -> Result:
-        if shared:
-            self._stmt_lock.acquire_read()
-            try:
-                return self._dispatch_stmt(stmt, session, sql_text)
-            finally:
-                self._stmt_lock.release_read()
-        with self._stmt_lock:
+        from ..utils import tracing as _trc
+        lock = self._stmt_lock
+        # the wait for the statement gate, up to the lock held
+        with _trc.span("gate", shared=shared):
+            if shared:
+                lock.acquire_read()
+            else:
+                lock.acquire_write()
+        try:
             return self._dispatch_stmt(stmt, session, sql_text)
+        finally:
+            if shared:
+                lock.release_read()
+            else:
+                lock.release_write()
 
     def _stmt_read_only(self, stmt, session: Session,
                         sql_text: str) -> bool:
@@ -1583,12 +1624,18 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             actuals = prof = None   # diagnostics must never fail the
             #                         statement
         lines = ["planning/execution:"]
-        for name in ("plan", "compile", "upload", "dispatch",
-                     "materialize"):
-            s = rec.find(name)
-            if s is not None:
-                tag_s = "".join(f" {k}={v}" for k, v in s.tags.items())
-                lines.append(f"  {name}: {s.duration_ms:.2f}ms{tag_s}")
+
+        def layers(s, depth):
+            # the statement's layer spans, nested as recorded
+            for c in s.children:
+                if c.name in ("plan", "compile", "upload", "dispatch",
+                              "queue", "materialize", "pull", "decode"):
+                    tag_s = "".join(f" {k}={v}"
+                                    for k, v in c.tags.items())
+                    lines.append(f"{'  ' * depth}{c.name}: "
+                                 f"{c.duration_ms:.2f}ms{tag_s}")
+                    layers(c, depth + 1)
+        layers(rec, 1)
         if xla_ms > 0:
             # "slow because compiling" vs "slow because executing":
             # XLA backend-compile time inside this statement (~0 on
@@ -2473,11 +2520,22 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                               no_topk: bool = False,
                               no_compact: bool = False,
                               no_dist: bool = False) -> "Prepared":
+        # one span over the whole prepare: planning, the device
+        # tables (an `upload` beneath it when one is not resident),
+        # the plan-shape cache lookup and, on a miss, `compile`
+        with self.tracer.span("plan"):
+            return self._prepare_select_planned(
+                sel, session, sql_text, no_memo, no_topk, no_compact,
+                no_dist)
+
+    def _prepare_select_planned(self, sel, session: Session,
+                                sql_text: str, no_memo: bool,
+                                no_topk: bool, no_compact: bool,
+                                no_dist: bool) -> "Prepared":
         for td in self.store.tables.values():
             if td.open_ts:
                 self.store.seal(td.schema.name)
-        with self.tracer.span("plan"):
-            node, meta = self._plan(sel, session, no_memo=no_memo)
+        node, meta = self._plan(sel, session, no_memo=no_memo)
 
         scan_aliases = _collect_scans(node)
         scan_cols = _collect_scan_columns(node)

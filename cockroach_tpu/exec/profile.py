@@ -4,9 +4,12 @@ The reference attributes execution statistics to individual
 processors via execinfrapb.ComponentStats collected by the
 execstatscollector and stitched into the statement bundle
 (``pkg/sql/execstats/traceanalyzer.go``). Our plans normally compile
-to ONE fused XLA program, so per-operator device time is unobservable
-on the hot path; attribution happens on the planes that already run
-host-side:
+to ONE fused XLA program. Its ops do carry their plan operator
+(``compile_plan`` runs each closure under ``jax.named_scope``), so a
+profiler capture gives per-operator device time of the real program
+(benchmark/span_reduce.py folds it); a statement cannot read that
+about itself while it runs, so its own attribution happens on the
+planes that already run host-side:
 
 - **coarse plane (always on)**: every statement activates a
   ``ProfileSink`` on a thread-local (``profile.active``). The

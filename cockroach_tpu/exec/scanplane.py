@@ -21,6 +21,7 @@ from ..parallel.distagg import make_distributed_fn, queued_collective_call
 from ..parallel.mesh import SHARD_AXIS
 from ..sql import plan as P
 from ..storage.hlc import Timestamp
+from ..utils import tracing as _trc
 from ..utils.mon import MemoryQuotaError
 from .compile import (ExecParams, RunContext, can_spill_sort,
                       can_stream, compile_plan)
@@ -902,8 +903,9 @@ class ScanPlaneMixin:
             # and the retrier becomes the new owner)
             ev.wait(timeout=5.0)
         try:
-            return self._device_upload(name, td, placement, cols,
-                                       narrow, mesh, devids)
+            with _trc.span("upload", table=name):
+                return self._device_upload(name, td, placement, cols,
+                                           narrow, mesh, devids)
         finally:
             with self._device_lock:
                 self._device_inflight.pop(flight, None)
@@ -1005,6 +1007,7 @@ class ScanPlaneMixin:
             "host->device bytes moved by table uploads").inc(nbytes)
         _prof.note(f"upload:{name}", batches=1, rows=td.row_count,
                    bytes_uploaded=nbytes)
+        _trc.tag(bytes=nbytes)   # on _device_table's `upload` span
         return b
 
     def narrow32_cols(self, name: str,
@@ -1114,10 +1117,10 @@ class ScanPlaneMixin:
         transfer for small batches; for wide (join-expanded) batches,
         one pull for (sel + flags), then one pull of the live rows
         gathered on device."""
-        from ..ops.batch import _SMALL_PULL, pull_arrays, \
+        from ..ops.batch import _SMALL_PULL, flag_any, pull_arrays, \
             pull_batch_columns
         sent = [(n, exc) for n, exc in self._SENTINELS if out.has(n)]
-        flags_dev = [jnp.any(out.col(n)) for n, _ in sent]
+        flags_dev = [flag_any(out.col(n)) for n, _ in sent]
         names = list(meta.names)
         if out.n <= _SMALL_PULL:
             pulled, flags = pull_batch_columns(out, names,
@@ -1130,16 +1133,18 @@ class ScanPlaneMixin:
             self._raise_sentinels(sent, first[1:])
             pulled, _ = pull_batch_columns(out, names,
                                            sel_np=first[0])
-        host = {c: np.ma.masked_array(d, mask=~v)
-                for c, (d, v) in pulled.items()}
-        res = Result(names=names, types=list(meta.types))
-        cols = []
-        for name, ty in zip(names, meta.types):
-            arr = host[name]
-            d = meta.dictionaries.get(name)
-            cols.append(_decode_column(arr, ty, d))
-        res.rows = list(zip(*cols)) if cols else []
-        return res
+        # everything after the last pull: masks, dictionaries, rows
+        with _trc.span("decode"):
+            host = {c: np.ma.masked_array(d, mask=~v)
+                    for c, (d, v) in pulled.items()}
+            res = Result(names=names, types=list(meta.types))
+            cols = []
+            for name, ty in zip(names, meta.types):
+                arr = host[name]
+                d = meta.dictionaries.get(name)
+                cols.append(_decode_column(arr, ty, d))
+            res.rows = list(zip(*cols)) if cols else []
+            return res
 
     @staticmethod
     def _raise_sentinels(sent, flags) -> None:

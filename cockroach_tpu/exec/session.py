@@ -12,7 +12,7 @@ from typing import Optional
 import numpy as np
 
 from ..kv.txn import Txn
-from ..ops.batch import ColumnBatch
+from ..ops.batch import H2D_BYTES, H2D_CALLS, PROGRAMS, ColumnBatch
 from ..sql import ast
 from ..storage.hlc import Timestamp
 from ..utils.settings import SessionVars
@@ -202,6 +202,13 @@ class Prepared:
             from .spill import run_spill_join
             return run_spill_join(self.engine, self, tsv)
         if self.stream is None:
+            # one program; its scalar arguments are host-to-device
+            # transfers of their own (the read timestamp, the two
+            # partition scalars, each stripped literal)
+            PROGRAMS.inc()
+            H2D_CALLS.inc(3 + len(self.params))
+            H2D_BYTES.inc(16 + sum(int(getattr(v, "nbytes", 8))
+                                   for v in self.params))
             return self.jfn(self.scans, tsv, np.int32(nparts),
                             np.int32(pid), self.params)
         # paged execution through the prefetch pipeline: a bounded

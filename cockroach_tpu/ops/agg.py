@@ -213,11 +213,20 @@ def shard_extreme(x, axis_name, op: str):
     fold locally; narrower ones take the native collective. One rule
     on every backend, so the CPU mesh tests run the program the chips
     run."""
-    if jnp.dtype(x.dtype).itemsize < 8:
-        return (jax.lax.pmin if op == "min"
-                else jax.lax.pmax)(x, axis_name)
-    gathered = jax.lax.all_gather(x, axis_name)  # [shards, ...]
-    return (jnp.min if op == "min" else jnp.max)(gathered, axis=0)
+    with jax.named_scope("shard_merge"):
+        if jnp.dtype(x.dtype).itemsize < 8:
+            return (jax.lax.pmin if op == "min"
+                    else jax.lax.pmax)(x, axis_name)
+        gathered = jax.lax.all_gather(x, axis_name)  # [shards, ...]
+        return (jnp.min if op == "min" else jnp.max)(gathered, axis=0)
+
+
+def shard_sum(x, axis_name):
+    """psum across the mesh under the `shard_merge` scope, which a
+    profile reads to tell the collectives of a distributed plan from
+    the operator around them."""
+    with jax.named_scope("shard_merge"):
+        return jax.lax.psum(x, axis_name)
 
 
 def group_any(data, group_ids, mask, num_groups: int):

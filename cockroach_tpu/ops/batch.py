@@ -22,6 +22,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils import tracing
+from ..utils.metric import Counter
+
+# What one statement sends across the host/device boundary, counted
+# where the call is made. ops/ has no registry of its own: Engine
+# exposes these as exec.dispatch.programs and
+# exec.transfer.{h2d,d2h}.{calls,bytes} (as exec.pallas.* reads
+# ops/pallas's tallies), so they are process-wide, always on.
+PROGRAMS = Counter("exec.dispatch.programs")
+H2D_CALLS = Counter("exec.transfer.h2d.calls")
+H2D_BYTES = Counter("exec.transfer.h2d.bytes")
+D2H_CALLS = Counter("exec.transfer.d2h.calls")
+D2H_BYTES = Counter("exec.transfer.d2h.bytes")
+
 
 @jax.tree_util.register_pytree_node_class
 @dataclass
@@ -162,7 +176,22 @@ def _to_bytes(a: jnp.ndarray) -> jnp.ndarray:
 
 @jax.jit
 def _pack(arrs):
-    return jnp.concatenate([_to_bytes(a) for a in arrs])
+    # `harness`: device work of the result path, not of a plan operator
+    with jax.named_scope("harness"):
+        return jnp.concatenate([_to_bytes(a) for a in arrs])
+
+
+@jax.jit
+def _any(a):
+    with jax.named_scope("harness"):
+        return jnp.any(a)
+
+
+def flag_any(a) -> jnp.ndarray:
+    """A sentinel column reduced to one device scalar (its own small
+    program, dispatched now, pulled with the result)."""
+    PROGRAMS.inc()
+    return _any(a)
 
 
 def _np_dtype(dt) -> np.dtype:
@@ -177,6 +206,22 @@ def pull_arrays(arrs: list) -> list[np.ndarray]:
     f64 arrays transfer individually with async prefetch overlapping
     the packed pull. Accepts numpy arrays transparently (passed
     through) so callers can mix host- and device-resident columns."""
+    with tracing.span("pull") as sp:
+        out, programs, transfers, nbytes = _pull(arrs)
+        if sp is not None:
+            sp.tags.update(programs=programs, transfers=transfers,
+                           bytes=nbytes)
+    if programs:
+        PROGRAMS.inc(programs)
+    if transfers:
+        D2H_CALLS.inc(transfers)
+        D2H_BYTES.inc(nbytes)
+    return out
+
+
+def _pull(arrs: list) -> tuple:
+    """(host arrays, pack programs dispatched, device-to-host
+    transfers, bytes they moved)."""
     metas = []
     packs = []
     singles = []
@@ -195,12 +240,17 @@ def pull_arrays(arrs: list) -> list[np.ndarray]:
         except Exception:
             pass
     pieces = []
+    programs = 0
+    nbytes = 0
     if packs:
         if len(packs) == 1 and packs[0].dtype != jnp.bool_:
             # a single non-bool array needs no pack program
             pieces = [np.asarray(packs[0])]
+            nbytes += pieces[0].nbytes
         else:
+            programs = 1
             flat = np.asarray(_pack(packs))
+            nbytes += flat.nbytes
             off = 0
             for kind, m in metas:
                 if kind != "pack":
@@ -217,6 +267,7 @@ def pull_arrays(arrs: list) -> list[np.ndarray]:
                 else:
                     pieces.append(chunk.view(npdt).reshape(shape))
     singles_np = [np.asarray(s) for s in singles]
+    nbytes += sum(s.nbytes for s in singles_np)
     out = []
     it = iter(pieces)
     for kind, m in metas:
@@ -226,7 +277,7 @@ def pull_arrays(arrs: list) -> list[np.ndarray]:
             out.append(singles_np[m])
         else:
             out.append(next(it))
-    return out
+    return out, programs, bool(packs) + len(singles), nbytes
 
 
 # below this row count a full-width packed pull is cheaper than the
@@ -300,6 +351,9 @@ def pull_batch_columns(batch: ColumnBatch, names: list,
         idx_np = np.full(padded, live[-1], dtype=np.int32)
         idx_np[:len(live)] = live
         idx = jax.device_put(idx_np)
+        H2D_CALLS.inc()
+        H2D_BYTES.inc(idx_np.nbytes)
+        PROGRAMS.inc(len(datas) + len(valids))  # one eager gather each
         pulled = pull_arrays([jnp.take(a, idx, axis=0)
                               for a in datas + valids])
         return assemble(pulled, trim=len(live)), extra_np

@@ -96,7 +96,12 @@ def hash_join(probe: ColumnBatch, build: ColumnBatch,
     copy j of probe row p follows the build side's per-key duplicate
     chain j hops (the two-pass count+materialize of the reference's
     hashjoiner.go:870, reshaped for the compiler: chains come from one
-    lexsort, emission is K strided gathers)."""
+    lexsort, emission is K strided gathers).
+
+    The phases a profile treats apart carry a jax.named_scope each
+    (`build`: the key->row table and what is folded into it over the
+    build domain; `probe`: every probe-width gather; `expand`), under
+    the operator scope exec/compile.py opens."""
     bkeys = tuple(build.col(k) for k in build_keys)
     pkeys = tuple(probe.col(k) for k in probe_keys)
     bmask = build.sel
@@ -139,12 +144,14 @@ def hash_join(probe: ColumnBatch, build: ColumnBatch,
         # and dimension join keys are almost always dense ints (pks,
         # dict codes). One scatter builds key->row; one gather probes.
         base, size = direct
-        bidx = jnp.clip(bkeys[0] - base, 0, size - 1).astype(jnp.int32)
-        bslot = jnp.where(bmask, bidx, size - 1)
-        # .min keeps the FIRST (lowest-rowid) duplicate — the same
-        # chain head _dup_chain produces
-        table = jnp.full((size,), build.n, dtype=jnp.int32) \
-            .at[bslot].min(jnp.arange(build.n, dtype=jnp.int32))
+        with jax.named_scope("build"):
+            bidx = jnp.clip(bkeys[0] - base, 0,
+                            size - 1).astype(jnp.int32)
+            bslot = jnp.where(bmask, bidx, size - 1)
+            # .min keeps the FIRST (lowest-rowid) duplicate — the
+            # same chain head _dup_chain produces
+            table = jnp.full((size,), build.n, dtype=jnp.int32) \
+                .at[bslot].min(jnp.arange(build.n, dtype=jnp.int32))
         pk0 = pkeys[0]
         in_range = jnp.logical_and(pk0 >= base, pk0 - base < size - 1)
         pidx = jnp.clip(pk0 - base, 0, size - 1).astype(jnp.int32)
@@ -164,8 +171,9 @@ def hash_join(probe: ColumnBatch, build: ColumnBatch,
             # partsupp at 61M slots over a 1M probe) would pay
             # table-width gathers per payload (~450ms each measured)
             # where the two-hop probe path pays probe-width (~8ms).
-            owner_slot = jnp.minimum(table, build.n - 1)
-            vtab = table < build.n               # slot -> live build?
+            with jax.named_scope("build"):
+                owner_slot = jnp.minimum(table, build.n - 1)
+                vtab = table < build.n           # slot -> live build?
             # Three-state packing: when a payload column is an int32
             # dict code (>= 0), fold the match bit AND the null bit
             # into the value table — the whole join then costs ONE
@@ -187,17 +195,20 @@ def hash_join(probe: ColumnBatch, build: ColumnBatch,
                     if name in packable:
                         col = build.col(name)
                         is_bool = col.dtype == jnp.bool_
-                        code = (col.astype(jnp.int32)
-                                if is_bool else col)[owner_slot]
-                        pval = build.col_valid(name)[owner_slot]
-                        packed = jnp.where(
-                            vtab, jnp.where(pval, code,
-                                            jnp.int32(-1)),
-                            jnp.int32(-2))
+                        with jax.named_scope("build"):
+                            code = (col.astype(jnp.int32)
+                                    if is_bool else col)[owner_slot]
+                            pval = build.col_valid(name)[owner_slot]
+                            packed = jnp.where(
+                                vtab, jnp.where(pval, code,
+                                                jnp.int32(-1)),
+                                jnp.int32(-2))
                         # barrier: XLA otherwise rematerializes the
                         # gather once per consumer fusion (observed:
                         # 2x probe-length gathers in the Q14 HLO)
-                        t = jax.lax.optimization_barrier(packed[pidx])
+                        with jax.named_scope("probe"):
+                            t = jax.lax.optimization_barrier(
+                                packed[pidx])
                         if name == first:
                             matched = jnp.logical_and(base_ok,
                                                       t >= -1)
@@ -207,30 +218,37 @@ def hash_join(probe: ColumnBatch, build: ColumnBatch,
                         out = out.with_column(name + suffix, data,
                                               valid)
                     else:
-                        ptab = build.col(name)[owner_slot]
-                        pvtab = jnp.logical_and(
-                            build.col_valid(name)[owner_slot], vtab)
-                        out = out.with_column(
-                            name + suffix, ptab[pidx],
-                            jnp.logical_and(pvtab[pidx], base_ok))
+                        with jax.named_scope("build"):
+                            ptab = build.col(name)[owner_slot]
+                            pvtab = jnp.logical_and(
+                                build.col_valid(name)[owner_slot],
+                                vtab)
+                        with jax.named_scope("probe"):
+                            out = out.with_column(
+                                name + suffix, ptab[pidx],
+                                jnp.logical_and(pvtab[pidx], base_ok))
                 return out.and_sel(matched) if join_type == "inner" \
                     else out
-            matched = jnp.logical_and(base_ok, vtab[pidx])
+            with jax.named_scope("probe"):
+                matched = jnp.logical_and(base_ok, vtab[pidx])
             if join_type == "semi":
                 return probe.and_sel(matched)
             if join_type == "anti":
                 return probe.and_sel(jnp.logical_not(matched))
             for name in build_payload:
-                ptab = build.col(name)[owner_slot]       # [size]
-                pvtab = jnp.logical_and(
-                    build.col_valid(name)[owner_slot], vtab)
-                data = ptab[pidx]
-                valid = jnp.logical_and(pvtab[pidx], matched)
+                with jax.named_scope("build"):
+                    ptab = build.col(name)[owner_slot]       # [size]
+                    pvtab = jnp.logical_and(
+                        build.col_valid(name)[owner_slot], vtab)
+                with jax.named_scope("probe"):
+                    data = ptab[pidx]
+                    valid = jnp.logical_and(pvtab[pidx], matched)
                 out = out.with_column(name + suffix, data, valid)
             return out.and_sel(matched) if join_type == "inner" \
                 else out
-        owner = table[pidx]
-        build_row = jnp.minimum(owner, build.n - 1)
+        with jax.named_scope("probe"):
+            owner = table[pidx]
+            build_row = jnp.minimum(owner, build.n - 1)
         # No key-equality re-check needed: direct addressing is
         # collision-free by construction — every live build key maps
         # to its own slot inside [0, size-2] (the engine sized the
@@ -242,9 +260,11 @@ def hash_join(probe: ColumnBatch, build: ColumnBatch,
                                   owner < build.n)
     else:
         cap = _next_pow2(max(2 * build.n, 16))
-        claim, _, _ = hashtable.build(bkeys, bmask, cap)  # cap>=2N
-        matched, build_row = hashtable.probe(claim, bkeys, pkeys, pmask,
-                                             cap, build.n)
+        with jax.named_scope("build"):
+            claim, _, _ = hashtable.build(bkeys, bmask, cap)  # cap>=2N
+        with jax.named_scope("probe"):
+            matched, build_row = hashtable.probe(claim, bkeys, pkeys,
+                                                 pmask, cap, build.n)
     # A probe row can land on a build row that was masked out (dead build
     # rows never insert, so claim only holds live rows — no extra check).
 
@@ -257,16 +277,18 @@ def hash_join(probe: ColumnBatch, build: ColumnBatch,
 
     if expand <= 1:
         out = probe
-        for name in build_payload:
-            data = build.col(name)[build_row]
-            valid = jnp.logical_and(build.col_valid(name)[build_row],
-                                    matched)
-            out = out.with_column(name + suffix, data, valid)
+        with jax.named_scope("probe"):
+            for name in build_payload:
+                data = build.col(name)[build_row]
+                valid = jnp.logical_and(
+                    build.col_valid(name)[build_row], matched)
+                out = out.with_column(name + suffix, data, valid)
         return out.and_sel(matched) if join_type == "inner" else out
 
-    return _expand_join(probe, build, bkeys, bmask, matched, build_row,
-                        build_payload, join_type, suffix, expand,
-                        sort_normalized)
+    with jax.named_scope("expand"):
+        return _expand_join(probe, build, bkeys, bmask, matched,
+                            build_row, build_payload, join_type, suffix,
+                            expand, sort_normalized)
 
 
 def _dup_chain(bkeys: tuple, bmask, n: int, mode: str = "off"):

@@ -232,10 +232,16 @@ def large_group_aggregate(gid, sel, mat_values: tuple, mm_values: tuple,
         out_shape.append(jax.ShapeDtypeStruct((gp, 1), jnp.int32))
         out_specs.append(by_slot(1))
 
-    args = (gid.astype(jnp.int32).reshape(1, n),
-            sel.astype(jnp.int32).reshape(1, n),
-            jnp.stack([v.astype(jnp.float32) for v in mat_values], axis=0),
-            *[v.astype(jnp.float32).reshape(1, n) for v in mm_values])
+    # the operand matrix is written out in full before the kernel
+    # reads it: a phase of its own to a profile (`operands`). The
+    # kernel's own scope is the caller's: XLA names the custom call by
+    # the last component of its path, this function's name
+    with jax.named_scope("operands"):
+        args = (gid.astype(jnp.int32).reshape(1, n),
+                sel.astype(jnp.int32).reshape(1, n),
+                jnp.stack([v.astype(jnp.float32) for v in mat_values],
+                          axis=0),
+                *[v.astype(jnp.float32).reshape(1, n) for v in mm_values])
     with jax.enable_x64(False):
         outs = pl.pallas_call(
             kernel,

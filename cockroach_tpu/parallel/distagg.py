@@ -38,6 +38,7 @@ import jax
 from jax import shard_map
 
 from ..sql import plan as P
+from ..utils import tracing
 from . import mesh as meshmod
 
 
@@ -393,6 +394,22 @@ def queued_collective_call(jfn, metrics=None, mesh=None,
                 return _call_inner(*args, **kwargs)
         return _call_inner(*args, **kwargs)
 
+    def _submit_and_wait(args, kwargs):
+        if tracing.current_span() is None:
+            return disp.submit(jfn, args, kwargs, on_start).result()
+        # recording: a `queue` span from enqueue to the dispatcher's
+        # pick-up, stamped on its thread, recorded here on ours
+        started = []
+
+        def picked_up(wait: float):
+            started.append(_time.monotonic_ns())
+            on_start(wait)
+        t_enq = _time.monotonic_ns()
+        out = disp.submit(jfn, args, kwargs, picked_up).result()
+        if started:
+            tracing.record("queue", t_enq, started[0])
+        return out
+
     def _call_inner(*args, **kwargs):
         t0 = _time.monotonic()
         try:
@@ -432,12 +449,10 @@ def queued_collective_call(jfn, metrics=None, mesh=None,
                 # still run concurrently
                 win = meshmod.execution_window(mesh)
                 if win is None:
-                    fut = disp.submit(jfn, args, kwargs, on_start)
-                    out = fut.result()
+                    out = _submit_and_wait(args, kwargs)
                 else:
                     with win:
-                        fut = disp.submit(jfn, args, kwargs, on_start)
-                        out = fut.result()
+                        out = _submit_and_wait(args, kwargs)
             return out
         finally:
             if m_calls is not None:

@@ -113,7 +113,7 @@ class _QueueReader:
                     raise ConnectionError("client disconnected")
                 if not s.cv.wait(timeout=_INLINE_READ_TIMEOUT):
                     raise ConnectionError("inline read timed out")
-            return s.frames.popleft()
+            return s.frames.popleft()[:2]
 
     def startup(self):  # pragma: no cover - loop owns startup framing
         raise _pg.ProtocolError("startup packets are parsed by the "
@@ -413,7 +413,9 @@ class ReactorServer:
     def _enqueue(self, sess: _Session, typ: bytes, body: bytes):
         submit = False
         with sess.lk:
-            sess.frames.append((typ, body))
+            # stamped on arrival: the wait for a worker is the
+            # statement's `wire.queue` span when tracing collects
+            sess.frames.append((typ, body, time.monotonic_ns()))
             sess.cv.notify_all()
             if sess.ready and not sess.active:
                 sess.active = True
@@ -450,9 +452,9 @@ class ReactorServer:
                     if sess.eof:
                         break
                     return
-                typ, body = sess.frames.popleft()
+                typ, body, queued_ns = sess.frames.popleft()
             try:
-                alive = sess.conn.process(typ, body)
+                alive = sess.conn.process(typ, body, queued_ns)
             except (ConnectionError, _pg.ProtocolError, OSError):
                 alive = False
             except Exception:

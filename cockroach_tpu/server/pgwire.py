@@ -32,8 +32,10 @@ import socket
 import socketserver
 import struct
 import threading
+import time
 
 from ..exec.engine import Engine, EngineError, Result, Session
+from ..utils import tracing
 
 PROTO_V3 = 196608          # 3.0
 SSL_REQUEST = 80877103
@@ -653,13 +655,21 @@ class _Conn:
         return res.tag
 
     def _send_result(self, res: Result, describe: bool = True):
-        if res.names:
-            oids = [_infer_oid(res.rows, i) for i in range(len(res.names))]
-            if describe:
-                self.w.row_description(res.names, oids)
-            for row in res.rows:
-                self.w.data_row([_encode_text(v) for v in row])
-        self.w.command_complete(self._complete_tag(res))
+        with tracing.span("encode", rows=len(res.rows)):
+            if res.names:
+                oids = [_infer_oid(res.rows, i)
+                        for i in range(len(res.names))]
+                if describe:
+                    self.w.row_description(res.names, oids)
+                for row in res.rows:
+                    self.w.data_row([_encode_text(v) for v in row])
+            self.w.command_complete(self._complete_tag(res))
+
+    def _ready(self):
+        """ReadyForQuery and the flush that carries it and everything
+        buffered before it: the socket write."""
+        with tracing.span("send"):
+            self.w.ready_for_query(self._txn_status())
 
     def _send_portal(self, p: dict, max_rows: int):
         """Row-limited portal execution: emit up to max_rows, then
@@ -882,11 +892,29 @@ class _Conn:
             except OSError:
                 pass
 
-    def process(self, typ: bytes, body: bytes) -> bool:
+    def process(self, typ: bytes, body: bytes,
+                queued_ns: int | None = None) -> bool:
         """Dispatch one frontend message; False = Terminate. Both
         front ends funnel through here — the thread loop above and
         the reactor's worker drain (server/pgfront.py) — so replies
-        are byte-identical by construction."""
+        are byte-identical by construction.
+
+        While the tracing collector is on, a frame that runs a
+        statement (Query, Execute) is one recorded root from here to
+        the flush of its reply; `queued_ns` is the reactor's stamp of
+        the frame's arrival, and the root starts there with the wait
+        for this worker as its first child."""
+        if typ in (b"Q", b"E") and tracing.collecting():
+            with tracing.capture("statement", record_request=False,
+                                 start_ns=queued_ns, collect=True,
+                                 served=True, frame=typ.decode()):
+                if queued_ns is not None:
+                    tracing.record("wire.queue", queued_ns,
+                                   time.monotonic_ns())
+                return self._process(typ, body)
+        return self._process(typ, body)
+
+    def _process(self, typ: bytes, body: bytes) -> bool:
         if typ == b"X":          # Terminate
             return False
         if typ == b"Q":
@@ -911,12 +939,12 @@ class _Conn:
                 self._copy(m)
             except Exception as e:
                 self.w.error(str(e), code=_sqlstate(e))
-            self.w.ready_for_query(self._txn_status())
+            self._ready()
             return
         stmts = split_statements(sql)
         if not stmts:
             self.w.empty_query()
-            self.w.ready_for_query(self._txn_status())
+            self._ready()
             return
         for s in stmts:
             try:
@@ -925,7 +953,7 @@ class _Conn:
                 self.w.error(str(e), code=_sqlstate(e))
                 break
             self._send_result(res)
-        self.w.ready_for_query(self._txn_status())
+        self._ready()
 
     # -- COPY (conn.go's processCopy; text format only) ----------------------
     def _copy_columns(self, table: str, collist: str | None) -> list[str]:

@@ -38,6 +38,8 @@ import threading
 import time
 from dataclasses import dataclass, field
 
+from . import tracing
+
 PRIORITIES = {"high": 0, "normal": 1, "low": 2}
 
 # EWMA smoothing for the recent grant-wait signal that drives shedding.
@@ -202,7 +204,8 @@ class AdmissionController:
             import bisect
             bisect.insort(self._queue, w)
             self.queued += 1
-        granted = w.event.wait(timeout)
+        with tracing.span("admission"):    # the wait only
+            granted = w.event.wait(timeout)
         obs = None
         with self._mu:
             if granted or w.granted:

@@ -18,10 +18,22 @@ for an RPC frame; the serving side runs its handler under its own
 `span_to_wire()`; the caller grafts it with `attach_remote()`. This
 mirrors CockroachDB's span "recording" payloads piggybacked on
 BatchResponse / SetupFlow (pkg/util/tracing/crdbspan.go).
+
+Off is off: with no recording open on a thread, `span()` hands back
+one shared no-op (no Span is allocated) and `current_span()` stays
+None, so `trace_context()` ships nothing. What opens a recording is a
+`capture()`: EXPLAIN ANALYZE, SET tracing, slow sampling, an armed
+diagnostics request, an inbound RPC frame that asks for one — or the
+process-wide collector below, which is the slow ring's mechanism made
+general: while it is on, every statement root (served or library) is
+recorded and kept, bounded, oldest dropped first. Stamps are
+`time.monotonic_ns()`, the clock a profiler capture's host events can
+be paired with, so collected roots line up with device ops.
 """
 
 from __future__ import annotations
 
+import collections
 import itertools
 import threading
 import time
@@ -74,6 +86,37 @@ class Span:
 
 def current_span() -> Optional[Span]:
     return getattr(_tls, "span", None)
+
+
+# -- the process-wide collector ----------------------------------------------
+# None while off. A deque(maxlen) drops the oldest root when full, and
+# its append is atomic, so every thread offers without a lock.
+_collected: Optional[collections.deque] = None
+COLLECTOR_MAX_ROOTS = 8192
+
+
+def start_collector(max_roots: int = COLLECTOR_MAX_ROOTS) -> None:
+    """Record every statement root, on all threads, until
+    stop_collector(); at most `max_roots` are kept, the oldest go."""
+    global _collected
+    _collected = collections.deque(maxlen=max_roots)
+
+
+def stop_collector() -> list:
+    """Turn the collector off and hand back the finished roots it
+    kept, oldest first."""
+    global _collected
+    roots, _collected = _collected, None
+    return list(roots or ())
+
+
+def collecting() -> bool:
+    return _collected is not None
+
+
+def collected() -> list:
+    """The roots kept so far (the collector stays on)."""
+    return list(_collected or ())
 
 
 def recording_requested() -> bool:
@@ -142,22 +185,70 @@ def attach_remote(wire: dict) -> Optional[Span]:
     return s
 
 
-@contextmanager
-def span(name: str, **tags):
-    """Module-level child span on the shared stack (open a child of
-    whatever is recording; cheap no-op nesting otherwise)."""
+class _NoSpan:
+    """What span() returns when nothing records on this thread: one
+    shared object, so the untraced path allocates no Span."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return None
+
+    def __exit__(self, *exc):
+        return False
+
+
+NO_SPAN = _NoSpan()
+
+
+class _OpenSpan:
+    """An open child span: pushes itself as the thread's active span,
+    pops on exit. `record_request`, when given, overrides the
+    recording's remote-recording bit while the span is open (a
+    statement with SET tracing = cluster nested under a root that was
+    opened for local recording only)."""
+    __slots__ = ("span", "parent", "rec_req", "prev_req")
+
+    def __init__(self, s: Span, parent: Span, rec_req):
+        self.span, self.parent, self.rec_req = s, parent, rec_req
+
+    def __enter__(self) -> Span:
+        _tls.span = self.span
+        if self.rec_req is not None:
+            self.prev_req = getattr(_tls, "rec_req", True)
+            _tls.rec_req = bool(self.rec_req)
+        return self.span
+
+    def __exit__(self, *exc):
+        self.span.end_ns = time.monotonic_ns()
+        _tls.span = self.parent
+        if self.rec_req is not None:
+            _tls.rec_req = self.prev_req
+        return False
+
+
+def span(name: str, record_request: Optional[bool] = None, **tags):
+    """Child span of whatever is recording on this thread; the shared
+    no-op (yields None) when nothing is."""
     parent = current_span()
-    s = Span(name, time.monotonic_ns(), tags=dict(tags),
-             span_id=next(_ids),
-             trace_id=parent.trace_id if parent is not None else 0)
-    if parent is not None:
-        parent.children.append(s)
-    _tls.span = s
-    try:
-        yield s
-    finally:
-        s.end_ns = time.monotonic_ns()
-        _tls.span = parent
+    if parent is None:
+        return NO_SPAN
+    s = Span(name, time.monotonic_ns(), tags=tags, span_id=next(_ids),
+             trace_id=parent.trace_id)
+    parent.children.append(s)
+    return _OpenSpan(s, parent, record_request)
+
+
+def record(name: str, start_ns: int, end_ns: int, **tags) -> Optional[Span]:
+    """A finished child span from stamps taken elsewhere (a wait that
+    ended on another thread: the frame queue, the mesh dispatcher).
+    None when nothing is recording."""
+    parent = current_span()
+    if parent is None:
+        return None
+    s = Span(name, start_ns, end_ns, tags=tags, span_id=next(_ids),
+             trace_id=parent.trace_id)
+    parent.children.append(s)
+    return s
 
 
 def event(name: str, **tags) -> Optional[Span]:
@@ -175,7 +266,9 @@ def event(name: str, **tags) -> Optional[Span]:
 
 @contextmanager
 def capture(name: str = "trace", remote_ctx: Optional[dict] = None,
-            record_request: Optional[bool] = None, **tags):
+            record_request: Optional[bool] = None,
+            start_ns: Optional[int] = None, collect: bool = False,
+            **tags):
     """Collect a full recording rooted at `name` on this thread.
 
     `remote_ctx` is the {"tid","sid","rec"?} dict from an inbound RPC
@@ -189,10 +282,14 @@ def capture(name: str = "trace", remote_ctx: Optional[dict] = None,
     keeps the recording gateway-local. Default: inherit the inbound
     frame's bit when remote_ctx is given, else True (every existing
     capture — EXPLAIN ANALYZE, slow sampling, tests — wants the
-    stitched tree)."""
+    stitched tree).
+
+    `start_ns` backdates the root to a stamp taken before this thread
+    had the work (the frame's arrival). `collect` marks a statement
+    root: the collector, while on, keeps it when it closes."""
     prev = current_span()
     prev_req = getattr(_tls, "rec_req", True)
-    root = Span(name, time.monotonic_ns(), tags=dict(tags),
+    root = Span(name, start_ns or time.monotonic_ns(), tags=dict(tags),
                 span_id=next(_ids))
     if remote_ctx:
         root.trace_id = int(remote_ctx.get("tid", 0))
@@ -211,6 +308,9 @@ def capture(name: str = "trace", remote_ctx: Optional[dict] = None,
         root.end_ns = time.monotonic_ns()
         _tls.span = prev
         _tls.rec_req = prev_req
+        kept = _collected
+        if collect and kept is not None:
+            kept.append(root)
 
 
 def tag(**tags) -> None:
