@@ -714,6 +714,13 @@ def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
     traced dtype falls outside the envelope (caller falls back to the
     XLA segment path).
 
+    The kernel is handed the aggregates' ARGUMENTS — each distinct
+    expression evaluated and masked once, whatever the number of
+    aggregates over it — and a static layout of the limb, count and
+    shadow rows to build from them in VMEM; no [rows, n] matrix is
+    written to HBM (TPC-H Q1: 0.4 GB of words where 2 GiB of f32 rows
+    were, PERF.md PR 26).
+
     With axis_name set (SPMD dense plans), per-shard kernel partials
     merge with ICI collectives: i32 limb/count rows psum EXACTLY
     (limb_width bounds them by the GLOBAL max_group_rows, so summed
@@ -724,12 +731,21 @@ def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
     from ..ops.pallas import groupagg as pg
     from ..ops.pallas import groupagg_large as pgl
     from ..ops.pallas import paritygate as _pgate
+    from ..sql.pushdown import expr_key
     n = b.n
     sel = b.sel
-    # the kernel's operands: arguments evaluated, sums split into limbs
+    # the kernel's operands: each DISTINCT argument evaluated once and
+    # masked. The limb, count and shadow rows of the matmul are a static
+    # layout over them, which the kernel builds per row block in VMEM
     with jax.named_scope("operands"):
-        argdata = {i: argf(ctx) for i, (a, argf) in enumerate(aggfs)
-                   if argf is not None}
+        argvals = {}    # distinct argument -> its traced (data, valid)
+        arg_of = {}     # agg index -> its argument's key
+        for i, (a, argf) in enumerate(aggfs):
+            if argf is not None:
+                arg_of[i] = j = expr_key(a.arg)
+                if j not in argvals:
+                    argvals[j] = argf(ctx)
+        argdata = {i: argvals[j] for i, j in arg_of.items()}
         for i, (a, _) in enumerate(aggfs):
             if a.func in ("sum", "sum_int", "avg", "min", "max") \
                     and a.arg is not None \
@@ -739,23 +755,44 @@ def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
                 # sums and the MIN/MAX hi-limb both need real ints
                 if argdata[i][0].dtype not in (jnp.int64, jnp.int32):
                     return None
-        f_cols, f_tags = [], []     # f32-accumulated matmul columns
-        i_cols, i_tags = [], []     # i32-accumulated (limb/count) columns
+        # per distinct argument:
+        masks, mask_of = [], {}     # sel & valid
+        sources, src_of = [], {}    # the masked argument of exact sums
+        f_cols, fcol_of = [], {}    # the f32 column of float sums
+        f_rows, i_rows = [], []     # the layout; a row two aggregates
+        # ask for (one argument, the same limb) is computed once
         mm_cols, mm_ops_l, mm_tags = [], [], []
         want_rep = False
-        exact = {}  # agg index -> (limb width w, limb count k)
+        exact = {}  # agg index -> (source, limb count k)
+        # the limb width tracks the plan's (possibly autotuned)
+        # block_rows so the f32 block-partial exactness bound holds at
+        # that block
+        w = pgl.limb_width(n, max_group_rows,
+                           block_rows=params.pallas_block_rows,
+                           cap=params.pallas_limb_cap)
+
+        def add(rows, row):
+            if row not in rows:
+                rows.append(row)
+
+        def sum_bits(a):    # the bits an exact sum's argument can hold
+            if a.arg_nonneg and a.arg_max_abs:
+                return max(1, int(a.arg_max_abs).bit_length())
+            return 64
+
         for i, (a, _) in enumerate(aggfs):
             if a.func == "count_rows":
-                i_cols.append(sel.astype(jnp.float32))
-                i_tags.append(("cnt", i))
-                continue
+                continue  # the liveness row: selected rows a group
             if a.func == "any":
                 want_rep = True  # rides the REPMIN slot + a host gather
                 continue
             d0, v0 = argdata[i]
-            m = jnp.logical_and(sel, v0)
-            i_cols.append(m.astype(jnp.float32))  # validity + avg divisor
-            i_tags.append(("cnt", i))
+            j = arg_of[i]
+            if j not in mask_of:
+                mask_of[j] = len(masks)
+                masks.append(jnp.logical_and(sel, v0))
+            m = masks[mask_of[j]]
+            add(i_rows, ("count", mask_of[j]))  # validity + avg divisor
             if a.func == "count":
                 continue
             if a.func in ("min", "max"):
@@ -777,42 +814,40 @@ def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
                 mm_tags.append(("mm", i))
                 continue
             if a.arg.type.family == Family.FLOAT:
-                f_cols.append(jnp.where(m, d0, 0).astype(jnp.float32))
-                f_tags.append(("fsum", i))
+                if j not in fcol_of:
+                    fcol_of[j] = len(f_cols)
+                    f_rows.append(("f", fcol_of[j]))
+                    f_cols.append(jnp.where(m, d0, 0).astype(jnp.float32))
                 continue
-            # exact int64 sum as w-bit i32 limbs, split OUTSIDE the
-            # kernel (no 64-bit lanes in Mosaic) and recombined below —
-            # the same decomposition as agg._group_sum_i64_limbs. The
-            # width tracks the plan's (possibly autotuned) block_rows so
-            # the f32 block-partial exactness bound holds at that block
-            w = pgl.limb_width(n, max_group_rows,
-                               block_rows=params.pallas_block_rows,
-                               cap=params.pallas_limb_cap)
-            bits = 64
-            if a.arg_nonneg and a.arg_max_abs:
-                bits = max(1, int(a.arg_max_abs).bit_length())
-            k = -(-bits // w)
-            exact[i] = (w, k)
-            d64 = d0.astype(jnp.int64)
-            dz = jnp.where(m, d64, jnp.zeros_like(d64))
-            lmask = jnp.int64((1 << w) - 1)
-            for jl in range(k):
-                limb = jax.lax.shift_right_logical(
-                    dz, jnp.int64(jl * w)) & lmask
-                i_cols.append(limb.astype(jnp.int32).astype(jnp.float32))
-                i_tags.append(("limb", i, jl))
+            # exact int64 sum as w-bit i32 limbs, cut out of the
+            # argument's words INSIDE the kernel and recombined below —
+            # the same decomposition as agg._group_sum_i64_limbs
+            bits = sum_bits(a)
+            if j not in src_of:
+                # a proven 31-bit argument travels as ONE word, unless
+                # another sum over it comes without the proof
+                narrow = all(sum_bits(x) < 32
+                             for ii, (x, _) in enumerate(aggfs)
+                             if arg_of.get(ii) == j
+                             and x.func in ("sum", "sum_int", "avg"))
+                src_of[j] = len(sources)
+                d64 = d0.astype(jnp.int64)
+                dz = jnp.where(m, d64, jnp.zeros_like(d64))
+                sources.append(dz.astype(jnp.int32) if narrow else dz)
+            src = src_of[j]
+            exact[i] = (src, -(-bits // w))
+            for row in pgl.limb_rows(src, bits, w):
+                add(i_rows, row)
             # f32 shadow sum feeds the overflow sentinel
-            f_cols.append(jnp.where(m, d64, 0).astype(jnp.float32))
-            f_tags.append(("shadow", i))
-        i_cols.append(sel.astype(jnp.float32))  # group liveness
-        i_tags.append(("live",))
+            add(f_rows, ("shadow", src))
+        i_rows.append(("live",))  # group liveness
 
-    mat = tuple(f_cols) + tuple(i_cols)
-    mat_int = (False,) * len(f_cols) + (True,) * len(i_cols)
+    layout = tuple(f_rows) + tuple(i_rows)
     with jax.named_scope("kernel"):
         acc_f, acc_i = pgl.large_group_aggregate(
-            gid, sel, mat, tuple(mm_cols), num_groups=num_groups,
-            mat_int=mat_int, mm_ops=tuple(mm_ops_l), want_rep=want_rep,
+            gid, sel, tuple(sources), tuple(masks), tuple(f_cols),
+            tuple(mm_cols), num_groups=num_groups, layout=layout,
+            mm_ops=tuple(mm_ops_l), want_rep=want_rep,
             group_tile=params.pallas_group_tile,
             block_rows=params.pallas_block_rows,
             interpret=params.pallas_interpret)
@@ -822,21 +857,22 @@ def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
 
     # after the kernel: limbs recombined, shards merged, sentinels
     with jax.named_scope("finalize"):
-        frow = {t: r for r, t in enumerate(f_tags)}
-        irow = {t: r for r, t in enumerate(i_tags)}
-        mmrow = {t: len(f_cols) + r for r, t in enumerate(mm_tags)}
-        live = ps(acc_i[irow[("live",)], :]) > 0
+        frow = {t: r for r, t in enumerate(f_rows)}
+        irow = {t: r for r, t in enumerate(i_rows)}
+        mmrow = {t: len(f_rows) + r for r, t in enumerate(mm_tags)}
+        nsel = ps(acc_i[irow[("live",)], :])
+        live = nsel > 0
         rep = rep_live = None
         if want_rep:
-            racc = acc_i[len(i_cols), :]  # REPMIN row (n = empty group)
+            racc = acc_i[len(i_rows), :]  # REPMIN row (n = empty group)
             rep_live = racc < n           # shard-LOCAL: rep ids are local
             rep = jnp.minimum(racc, n - 1)
 
         overflow = jnp.bool_(False)
         aggs_out = []
         for i, (a, _) in enumerate(aggfs):
-            if a.func in ("count_rows", "count"):
-                d = ps(acc_i[irow[("cnt", i)], :]).astype(jnp.int64)
+            if a.func == "count_rows":
+                d = nsel.astype(jnp.int64)
                 aggs_out.append((d, jnp.ones_like(d, dtype=jnp.bool_)))
                 continue
             if a.func == "any":
@@ -853,8 +889,12 @@ def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
                                          axis_name) > 0
                 aggs_out.append((d, v))
                 continue
-            cnt = ps(acc_i[irow[("cnt", i)], :])
+            cnt = ps(acc_i[irow[("count", mask_of[arg_of[i]])], :])
             nonempty = cnt > 0
+            if a.func == "count":
+                d = cnt.astype(jnp.int64)
+                aggs_out.append((d, jnp.ones_like(d, dtype=jnp.bool_)))
+                continue
             if a.func in ("min", "max"):
                 d = acc_f[mmrow[("mm", i)], :]
                 if axis_name:
@@ -884,15 +924,16 @@ def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
                 aggs_out.append((d.astype(jnp.float64), nonempty))
                 continue
             if i not in exact:  # float sum/avg ("on" or promoted)
-                d = ps(acc_f[frow[("fsum", i)], :]).astype(jnp.float64)
+                d = ps(acc_f[frow[("f", fcol_of[arg_of[i]])], :]) \
+                    .astype(jnp.float64)
                 if a.func == "avg":
                     d = d / jnp.maximum(cnt, 1).astype(jnp.float64)
                 aggs_out.append((d, nonempty))
                 continue
-            w, k = exact[i]
+            src, k = exact[i]
             total = jnp.zeros(cnt.shape, jnp.int64)
             for jl in range(k):
-                s = ps(acc_i[irow[("limb", i, jl)], :])
+                s = ps(acc_i[irow[("limb", src, jl * w, w)], :])
                 # wrapping IS int64 modular arithmetic — bit-identical to
                 # _group_sum_i64_limbs' recombination
                 total = total + (s.astype(jnp.int64) << jnp.int64(jl * w))
@@ -907,7 +948,7 @@ def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
             # psum makes the bound global: every shard agrees
             cannot = ps(jnp.float64(n) * jnp.max(jnp.abs(dz64))) \
                 < jnp.float64(2 ** 62)
-            sh = ps(acc_f[frow[("shadow", i)], :]).astype(jnp.float64)
+            sh = ps(acc_f[frow[("shadow", src)], :]).astype(jnp.float64)
             err = jnp.abs(total.astype(jnp.float64) - sh)
             tol = jnp.maximum(jnp.abs(sh) * 1e-2, 1e12)
             overflow = jnp.logical_or(

@@ -393,6 +393,12 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             "aggregations compiled on the XLA segment path while "
             "pallas_groupagg was enabled (outside a kernel envelope)")
         self.metrics.func_counter(
+            "exec.pallas.kernel.operand_bytes",
+            lambda: _ga.OPERAND_BYTES.value("large"),
+            "bytes of the HBM arrays handed to the large-G kernel, a "
+            "build: the aggregates' arguments as 32-bit words, the "
+            "packed masks and the group ids, not the limb rows")
+        self.metrics.func_counter(
             "exec.pallas.rows",
             lambda: _ga.ROWS.value(),
             "rows offered to Pallas group-aggregate kernels at trace "

@@ -134,9 +134,10 @@ def _save(root: str, backend: str, tile: tuple[int, int, int],
 def _time_candidate(gt: int, br: int, cap: int, n: int,
                     num_groups: int, interpret: bool) -> float:
     """Median-of-3 wall time of one kernel call at a synthetic shape
-    modelled on the q18-class plans the kernel serves: one f32 shadow
-    column, count + liveness + int64-limb i32 columns (limb count
-    follows the candidate's own width bound), one MIN slot."""
+    modelled on the q18-class plans the kernel serves: one int64
+    argument with its f32 shadow row, count + liveness + limb rows
+    (limb count follows the candidate's own width bound), one MIN
+    slot."""
     import time
 
     import jax
@@ -144,23 +145,21 @@ def _time_candidate(gt: int, br: int, cap: int, n: int,
     import numpy as np
 
     w = pgl.limb_width(n, n, block_rows=br, cap=cap)
-    k = -(-64 // w)
     rng = np.random.default_rng(n + gt + br)
     gid = jnp.asarray(rng.integers(0, num_groups, n), jnp.int32)
     sel = jnp.asarray(rng.random(n) < 0.9)
-    selsf = jnp.asarray(sel, jnp.float32)
-    vals = jnp.asarray(rng.integers(0, 1 << w, n), jnp.float32) * selsf
-    mat = (jnp.asarray(rng.random(n), jnp.float32),) \
-        + (vals,) * k + (selsf, selsf)
-    mat_int = (False,) + (True,) * (k + 2)
+    src = jnp.where(sel, jnp.asarray(
+        rng.integers(-(1 << 62), 1 << 62, n, dtype=np.int64)), 0)
+    layout = (("shadow", 0),) + pgl.limb_rows(0, 64, w) \
+        + (("count", 0), ("live",))
     mm = (jnp.where(sel, jnp.asarray(rng.random(n), jnp.float32),
                     jnp.float32(np.inf)),)
 
     def call():
         return pgl.large_group_aggregate(
-            gid, sel, mat, mm, num_groups=num_groups, mat_int=mat_int,
-            mm_ops=(pgl.MIN,), want_rep=True, group_tile=gt,
-            block_rows=br, interpret=interpret)
+            gid, sel, (src,), (sel,), (), mm, num_groups=num_groups,
+            layout=layout, mm_ops=(pgl.MIN,), want_rep=True,
+            group_tile=gt, block_rows=br, interpret=interpret)
 
     jax.block_until_ready(call())  # compile outside the timed window
     times = []

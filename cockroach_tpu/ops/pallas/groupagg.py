@@ -128,6 +128,8 @@ BUILDS = _KernelTally()      # kernel (re)builds, per kernel kind
 ROWS = _KernelTally()        # rows offered to a kernel at trace time
 FALLBACKS = _KernelTally()   # aggregations that wanted a kernel but
                              # compiled on the XLA segment path
+OPERAND_BYTES = _KernelTally()   # bytes of the HBM arrays a build hands
+                                 # its kernel (what XLA writes for it)
 
 
 @functools.partial(jax.jit, static_argnames=("num_groups", "ops",

@@ -22,8 +22,9 @@ Dtype envelope — wider than the small kernel's f32-only one:
   partial is exact for integer-valued columns while
   blk * max|value| < 2^24 (f32's integer range).
 - exact int64 SUMs ride the limb decomposition `ops/agg.py` proves
-  correct: the caller splits each 64-bit argument into w-bit i32
-  limbs OUTSIDE the kernel (Mosaic has no 64-bit lanes), the kernel
+  correct: each 64-bit argument reaches the kernel as its two 32-bit
+  words (Mosaic has no 64-bit lanes), the kernel cuts the w-bit limbs
+  out of them per row block, in VMEM, and
   accumulates each limb column in an i32 tile (the f32 matmul block
   partial is exact while blk*(2^w-1) < 2^24, i.e. w <= 24-log2(blk);
   the per-group i32 accumulator is exact while
@@ -52,7 +53,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .groupagg import BUILDS, FALLBACKS, LANES, MAX, MIN, ROWS  # noqa: F401
+from .groupagg import (  # noqa: F401
+    BUILDS, FALLBACKS, LANES, MAX, MIN, OPERAND_BYTES, ROWS)
 
 # group-domain tile (VMEM accumulator minor dim; multiple of 128 lanes)
 GROUP_TILE = 512
@@ -86,11 +88,50 @@ def limb_width(n: int, max_group_rows: int,
     return max(1, w)
 
 
-def _kernel(gid_ref, sel_ref, mat_ref, *refs, n_mat_f: int, n_mat: int,
+def limb_rows(src: int, bits: int, w: int) -> tuple:
+    """The layout rows that cover the low `bits` bits of source `src`
+    in w-bit limbs, least significant first."""
+    return tuple(("limb", src, j * w, w) for j in range(-(-bits // w)))
+
+
+def _srl(x, s: int):
+    return jax.lax.shift_right_logical(x, np.int32(s)) if s else x
+
+
+def _limb(lo, hi, shift: int, width: int):
+    """Bits [shift, shift + width) of the 64-bit value (hi, lo) as
+    f32: one shift inside a word, two where the limb straddles the
+    32-bit boundary. Logical shifts, so bits past 63 read 0."""
+    if shift >= 32:
+        assert hi is not None, "a one-word source has no bits past 31"
+        x = _srl(hi, shift - 32)
+    else:
+        x = _srl(lo, shift)
+        if hi is not None and shift + width > 32:
+            x = x | (hi << np.int32(32 - shift))
+    return (x & np.int32((1 << width) - 1)).astype(jnp.float32)
+
+
+def _shadow(lo, hi):
+    """f32 approximation of the 64-bit value (hi, lo): it only feeds
+    the overflow sentinel, which tolerates 1e-2 relative."""
+    if hi is None:
+        return lo.astype(jnp.float32)  # one word: 0 <= v < 2^31
+    ulo = _srl(lo, 16).astype(jnp.float32) * np.float32(1 << 16) \
+        + (lo & np.int32(0xFFFF)).astype(jnp.float32)
+    return hi.astype(jnp.float32) * np.float32(2.0 ** 32) + ulo
+
+
+def _kernel(gid_ref, *refs, layout: tuple, src_words: tuple,
+            n_mwords: int, n_words: int, n_f: int, n_mat_f: int,
             mm_ops: tuple, want_rep: bool, group_tile: int, blk: int,
             n: int, nf: int, ni: int):
-    mm_refs = refs[:len(mm_ops)]
-    outs = list(refs[len(mm_ops):])
+    n_mat = len(layout)
+    mask_refs, refs = refs[:n_mwords], refs[n_mwords:]
+    word_refs, refs = refs[:n_words], refs[n_words:]
+    f_refs, refs = refs[:n_f], refs[n_f:]
+    mm_refs, refs = refs[:len(mm_ops)], refs[len(mm_ops):]
+    *outs, mat_ref = refs   # mat_ref: the [n_mat, blk] f32 VMEM scratch
     acc_f_ref, acc_i_ref = outs[:2]
     acc_mm_ref = outs[2] if mm_ops else None
     acc_rep_ref = outs[-1] if want_rep else None
@@ -110,9 +151,35 @@ def _kernel(gid_ref, sel_ref, mat_ref, *refs, n_mat_f: int, n_mat: int,
             acc_rep_ref[:, :] = jnp.full(
                 (group_tile, 1), np.int32(n), jnp.int32)
 
-    # rows ride the LANE axis: every per-row input is a (1, blk) or
-    # (n_mat, blk) block of a lane-dense array, and the one-hot is
-    # built transposed, [GT, blk] (group ids down the sublanes)
+    # the matmul operand of this row block, built here from the
+    # aggregates' ARGUMENTS: each source's words and each mask word are
+    # loaded once, every limb/count/shadow row derived on the VPU and
+    # written to the scratch. The [n_mat, n] matrix exists nowhere else
+    words = [ref[:, :] for ref in word_refs]
+    srcs = [(words[lo], None if hi is None else words[hi])
+            for lo, hi in src_words]
+    mwords = [ref[:, :] for ref in mask_refs]
+
+    def mask_bit(k):
+        return _srl(mwords[k // 32], k % 32) & np.int32(1)
+
+    for r, row in enumerate(layout):
+        kind = row[0]
+        if kind == "f":
+            v = f_refs[row[1]][:, :]
+        elif kind == "shadow":
+            v = _shadow(*srcs[row[1]])
+        elif kind == "limb":
+            v = _limb(*srcs[row[1]], row[2], row[3])
+        elif kind == "count":      # bit 0 is sel, mask k rides bit k + 1
+            v = mask_bit(row[1] + 1).astype(jnp.float32)
+        else:                      # "live"
+            v = mask_bit(0).astype(jnp.float32)
+        mat_ref[r:r + 1, :] = v
+
+    # rows ride the LANE axis: every per-row input is a (1, blk) block
+    # of a lane-dense array, and the one-hot is built transposed,
+    # [GT, blk] (group ids down the sublanes)
     ids = j * group_tile + jax.lax.broadcasted_iota(
         jnp.int32, (group_tile, blk), 0)
     onehot = gid_ref[:, :] == ids  # (1, blk) == (GT, blk) -> broadcast
@@ -145,7 +212,7 @@ def _kernel(gid_ref, sel_ref, mat_ref, *refs, n_mat_f: int, n_mat: int,
         acc_mm_ref[:, r:r + 1] = comb(acc_mm_ref[:, r:r + 1], red)
 
     if want_rep:
-        sel = sel_ref[:, :] != 0
+        sel = mask_bit(0) != 0
         rid = i * blk + jax.lax.broadcasted_iota(
             jnp.int32, (group_tile, blk), 1)
         rv = jnp.where(jnp.logical_and(onehot, sel), rid, np.int32(n))
@@ -154,45 +221,61 @@ def _kernel(gid_ref, sel_ref, mat_ref, *refs, n_mat_f: int, n_mat: int,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "num_groups", "mat_int", "mm_ops", "want_rep", "group_tile",
+    "num_groups", "layout", "mm_ops", "want_rep", "group_tile",
     "block_rows", "interpret"))
-def large_group_aggregate(gid, sel, mat_values: tuple, mm_values: tuple,
-                          num_groups: int, mat_int: tuple,
+def large_group_aggregate(gid, sel, sources: tuple, masks: tuple,
+                          f_values: tuple, mm_values: tuple,
+                          num_groups: int, layout: tuple,
                           mm_ops: tuple = (), want_rep: bool = False,
                           group_tile: int = GROUP_TILE,
                           block_rows: int = BLOCK_ROWS,
                           interpret: bool = False):
     """One-pass large-G grouped aggregation.
 
-    gid: int32[n] dense ids (0..num_groups-1); rows outside [0, G) or
-    with sel False simply match no one-hot column, so the caller folds
-    `sel` into the matmul columns (pre-masked to 0) and the kernel
-    only consults `sel` for the REPMIN slot. mat_values: one [n]
-    column per matmul slot, f32-valued; the first columns accumulate
-    in f32 rows, the `mat_int[k]` == True tail in i32 rows (limb and
-    count columns — small non-negative ints). mm_values/mm_ops:
-    MIN/MAX slots, pre-masked to their +/-inf identities. Returns
-    (f32[NF, num_groups], i32[NI, num_groups]) where
-    NF = max(1, n_f + len(mm_ops)) (f sums first, then MIN/MAX rows)
-    and NI = max(1, n_i + want_rep) (i sums first, then the rep row:
-    min selected row id, n when the group is empty).
+    gid: int32[n] dense ids (0..num_groups-1); rows outside [0, G)
+    match no one-hot column. The kernel is handed the aggregates'
+    ARGUMENTS and builds its matmul operand itself, per row block, in
+    VMEM:
 
-    Every operand reaches the kernel lane-dense — per-row vectors as
-    [1, n], the matmul columns stacked [n_mat, n]. A per-row [n, 1]
-    operand is tiled (8, 128) in HBM, 128x its size: at n = 2^23 one
-    such column is 4 GB, and XLA refused TPC-H Q1's 61 of them at SF1
-    ("Used 208.14G of 15.75G hbm").
+    - sources: one integer [n] array per distinct exact-sum argument,
+      pre-masked to 0 where the row does not take part. int64 travels
+      as its two 32-bit words; an int32 source (the caller proved
+      0 <= v < 2^31) as one.
+    - masks: bool[n], each already ANDed with `sel`; with `sel` they
+      are packed 32 to an int32 word (bit 0 is `sel`).
+    - f_values: f32[n] float-sum columns, pre-masked to 0.
+    - layout: one entry per matmul row, f32-accumulated rows first —
+      ("f", j) is f_values[j], ("shadow", s) an f32 approximation of
+      source s — then the i32-accumulated ones: ("limb", s, shift,
+      width) is bits [shift, shift + width) of source s (limb_rows),
+      ("count", k) counts masks[k], ("live",) counts `sel`.
+    - mm_values/mm_ops: MIN/MAX slots, pre-masked to their +/-inf
+      identities.
+
+    Returns (f32[NF, num_groups], i32[NI, num_groups]): the layout's
+    f rows then the MIN/MAX rows (NF >= 1), its i rows then, with
+    want_rep, the rep row: min selected row id, n when the group is
+    empty (NI >= 1).
+
+    Every operand reaches the kernel lane-dense, as [1, n]: a per-row
+    [n, 1] operand is tiled (8, 128) in HBM, 128x its size. The
+    [n_mat, n] f32 matrix of limb, count and shadow rows is never an
+    HBM array (TPC-H Q1 at SF1: 61 rows, 2 GiB a statement): the
+    kernel reads each source once and splits it on the VPU, under the
+    matmul. With several group tiles (gtiles > 1) the split is redone
+    for every tile, against an HBM read per tile that is the words',
+    not the rows', size.
     """
     n = gid.shape[0]
     BUILDS.bump("large")
     ROWS.bump("large", n)
-    n_mat = len(mat_values)
-    assert n_mat >= 1 and len(mat_int) == n_mat
-    n_mat_i = sum(bool(b) for b in mat_int)
-    n_mat_f = n_mat - n_mat_i
-    # f columns first, then i columns — the kernel slices `part` once
-    assert all(not b for b in mat_int[:n_mat_f]) and \
-        all(bool(b) for b in mat_int[n_mat_f:])
+    n_mat = len(layout)
+    assert n_mat >= 1
+    n_mat_f = sum(row[0] in ("f", "shadow") for row in layout)
+    # f rows first, then i rows — the kernel slices `part` once
+    assert all(row[0] in ("limb", "count", "live")
+               for row in layout[n_mat_f:])
+    n_mat_i = n_mat - n_mat_f
     blk = row_block(n, block_rows)
     gtiles = -(-num_groups // group_tile)
     gp = gtiles * group_tile
@@ -200,17 +283,48 @@ def large_group_aggregate(gid, sel, mat_values: tuple, mm_values: tuple,
     ni = max(1, n_mat_i)
     n_mm = len(mm_ops)
 
-    def kernel(gid_ref, sel_ref, mat_ref, *refs):
-        _kernel(gid_ref, sel_ref, mat_ref, *refs, n_mat_f=n_mat_f,
-                n_mat=n_mat, mm_ops=mm_ops, want_rep=want_rep,
-                group_tile=group_tile, blk=blk, n=n, nf=nf, ni=ni)
+    def row(x, dtype):
+        return x.astype(dtype).reshape(1, n)
+
+    # what XLA still writes for the kernel: the arguments evaluated and
+    # masked, as 32-bit words (on TPU an int64 already lives as two)
+    with jax.named_scope("operands"):
+        words, src_words = [], []
+        for s in sources:
+            if s.dtype == jnp.int32:
+                src_words.append((len(words), None))
+                words.append(row(s, jnp.int32))
+            else:
+                s = s.astype(jnp.int64)
+                src_words.append((len(words), len(words) + 1))
+                words.append(row(s, jnp.int32))   # truncates: low word
+                words.append(row(s >> jnp.int64(32), jnp.int32))
+        bits = [sel] + list(masks)
+        mwords = []
+        for k0 in range(0, len(bits), 32):
+            w = jnp.zeros((n,), jnp.uint32)
+            for k, m in enumerate(bits[k0:k0 + 32]):
+                w = w | (m.astype(jnp.uint32) << jnp.uint32(k))
+            mwords.append(jax.lax.bitcast_convert_type(
+                w, jnp.int32).reshape(1, n))
+        args = (row(gid, jnp.int32), *mwords, *words,
+                *[row(v, jnp.float32) for v in f_values],
+                *[row(v, jnp.float32) for v in mm_values])
+    # a count row past the masks handed in would read a zero bit
+    assert all(r[1] < len(masks) for r in layout if r[0] == "count")
+    OPERAND_BYTES.bump("large", sum(a.nbytes for a in args))
+
+    def kernel(gid_ref, *refs):
+        _kernel(gid_ref, *refs, layout=layout, src_words=tuple(src_words),
+                n_mwords=len(mwords), n_words=len(words),
+                n_f=len(f_values), n_mat_f=n_mat_f, mm_ops=mm_ops,
+                want_rep=want_rep, group_tile=group_tile, blk=blk, n=n,
+                nf=nf, ni=ni)
 
     # i32 index-map coordinates: under the engine's jax_enable_x64 a
     # literal 0 traces as i64, which Mosaic rejects
     row1 = pl.BlockSpec((1, blk), lambda j, i: (jnp.int32(0), i),
                         memory_space=pltpu.VMEM)
-    matspec = pl.BlockSpec((n_mat, blk), lambda j, i: (jnp.int32(0), i),
-                           memory_space=pltpu.VMEM)
 
     def by_group(rows):   # [rows, G] sums: group ids along the lanes
         return pl.BlockSpec((rows, group_tile),
@@ -232,23 +346,16 @@ def large_group_aggregate(gid, sel, mat_values: tuple, mm_values: tuple,
         out_shape.append(jax.ShapeDtypeStruct((gp, 1), jnp.int32))
         out_specs.append(by_slot(1))
 
-    # the operand matrix is written out in full before the kernel
-    # reads it: a phase of its own to a profile (`operands`). The
-    # kernel's own scope is the caller's: XLA names the custom call by
-    # the last component of its path, this function's name
-    with jax.named_scope("operands"):
-        args = (gid.astype(jnp.int32).reshape(1, n),
-                sel.astype(jnp.int32).reshape(1, n),
-                jnp.stack([v.astype(jnp.float32) for v in mat_values],
-                          axis=0),
-                *[v.astype(jnp.float32).reshape(1, n) for v in mm_values])
+    # the kernel's own scope is the caller's: XLA names the custom call
+    # by the last component of its path, this function's name
     with jax.enable_x64(False):
         outs = pl.pallas_call(
             kernel,
             out_shape=tuple(out_shape),
             grid=(gtiles, n // blk),
-            in_specs=[row1, row1, matspec] + [row1] * n_mm,
+            in_specs=[row1] * len(args),
             out_specs=tuple(out_specs),
+            scratch_shapes=[pltpu.VMEM((n_mat, blk), jnp.float32)],
             interpret=interpret,
         )(*args)
     acc_f, acc_i = outs[0][:, :num_groups], outs[1][:, :num_groups]

@@ -136,8 +136,8 @@ def _fuzz_int_minmax(interpret: bool) -> bool:
               jnp.where(sel, hi.astype(jnp.float32),
                         jnp.float32(-np.inf)))
         acc_f, _ = pgl.large_group_aggregate(
-            gid, sel, (sel.astype(jnp.float32),), mm, num_groups=g,
-            mat_int=(True,), mm_ops=(pgl.MIN, pgl.MAX),
+            gid, sel, (), (), (), mm, num_groups=g,
+            layout=(("live",),), mm_ops=(pgl.MIN, pgl.MAX),
             interpret=interpret)
         # no f32 sum columns here, so the MM rows lead acc_f
         for row, fold in ((0, aggops.group_min),
@@ -169,9 +169,8 @@ def _fuzz_float_sum(interpret: bool) -> bool:
         d = jnp.asarray(rng.standard_normal(n) * 1e3)
         col = jnp.where(sel, d, 0).astype(jnp.float32)
         acc_f, _ = pgl.large_group_aggregate(
-            gid, sel, (col, sel.astype(jnp.float32)), (),
-            num_groups=g, mat_int=(False, True),
-            interpret=interpret)
+            gid, sel, (), (), (col,), (), num_groups=g,
+            layout=(("f", 0), ("live",)), interpret=interpret)
         got = np.asarray(acc_f[0, :].astype(jnp.float64))
         want = np.asarray(aggops.group_sum(
             d.astype(jnp.float64), gid, sel, g))

@@ -28,7 +28,7 @@ from .bound import (BAggRef, BCol, BConst, BExpr, BWinRef,
 from .types import Family
 
 
-def _expr_key(e: BExpr) -> str:
+def expr_key(e: BExpr) -> str:
     """Structural dedup key. repr() alone is unsafe: numpy summarizes
     arrays >1000 elements ('[False False ... False]'), so two distinct
     dictionary LUTs could collide — include a digest of every table's
@@ -36,11 +36,12 @@ def _expr_key(e: BExpr) -> str:
     import hashlib
     h = hashlib.sha256(repr(e).encode())
     for x in walk(e):
-        t = getattr(x, "table", None)
-        if t is not None and hasattr(t, "tobytes"):
-            h.update(t.tobytes())
-        elif isinstance(t, (list, tuple)):
-            h.update(repr(t).encode())
+        for name in ("table", "null_table"):
+            t = getattr(x, name, None)
+            if t is not None and hasattr(t, "tobytes"):
+                h.update(t.tobytes())
+            elif isinstance(t, (list, tuple)):
+                h.update(repr(t).encode())
     return h.hexdigest()
 
 
@@ -111,7 +112,7 @@ def push_build_exprs(root: plan.PlanNode) -> list:
             return None
         for alias, (j, cols) in by_alias.items():
             if refs <= cols:
-                key = (alias, _expr_key(e))
+                key = (alias, expr_key(e))
                 name = created.get(key)
                 if name is None:
                     name = f"{alias}.__push{counter[0]}"

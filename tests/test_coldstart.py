@@ -391,15 +391,13 @@ class TestAutotune:
                             (1024, 2048, 22)):
             w = pgl.limb_width(n, n, block_rows=br, cap=cap)
             k = -(-bits // w)
-            limbs = [np.where(sel, (vals >> (j * w)) & ((1 << w) - 1),
-                              0) for j in range(k)]
-            mat = tuple(jnp.asarray(l, jnp.float32) for l in limbs) \
-                + (jnp.asarray(sel, jnp.float32),)
             mm = (jnp.asarray(np.where(sel, vf32, np.float32(np.inf)),
                               jnp.float32),)
             _, acc_i = pgl.large_group_aggregate(
-                jnp.asarray(gid), jnp.asarray(sel), mat, mm,
-                num_groups=G, mat_int=(True,) * (k + 1),
+                jnp.asarray(gid), jnp.asarray(sel),
+                (jnp.asarray(np.where(sel, vals, 0)),), (), (), mm,
+                num_groups=G,
+                layout=pgl.limb_rows(0, bits, w) + (("live",),),
                 mm_ops=(pgl.MIN,), want_rep=False, group_tile=gt,
                 block_rows=br, interpret=True)
             acc_i = np.asarray(acc_i).astype(np.int64)

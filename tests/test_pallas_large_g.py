@@ -3,9 +3,11 @@
 Three layers:
 
 1. kernel-level fuzzed parity of ``large_group_aggregate`` against a
-   numpy oracle — exact for counts and recombined int64 limb sums
-   (negative values / high limbs included), identity-filled for empty
-   groups, tolerance-checked for f32 sums;
+   numpy int64 oracle — the kernel is handed the ARGUMENTS and cuts
+   the limbs itself: exact for counts and recombined int64 limb sums
+   (negative values, both words, limbs astride the 32-bit boundary,
+   one-word arguments, shared arguments, distinct validities),
+   identity-filled for empty groups, tolerance-checked for f32 sums;
 2. unit tests for the helpers (``row_block``, ``limb_width``) and the
    thread-safe ``_KernelTally``;
 3. engine-level eligibility + parity: q18's inner GROUP BY rides the
@@ -23,32 +25,37 @@ import pytest
 from cockroach_tpu.ops.pallas import groupagg as pg
 from cockroach_tpu.ops.pallas.groupagg import MAX, MIN, _KernelTally
 from cockroach_tpu.ops.pallas.groupagg_large import (
-    BLOCK_ROWS, GROUP_TILE, large_group_aggregate, limb_width, row_block)
+    BLOCK_ROWS, GROUP_TILE, large_group_aggregate, limb_rows, limb_width,
+    row_block)
 
 
 # ---------------------------------------------------------------- helpers
 
-def _limb_cols(vals: np.ndarray, mask: np.ndarray, w: int):
-    """Split int64 values into w-bit unsigned limbs (logical shifts,
-    exactly the compile.py column build) and pre-mask them to 0 —
-    the kernel contract folds sel/mask into the matmul columns."""
-    k = -(-64 // w)
-    u = vals.view(np.uint64)
-    cols = []
-    for j in range(k):
-        limb = (u >> np.uint64(j * w)) & np.uint64((1 << w) - 1)
-        cols.append(np.where(mask, limb, 0).astype(np.float32))
-    return cols
-
-
-def _recombine(acc_rows: np.ndarray, w: int) -> np.ndarray:
-    """sum_j limbs[j] << (j*w) in mod-2^64 arithmetic (int64 wrap),
+def _recombine(acc_i: np.ndarray, layout: tuple, src: int,
+               rows=None) -> np.ndarray:
+    """sum of (limb row << its shift) over source `src`'s limb rows
+    (or the given subset) in mod-2^64 arithmetic (int64 wrap),
     matching both the XLA `_group_sum_i64_limbs` path and the engine's
     kernel-partial reconstruction."""
-    total = np.zeros(acc_rows.shape[1], np.uint64)
-    for j in range(acc_rows.shape[0]):
-        total += acc_rows[j].astype(np.uint64) << np.uint64(j * w)
+    i_rows = [r for r in layout if r[0] in ("limb", "count", "live")]
+    total = np.zeros(acc_i.shape[1], np.uint64)
+    for r in (rows if rows is not None else i_rows):
+        if r[0] == "limb" and r[1] == src and r[2] < 64:
+            total += acc_i[i_rows.index(r)].astype(np.uint64) \
+                << np.uint64(r[2])
     return total.view(np.int64)
+
+
+def _group_sum(gid, mask, vals, num_groups) -> np.ndarray:
+    """Exact per-group int64 sums (wrapping, like the engine's)."""
+    out = np.zeros(num_groups, np.int64)
+    with np.errstate(over="ignore"):
+        np.add.at(out, gid[mask], vals[mask])
+    return out
+
+
+def _group_count(gid, mask, num_groups) -> np.ndarray:
+    return np.bincount(gid[mask], minlength=num_groups)
 
 
 def _oracle(gid, sel, vals, mask, num_groups):
@@ -151,6 +158,24 @@ CASES = [
 ]
 
 
+I64 = np.iinfo(np.int64)
+
+# what the in-kernel limb split has to get right, by the argument's
+# values: name -> f(rng, n) -> int64[n]
+SPLIT_VALUES = {
+    # both 32-bit words populated, either sign
+    "both_words": lambda rng, n: rng.integers(
+        I64.min, I64.max, n, dtype=np.int64, endpoint=True),
+    # negative only: the high word is all ones down to small magnitudes
+    "negative": lambda rng, n: -rng.integers(
+        1, 1 << rng.integers(1, 63), n, dtype=np.int64),
+    # the ends of the range among small values: group sums wrap
+    "extremes": lambda rng, n: rng.choice(
+        np.array([I64.min, I64.max, -1, 0, 1, 1 << 32, -(1 << 32),
+                  (1 << 31) - 1, 1 << 31], np.int64), n),
+}
+
+
 class TestLargeKernelParity:
     @pytest.mark.parametrize("n,G,sf,mf,seed", CASES)
     def test_int64_limb_sums_exact(self, n, G, sf, mf, seed):
@@ -162,22 +187,19 @@ class TestLargeKernelParity:
         vals = rng.integers(-(1 << 40), 1 << 40, size=n, dtype=np.int64)
         eff = sel & mask
         w = limb_width(n, max_group_rows=n, block_rows=256)
-        limbs = _limb_cols(vals, eff, w)
-        cnt_col = eff.astype(np.float32)
+        layout = (("shadow", 0),) + limb_rows(0, 64, w) + (("count", 0),)
         mm = np.where(eff, vals, np.inf).astype(np.float32)
         mx = np.where(eff, vals, -np.inf).astype(np.float32)
-        fshadow = np.where(eff, vals, 0).astype(np.float32)
-        mat = (fshadow, *limbs, cnt_col)
-        mat_int = (False,) + (True,) * (len(limbs) + 1)
         acc_f, acc_i = large_group_aggregate(
-            gid, sel, mat, (mm, mx), G, mat_int, mm_ops=(MIN, MAX),
-            want_rep=True, group_tile=128, block_rows=256,
-            interpret=True)
+            gid, sel, (np.where(eff, vals, 0),), (eff,), (), (mm, mx),
+            G, layout, mm_ops=(MIN, MAX), want_rep=True, group_tile=128,
+            block_rows=256, interpret=True)
         acc_f, acc_i = np.asarray(acc_f), np.asarray(acc_i)
         sums, cnts, mins, maxs, reps = _oracle(gid, sel, vals, mask, G)
-        got_sums = _recombine(acc_i[:len(limbs)], w)
-        np.testing.assert_array_equal(got_sums, sums)  # bit-exact
-        np.testing.assert_array_equal(acc_i[len(limbs)], cnts)
+        k = len(layout) - 2
+        np.testing.assert_array_equal(
+            _recombine(acc_i, layout, 0), sums)  # bit-exact
+        np.testing.assert_array_equal(acc_i[k], cnts)
         # MIN/MAX: identity fill survives for empty groups
         np.testing.assert_array_equal(acc_f[1], mins)
         np.testing.assert_array_equal(acc_f[2], maxs)
@@ -185,12 +207,158 @@ class TestLargeKernelParity:
         tol = np.maximum(np.abs(sums).astype(np.float64) * 1e-2, 1e6)
         assert np.all(np.abs(acc_f[0].astype(np.float64) - sums) <= tol)
         # rep: min selected row id per group, n when none
-        want_rep = np.full(G, n, np.int64)
-        for g in range(G):
-            sm = sel & (gid == g)
-            if sm.any():
-                want_rep[g] = np.flatnonzero(sm)[0]
-        np.testing.assert_array_equal(acc_i[len(limbs) + 1], want_rep)
+        np.testing.assert_array_equal(acc_i[k + 1], reps)
+
+    @pytest.mark.parametrize("w", [8, 9, 14])
+    @pytest.mark.parametrize("kind", sorted(SPLIT_VALUES))
+    def test_in_kernel_limb_split(self, kind, w):
+        """The limbs the kernel cuts out of the argument's two words
+        recombine to the numpy int64 group sums, and the f32 shadow
+        row follows them."""
+        n, G = 1024, 150
+        rng = np.random.default_rng(w * 31 + len(kind))
+        gid = rng.integers(0, G, n).astype(np.int32)
+        sel = rng.random(n) < 0.85
+        vals = SPLIT_VALUES[kind](rng, n)
+        layout = (("shadow", 0),) + limb_rows(0, 64, w) + (("live",),)
+        acc_f, acc_i = large_group_aggregate(
+            gid, sel, (np.where(sel, vals, 0),), (), (), (), G, layout,
+            group_tile=128, block_rows=256, interpret=True)
+        want = _group_sum(gid, sel, vals, G)
+        np.testing.assert_array_equal(
+            _recombine(np.asarray(acc_i), layout, 0), want)
+        exact = np.zeros(G)     # the unwrapped sum the shadow tracks
+        np.add.at(exact, gid[sel], vals[sel].astype(np.float64))
+        tol = np.maximum(np.abs(exact) * 1e-2, 1e12)
+        assert np.all(np.abs(np.asarray(acc_f)[0] - exact) <= tol)
+
+    @pytest.mark.parametrize("w", [8, 9, 14])
+    def test_limbs_astride_the_word_boundary(self, w):
+        """A 3-bit leading limb puts a limb of every width across bit
+        32, where the kernel ORs the two words' shifts together."""
+        n, G = 1024, 96
+        rng = np.random.default_rng(w)
+        gid = rng.integers(0, G, n).astype(np.int32)
+        sel = rng.random(n) < 0.9
+        vals = SPLIT_VALUES["both_words"](rng, n)
+        limbs = (("limb", 0, 0, 3),) + tuple(
+            ("limb", 0, s, w) for s in range(3, 64, w))
+        assert any(s < 32 < s + wd for _, _, s, wd in limbs)
+        layout = limbs + (("live",),)
+        _, acc_i = large_group_aggregate(
+            gid, sel, (np.where(sel, vals, 0),), (), (), (), G, layout,
+            group_tile=128, block_rows=256, interpret=True)
+        np.testing.assert_array_equal(
+            _recombine(np.asarray(acc_i), layout, 0),
+            _group_sum(gid, sel, vals, G))
+
+    @pytest.mark.parametrize("w", [8, 9, 14])
+    def test_one_word_argument(self, w):
+        """A proven 31-bit argument travels as int32: one operand row,
+        no high word, the same sums."""
+        n, G = 1024, 96
+        rng = np.random.default_rng(w)
+        gid = rng.integers(0, G, n).astype(np.int32)
+        sel = rng.random(n) < 0.9
+        vals = rng.integers(0, 1 << 31, n, dtype=np.int64)
+        vals[:2] = (1 << 31) - 1, 0
+        layout = (("shadow", 0),) + limb_rows(0, 31, w) + (("live",),)
+        before = pg.OPERAND_BYTES.value("large")
+        acc_f, acc_i = large_group_aggregate(
+            gid, sel, (np.where(sel, vals, 0).astype(np.int32),), (), (),
+            (), G, layout, group_tile=128, block_rows=256,
+            interpret=True)
+        # gid, the packed masks, one word
+        assert pg.OPERAND_BYTES.value("large") - before == 3 * 4 * n
+        want = _group_sum(gid, sel, vals, G)
+        np.testing.assert_array_equal(
+            _recombine(np.asarray(acc_i), layout, 0), want)
+        np.testing.assert_allclose(np.asarray(acc_f)[0], want, rtol=1e-5)
+
+    @pytest.mark.parametrize("w", [8, 9, 14])
+    def test_two_aggregates_share_one_argument(self, w):
+        """sum(x) with a proven 13-bit bound and avg(x) without one
+        read ONE source: the narrow sum's limbs are the wide one's
+        first rows, and both recombine to the same answer."""
+        n, G = 1024, 96
+        rng = np.random.default_rng(w)
+        gid = rng.integers(0, G, n).astype(np.int32)
+        sel = rng.random(n) < 0.9
+        vals = rng.integers(0, 1 << 13, n, dtype=np.int64)
+        narrow, wide = limb_rows(0, 13, w), limb_rows(0, 64, w)
+        assert set(narrow) <= set(wide)
+        layout = (("shadow", 0),) + wide + (("count", 0), ("live",))
+        _, acc_i = large_group_aggregate(
+            gid, sel, (np.where(sel, vals, 0),), (sel,), (), (), G,
+            layout, group_tile=128, block_rows=256, interpret=True)
+        acc_i = np.asarray(acc_i)
+        want = _group_sum(gid, sel, vals, G)
+        np.testing.assert_array_equal(
+            _recombine(acc_i, layout, 0, narrow), want)
+        np.testing.assert_array_equal(
+            _recombine(acc_i, layout, 0, wide), want)
+        np.testing.assert_array_equal(acc_i[len(wide)],
+                                      _group_count(gid, sel, G))
+
+    @pytest.mark.parametrize("w", [8, 9, 14])
+    def test_distinct_validities(self, w):
+        """Two arguments with their own NULLs: each count row reads its
+        own bit of the packed mask word, liveness reads `sel`."""
+        n, G = 1024, 96
+        rng = np.random.default_rng(w)
+        gid = rng.integers(0, G, n).astype(np.int32)
+        sel = rng.random(n) < 0.9
+        va, vb = sel & (rng.random(n) < 0.7), sel & (rng.random(n) < 0.4)
+        a = SPLIT_VALUES["both_words"](rng, n)
+        b = rng.integers(0, 1 << 20, n, dtype=np.int64)
+        layout = limb_rows(0, 64, w) + limb_rows(1, 20, w) \
+            + (("count", 0), ("count", 1), ("live",))
+        _, acc_i = large_group_aggregate(
+            gid, sel, (np.where(va, a, 0),
+                       np.where(vb, b, 0).astype(np.int32)),
+            (va, vb), (), (), G, layout, group_tile=128, block_rows=256,
+            interpret=True)
+        acc_i = np.asarray(acc_i)
+        np.testing.assert_array_equal(_recombine(acc_i, layout, 0),
+                                      _group_sum(gid, va, a, G))
+        np.testing.assert_array_equal(_recombine(acc_i, layout, 1),
+                                      _group_sum(gid, vb, b, G))
+        for r, m in ((-3, va), (-2, vb), (-1, sel)):
+            np.testing.assert_array_equal(acc_i[r],
+                                          _group_count(gid, m, G))
+
+    def test_more_masks_than_one_word_holds(self):
+        # 40 validities: mask 31 onward rides a second packed word
+        n, G = 512, 40
+        rng = np.random.default_rng(5)
+        gid = rng.integers(0, G, n).astype(np.int32)
+        sel = rng.random(n) < 0.9
+        masks = tuple(sel & (rng.random(n) < 0.5) for _ in range(40))
+        layout = tuple(("count", k) for k in range(40)) + (("live",),)
+        _, acc_i = large_group_aggregate(
+            gid, sel, (), masks, (), (), G, layout, group_tile=128,
+            block_rows=256, interpret=True)
+        for k, m in enumerate(masks + (sel,)):
+            np.testing.assert_array_equal(np.asarray(acc_i)[k],
+                                          _group_count(gid, m, G))
+
+    def test_float_sum_column(self):
+        # an f32 column ("on", or a promoted float_sum) is copied into
+        # the operand beside the rows the kernel derives
+        n, G = 1024, 96
+        rng = np.random.default_rng(6)
+        gid = rng.integers(0, G, n).astype(np.int32)
+        sel = rng.random(n) < 0.9
+        x = rng.integers(-1000, 1000, n).astype(np.float32)
+        acc_f, acc_i = large_group_aggregate(
+            gid, sel, (), (), (np.where(sel, x, 0),), (), G,
+            (("f", 0), ("live",)), group_tile=128, block_rows=256,
+            interpret=True)
+        want = np.zeros(G)
+        np.add.at(want, gid[sel], x[sel])
+        np.testing.assert_array_equal(np.asarray(acc_f)[0], want)
+        np.testing.assert_array_equal(np.asarray(acc_i)[0],
+                                      _group_count(gid, sel, G))
 
     def test_all_rows_masked(self):
         # empty state: every accumulator keeps its identity
@@ -198,11 +366,11 @@ class TestLargeKernelParity:
         rng = np.random.default_rng(9)
         gid = rng.integers(0, G, size=n).astype(np.int32)
         sel = np.zeros(n, bool)
-        zero = np.zeros(n, np.float32)
+        zero = np.zeros(n, np.int64)
         inf = np.full(n, np.inf, np.float32)
         acc_f, acc_i = large_group_aggregate(
-            gid, sel, (zero, zero), (inf, -inf), G,
-            (False, True), mm_ops=(MIN, MAX), want_rep=True,
+            gid, sel, (zero,), (), (), (inf, -inf), G,
+            (("shadow", 0), ("live",)), mm_ops=(MIN, MAX), want_rep=True,
             group_tile=128, block_rows=256, interpret=True)
         acc_f, acc_i = np.asarray(acc_f), np.asarray(acc_i)
         assert np.all(acc_f[0] == 0.0)
@@ -214,9 +382,8 @@ class TestLargeKernelParity:
         n = 4096
         gid = np.zeros(n, np.int32)
         sel = np.ones(n, bool)
-        cnt = np.ones(n, np.float32)
         _, acc_i = large_group_aggregate(
-            gid, sel, (cnt,), (), 1, (True,), group_tile=128,
+            gid, sel, (), (), (), (), 1, (("live",),), group_tile=128,
             block_rows=512, interpret=True)
         assert int(np.asarray(acc_i)[0, 0]) == n
 
@@ -399,3 +566,154 @@ class TestNoScatterHLO:
             "oracle arm: the XLA segment path should lower scatters"
         assert "scatter" not in auto, \
             "auto arm still lowers aggregation scatters"
+
+
+# ---------------------------------------------------------------- Q1: the
+# aggregate reads its arguments once
+
+def _q1_rows(eng, session, mode, sql=None):
+    from cockroach_tpu.models import tpch
+    session.vars.set("pallas_groupagg", mode)
+    return eng.execute(sql or tpch.Q1, session=session).rows
+
+
+Q1_AVGS = (6, 7, 8)     # avg_qty, avg_price, avg_disc: float8 quotients
+
+
+def _assert_q1_equal(got, want):
+    """Every exact column (group keys, DECIMAL sums, count) bit for
+    bit; the avgs are float divisions of the same exact sums and
+    counts, which the two arms order differently (a last-digit
+    difference on the seed tree too)."""
+    assert len(got) == len(want) >= 3
+    for g, w in zip(got, want):
+        for c, (x, y) in enumerate(zip(g, w)):
+            if c in Q1_AVGS:
+                assert x == pytest.approx(y, rel=1e-14)
+            else:
+                assert x == y, (c, x, y)
+
+
+class TestQ1ReadsArgumentsOnce:
+    """TPC-H Q1 (seven exact DECIMAL sums/avgs over five distinct
+    arguments, count(*)) hands the kernel its arguments, not the limb
+    matrix: what the `.scan` cell runs."""
+
+    def test_auto_matches_off_bit_for_bit(self, teng):
+        s = _local_session(teng)
+        want = _q1_rows(teng, s, "off")
+        before = pg.BUILDS.value("large")
+        got = _q1_rows(teng, s, "auto")
+        assert pg.BUILDS.value("large") > before, "Q1 missed the kernel"
+        _assert_q1_equal(got, want)
+
+    def test_operand_bytes_exported_and_small(self, teng, monkeypatch):
+        from cockroach_tpu.models import tpch
+        from cockroach_tpu.ops.pallas import groupagg_large as pgl
+        assert "exec.pallas.kernel.operand_bytes" in teng.metrics.snapshot()
+        seen = []
+        orig = pgl.large_group_aggregate
+
+        def spy(gid, sel, sources, *a, **kw):
+            seen.append((gid.shape[0], len(kw["layout"]), len(sources)))
+            return orig(gid, sel, sources, *a, **kw)
+
+        monkeypatch.setattr(pgl, "large_group_aggregate", spy)
+        # the limb width of TPC-H SF1 (8,388,608 rows a group at most):
+        # any width under the exactness bound gives the same answer
+        monkeypatch.setattr(pgl, "limb_width", lambda *a, **kw: 8)
+        s = _local_session(teng)
+        want = _q1_rows(teng, s, "off")
+        name = "exec.pallas.kernel.operand_bytes"
+        before = teng.metrics.snapshot()[name]
+        # one more item than tpch.Q1, so no cached plan answers
+        sql = tpch.Q1.replace("count(*) AS count_order",
+                              "count(*) AS count_order, count(*) AS c2")
+        got = _q1_rows(teng, s, "auto", sql)
+        _assert_q1_equal([r[:-1] for r in got], want)
+        (n, n_mat, n_src), = seen
+        # l_quantity and l_extendedprice serve a sum and an avg each
+        assert n_src == 5
+        handed = teng.metrics.snapshot()[name] - before
+        # gid, one packed mask word, two words an argument
+        assert handed == (2 + 2 * n_src) * 4 * n
+        assert handed < 4 * n_mat * n / 4
+
+    def test_overflow_sentinel_still_raises(self, teng):
+        from cockroach_tpu.exec.engine import EngineError
+        teng.execute("CREATE TABLE ovf (g INT8 NOT NULL, v INT8)")
+        n = 8192
+        g = (np.arange(n) % 3).astype(np.int64)
+        v = np.full(n, np.iinfo(np.int64).max // 1000, np.int64)
+        teng.store.insert_columns("ovf", {"g": g, "v": v},
+                                  teng.clock.now())
+        s = _local_session(teng)
+        sql = "SELECT g, sum(v) FROM ovf GROUP BY g"
+        for mode in ("off", "auto"):
+            s.vars.set("pallas_groupagg", mode)
+            with pytest.raises(EngineError, match="overflowed int64"):
+                teng.execute(sql, session=s)
+
+
+class TestOperandHLO:
+    """Beside TestNoScatterHLO: Q1's program as the TPU would get it
+    (lowered for that platform, Mosaic kernel and all) holds no
+    row-major operand matrix, and the custom call reads a dozen
+    [1, n] rows."""
+
+    def test_q1_has_no_operand_matrix(self, teng, monkeypatch):
+        import re
+
+        from cockroach_tpu.exec.engine import Engine
+        from cockroach_tpu.models import tpch
+        s = _local_session(teng)
+        s.vars.set("pallas_autotune", "off")
+        # an `auto` run first: the parity gate's verdicts are then in
+        # memory and nothing probes the kernel with interpret off
+        _q1_rows(teng, s, "auto")
+        monkeypatch.setattr(Engine, "_pallas_interpret",
+                            staticmethod(lambda: False))
+        sql = tpch.Q1.replace("count(*) AS count_order",
+                              "count(*) AS count_order, count(*) AS c3")
+        p = teng.prepare(sql, session=s)
+        tsv = np.int64(teng._read_ts(s).to_int())
+        text = p.jfn.trace(p.scans, tsv, np.int32(1), np.int32(0),
+                           p.params) \
+            .lower(lowering_platforms=("tpu",)).as_text()
+        n = N_ROWS
+        wide = [int(k) for k in re.findall(rf"tensor<(\d+)x{n}xf32>", text)]
+        assert not [k for k in wide if k > 1], \
+            "an f32[k, n] array: the limb matrix is back in HBM"
+        calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+        assert len(calls) == 1
+        operands = re.findall(rf"tensor<1x{n}x[a-z0-9]+>",
+                              calls[0].split("->")[0])
+        # five distinct arguments, their validities packed in one word
+        assert 0 < len(operands) <= 2 * 5 + 1 + 2
+        assert not re.search(rf"tensor<\d+x{n}x", calls[0].replace(
+            f"tensor<1x{n}x", ""))
+
+
+class TestMeshParity:
+    """The path `tpch_sf1_mesh4.mixed` runs: Q1 under shard_map, the
+    kernel on every shard's rows, i32 limb rows psummed."""
+
+    def test_q1_sharded_equals_one_device(self):
+        from cockroach_tpu.exec.engine import Engine
+        from cockroach_tpu.models import tpch
+        from cockroach_tpu.parallel.mesh import make_mesh
+        eng = Engine(mesh=make_mesh(n=4))
+        # 4 shards of 8,192 rows: over auto's row floor on each
+        tpch.load(eng, SF, rows=4 * N_ROWS, tables=("lineitem",))
+        local = _local_session(eng)
+        want = _q1_rows(eng, local, "off")
+        one = _q1_rows(eng, local, "auto")
+        _assert_q1_equal(one, want)
+        dist = eng.session()
+        before = pg.BUILDS.value("large")
+        got = _q1_rows(eng, dist, "auto")
+        assert pg.BUILDS.value("large") > before, \
+            "the sharded Q1 missed the kernel"
+        # sharded against one device, both through the kernel: the
+        # same limb sums and counts, so the avgs agree to the bit too
+        assert got == one
