@@ -19,7 +19,7 @@ caches the jitted callable keyed by plan fingerprint + input shapes
 from __future__ import annotations
 
 import threading
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Callable
 
 import jax
@@ -704,6 +704,149 @@ def _pallas_large_ok(aggs, mode: str, exact_paths: tuple = ()) -> bool:
     return True
 
 
+def dense_num_groups(node: P.Aggregate) -> int:
+    """Group domain of a dense GROUP BY: every dimension and its NULL
+    code (what run_agg's mixed-radix group id spans)."""
+    g = 1
+    for dim in node.group_dims:
+        g *= dim + 1
+    return g
+
+
+def large_kernel_eligible(node: P.Aggregate, n: int,
+                          params: "ExecParams") -> bool:
+    """Does this Aggregate over an n-row batch compile onto the large-G
+    kernel? Static in the plan and the batch's row count, so the
+    placement model (exec/scanplane.py) asks the question the compile
+    will ask."""
+    mode = params.pallas_groupagg
+    if mode not in ("on", "auto") or node.max_groups <= 0 \
+            or not node.group_by or n % 128:
+        return False
+    num_groups = dense_num_groups(node)
+    if num_groups <= 64 and mode == "on" \
+            and _pallas_agg_slots(node.aggs) is not None:
+        return False  # the small-G kernel takes it first
+    return (num_groups <= LARGE_G_MAX
+            and not (mode == "auto" and n < AUTO_MIN_ROWS)
+            and not (mode == "auto" and _large_interpret_over_budget(
+                params.pallas_interpret, n, num_groups,
+                params.pallas_group_tile, params.pallas_block_rows))
+            and _pallas_large_ok(node.aggs, mode,
+                                 params.pallas_exact_paths))
+
+
+@dataclass
+class LargeLayout:
+    """What the large-G kernel is handed for a list of aggregates, and
+    the rows it builds from that: fixed by the plan alone, before
+    anything is traced."""
+    w: int                  # limb width of the exact sums
+    arg_of: dict            # agg index -> its argument's expr_key
+    # argument -> index of its sel & valid mask / exact-sum source /
+    # f32 sum column
+    mask_of: dict = field(default_factory=dict)
+    src_of: dict = field(default_factory=dict)
+    fcol_of: dict = field(default_factory=dict)
+    narrow: list = field(default_factory=list)  # source -> one word
+    f_rows: list = field(default_factory=list)  # rows summed in f32
+    i_rows: list = field(default_factory=list)  # rows summed in i32
+    mm: list = field(default_factory=list)  # (agg index, MIN | MAX)
+    want_rep: bool = False
+    exact: dict = field(default_factory=dict)   # agg index ->
+    # (source, limb count)
+
+    @property
+    def n_words(self) -> int:
+        """[1, n] 32-bit arrays a build hands the kernel: the group
+        ids, the packed mask words (bit 0 is sel), one or two words a
+        source, one f32 column a float sum and a MIN/MAX slot."""
+        return (1 + -(-(1 + len(self.mask_of)) // 32)
+                + sum(1 if nr else 2 for nr in self.narrow)
+                + len(self.fcol_of) + len(self.mm))
+
+
+def large_layout(aggs, n: int, max_group_rows: int,
+                 params: "ExecParams") -> LargeLayout:
+    """Plan the large-G kernel's operands and matmul rows for `aggs`
+    over n rows (see _pallas_large_partials, which traces it)."""
+    from ..ops.pallas import groupagg as pg
+    from ..ops.pallas import groupagg_large as pgl
+    from ..sql.pushdown import expr_key
+    # the limb width tracks the plan's (possibly autotuned) block_rows
+    # so the f32 block-partial exactness bound holds at that block
+    lay = LargeLayout(
+        w=pgl.limb_width(n, max_group_rows,
+                         block_rows=params.pallas_block_rows,
+                         cap=params.pallas_limb_cap),
+        arg_of={i: expr_key(a.arg) for i, a in enumerate(aggs)
+                if a.arg is not None})
+
+    def add(rows, row):     # a row two aggregates ask for (one
+        if row not in rows:     # argument, the same limb) is built once
+            rows.append(row)
+
+    def sum_bits(a):    # the bits an exact sum's argument can hold
+        if a.arg_nonneg and a.arg_max_abs:
+            return max(1, int(a.arg_max_abs).bit_length())
+        return 64
+
+    for i, a in enumerate(aggs):
+        if a.func == "count_rows":
+            continue  # the liveness row: selected rows a group
+        if a.func == "any":
+            lay.want_rep = True  # rides the REPMIN slot + a host gather
+            continue
+        j = lay.arg_of[i]
+        if j not in lay.mask_of:
+            lay.mask_of[j] = len(lay.mask_of)
+        add(lay.i_rows, ("count", lay.mask_of[j]))  # validity, avg divisor
+        if a.func == "count":
+            continue
+        if a.func in ("min", "max"):
+            lay.mm.append((i, pg.MIN if a.func == "min" else pg.MAX))
+            continue
+        if a.arg.type.family == Family.FLOAT:
+            if j not in lay.fcol_of:
+                lay.fcol_of[j] = len(lay.fcol_of)
+                lay.f_rows.append(("f", lay.fcol_of[j]))
+            continue
+        # exact int64 sum as w-bit i32 limbs, cut out of the argument's
+        # words INSIDE the kernel and recombined by the caller — the
+        # same decomposition as agg._group_sum_i64_limbs
+        bits = sum_bits(a)
+        if j not in lay.src_of:
+            # a proven 31-bit argument travels as ONE word, unless
+            # another sum over it comes without the proof
+            lay.src_of[j] = len(lay.narrow)
+            lay.narrow.append(all(
+                sum_bits(x) < 32 for ii, x in enumerate(aggs)
+                if lay.arg_of.get(ii) == j
+                and x.func in ("sum", "sum_int", "avg")))
+        src = lay.src_of[j]
+        lay.exact[i] = (src, -(-bits // lay.w))
+        for row in pgl.limb_rows(src, bits, lay.w):
+            add(lay.i_rows, row)
+        add(lay.f_rows, ("shadow", src))  # feeds the overflow sentinel
+    lay.i_rows.append(("live",))  # group liveness
+    return lay
+
+
+def large_kernel_bytes(node: P.Aggregate, n: int,
+                       params: "ExecParams") -> int:
+    """Device bytes the large-G path allocates beside its input, for
+    the placement model: the [1, n] words XLA writes for the kernel
+    (`exec.pallas.kernel.operand_bytes` is this term) and the kernel's
+    accumulator tiles over the padded group domain. The limb, count
+    and shadow rows live in VMEM and cost no HBM (PERF.md, PR 26)."""
+    lay = large_layout(node.aggs, n, node.max_group_rows, params)
+    gp = -(-dense_num_groups(node) // params.pallas_group_tile) \
+        * params.pallas_group_tile
+    acc_rows = (max(1, len(lay.f_rows)) + max(1, len(lay.i_rows))
+                + len(lay.mm) + int(lay.want_rep))
+    return 4 * n * lay.n_words + 4 * gp * acc_rows
+
+
 def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
                            max_group_rows: int, axis_name,
                            params: "ExecParams"):
@@ -728,23 +871,23 @@ def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
     merges each shard's rep-gathered value with a pmax over an
     identity fill (the FD guarantees every shard that has the group
     agrees on the value)."""
-    from ..ops.pallas import groupagg as pg
     from ..ops.pallas import groupagg_large as pgl
     from ..ops.pallas import paritygate as _pgate
-    from ..sql.pushdown import expr_key
     n = b.n
     sel = b.sel
+    lay = large_layout([a for a, _ in aggfs], n, max_group_rows, params)
+    w, arg_of, mask_of, exact = lay.w, lay.arg_of, lay.mask_of, lay.exact
+    fcol_of, f_rows, i_rows = lay.fcol_of, lay.f_rows, lay.i_rows
+    want_rep = lay.want_rep
     # the kernel's operands: each DISTINCT argument evaluated once and
-    # masked. The limb, count and shadow rows of the matmul are a static
-    # layout over them, which the kernel builds per row block in VMEM
+    # masked. The limb, count and shadow rows of the matmul are the
+    # static layout over them, which the kernel builds per row block in
+    # VMEM
     with jax.named_scope("operands"):
         argvals = {}    # distinct argument -> its traced (data, valid)
-        arg_of = {}     # agg index -> its argument's key
         for i, (a, argf) in enumerate(aggfs):
-            if argf is not None:
-                arg_of[i] = j = expr_key(a.arg)
-                if j not in argvals:
-                    argvals[j] = argf(ctx)
+            if argf is not None and arg_of[i] not in argvals:
+                argvals[arg_of[i]] = argf(ctx)
         argdata = {i: argvals[j] for i, j in arg_of.items()}
         for i, (a, _) in enumerate(aggfs):
             if a.func in ("sum", "sum_int", "avg", "min", "max") \
@@ -755,92 +898,38 @@ def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
                 # sums and the MIN/MAX hi-limb both need real ints
                 if argdata[i][0].dtype not in (jnp.int64, jnp.int32):
                     return None
-        # per distinct argument:
-        masks, mask_of = [], {}     # sel & valid
-        sources, src_of = [], {}    # the masked argument of exact sums
-        f_cols, fcol_of = [], {}    # the f32 column of float sums
-        f_rows, i_rows = [], []     # the layout; a row two aggregates
-        # ask for (one argument, the same limb) is computed once
+        masks = [None] * len(mask_of)       # sel & valid, an argument
+        for j, k in mask_of.items():
+            masks[k] = jnp.logical_and(sel, argvals[j][1])
+        sources = [None] * len(lay.src_of)  # the masked exact-sum args
+        for j, k in lay.src_of.items():
+            d64 = argvals[j][0].astype(jnp.int64)
+            dz = jnp.where(masks[mask_of[j]], d64, jnp.zeros_like(d64))
+            sources[k] = dz.astype(jnp.int32) if lay.narrow[k] else dz
+        f_cols = [None] * len(fcol_of)      # the f32 float-sum columns
+        for j, k in fcol_of.items():
+            f_cols[k] = jnp.where(masks[mask_of[j]], argvals[j][0],
+                                  0).astype(jnp.float32)
         mm_cols, mm_ops_l, mm_tags = [], [], []
-        want_rep = False
-        exact = {}  # agg index -> (source, limb count k)
-        # the limb width tracks the plan's (possibly autotuned)
-        # block_rows so the f32 block-partial exactness bound holds at
-        # that block
-        w = pgl.limb_width(n, max_group_rows,
-                           block_rows=params.pallas_block_rows,
-                           cap=params.pallas_limb_cap)
-
-        def add(rows, row):
-            if row not in rows:
-                rows.append(row)
-
-        def sum_bits(a):    # the bits an exact sum's argument can hold
-            if a.arg_nonneg and a.arg_max_abs:
-                return max(1, int(a.arg_max_abs).bit_length())
-            return 64
-
-        for i, (a, _) in enumerate(aggfs):
-            if a.func == "count_rows":
-                continue  # the liveness row: selected rows a group
-            if a.func == "any":
-                want_rep = True  # rides the REPMIN slot + a host gather
-                continue
-            d0, v0 = argdata[i]
-            j = arg_of[i]
-            if j not in mask_of:
-                mask_of[j] = len(masks)
-                masks.append(jnp.logical_and(sel, v0))
-            m = masks[mask_of[j]]
-            add(i_rows, ("count", mask_of[j]))  # validity + avg divisor
-            if a.func == "count":
-                continue
-            if a.func in ("min", "max"):
-                ident = np.float32(np.inf if a.func == "min" else -np.inf)
-                if a.arg.type.family in (Family.INT, Family.DECIMAL):
-                    # exact ordered-int path (paritygate "int_minmax"):
-                    # the kernel reduces the ARITHMETIC high limb — order-
-                    # preserving, |limb| <= 2^23 so f32-exact — and the
-                    # full-width winner is refined on XLA in the output
-                    # loop below over just the rows holding that limb
-                    hi = jnp.right_shift(d0.astype(jnp.int64),
-                                         jnp.int64(_pgate.MM_HI_SHIFT))
-                    mm_cols.append(
-                        jnp.where(m, hi.astype(jnp.float32), ident))
-                else:
-                    mm_cols.append(
-                        jnp.where(m, d0.astype(jnp.float32), ident))
-                mm_ops_l.append(pg.MIN if a.func == "min" else pg.MAX)
-                mm_tags.append(("mm", i))
-                continue
-            if a.arg.type.family == Family.FLOAT:
-                if j not in fcol_of:
-                    fcol_of[j] = len(f_cols)
-                    f_rows.append(("f", fcol_of[j]))
-                    f_cols.append(jnp.where(m, d0, 0).astype(jnp.float32))
-                continue
-            # exact int64 sum as w-bit i32 limbs, cut out of the
-            # argument's words INSIDE the kernel and recombined below —
-            # the same decomposition as agg._group_sum_i64_limbs
-            bits = sum_bits(a)
-            if j not in src_of:
-                # a proven 31-bit argument travels as ONE word, unless
-                # another sum over it comes without the proof
-                narrow = all(sum_bits(x) < 32
-                             for ii, (x, _) in enumerate(aggfs)
-                             if arg_of.get(ii) == j
-                             and x.func in ("sum", "sum_int", "avg"))
-                src_of[j] = len(sources)
-                d64 = d0.astype(jnp.int64)
-                dz = jnp.where(m, d64, jnp.zeros_like(d64))
-                sources.append(dz.astype(jnp.int32) if narrow else dz)
-            src = src_of[j]
-            exact[i] = (src, -(-bits // w))
-            for row in pgl.limb_rows(src, bits, w):
-                add(i_rows, row)
-            # f32 shadow sum feeds the overflow sentinel
-            add(f_rows, ("shadow", src))
-        i_rows.append(("live",))  # group liveness
+        for i, op in lay.mm:
+            a = aggfs[i][0]
+            d0, m = argdata[i][0], masks[mask_of[arg_of[i]]]
+            ident = np.float32(np.inf if a.func == "min" else -np.inf)
+            if a.arg.type.family in (Family.INT, Family.DECIMAL):
+                # exact ordered-int path (paritygate "int_minmax"):
+                # the kernel reduces the ARITHMETIC high limb — order-
+                # preserving, |limb| <= 2^23 so f32-exact — and the
+                # full-width winner is refined on XLA in the output
+                # loop below over just the rows holding that limb
+                hi = jnp.right_shift(d0.astype(jnp.int64),
+                                     jnp.int64(_pgate.MM_HI_SHIFT))
+                mm_cols.append(
+                    jnp.where(m, hi.astype(jnp.float32), ident))
+            else:
+                mm_cols.append(
+                    jnp.where(m, d0.astype(jnp.float32), ident))
+            mm_ops_l.append(op)
+            mm_tags.append(("mm", i))
 
     layout = tuple(f_rows) + tuple(i_rows)
     with jax.named_scope("kernel"):
@@ -1131,17 +1220,7 @@ def _compile_aggregate(node: P.Aggregate, params: ExecParams) -> CompiledNode:
         # group bound and an all-exact aggregate envelope under
         # `auto`; distributed dense plans merge the kernel partials
         # with collectives inside _pallas_large_partials
-        if (pslots is None and mode in ("on", "auto") and dense
-                and groupfs and b.n % 128 == 0
-                and num_groups <= LARGE_G_MAX
-                and not (mode == "auto" and b.n < AUTO_MIN_ROWS)
-                and not (mode == "auto"
-                         and _large_interpret_over_budget(
-                             params.pallas_interpret, b.n, num_groups,
-                             params.pallas_group_tile,
-                             params.pallas_block_rows))
-                and _pallas_large_ok([a for a, _ in aggfs], mode,
-                                     params.pallas_exact_paths)):
+        if pslots is None and large_kernel_eligible(node, b.n, params):
             large = True
         overflow = jnp.bool_(False)
         rep_state = None

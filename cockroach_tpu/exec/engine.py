@@ -63,7 +63,7 @@ from .stmtutil import (_StreamFns, _RerunPrepared, _host_sort, _count_aggs,
                       _collect_scan_columns, _collect_scans,
                       _contains_func, _decode_column,
                       _decode_scalar, _decode_storage_value,
-                      _next_pow2, _pad, _propagate_as_of,
+                      _next_pow2, _propagate_as_of,
                       _render_create, _rewrite_table_names,
                       _slice_chunks, _stmt_table_refs,
                       split_conjuncts_ast)
@@ -370,6 +370,31 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 (_ob.D2H_CALLS, "device-to-host result transfers"),
                 (_ob.D2H_BYTES, "bytes those transfers moved")):
             self.metrics.func_counter(tally.name, tally.value, help_)
+        # the placement verdict of each prepare (resident | stream |
+        # spill | distributed), and the largest working set the
+        # resident-or-stream model has weighed (scanplane.
+        # _stream_decision; the statement's own is on its `plan` span)
+        self._m_placement = {
+            v: self.metrics.counter(
+                f"sql.exec.placement.{v}",
+                "prepares by placement verdict: resident, stream "
+                "(paged through HBM), spill (out-of-core join or "
+                "sort), distributed (over the mesh)")
+            for v in ("resident", "stream", "spill", "distributed")}
+        self._placement_model_max = 0
+        self.metrics.func_gauge(
+            "sql.exec.placement.model_bytes.max",
+            lambda: self._placement_model_max,
+            "largest working set (pruned upload + what the "
+            "aggregation path allocates) the placement model has "
+            "weighed against sql.exec.hbm_budget_bytes since start")
+        self.metrics.func_counter(
+            "storage.ingest.rows", lambda: self.store.ingest_rows,
+            "rows taken by bulk columnar ingest (insert_columns)")
+        self.metrics.func_counter(
+            "storage.ingest.seconds", lambda: self.store.ingest_seconds,
+            "seconds bulk columnar ingest held: encoding checks, "
+            "chunking and the chunks' seal-time statistics")
         # TPU-plane visibility: Pallas kernel tallies are trace-time
         # module counters (ops/pallas/groupagg.py); read live at
         # scrape. All of them count at TRACE time — executions run
@@ -398,6 +423,17 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             "bytes of the HBM arrays handed to the large-G kernel, a "
             "build: the aggregates' arguments as 32-bit words, the "
             "packed masks and the group ids, not the limb rows")
+        self.metrics.func_counter(
+            "exec.pallas.kernel.limb_bits",
+            lambda: _ga.LIMB_BITS.value("large"),
+            "limb width of the large-G kernel's exact int64 sums, "
+            "summed over builds: over builds.large, the width the "
+            "group-rows bound gave (8 at 2^23 rows, 6 or 5 at 2^26)")
+        self.metrics.func_counter(
+            "exec.pallas.kernel.matmul_rows",
+            lambda: _ga.MATMUL_ROWS.value("large"),
+            "rows of the large-G kernel's matmul operand (limb, count "
+            "and shadow rows, built in VMEM), summed over builds")
         self.metrics.func_counter(
             "exec.pallas.rows",
             lambda: _ga.ROWS.value(),
@@ -2566,6 +2602,12 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                            or spill is not None)
                   else self._stream_decision(node, scan_aliases, scan_cols,
                                              session))
+        planned = node  # as the placement verdicts saw it
+        verdict = ("distributed" if decision is not None
+                   else "spill" if spill is not None
+                   else "stream" if stream is not None else "resident")
+        self.tracer.tag(placement=verdict)
+        self._m_placement[verdict].inc()
         read_ts = self._read_ts(session)
         # the join-build uniqueness guard is snapshot-aware: it must
         # judge the rows visible at THIS query's read timestamp — and
@@ -2630,14 +2672,8 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 no_topk=no_topk, no_compact=no_compact, no_dist=True)
 
         cap = int(session.vars.get("hash_group_capacity", 1 << 17))
-        # auto | on | off; legacy bool spellings normalize (True was
-        # the old opt-in), anything unrecognized means off
         pallas = session.vars.get("pallas_groupagg", "auto")
-        if isinstance(pallas, bool):
-            pallas = "on" if pallas else "off"
-        pallas = str(pallas).lower()
-        if pallas not in ("auto", "on", "off"):
-            pallas = "off"
+        pallas = self._pallas_mode(pallas)
         # same normalization discipline for the sort-key plane
         sortn = session.vars.get("sort_normalized", "auto")
         if isinstance(sortn, bool):
@@ -2700,6 +2736,9 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             "sql.plan.cache.hit" if cached else "sql.plan.cache.miss",
             "compiled-plan cache lookups, by outcome").inc()
         if cached is None:
+            if verdict == "resident" and not overlay:
+                self.note_placement_model(planned, scan_aliases,
+                                          scan_cols, session)
             # feed the startup pre-warm: texts that missed here are
             # what a restarted process should compile first, at the
             # shape bucket their paged executables specialize on

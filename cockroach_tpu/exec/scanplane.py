@@ -32,7 +32,8 @@ EPOCH_DT = datetime.datetime(1970, 1, 1)
 from .session import (SENTINEL_COLUMNS, CompactOverflow, EngineError,
                       HashCapacityExceeded, Prepared, TopKInexact,
                       Result, Session)
-from .stmtutil import (_collect_scans, _count_aggs, _decode_column, _has_join, _host_sort, _pad)
+from .stmtutil import (_collect_scans, _count_aggs, _decode_column,
+                       _has_join, _host_sort, _root_aggregate)
 from .stream import PageSource
 from .stream import prefetch as stream_prefetch
 from . import profile as _prof
@@ -292,15 +293,48 @@ class ScanPlaneMixin:
     # -- beyond-HBM streaming ------------------------------------------------
     def _stream_decision(self, node, scan_aliases: dict, scan_cols: dict,
                          session: Session):
-        """Page the fact table through HBM when its pruned upload would
-        not fit the device budget. Eligibility mirrors the mesh
-        distribution analysis (the plan must reduce to mergeable
-        aggregate partials); only the probe-spine scan streams.
+        """Page the fact table through HBM when the statement's working
+        set would not fit the device budget (``sql.exec.hbm_budget_
+        bytes``). The working set is a model of the program that will
+        run (placement_model): the pruned upload the resident path
+        would make, plus what the plan's aggregation path allocates
+        beside it. Eligibility mirrors the mesh distribution analysis
+        (the plan must reduce to mergeable aggregate partials); only
+        the probe-spine scan streams.
         Returns (alias, table, page_rows) or None."""
+        scan = self._streamable_scan(node, scan_aliases, scan_cols,
+                                     session)
+        if scan is None:
+            return None
+        alias, tname, eff_bytes, padded = scan
+        budget = int(self.settings.get("sql.exec.hbm_budget_bytes"))
+        # the scatter path's term bounds the kernel path's words from
+        # above (two 64-bit temporaries an aggregate against at most
+        # two 32-bit words an aggregate and two they share), so a
+        # statement that fits under it fits on either path, and a
+        # plan-cache hit far from the budget does not plan the
+        # kernel's operands to learn that. (The accumulator tiles, a
+        # few hundred MB at the widest group domain, are not in the
+        # bound.)
+        if eff_bytes + 16 * _count_aggs(node) * padded <= budget:
+            return None
+        if self.placement_model(node, session, eff_bytes,
+                                padded) <= budget:
+            return None
+        # Build-side tables still upload whole: streaming the probe is
+        # strictly better than not, and an over-budget build fails
+        # upstream with a clean quota error rather than silently here.
+        return (alias, tname, self._page_rows(session))
+
+    def _streamable_scan(self, node, scan_aliases: dict, scan_cols: dict,
+                         session: Session):
+        """(alias, table, upload bytes, padded rows) of the one scan a
+        resident-or-stream verdict is about, or None where the plan is
+        not one paging can run (or streaming is off, or the table
+        empty)."""
         if session.vars.get("streaming", "auto") == "off":
             return None
-        budget = int(self.settings.get("sql.exec.hbm_budget_bytes"))
-        if budget <= 0:
+        if int(self.settings.get("sql.exec.hbm_budget_bytes")) <= 0:
             return None
         if not can_stream(node):
             # dist_analyze accepts more shapes (e.g. hash GROUP BY)
@@ -311,15 +345,8 @@ class ScanPlaneMixin:
             return None
         alias = next(iter(d.sharded))
         tname = scan_aliases[alias]
-        td = self.store.table(tname)
-        if td.row_count == 0:
+        if self.store.table(tname).row_count == 0:
             return None
-        # working set = pruned upload + aggregation temporaries. XLA's
-        # segment reductions materialize ~2 n-length temps per
-        # aggregate concurrently (measured: TPC-H Q1 at 2^27 rows
-        # compiles to ~12GB of HLO temps), so a table that "fits" can
-        # still OOM at compile time without this term.
-        n_aggs = _count_aggs(node)
         # the resident upload this decision weighs would narrow its
         # int32-provable columns UNLESS the scan feeds a join
         # (_set_scan_narrowing keeps probe spines wide) — charging
@@ -333,13 +360,80 @@ class ScanPlaneMixin:
         # (selective scans stop escalating to paging unnecessarily)
         eff_bytes, eff_rows = self._effective_table_bytes(
             node, alias, tname, cols, narrow=narrow)
-        temp_bytes = 16 * n_aggs * self._row_bucket(eff_rows)
-        if eff_bytes + temp_bytes <= budget:
-            return None
-        # Build-side tables still upload whole: streaming the probe is
-        # strictly better than not, and an over-budget build fails
-        # upstream with a clean quota error rather than silently here.
-        return (alias, tname, self._page_rows(session))
+        return alias, tname, eff_bytes, self._row_bucket(eff_rows)
+
+    def placement_model(self, node, session: Session, eff_bytes: int,
+                        padded: int) -> int:
+        """The working set the resident-or-stream verdict weighs: the
+        upload plus _agg_temp_bytes. Goes onto the open `plan` span
+        (tag `model_bytes`) and raises the gauge
+        ``sql.exec.placement.model_bytes.max``. Evaluated where the
+        verdict needs it and where a plan is compiled
+        (note_placement_model), not on every plan-cache hit."""
+        model_bytes = eff_bytes + self._agg_temp_bytes(node, session,
+                                                       padded)
+        _trc.tag(model_bytes=model_bytes)
+        self._placement_model_max = max(self._placement_model_max,
+                                        model_bytes)
+        return model_bytes
+
+    def note_placement_model(self, node, scan_aliases: dict,
+                             scan_cols: dict, session: Session) -> None:
+        """placement_model for a plan about to be compiled resident:
+        the statement's span and the gauge carry the model even where
+        the bound above settled the verdict."""
+        scan = self._streamable_scan(node, scan_aliases, scan_cols,
+                                     session)
+        if scan is not None:
+            self.placement_model(node, session, *scan[2:])
+
+    def _agg_temp_bytes(self, node, session: Session, padded: int) -> int:
+        """Device bytes the plan's aggregation allocates beside its
+        `padded`-row input, by the path it will compile onto.
+
+        Large-G kernel (a dense GROUP BY inside compile.large_kernel_
+        eligible, asked with the shipped tile and no parity-promoted
+        path): the 32-bit words the kernel is handed and its
+        accumulator tiles (compile.large_kernel_bytes). Measured on
+        the v5e at TPC-H SF10, 2^26 rows, Q1 (my chip run, PR 28,
+        PERF.md): 3.0 GiB of operand words modelled, 3.375 GiB of
+        temporaries reserved by the loaded programs beside a 3.19 GiB
+        upload, where the scatter term below reads 8.0 GiB.
+
+        Anything else (hash GROUP BY, ungrouped or tiny aggregates,
+        kernels off): XLA's segment reductions, which materialize
+        about two n-length 64-bit temporaries an aggregate at once
+        (16 bytes a row an aggregate; the figure comes from Q1 on the
+        scatter path at 2^27 rows, ~12 GB of HLO temporaries, a
+        reading older than the v5e runs and not repeated there), so a
+        table that "fits" can still run out at compile time without
+        this term."""
+        from ..ops.pallas import autotune as _tune
+        from .compile import large_kernel_bytes, large_kernel_eligible
+        agg = _root_aggregate(node)
+        if agg is not None:
+            gt, br, limb_cap = _tune.DEFAULT
+            # graftlint: waive[plan-key-completeness] the verdict this
+            # feeds (`stream`) is a key element, and so is the var
+            pallas = session.vars.get("pallas_groupagg", "auto")
+            params = ExecParams(
+                pallas_groupagg=self._pallas_mode(pallas),
+                pallas_interpret=self._pallas_interpret(),
+                pallas_group_tile=gt, pallas_block_rows=br,
+                pallas_limb_cap=limb_cap)
+            if large_kernel_eligible(agg, padded, params):
+                return large_kernel_bytes(agg, padded, params)
+        return 16 * _count_aggs(node) * padded
+
+    @staticmethod
+    def _pallas_mode(pallas) -> str:
+        """A value of session var pallas_groupagg as auto | on | off:
+        legacy bool spellings normalize (True was the old opt-in),
+        anything unrecognized means off."""
+        if isinstance(pallas, bool):
+            pallas = "on" if pallas else "off"
+        pallas = str(pallas).lower()
+        return pallas if pallas in ("auto", "on", "off") else "off"
 
     def _page_rows(self, session: Session) -> int:
         """Session page size rounded UP to a shape-ladder bucket: page
@@ -1059,31 +1153,37 @@ class ScanPlaneMixin:
         valid: dict[str, np.ndarray] = {}
         n = sum(c.n for c in chunks)
         padded = self._row_bucket(n)
+
+        def gather(parts, dtype, fill=0) -> np.ndarray:
+            """The chunks' arrays in one padded array of `dtype`, each
+            written once into its place: a 2^26-row column is held
+            once on the host, not as a concatenation, a cast and a
+            padded copy of it."""
+            out = np.empty(padded, dtype=dtype)
+            at = 0
+            for part in parts:
+                out[at:at + len(part)] = part
+                at += len(part)
+            out[at:] = fill
+            return out
+
         for col in td.schema.columns:
             cn = col.name
             if prune is not None and cn not in prune:
                 continue
             parts = [c.data[cn] for c in chunks]
-            arr = (np.concatenate(parts) if parts
-                   else np.zeros(0, dtype=col.type.np_dtype))
-            if cn in narrow:
-                arr = arr.astype(np.int32)
-            vparts = [c.valid[cn] for c in chunks]
-            va = np.concatenate(vparts) if vparts else np.zeros(0, bool)
-            cols[cn] = _pad(arr, padded)
-            if not va.all():
+            cols[cn] = gather(
+                parts, np.int32 if cn in narrow
+                else parts[0].dtype if parts else col.type.np_dtype)
+            if any(c.zone(cn)[2] for c in chunks):
                 # all-valid masks regenerate on device (ones) for free
                 # instead of paying PCIe for a constant
-                valid[cn] = _pad(va, padded)
-        ts_parts = [c.mvcc_ts for c in chunks]
-        del_parts = [c.mvcc_del for c in chunks]
-        mts = np.concatenate(ts_parts) if ts_parts else np.zeros(0, np.int64)
-        mdl = (np.concatenate(del_parts) if del_parts
-               else np.zeros(0, np.int64))
+                valid[cn] = gather([c.valid[cn] for c in chunks], bool)
         # padding rows are never visible: created at +inf
-        cols["_mvcc_ts"] = _pad(mts, padded, fill=np.int64(2**62))
-        cols["_mvcc_del"] = _pad(mdl, padded, fill=np.int64(0))
-        # cols/valid hold fresh np.concatenate/_pad outputs built
+        cols["_mvcc_ts"] = gather([c.mvcc_ts for c in chunks], np.int64,
+                                  fill=np.int64(2**62))
+        cols["_mvcc_del"] = gather([c.mvcc_del for c in chunks], np.int64)
+        # cols/valid hold fresh arrays built
         # above, with no later writes. All-valid masks and sel are
         # created in place under the same sharding, so nothing of the
         # batch lands whole on one device first.

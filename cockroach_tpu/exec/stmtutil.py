@@ -58,17 +58,21 @@ def _host_sort(rows: list, meta: P.OutputMeta, keys) -> list:
     return out
 
 
-def _count_aggs(node: P.PlanNode) -> int:
-    """Aggregate-function count of the plan's root aggregate (for the
-    streaming working-set estimate)."""
+def _root_aggregate(node: P.PlanNode):
+    """The plan's root Aggregate (under a Limit and a Sort), or None."""
     n = node
     if isinstance(n, P.Limit):
         n = n.child
     if isinstance(n, P.Sort):
         n = n.child
-    if isinstance(n, P.Aggregate):
-        return max(len(n.aggs), 1)
-    return 1
+    return n if isinstance(n, P.Aggregate) else None
+
+
+def _count_aggs(node: P.PlanNode) -> int:
+    """Aggregate-function count of the plan's root aggregate (for the
+    streaming working-set estimate)."""
+    agg = _root_aggregate(node)
+    return max(len(agg.aggs), 1) if agg is not None else 1
 
 
 def _collect_scan_columns(node: P.PlanNode) -> dict[str, frozenset]:
@@ -129,14 +133,6 @@ def _collect_scans(node: P.PlanNode) -> dict[str, str]:
 # shared impl in utils/num.py; the alias keeps importers of
 # stmtutil._next_pow2 (exec/scanplane.py, exec/engine.py) working
 from ..utils.num import next_pow2 as _next_pow2  # noqa: E402
-
-
-def _pad(a: np.ndarray, n: int, fill=0) -> np.ndarray:
-    if a.shape[0] == n:
-        return a
-    out = np.full(n, fill, dtype=a.dtype)
-    out[: a.shape[0]] = a
-    return out
 
 
 @dataclass

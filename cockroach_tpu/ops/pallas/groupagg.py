@@ -130,6 +130,10 @@ FALLBACKS = _KernelTally()   # aggregations that wanted a kernel but
                              # compiled on the XLA segment path
 OPERAND_BYTES = _KernelTally()   # bytes of the HBM arrays a build hands
                                  # its kernel (what XLA writes for it)
+LIMB_BITS = _KernelTally()       # limb width of a large-G build's exact
+                                 # sums, summed over builds (/ builds)
+MATMUL_ROWS = _KernelTally()     # rows of a large-G build's matmul
+                                 # operand, summed over builds
 
 
 @functools.partial(jax.jit, static_argnames=("num_groups", "ops",

@@ -54,7 +54,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .groupagg import (  # noqa: F401
-    BUILDS, FALLBACKS, LANES, MAX, MIN, OPERAND_BYTES, ROWS)
+    BUILDS, FALLBACKS, LANES, LIMB_BITS, MATMUL_ROWS, MAX, MIN,
+    OPERAND_BYTES, ROWS)
 
 # group-domain tile (VMEM accumulator minor dim; multiple of 128 lanes)
 GROUP_TILE = 512
@@ -313,6 +314,9 @@ def large_group_aggregate(gid, sel, sources: tuple, masks: tuple,
     # a count row past the masks handed in would read a zero bit
     assert all(r[1] < len(masks) for r in layout if r[0] == "count")
     OPERAND_BYTES.bump("large", sum(a.nbytes for a in args))
+    MATMUL_ROWS.bump("large", n_mat)
+    LIMB_BITS.bump("large", max((r[3] for r in layout if r[0] == "limb"),
+                                default=0))
 
     def kernel(gid_ref, *refs):
         _kernel(gid_ref, *refs, layout=layout, src_words=tuple(src_words),

@@ -15,6 +15,8 @@ full memo/xform search (opt/xform/optimizer.go:239, later rounds).
 
 from __future__ import annotations
 
+import os
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -52,29 +54,72 @@ class TableStats:
     blooms: dict = field(default_factory=dict)
 
 
+# widest integer domain ANALYZE counts with one flag a value (a byte
+# each) in place of a sort
+ANALYZE_FLAG_DOMAIN = 1 << 27
+
+
+def _column_distinct(td, cn: str, lives: list) -> tuple[int, int]:
+    """(distinct values, NULLs) of one column over the live rows.
+    `lives[i]` is chunk i's live mask, None where every row is live.
+    An integer column (dictionary codes among them) whose zone maps
+    span a modest domain marks each value it meets in a flag array —
+    one pass, a chunk at a time — where anything else is gathered and
+    sorted (np.unique), as every column was before."""
+    nulls = 0
+    parts = []
+    for chunk, live in zip(td.chunks, lives):
+        v, d = chunk.valid[cn], chunk.data[cn]
+        if live is not None:
+            v, d = v[live], d[live]
+        nvalid = int(v.sum())
+        nulls += len(v) - nvalid
+        parts.append(d if nvalid == len(v) else d[v])
+    kind = {p.dtype.kind for p in parts}
+    if kind <= {"i", "u"} and parts:
+        zones = [c.zone(cn) for c in td.chunks]
+        los = [z[0] for z in zones if z[0] is not None]
+        if los:
+            lo = min(los)
+            span = max(z[1] for z in zones if z[1] is not None) - lo + 1
+            if span <= ANALYZE_FLAG_DOMAIN:
+                seen = np.zeros(span, dtype=bool)
+                for p in parts:
+                    seen[p.astype(np.int64) - lo] = True
+                return int(seen.sum()), nulls
+    arr = np.concatenate(parts) if parts else np.zeros(0)
+    return (int(len(np.unique(arr))) if arr.size else 0), nulls
+
+
 def analyze_columns(td) -> TableStats:
-    """Exact stats over a table's live rows (ANALYZE)."""
+    """Exact stats over a table's live rows (ANALYZE). The columns are
+    counted side by side (numpy gives up the interpreter lock in its
+    loops): sixteen columns of a 60M-row table are a few seconds, not
+    sixteen 60M-row sorts."""
+    from ..storage.chunkstats import MAX_WORKERS
     from ..storage.columnstore import MAX_TS_INT
 
     st = TableStats(analyzed=True, source="analyze")
     total = 0
-    parts: dict[str, list] = {c.name: [] for c in td.schema.columns}
-    nulls: dict[str, int] = {c.name: 0 for c in td.schema.columns}
+    lives = []
     for chunk in td.chunks:
         live = chunk.mvcc_del == MAX_TS_INT
-        total += int(live.sum())
-        for col in td.schema.columns:
-            cn = col.name
-            v = chunk.valid[cn][live]
-            d = chunk.data[cn][live]
-            nulls[cn] += int((~v).sum())
-            parts[cn].append(d[v])
+        n_live = int(live.sum())
+        total += n_live
+        lives.append(None if n_live == chunk.n else live)
     st.row_count = total
     st.analyzed_rows = total
-    for cn, ps in parts.items():
-        arr = np.concatenate(ps) if ps else np.zeros(0)
-        st.distinct[cn] = int(len(np.unique(arr))) if arr.size else 0
-        st.null_frac[cn] = nulls[cn] / total if total else 0.0
+    names = [c.name for c in td.schema.columns]
+    workers = min(len(names), os.cpu_count() or 1, MAX_WORKERS)
+    if workers > 1 and len(td.chunks) > 1:
+        with ThreadPoolExecutor(max_workers=workers) as pool:
+            counted = list(pool.map(
+                lambda cn: _column_distinct(td, cn, lives), names))
+    else:
+        counted = [_column_distinct(td, cn, lives) for cn in names]
+    for cn, (distinct, nulls) in zip(names, counted):
+        st.distinct[cn] = distinct
+        st.null_frac[cn] = nulls / total if total else 0.0
     return st
 
 

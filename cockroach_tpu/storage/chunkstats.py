@@ -28,6 +28,9 @@ del_max <= read_ts (everything dead by then).
 
 from __future__ import annotations
 
+import os
+import threading
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -36,19 +39,55 @@ import numpy as np
 _MIX1 = np.uint64(0xFF51AFD7ED558CCD)
 _MIX2 = np.uint64(0xC4CEB9FE1A85EC53)
 _S33 = np.uint64(33)
+_U6 = np.uint64(6)
+_U63 = np.uint64(63)
 
 
-def mix64(keys: np.ndarray) -> np.ndarray:
+def mix64(keys: np.ndarray, work: "Workspace | None" = None) -> np.ndarray:
     """Vectorized 64-bit avalanche over int-family keys (splitmix64
-    finalizer). Views through int64 first so every int width hashes
-    its sign-extended value identically."""
-    h = np.ascontiguousarray(keys).astype(np.int64,
-                                          copy=False).view(np.uint64)
-    h = h ^ (h >> _S33)
-    h = h * _MIX1
-    h = h ^ (h >> _S33)
-    h = h * _MIX2
-    return h ^ (h >> _S33)
+    finalizer). Every int width hashes its sign-extended value
+    identically. With `work` the digests are its `hashes` buffer."""
+    work = work or Workspace()
+    n = len(keys)
+    h, (t, _) = work.hashes(n), work.pair(n)
+    np.copyto(h.view(np.int64), keys, casting="unsafe")
+    for mult in (_MIX1, _MIX2, None):
+        np.right_shift(h, _S33, out=t)
+        np.bitwise_xor(h, t, out=h)
+        if mult is not None:
+            np.multiply(h, mult, out=h)
+    return h
+
+
+class Workspace:
+    """What the summaries of one chunk after another are built in:
+    three uint64 buffers of a chunk's rows (`hashes`, a column's
+    digests; `pair`, two the hash, the bloom and the sketch work in)
+    and the bloom's `flags`. A fresh array is paid for in page faults
+    when first written, which on a bulk ingest is most of the cost of
+    a summary, and threads that allocate queue on the allocator; a
+    thread that keeps one workspace pays once."""
+
+    def __init__(self):
+        self._words = np.empty((3, 0), dtype=np.uint64)
+        self._flags = np.empty(0, dtype=bool)
+
+    def _rows(self, n: int) -> np.ndarray:
+        if self._words.shape[1] < n:
+            self._words = np.empty((3, n), dtype=np.uint64)
+        return self._words[:, :n]
+
+    def hashes(self, n: int) -> np.ndarray:
+        return self._rows(n)[0]
+
+    def pair(self, n: int) -> tuple:
+        rows = self._rows(n)
+        return rows[1], rows[2]
+
+    def flags(self, n: int) -> np.ndarray:
+        if len(self._flags) < n:
+            self._flags = np.empty(n, dtype=bool)
+        return self._flags[:n]
 
 
 def _next_pow2(n: int) -> int:
@@ -75,13 +114,33 @@ class BlockedBloom:
         if len(keys):
             self.add_hashed(mix64(keys))
 
-    def add_hashed(self, h: np.ndarray) -> None:
+    def add_hashed(self, h: np.ndarray,
+                   work: Workspace | None = None) -> None:
         """Insert pre-hashed keys (seal-time stats hash each column
-        once and feed the same digest to bloom and sketch)."""
+        once and feed the same digest to bloom and sketch). A key's
+        four bits are set by four plain scatters into a flag a bit,
+        packed back into the words: unlike a read-modify-write a key
+        (`bitwise_or.at`) a scatter runs outside the interpreter lock,
+        so chunks summarized side by side do not queue."""
         if len(h) == 0:
             return
-        block = (h & np.uint64(len(self.words) - 1)).astype(np.int64)
-        np.bitwise_or.at(self.words, block, self._masks(h))
+        work = work or Workspace()
+        nw = len(self.words)
+        t, base = work.pair(len(h))
+        bits = work.flags(nw * 64)
+        if self.words.any():
+            bits[:] = np.unpackbits(self.words.view(np.uint8),
+                                    bitorder="little")
+        else:
+            bits[:] = False
+        np.bitwise_and(h, np.uint64(nw - 1), out=base)   # the key's word
+        np.left_shift(base, _U6, out=base)
+        for shift in self._SHIFTS:
+            np.right_shift(h, shift, out=t)
+            np.bitwise_and(t, _U63, out=t)
+            np.bitwise_or(t, base, out=t)
+            bits[t.view(np.int64)] = True
+        self.words = np.packbits(bits, bitorder="little").view(np.uint64)
 
     def might_contain(self, keys: np.ndarray) -> np.ndarray:
         """Boolean array: False is definite absence."""
@@ -95,13 +154,16 @@ class BlockedBloom:
     def might_contain_any(self, keys: np.ndarray) -> bool:
         return bool(self.might_contain(keys).any())
 
-    @staticmethod
-    def _masks(h: np.ndarray) -> np.ndarray:
+    # a key's four bit positions inside its word: bits 32.., 38..,
+    # 44.. and 50.. of its hash, six each
+    _SHIFTS = tuple(np.uint64(x) for x in (32, 38, 44, 50))
+
+    @classmethod
+    def _masks(cls, h: np.ndarray) -> np.ndarray:
         one = np.uint64(1)
-        m = one << ((h >> np.uint64(32)) & np.uint64(63))
-        m |= one << ((h >> np.uint64(38)) & np.uint64(63))
-        m |= one << ((h >> np.uint64(44)) & np.uint64(63))
-        m |= one << ((h >> np.uint64(50)) & np.uint64(63))
+        m = np.zeros(len(h), dtype=np.uint64)
+        for shift in cls._SHIFTS:
+            m |= one << ((h >> shift) & _U63)
         return m
 
     def tobytes(self) -> bytes:
@@ -130,17 +192,32 @@ class DistinctSketch:
         if len(keys):
             self.add_hashed(mix64(keys))
 
-    def add_hashed(self, h: np.ndarray) -> None:
-        if len(h) == 0:
+    def add_hashed(self, h: np.ndarray,
+                   work: Workspace | None = None) -> None:
+        n = len(h)
+        if n == 0:
             return
-        idx = (h >> np.uint64(56)).astype(np.int64)
-        low = (h & np.uint64((1 << 56) - 1)).astype(np.int64)
-        # rank = leading zeros of the 56-bit suffix, + 1
-        nbits = np.zeros(len(low), dtype=np.int64)
-        nz = low > 0
-        nbits[nz] = np.floor(np.log2(low[nz].astype(np.float64))) + 1
-        rho = (56 - nbits + 1).astype(np.uint8)
-        np.maximum.at(self.regs, idx, rho)
+        t, key = (work or Workspace()).pair(n)
+        # rank = leading zeros of the 56-bit suffix, + 1. frexp's
+        # exponent of a positive float is its bit length (0 for 0)
+        np.bitwise_and(h, np.uint64((1 << 56) - 1), out=t)
+        low = key.view(np.float64)
+        np.copyto(low, t, casting="unsafe")
+        rho = t.view(np.int32)[:n]
+        np.frexp(low, out=(low, rho))
+        np.subtract(57, rho, out=rho)
+        # the max rank a register, without a read-modify-write a key:
+        # mark every (register, rank) pair that occurs (a plain
+        # scatter), then the highest marked rank of each register
+        key = key.view(np.int64)
+        np.right_shift(h, np.uint64(56), out=key.view(np.uint64))
+        np.left_shift(key, 6, out=key)
+        np.bitwise_or(key, rho, out=key)
+        seen = np.zeros(self._M * 64, dtype=bool)
+        seen[key] = True
+        top = (seen.reshape(self._M, 64)
+               * np.arange(64, dtype=np.uint8)).max(axis=1)
+        np.maximum(self.regs, top, out=self.regs)
 
     def merge(self, other: "DistinctSketch") -> None:
         np.maximum(self.regs, other.regs, out=self.regs)
@@ -187,11 +264,15 @@ def column_zone(vals: np.ndarray, valid: np.ndarray):
 
 
 def compute(data: dict, valid: dict, mvcc_ts: np.ndarray,
-            mvcc_del: np.ndarray) -> ChunkStats:
+            mvcc_del: np.ndarray,
+            work: Workspace | None = None) -> ChunkStats:
     """Build the full seal-time summary for one chunk. Blooms and
     sketches cover int-family columns only (ints + dict codes); float
-    and object columns still get zones."""
+    and object columns still get zones. `work`: the buffers to work
+    in, for a caller that summarizes many chunks (compute_many)."""
     st = ChunkStats()
+    n = len(mvcc_ts)
+    work = work or Workspace()
     for col, vals in data.items():
         v = valid[col]
         z = column_zone(vals, v)
@@ -201,17 +282,40 @@ def compute(data: dict, valid: dict, mvcc_ts: np.ndarray,
             # gather on fully-valid columns, and hash once for both
             # summaries — this runs on every ingest/compaction seal
             keys = vals if z[3] == len(vals) else vals[v]
-            h = mix64(keys) if len(keys) else keys
+            h = mix64(keys, work)
             bl = BlockedBloom(len(keys))
-            bl.add_hashed(h)
+            bl.add_hashed(h, work)
             st.blooms[col] = bl
             sk = DistinctSketch()
-            sk.add_hashed(h)
+            sk.add_hashed(h, work)
             st.distinct[col] = sk
-    n = len(mvcc_ts)
     st.ts_min = int(mvcc_ts.min()) if n else 0
     st.del_max = int(mvcc_del.max()) if n else 0
     return st
+
+
+# threads that build chunk summaries side by side on a bulk ingest:
+# numpy gives up the interpreter lock inside its loops, so they scale
+# until the memory bus is full
+MAX_WORKERS = 16
+
+
+def compute_many(chunks: list) -> list:
+    """compute() for each (data, valid, mvcc_ts, mvcc_del), in order.
+    More than one chunk is spread over a thread pool, each thread with
+    a Workspace of its own."""
+    workers = min(len(chunks), os.cpu_count() or 1, MAX_WORKERS)
+    if workers <= 1:
+        return [compute(*c) for c in chunks]
+    local = threading.local()
+
+    def one(chunk):
+        if not hasattr(local, "work"):
+            local.work = Workspace()
+        return compute(*chunk, work=local.work)
+
+    with ThreadPoolExecutor(max_workers=workers) as pool:
+        return list(pool.map(one, chunks))
 
 
 def extend(st: ChunkStats, col: str, vals: np.ndarray,

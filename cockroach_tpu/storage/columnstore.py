@@ -28,6 +28,7 @@ layer (storage/memtable.py, kv/); this module is the scan plane.
 from __future__ import annotations
 
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -45,7 +46,21 @@ class Dictionary:
 
     def __init__(self):
         self.values: list[str] = []
-        self.codes: dict[str, int] = {}
+        # value -> code; None while a list taken whole by seed() waits
+        # for the first lookup that needs the other direction
+        self._codes: Optional[dict[str, int]] = {}
+
+    @property
+    def codes(self) -> dict[str, int]:
+        if self._codes is None:
+            vals = self.values
+            codes = dict(zip(vals, range(len(vals))))
+            if len(codes) != len(vals):
+                raise ValueError(
+                    "a dictionary was seeded with a repeated value: "
+                    "set_dictionary takes distinct values")
+            self._codes = codes
+        return self._codes
 
     def encode(self, v: str) -> int:
         c = self.codes.get(v)
@@ -54,6 +69,21 @@ class Dictionary:
             self.values.append(v)
             self.codes[v] = c
         return c
+
+    def seed(self, values) -> None:
+        """Take a loader's own dictionary of a column: its values, which
+        it promises are distinct, become codes 0..n-1 in one step, with
+        no call a value. The other direction (value -> code, which only
+        a string literal or a string insert asks for) is built in one
+        pass when first needed, and that pass refuses a repeated value:
+        tens of millions of comment strings are neither hashed nor held
+        a second time by a load that never looks one up. A dictionary
+        that already has values goes value by value."""
+        if self.values:
+            for v in values:
+                self.encode(v)
+        else:
+            self.values, self._codes = list(values), None
 
     def encode_array(self, vals) -> np.ndarray:
         arr = np.asarray(vals)
@@ -111,8 +141,10 @@ class Chunk:
         sketches + MVCC window) for this chunk. Called by every store
         path that creates or rebuilds a chunk, so the scan plane never
         has to compute a zone on demand."""
-        st = chunkstats.compute(self.data, self.valid,
-                                self.mvcc_ts, self.mvcc_del)
+        self.set_stats(chunkstats.compute(self.data, self.valid,
+                                          self.mvcc_ts, self.mvcc_del))
+
+    def set_stats(self, st) -> None:
         self._stats = st
         self._zones.update(st.zones)
 
@@ -233,6 +265,10 @@ class ColumnStore:
         # orphaned KV rows can never alias a new table's keyspace
         # (the reference keeps descriptor ids monotonic the same way)
         self._next_table_id = 100
+        # bulk ingest so far (insert_columns): rows taken and the
+        # seconds it held, read by the engine's metric registry
+        self.ingest_rows = 0
+        self.ingest_seconds = 0.0
 
     def alloc_table_id(self) -> int:
         with self._lock:
@@ -270,19 +306,23 @@ class ColumnStore:
         """Pre-seed a string column's dictionary so bulk ingest can pass
         already-encoded int32 codes (the big-data path: encoding 600M
         object strings through np.unique would dominate ingest)."""
-        d = self.table(name).dictionaries[col]
-        for v in values:
-            d.encode(v)
+        self.table(name).dictionaries[col].seed(values)
 
     def insert_columns(self, name: str, cols: dict[str, np.ndarray],
                        ts: Timestamp,
                        valid: Optional[dict[str, np.ndarray]] = None) -> int:
-        """Bulk columnar ingest (IMPORT path; one sealed chunk per call,
-        the analogue of AddSSTable ingestion in pkg/sql/importer).
+        """Bulk columnar ingest (IMPORT path, the analogue of AddSSTable
+        ingestion in pkg/sql/importer): the rows are sealed in chunks
+        of the table's `chunk_rows`, so that a zone map or a bloom
+        covers a megarow and not the whole load, and the chunks'
+        statistics are built side by side (chunkstats.compute_many).
+        A chunk's arrays are views of the caller's where the dtype
+        already fits: a 60M-row load is held once.
 
         String columns accept either string arrays (dictionary-encoded
         here) or int32 code arrays into a dictionary pre-seeded via
         set_dictionary."""
+        t0 = time.monotonic()
         td = self.table(name)
         valid = valid or {}
         n = len(next(iter(cols.values())))
@@ -324,18 +364,28 @@ class ColumnStore:
                 data[cn] = arr
                 vmap[cn] = (np.asarray(valid[cn], dtype=bool) if cn in valid
                             else np.ones(n, dtype=bool))
-            tsi = ts.to_int()
             rid0 = td.next_rowid
             td.next_rowid += n
-            chunk = Chunk(data=data, valid=vmap,
-                          mvcc_ts=np.full(n, tsi, dtype=np.int64),
-                          mvcc_del=np.full(n, MAX_TS_INT, dtype=np.int64),
-                          n=n,
-                          rowid=np.arange(rid0, rid0 + n, dtype=np.int64))
-            chunk.finalize_stats()
-            td.chunks.append(chunk)
+            mvcc_ts = np.full(n, ts.to_int(), dtype=np.int64)
+            mvcc_del = np.full(n, MAX_TS_INT, dtype=np.int64)
+            rowid = np.arange(rid0, rid0 + n, dtype=np.int64)
+            chunks = []
+            for lo in range(0, max(n, 1), td.chunk_rows):
+                rows = slice(lo, lo + td.chunk_rows)
+                chunks.append(Chunk(
+                    data={k: v[rows] for k, v in data.items()},
+                    valid={k: v[rows] for k, v in vmap.items()},
+                    mvcc_ts=mvcc_ts[rows], mvcc_del=mvcc_del[rows],
+                    n=len(mvcc_ts[rows]), rowid=rowid[rows]))
+            for chunk, st in zip(chunks, chunkstats.compute_many(
+                    [(c.data, c.valid, c.mvcc_ts, c.mvcc_del)
+                     for c in chunks])):
+                chunk.set_stats(st)
+            td.chunks.extend(chunks)
             td.pk_index = None  # rebuilt lazily if DML touches this table
             td.generation += 1
+            self.ingest_rows += n
+            self.ingest_seconds += time.monotonic() - t0
         return n
 
     def insert_rows(self, name: str, rows: list[dict], ts: Timestamp) -> int:
@@ -1086,6 +1136,10 @@ class ColumnStore:
                                      read_ts_int: int,
                                      include_null_group: bool = False
                                      ) -> int:
+        dense = ColumnStore._dense_key_multiplicity(
+            td, cols, read_ts_int, include_null_group)
+        if dense is not None:
+            return dense
         parts: list[list[np.ndarray]] = [[] for _ in cols]
         null_rows = 0
         for chunk in td.chunks:
@@ -1113,6 +1167,53 @@ class ColumnStore:
         runs = np.diff(np.append(starts, n))
         return max(int(runs.max()), null_rows)
 
+    # widest key domain counted directly (one int64 counter a key)
+    DENSE_KEY_DOMAIN = 1 << 22
+
+    @staticmethod
+    def _dense_key_multiplicity(td: TableData, cols: tuple,
+                                read_ts_int: int,
+                                include_null_group: bool):
+        """_key_max_multiplicity_locked without the sort, for integer
+        keys (dictionary codes among them) whose zone maps bound a
+        small domain: a chunk at a time, the keys become one mixed-
+        radix index and np.bincount counts them. A GROUP BY over two
+        flags of a 60M-row table is a second, not a 60M-row lexsort.
+        None where a key is not an integer or the domain is wide."""
+        los, dims = [], []
+        for c in cols:
+            lo = hi = None
+            for chunk in td.chunks:
+                if chunk.data[c].dtype.kind not in "iu":
+                    return None
+                zlo, zhi, _, nvalid = chunk.zone(c)
+                if nvalid:
+                    lo = zlo if lo is None else min(lo, zlo)
+                    hi = zhi if hi is None else max(hi, zhi)
+            if lo is None:
+                lo = hi = 0   # no valid value anywhere
+            los.append(lo)
+            dims.append(hi - lo + 1)
+        domain = 1
+        for d in dims:
+            domain *= d
+        if domain > ColumnStore.DENSE_KEY_DOMAIN:
+            return None
+        counts = np.zeros(domain, dtype=np.int64)
+        null_rows = 0
+        for chunk in td.chunks:
+            live = chunk.live_mask(read_ts_int)
+            m = live
+            for c in cols:
+                m = m & chunk.valid[c]
+            if include_null_group:
+                null_rows += int(live.sum()) - int(m.sum())
+            idx = np.zeros(chunk.n, dtype=np.int64)
+            for c, lo, d in zip(cols, los, dims):
+                idx = idx * d + (chunk.data[c].astype(np.int64) - lo)
+            counts += np.bincount(idx[m], minlength=domain)
+        return max(int(counts.max()), null_rows)
+
     def key_int_range(self, name: str, col: str):
         """(min, max, count) of an int-family key column over ALL
         versions (NULLs excluded), or None when empty. Sizes the
@@ -1130,14 +1231,16 @@ class ColumnStore:
             lo = hi = None
             n = 0
             for chunk in td.chunks:
-                m = chunk.valid[col]
-                if not m.any():
+                # the seal-time zone map is this chunk's answer
+                cmin, cmax, _, nvalid = chunk.zone(col)
+                if not nvalid:
                     continue
-                vals = chunk.data[col][m]
-                cmin, cmax = int(vals.min()), int(vals.max())
+                if cmin is None:
+                    raise TypeError(f"{name}.{col} has no integer range")
+                cmin, cmax = int(cmin), int(cmax)
                 lo = cmin if lo is None else min(lo, cmin)
                 hi = cmax if hi is None else max(hi, cmax)
-                n += int(m.sum())
+                n += nvalid
             out = None if lo is None else (lo, hi, n)
             td.key_distinct_cache[ck] = (td.generation, out)
             return out
