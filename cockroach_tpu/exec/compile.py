@@ -71,13 +71,15 @@ class ExecParams:
     pallas_interpret: bool = False
     # Large-G kernel tile point, normally the shipped
     # groupagg_large.py constants or the per-backend autotuned winner
-    # (ops/pallas/autotune.py). Any valid point is bit-identical —
-    # limb widths are recomputed from block_rows via the exactness
-    # bound — so these are perf-only and deliberately NOT part of the
-    # engine's executable-cache key.
+    # (ops/pallas/autotune.py). The group tile is an upper bound: a
+    # build takes pgl.effective_group_tile(num_groups, tile). Any valid
+    # point is bit-identical — limb widths are recomputed from
+    # block_rows via the exactness bound, never past the 8 bits one
+    # bf16 pass holds — so these are perf-only and deliberately NOT
+    # part of the engine's executable-cache key.
     pallas_group_tile: int = 512
-    pallas_block_rows: int = 1024
-    pallas_limb_cap: int = 22
+    pallas_block_rows: int = 4096
+    pallas_limb_cap: int = 8
     # Kernel paths the parity gate (ops/pallas/paritygate.py) proved
     # bit-identical to the XLA oracle on this backend: `auto` routing
     # admits exactly these beyond its always-exact envelope. Perf-only
@@ -659,12 +661,14 @@ def _large_interpret_over_budget(interpret: bool, n: int,
                                  block_rows: int | None = None) -> bool:
     """auto-mode cost check: would the large-G kernel's grid exceed
     the interpret-execution step budget on this backend? Counts the
-    grid at the plan's actual (possibly autotuned) tile point."""
+    grid at the plan's actual (possibly autotuned) tile point, the
+    group tile as the kernel sizes it."""
     if not interpret:
         return False
     from ..ops.pallas import groupagg_large as pgl
     blk = pgl.row_block(n, block_rows or pgl.BLOCK_ROWS)
-    gtiles = -(-num_groups // (group_tile or pgl.GROUP_TILE))
+    gtiles = -(-num_groups // pgl.effective_group_tile(
+        num_groups, group_tile or pgl.GROUP_TILE))
     return gtiles * (n // blk) > AUTO_INTERPRET_STEPS
 
 
@@ -839,9 +843,11 @@ def large_kernel_bytes(node: P.Aggregate, n: int,
     (`exec.pallas.kernel.operand_bytes` is this term) and the kernel's
     accumulator tiles over the padded group domain. The limb, count
     and shadow rows live in VMEM and cost no HBM (PERF.md, PR 26)."""
+    from ..ops.pallas import groupagg_large as pgl
     lay = large_layout(node.aggs, n, node.max_group_rows, params)
-    gp = -(-dense_num_groups(node) // params.pallas_group_tile) \
-        * params.pallas_group_tile
+    num_groups = dense_num_groups(node)
+    tile = pgl.effective_group_tile(num_groups, params.pallas_group_tile)
+    gp = -(-num_groups // tile) * tile
     acc_rows = (max(1, len(lay.f_rows)) + max(1, len(lay.i_rows))
                 + len(lay.mm) + int(lay.want_rep))
     return 4 * n * lay.n_words + 4 * gp * acc_rows

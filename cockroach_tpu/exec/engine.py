@@ -432,8 +432,21 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         self.metrics.func_counter(
             "exec.pallas.kernel.matmul_rows",
             lambda: _ga.MATMUL_ROWS.value("large"),
-            "rows of the large-G kernel's matmul operand (limb, count "
-            "and shadow rows, built in VMEM), summed over builds")
+            "rows of the large-G kernel's matmul operands (limb and "
+            "count rows, three rows a shadow; built in VMEM), summed "
+            "over builds")
+        self.metrics.func_counter(
+            "exec.pallas.kernel.group_tile",
+            lambda: _ga.GROUP_TILE_LANES.value("large"),
+            "lanes of the group tile the large-G kernel took, summed "
+            "over builds: the group count rounded up to 128, at most "
+            "the tile parameter (128 for TPC-H Q1's 12 groups)")
+        self.metrics.func_counter(
+            "exec.pallas.kernel.mxu_passes",
+            lambda: _ga.MXU_PASSES.value("large"),
+            "bf16 MXU passes of the large-G kernel's contraction of "
+            "its exact rows, summed over builds (1 a build: limbs of "
+            "at most 8 bits, counts and the one-hot are exact in bf16)")
         self.metrics.func_counter(
             "exec.pallas.rows",
             lambda: _ga.ROWS.value(),

@@ -1,13 +1,19 @@
 """Pallas tile autotuner for the large-G grouped-aggregation kernel.
 
 `groupagg_large.py` shipped with hand-picked constants
-(GROUP_TILE = 512, BLOCK_ROWS = 1024) tuned on one chip generation.
-The right (group_tile, block_rows, limb_cap) point moves with the MXU
-shape, VMEM size and HBM bandwidth of the backend, so this module
-times a small candidate grid on first use per backend and persists
-the winner in a tuning table next to the persistent compile cache
+(GROUP_TILE = 512, BLOCK_ROWS = 4096) tuned on one chip generation.
+The right (group_tile, block_rows) point moves with the MXU shape,
+VMEM size and HBM bandwidth of the backend, so this module times a
+small candidate grid on first use per backend and persists the winner
+in a tuning table next to the persistent compile cache
 (exec/coldstart.py). Restarted processes read the table instead of
-re-timing — the autotune analogue of the compile cache.
+re-timing — the autotune analogue of the compile cache. `group_tile`
+is the UPPER bound of the tile: a build takes the plan's group count
+rounded up to 128 lanes where that is smaller
+(groupagg_large.effective_group_tile), so the sweep's q18-class shape
+decides nothing for a twelve-group plan. `limb_cap` stays in the
+table's format; every candidate carries the widest limb one bf16 pass
+allows, 8, and an older table's wider cap reads as 8.
 
 Correctness is NOT at stake: every candidate satisfies the kernel's
 alignment contract (group_tile a multiple of 128, block_rows a power
@@ -43,12 +49,11 @@ _TABLE_NAME = "pallas_autotune.json"
 # row block (pow2) x limb-width cap. Small on purpose — each point
 # costs a kernel compile at tuning time.
 CANDIDATES: tuple[tuple[int, int, int], ...] = (
-    (512, 1024, 22),   # the shipped constants
-    (256, 1024, 22),
-    (1024, 1024, 22),
-    (512, 512, 22),
-    (512, 2048, 22),
-    (512, 1024, 16),   # narrower limbs: more columns, denser matmul
+    (512, 4096, 8),    # the shipped constants
+    (256, 4096, 8),
+    (1024, 1024, 8),
+    (512, 1024, 8),
+    (512, 2048, 8),
 )
 
 DEFAULT = CANDIDATES[0]
@@ -97,7 +102,8 @@ def _valid_entry(e) -> tuple[int, int, int] | None:
     if gt <= 0 or gt % 128 or br < 128 or br & (br - 1) \
             or not (1 <= cap <= 22):
         return None
-    return gt, br, cap
+    # a table written before the one-pass kernel may hold 16 or 22
+    return gt, br, min(cap, pgl.MAX_LIMB_BITS)
 
 
 def load_table(root: str) -> dict:
@@ -178,7 +184,7 @@ def autotune(backend: str, root: str | None, interpret: bool,
     shape so the Python grid loop stays in seconds."""
     import time
     if n is None:
-        n = 1 << 10 if interpret else 1 << 16
+        n = 1 << 12 if interpret else 1 << 16
     if num_groups is None:
         num_groups = 256 if interpret else 1 << 12
     RUNS.bump("sweep")

@@ -133,7 +133,11 @@ OPERAND_BYTES = _KernelTally()   # bytes of the HBM arrays a build hands
 LIMB_BITS = _KernelTally()       # limb width of a large-G build's exact
                                  # sums, summed over builds (/ builds)
 MATMUL_ROWS = _KernelTally()     # rows of a large-G build's matmul
-                                 # operand, summed over builds
+                                 # operands, summed over builds
+GROUP_TILE_LANES = _KernelTally()    # lanes of the group tile a large-G
+                                     # build took, summed over builds
+MXU_PASSES = _KernelTally()      # bf16 MXU passes of a large-G build's
+                                 # exact-rows contraction, over builds
 
 
 @functools.partial(jax.jit, static_argnames=("num_groups", "ops",

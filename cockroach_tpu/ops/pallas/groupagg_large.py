@@ -16,6 +16,16 @@ with the row-block dimension innermost, so each output tile is
 revisited across consecutive steps (the standard Pallas reduction
 pattern; the accumulator is initialised under `pl.when(i == 0)`).
 
+The contraction's geometry comes from the plan. The group tile is the
+group count rounded up to whole 128-lane vregs, at most the tile
+parameter (effective_group_tile): TPC-H Q1's 12 groups take 128 lanes,
+not 512. And every row whose values are exact in bf16 — limbs of at
+most 8 bits, count bits, the liveness bit, the three bf16 pieces of a
+shadow — is contracted against the one-hot (0 and 1: exact too) in ONE
+bf16 MXU pass with f32 accumulation, not the six passes of an f32
+matmul at `Precision.HIGHEST`; only float-sum rows keep such a
+contraction, of their own.
+
 Dtype envelope — wider than the small kernel's f32-only one:
 
 - f32 value columns accumulate in a f32 [NF, G_tile] tile. A block
@@ -25,14 +35,21 @@ Dtype envelope — wider than the small kernel's f32-only one:
   correct: each 64-bit argument reaches the kernel as its two 32-bit
   words (Mosaic has no 64-bit lanes), the kernel cuts the w-bit limbs
   out of them per row block, in VMEM, and
-  accumulates each limb column in an i32 tile (the f32 matmul block
-  partial is exact while blk*(2^w-1) < 2^24, i.e. w <= 24-log2(blk);
-  the per-group i32 accumulator is exact while
-  max_group_rows*(2^w-1) < 2^31 — `limb_width` takes the min), and
-  the caller recombines with `sum_j limbs[j] << (j*w)` in int64,
+  accumulates each limb column in an i32 tile. Three bounds keep that
+  exact, and `limb_width` takes the min: w <= 8, so a limb is an
+  integer in [0, 255] and exact in bf16, as is its product with the
+  one-hot; the MXU's f32 block partial is exact while
+  blk*(2^w-1) < 2^24, i.e. w <= 24-log2(blk) (never the tighter one
+  for a block of at most 2^16 rows); the per-group i32 accumulator is
+  exact while max_group_rows*(2^w-1) < 2^31. The caller recombines
+  with `sum_j limbs[j] << (j*w)` in int64,
   whose wrapping IS int64 modular arithmetic — bit-identical to the
   XLA `_group_sum_i64_limbs` path. DECIMAL-exact q1/q3/q18 revenue
   sums are therefore eligible here.
+- the f32 shadow of an exact sum, which only feeds the overflow
+  sentinel, is cut into its hi, mid and lo bf16 pieces (the split
+  HIGHEST itself makes of an f32 operand, 24 significant bits) and
+  rides the same pass as three rows, summed again in f32.
 - MIN/MAX slots are per-row masked reductions folded with
   minimum/maximum against +/-inf identities (no matmul).
 - a REPMIN slot (i32 min of row id over onehot & sel) replaces the
@@ -54,14 +71,33 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .groupagg import (  # noqa: F401
-    BUILDS, FALLBACKS, LANES, LIMB_BITS, MATMUL_ROWS, MAX, MIN,
-    OPERAND_BYTES, ROWS)
+    BUILDS, FALLBACKS, GROUP_TILE_LANES, LANES, LIMB_BITS, MATMUL_ROWS,
+    MAX, MIN, MXU_PASSES, OPERAND_BYTES, ROWS)
 
-# group-domain tile (VMEM accumulator minor dim; multiple of 128 lanes)
+# group-domain tile (VMEM accumulator minor dim; multiple of 128
+# lanes): the UPPER bound of the tile a build takes, which is sized by
+# the plan's group count (effective_group_tile)
 GROUP_TILE = 512
 # row-block size per grid step (bounds the one-hot tile and the f32
-# matmul partial exactness window: blk*(2^w-1) < 2^24)
-BLOCK_ROWS = 1024
+# matmul partial exactness window: blk*(2^w-1) < 2^24, which an 8-bit
+# limb keeps up to 2^16 rows). On the v5e a step's fixed cost and its
+# one [1, blk] DMA an operand are most of a 1,024-row step once the
+# contraction is one bf16 pass: 4,096 rows is 1.7-2.4x faster than
+# 1,024 at 128 and at 512 lanes, and 8,192 rows of TPC-H Q1's thirteen
+# operands pass the 16 MB of scoped VMEM (PERF.md, PR 29)
+BLOCK_ROWS = 4096
+# the widest limb: an 8-bit limb, a count bit and the one-hot are all
+# exact in bf16, so the exact rows' contraction is ONE bf16 MXU pass
+MAX_LIMB_BITS = 8
+
+
+def effective_group_tile(num_groups: int,
+                         group_tile: int = GROUP_TILE) -> int:
+    """Lanes of the group tile a build over `num_groups` dense groups
+    takes: the domain rounded up to whole 128-lane vregs, at most the
+    (possibly autotuned) `group_tile`. TPC-H Q1's 12 groups take 128
+    lanes, not 512; a domain past group_tile - 128 sees the parameter."""
+    return min(group_tile, -(-max(1, num_groups) // LANES) * LANES)
 
 
 def row_block(n: int, block_rows: int = BLOCK_ROWS) -> int:
@@ -72,20 +108,22 @@ def row_block(n: int, block_rows: int = BLOCK_ROWS) -> int:
 
 
 def limb_width(n: int, max_group_rows: int,
-               block_rows: int = BLOCK_ROWS, cap: int = 22) -> int:
-    """The widest limb w such that BOTH accumulations stay exact:
-    the f32 matmul block partial (blk*(2^w-1) < 2^24) and the
-    per-group i32 running sum (maxg*(2^w-1) < 2^31). Mirrors
-    agg._group_sum_i64_limbs' bound, tightened by the block term.
-    `cap` (autotuned, ops/pallas/autotune.py) may only narrow the
-    width below the exactness bound — results stay bit-identical for
-    any cap in [1, 22], a narrower cap just trades more limb columns
-    for a denser matmul."""
+               block_rows: int = BLOCK_ROWS,
+               cap: int = MAX_LIMB_BITS) -> int:
+    """The widest limb w such that ALL THREE steps stay exact: the
+    bf16 operand (w <= 8: a limb is an integer in [0, 255]), the MXU's
+    f32 block partial (blk*(2^w-1) < 2^24) and the per-group i32
+    running sum (maxg*(2^w-1) < 2^31). Mirrors
+    agg._group_sum_i64_limbs' bound, tightened by the first two.
+    `cap` (a tuning table's, ops/pallas/autotune.py) may only narrow
+    the width below the exactness bound — results stay bit-identical
+    for any cap >= 1, a narrower cap just trades more limb rows for
+    nothing; a cap past 8 reads as 8."""
     blk = row_block(n, block_rows)
     maxg = max_group_rows if max_group_rows and 0 < max_group_rows <= n else n
     maxg = max(1, maxg)
     w = int(math.floor(math.log2((2 ** 31 - 1) / maxg + 1)))
-    w = min(w, 24 - int(math.log2(blk)), 22, cap)
+    w = min(w, 24 - int(math.log2(blk)), MAX_LIMB_BITS, cap)
     return max(1, w)
 
 
@@ -123,22 +161,41 @@ def _shadow(lo, hi):
     return hi.astype(jnp.float32) * np.float32(2.0 ** 32) + ulo
 
 
-def _kernel(gid_ref, *refs, layout: tuple, src_words: tuple,
-            n_mwords: int, n_words: int, n_f: int, n_mat_f: int,
+def _bf16_pieces(v):
+    """f32 v as three f32 pieces, hi + mid + lo == v exactly, each of
+    at most 8 significant bits and so exact in bf16: the split
+    Precision.HIGHEST makes of an f32 operand (24 significant bits),
+    by truncation."""
+    def top8(x):    # sign, exponent and the 7 stored bits bf16 keeps
+        return jax.lax.bitcast_convert_type(
+            jax.lax.bitcast_convert_type(x, jnp.int32)
+            & np.int32(-65536), jnp.float32)
+    hi = top8(v)
+    mid = top8(v - hi)
+    return hi, mid, v - hi - mid
+
+
+def _kernel(gid_ref, *refs, i_rows: tuple, split: tuple, f_rows: tuple,
+            src_words: tuple, n_mwords: int, n_words: int, n_f: int,
             mm_ops: tuple, want_rep: bool, group_tile: int, blk: int,
             n: int, nf: int, ni: int):
-    n_mat = len(layout)
     mask_refs, refs = refs[:n_mwords], refs[n_mwords:]
     word_refs, refs = refs[:n_words], refs[n_words:]
     f_refs, refs = refs[:n_f], refs[n_f:]
     mm_refs, refs = refs[:len(mm_ops)], refs[len(mm_ops):]
-    *outs, mat_ref = refs   # mat_ref: the [n_mat, blk] f32 VMEM scratch
+    n_mat_i, n_split = len(i_rows), len(split)
+    n_x = n_mat_i + 3 * n_split
+    n_out = 2 + bool(mm_ops) + want_rep
+    # scratch: the [rows, blk] f32 operands of the bf16 pass (x) and of
+    # the f32 one (f), each only where the layout has such rows
+    outs, scratch = refs[:n_out], iter(refs[n_out:])
+    x_ref = next(scratch) if n_x else None
+    f_ref = next(scratch) if f_rows else None
     acc_f_ref, acc_i_ref = outs[:2]
     acc_mm_ref = outs[2] if mm_ops else None
     acc_rep_ref = outs[-1] if want_rep else None
     j = pl.program_id(0)   # group tile (outer)
     i = pl.program_id(1)   # row block (inner: output tile revisited)
-    n_mat_i = n_mat - n_mat_f
 
     @pl.when(i == 0)
     def _init():
@@ -152,10 +209,10 @@ def _kernel(gid_ref, *refs, layout: tuple, src_words: tuple,
             acc_rep_ref[:, :] = jnp.full(
                 (group_tile, 1), np.int32(n), jnp.int32)
 
-    # the matmul operand of this row block, built here from the
+    # the matmul operands of this row block, built here from the
     # aggregates' ARGUMENTS: each source's words and each mask word are
     # loaded once, every limb/count/shadow row derived on the VPU and
-    # written to the scratch. The [n_mat, n] matrix exists nowhere else
+    # written to the scratch. The [rows, n] matrix exists nowhere else
     words = [ref[:, :] for ref in word_refs]
     srcs = [(words[lo], None if hi is None else words[hi])
             for lo, hi in src_words]
@@ -164,19 +221,23 @@ def _kernel(gid_ref, *refs, layout: tuple, src_words: tuple,
     def mask_bit(k):
         return _srl(mwords[k // 32], k % 32) & np.int32(1)
 
-    for r, row in enumerate(layout):
+    for r, row in enumerate(i_rows):
         kind = row[0]
-        if kind == "f":
-            v = f_refs[row[1]][:, :]
-        elif kind == "shadow":
-            v = _shadow(*srcs[row[1]])
-        elif kind == "limb":
+        if kind == "limb":
             v = _limb(*srcs[row[1]], row[2], row[3])
         elif kind == "count":      # bit 0 is sel, mask k rides bit k + 1
             v = mask_bit(row[1] + 1).astype(jnp.float32)
         else:                      # "live"
             v = mask_bit(0).astype(jnp.float32)
-        mat_ref[r:r + 1, :] = v
+        x_ref[r:r + 1, :] = v
+    # a shadow rides the same pass as its hi, mid and lo rows, the
+    # three blocks after the i rows
+    for k, (_, src) in enumerate(split):
+        for p, piece in enumerate(_bf16_pieces(_shadow(*srcs[src]))):
+            r = n_mat_i + p * n_split + k
+            x_ref[r:r + 1, :] = piece
+    for k, (_, col) in enumerate(f_rows):
+        f_ref[k:k + 1, :] = f_refs[col][:, :]
 
     # rows ride the LANE axis: every per-row input is a (1, blk) block
     # of a lane-dense array, and the one-hot is built transposed,
@@ -184,23 +245,37 @@ def _kernel(gid_ref, *refs, layout: tuple, src_words: tuple,
     ids = j * group_tile + jax.lax.broadcasted_iota(
         jnp.int32, (group_tile, blk), 0)
     onehot = gid_ref[:, :] == ids  # (1, blk) == (GT, blk) -> broadcast
+    onehot_f32 = onehot.astype(jnp.float32)
+    contract_rows = (((1,), (1,)), ((), ()))
 
-    # the whole block's segment partial as ONE [n_mat, GT] MXU matmul
-    # (contracting the row axis of both operands). HIGHEST: the
-    # exactness argument in the module docstring needs the f32
-    # contraction at full precision — a bf16 pass would round any limb
-    # wider than 8 bits.
-    part = jax.lax.dot_general(
-        mat_ref[:, :], onehot.astype(jnp.float32),
-        (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32)
-    if n_mat_f:
-        acc_f_ref[0:n_mat_f, :] += part[0:n_mat_f, :]
-    if n_mat_i:
-        # limb/count columns are small non-negative ints: the f32
-        # partial is exact by the limb_width bound, so the i32 cast
-        # is lossless
-        acc_i_ref[0:n_mat_i, :] += part[n_mat_f:n_mat, :].astype(jnp.int32)
+    if n_x:
+        # the block's segment partial of every exact row as ONE
+        # [n_x, GT] MXU matmul, in ONE bf16 pass: a limb of at most 8
+        # bits, a count bit, a shadow piece and the one-hot's 0 and 1
+        # are all exact in bf16, so is every product, and the MXU
+        # accumulates them in f32 — exact for the integer rows by the
+        # limb_width bound (blk * 255 < 2^24)
+        part = jax.lax.dot_general(
+            x_ref[:, :].astype(jnp.bfloat16),
+            onehot_f32.astype(jnp.bfloat16), contract_rows,
+            preferred_element_type=jnp.float32)
+        if n_mat_i:
+            # the f32 partial of a limb/count row is an exact integer
+            # under 2^24, so the i32 cast is lossless
+            acc_i_ref[0:n_mat_i, :] += part[0:n_mat_i, :].astype(jnp.int32)
+        for k, (at, _) in enumerate(split):
+            hi, mid, lo = (part[r:r + 1, :] for r in (
+                n_mat_i + p * n_split + k for p in range(3)))
+            acc_f_ref[at:at + 1, :] += lo + mid + hi
+    if f_rows:
+        # float sums are not bf16-exact: their own small contraction,
+        # six bf16 passes (HIGHEST), f32 precision
+        part = jax.lax.dot_general(
+            f_ref[:, :], onehot_f32, contract_rows,
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        for k, (at, _) in enumerate(f_rows):
+            acc_f_ref[at:at + 1, :] += part[k:k + 1, :]
 
     # MIN/MAX and REPMIN reduce along the lanes, so their accumulators
     # are [GT, 1] columns of outputs laid out [G, slots]
@@ -235,7 +310,7 @@ def large_group_aggregate(gid, sel, sources: tuple, masks: tuple,
 
     gid: int32[n] dense ids (0..num_groups-1); rows outside [0, G)
     match no one-hot column. The kernel is handed the aggregates'
-    ARGUMENTS and builds its matmul operand itself, per row block, in
+    ARGUMENTS and builds its matmul operands itself, per row block, in
     VMEM:
 
     - sources: one integer [n] array per distinct exact-sum argument,
@@ -245,11 +320,15 @@ def large_group_aggregate(gid, sel, sources: tuple, masks: tuple,
     - masks: bool[n], each already ANDed with `sel`; with `sel` they
       are packed 32 to an int32 word (bit 0 is `sel`).
     - f_values: f32[n] float-sum columns, pre-masked to 0.
-    - layout: one entry per matmul row, f32-accumulated rows first —
+    - layout: one entry per row of sums, f32-accumulated rows first —
       ("f", j) is f_values[j], ("shadow", s) an f32 approximation of
       source s — then the i32-accumulated ones: ("limb", s, shift,
-      width) is bits [shift, shift + width) of source s (limb_rows),
-      ("count", k) counts masks[k], ("live",) counts `sel`.
+      width) is bits [shift, shift + width <= 8) of source s
+      (limb_rows), ("count", k) counts masks[k], ("live",) counts
+      `sel`. The i rows and the shadows (three rows each) are one bf16
+      MXU pass, the f rows an f32 contraction of their own.
+    - group_tile: the tile's upper bound; the build takes
+      effective_group_tile(num_groups, group_tile) lanes.
     - mm_values/mm_ops: MIN/MAX slots, pre-masked to their +/-inf
       identities.
 
@@ -273,11 +352,22 @@ def large_group_aggregate(gid, sel, sources: tuple, masks: tuple,
     n_mat = len(layout)
     assert n_mat >= 1
     n_mat_f = sum(row[0] in ("f", "shadow") for row in layout)
-    # f rows first, then i rows — the kernel slices `part` once
-    assert all(row[0] in ("limb", "count", "live")
-               for row in layout[n_mat_f:])
-    n_mat_i = n_mat - n_mat_f
+    # f rows first, then i rows
+    i_rows = layout[n_mat_f:]
+    assert all(row[0] in ("limb", "count", "live") for row in i_rows)
+    # the one bf16 pass is exact only for limbs of at most 8 bits
+    assert all(r[3] <= MAX_LIMB_BITS for r in i_rows if r[0] == "limb")
+    # a shadow rides the bf16 pass as three rows; a float sum keeps a
+    # HIGHEST contraction of its own. Each as (its row of the f
+    # accumulator, its source or column)
+    f_rows = tuple((r, row[1]) for r, row in enumerate(layout[:n_mat_f])
+                   if row[0] == "f")
+    split = tuple((r, row[1]) for r, row in enumerate(layout[:n_mat_f])
+                  if row[0] == "shadow")
+    n_mat_i = len(i_rows)
+    n_x = n_mat_i + 3 * len(split)
     blk = row_block(n, block_rows)
+    group_tile = effective_group_tile(num_groups, group_tile)
     gtiles = -(-num_groups // group_tile)
     gp = gtiles * group_tile
     nf = max(1, n_mat_f)
@@ -314,14 +404,17 @@ def large_group_aggregate(gid, sel, sources: tuple, masks: tuple,
     # a count row past the masks handed in would read a zero bit
     assert all(r[1] < len(masks) for r in layout if r[0] == "count")
     OPERAND_BYTES.bump("large", sum(a.nbytes for a in args))
-    MATMUL_ROWS.bump("large", n_mat)
+    MATMUL_ROWS.bump("large", n_x + len(f_rows))
     LIMB_BITS.bump("large", max((r[3] for r in layout if r[0] == "limb"),
                                 default=0))
+    GROUP_TILE_LANES.bump("large", group_tile)
+    MXU_PASSES.bump("large", int(n_x > 0))
 
     def kernel(gid_ref, *refs):
-        _kernel(gid_ref, *refs, layout=layout, src_words=tuple(src_words),
+        _kernel(gid_ref, *refs, i_rows=i_rows, split=split, f_rows=f_rows,
+                src_words=tuple(src_words),
                 n_mwords=len(mwords), n_words=len(words),
-                n_f=len(f_values), n_mat_f=n_mat_f, mm_ops=mm_ops,
+                n_f=len(f_values), mm_ops=mm_ops,
                 want_rep=want_rep, group_tile=group_tile, blk=blk, n=n,
                 nf=nf, ni=ni)
 
@@ -359,7 +452,8 @@ def large_group_aggregate(gid, sel, sources: tuple, masks: tuple,
             grid=(gtiles, n // blk),
             in_specs=[row1] * len(args),
             out_specs=tuple(out_specs),
-            scratch_shapes=[pltpu.VMEM((n_mat, blk), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((rows, blk), jnp.float32)
+                            for rows in (n_x, len(f_rows)) if rows],
             interpret=interpret,
         )(*args)
     acc_f, acc_i = outs[0][:, :num_groups], outs[1][:, :num_groups]

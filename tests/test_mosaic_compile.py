@@ -1,0 +1,91 @@
+"""The large-G kernel compiled for the v5e here, without a chip: the
+TPU's own compiler (XLA:TPU and Mosaic) is installed and compiles for
+a topology that is described, not attached. Interpret mode cannot see
+what these see: a contraction Mosaic refuses, a block that passes the
+16 MB of scoped VMEM. Shapes are the benchmark's (TPC-H Q1's layout at
+SF1's 2^23 and SF10's 2^26 rows) and the autotuner's q18-class one.
+Nothing runs, so nothing here is a time or an answer.
+
+The topology is described inside a fixture, never at import: only the
+worker that is given this file loads the TPU's library.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from cockroach_tpu.ops.pallas import groupagg_large as pgl
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _q1_layout(w):
+    """TPC-H Q1's kernel layout: five 64-bit arguments, a shadow and
+    a validity each, liveness (66 rows at SF10's width 6)."""
+    layout = tuple(("shadow", s) for s in range(5))
+    for s in range(5):
+        layout += pgl.limb_rows(s, 64, w)
+    return layout + tuple(("count", k) for k in range(5)) + (("live",),)
+
+
+def _compile(one_chip, n, num_groups, layout, n_src, n_f=0, mm_ops=(),
+             want_rep=False, **kw):
+    def spec(dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+
+    def fn(gid, sel, sources, masks, f_values, mm_values):
+        # the engine traces under x64: index maps must stay i32
+        return pgl.large_group_aggregate(
+            gid, sel, sources, masks, f_values, mm_values,
+            num_groups=num_groups, layout=layout, mm_ops=mm_ops,
+            want_rep=want_rep, **kw)
+
+    n_masks = 1 + max((r[1] for r in layout if r[0] == "count"),
+                      default=-1)
+    with jax.enable_x64(True):
+        compiled = jax.jit(fn).trace(
+            spec(jnp.int32), spec(jnp.bool_),
+            tuple(spec(jnp.int64) for _ in range(n_src)),
+            tuple(spec(jnp.bool_) for _ in range(n_masks)),
+            tuple(spec(jnp.float32) for _ in range(n_f)),
+            tuple(spec(jnp.float32) for _ in mm_ops)).lower().compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("n,w", [(1 << 23, 8), (1 << 26, 6)])
+def test_q1_layout_compiles_at_the_benchmarks_sizes(one_chip, n, w):
+    """The bf16 transposed contraction over a 128-lane tile, at the
+    default 4,096 rows a step: Q1 at SF1 (61 matmul rows) and at SF10
+    (76), thirteen [1, n] operands."""
+    _compile(one_chip, n, 12, _q1_layout(w), n_src=5)
+
+
+def test_q18_class_shape_compiles_at_the_tile_parameter(one_chip):
+    """4,096 groups: the tile is the parameter's 512 lanes, with the
+    MIN and REPMIN slots the autotuner's shape has."""
+    layout = (("shadow", 0),) + pgl.limb_rows(0, 64, 8) \
+        + (("count", 0), ("live",))
+    _compile(one_chip, 1 << 22, 4096, layout, n_src=1,
+             mm_ops=(pgl.MIN,), want_rep=True)
+
+
+def test_float_sums_beside_the_bf16_pass_compile(one_chip):
+    """Two float-sum rows keep their own HIGHEST contraction beside
+    the exact rows' pass."""
+    layout = (("f", 0), ("shadow", 0), ("f", 1)) \
+        + pgl.limb_rows(0, 64, 8) + (("count", 0), ("live",))
+    _compile(one_chip, 1 << 20, 300, layout, n_src=1, n_f=2)

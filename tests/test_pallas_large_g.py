@@ -25,8 +25,8 @@ import pytest
 from cockroach_tpu.ops.pallas import groupagg as pg
 from cockroach_tpu.ops.pallas.groupagg import MAX, MIN, _KernelTally
 from cockroach_tpu.ops.pallas.groupagg_large import (
-    BLOCK_ROWS, GROUP_TILE, large_group_aggregate, limb_rows, limb_width,
-    row_block)
+    BLOCK_ROWS, GROUP_TILE, effective_group_tile, large_group_aggregate,
+    limb_rows, limb_width, row_block)
 
 
 # ---------------------------------------------------------------- helpers
@@ -90,7 +90,8 @@ class TestRowBlock:
     def test_odd_multiple_of_128(self):
         # 384 = 128 * 3: largest pow2 divisor is 128
         assert row_block(384) == 128
-        assert row_block(2048 * 3) == 1024  # capped before the odd part
+        assert row_block(2048 * 3) == 2048  # the odd part caps it
+        assert row_block(8192 * 3) == BLOCK_ROWS  # the budget does
 
     def test_rejects_unaligned(self):
         with pytest.raises(AssertionError):
@@ -104,7 +105,7 @@ class TestLimbWidth:
     ])
     def test_both_exactness_bounds(self, n, maxg, blk):
         w = limb_width(n, maxg, block_rows=blk)
-        assert 1 <= w <= 22
+        assert 1 <= w <= 8     # exact in bf16
         eff_blk = row_block(n, blk)
         eff_maxg = maxg if 0 < maxg <= n else n
         # f32 matmul block partial stays in f32's exact-integer range
@@ -113,8 +114,15 @@ class TestLimbWidth:
         assert eff_maxg * (2 ** w - 1) < 2 ** 31
 
     def test_known_value(self):
-        # blk=1024 -> w capped at 24-10=14 regardless of tiny maxg
-        assert limb_width(4096, 1, block_rows=1024) == 14
+        # one bf16 pass: never wider than 8, however small the group
+        # (the f32 block bound alone would give 24-10=14 at blk=1024)
+        assert limb_width(4096, 1, block_rows=1024) == 8
+        # blk = 2^16 is the largest block an 8-bit limb allows
+        assert limb_width(1 << 16, 1, block_rows=1 << 16) == 8
+        assert limb_width(1 << 17, 1, block_rows=1 << 17) == 7
+        # a table's cap may only narrow; one past 8 reads as 8
+        assert limb_width(4096, 1, cap=22) == 8
+        assert limb_width(4096, 1, cap=5) == 5
 
 
 class TestKernelTally:
@@ -209,7 +217,7 @@ class TestLargeKernelParity:
         # rep: min selected row id per group, n when none
         np.testing.assert_array_equal(acc_i[k + 1], reps)
 
-    @pytest.mark.parametrize("w", [8, 9, 14])
+    @pytest.mark.parametrize("w", [5, 6, 8])
     @pytest.mark.parametrize("kind", sorted(SPLIT_VALUES))
     def test_in_kernel_limb_split(self, kind, w):
         """The limbs the kernel cuts out of the argument's two words
@@ -232,7 +240,7 @@ class TestLargeKernelParity:
         tol = np.maximum(np.abs(exact) * 1e-2, 1e12)
         assert np.all(np.abs(np.asarray(acc_f)[0] - exact) <= tol)
 
-    @pytest.mark.parametrize("w", [8, 9, 14])
+    @pytest.mark.parametrize("w", [5, 6, 8])
     def test_limbs_astride_the_word_boundary(self, w):
         """A 3-bit leading limb puts a limb of every width across bit
         32, where the kernel ORs the two words' shifts together."""
@@ -252,7 +260,7 @@ class TestLargeKernelParity:
             _recombine(np.asarray(acc_i), layout, 0),
             _group_sum(gid, sel, vals, G))
 
-    @pytest.mark.parametrize("w", [8, 9, 14])
+    @pytest.mark.parametrize("w", [5, 6, 8])
     def test_one_word_argument(self, w):
         """A proven 31-bit argument travels as int32: one operand row,
         no high word, the same sums."""
@@ -275,7 +283,7 @@ class TestLargeKernelParity:
             _recombine(np.asarray(acc_i), layout, 0), want)
         np.testing.assert_allclose(np.asarray(acc_f)[0], want, rtol=1e-5)
 
-    @pytest.mark.parametrize("w", [8, 9, 14])
+    @pytest.mark.parametrize("w", [5, 6, 8])
     def test_two_aggregates_share_one_argument(self, w):
         """sum(x) with a proven 13-bit bound and avg(x) without one
         read ONE source: the narrow sum's limbs are the wide one's
@@ -300,7 +308,7 @@ class TestLargeKernelParity:
         np.testing.assert_array_equal(acc_i[len(wide)],
                                       _group_count(gid, sel, G))
 
-    @pytest.mark.parametrize("w", [8, 9, 14])
+    @pytest.mark.parametrize("w", [5, 6, 8])
     def test_distinct_validities(self, w):
         """Two arguments with their own NULLs: each count row reads its
         own bit of the packed mask word, liveness reads `sel`."""
@@ -389,6 +397,163 @@ class TestLargeKernelParity:
 
     def test_default_tile_constants_sane(self):
         assert GROUP_TILE % 128 == 0 and BLOCK_ROWS % 128 == 0
+
+
+# ---------------------------------------------------------------- the
+# contraction's geometry comes from the plan
+
+def _xla_limb_sums(vals, gid, G, n):
+    import jax.numpy as jnp
+
+    from cockroach_tpu.ops import agg
+    return np.asarray(agg._group_sum_i64_limbs(
+        jnp.asarray(vals), jnp.asarray(gid), G, n))
+
+
+def _dots(jaxpr, inside_kernel=False):
+    """Every dot_general of the kernel's body, as (operand dtypes,
+    precision), found by walking the jaxpr down through the
+    pallas_call (as TestNoScatterHLO reads HLO for scatters)."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general" and inside_kernel:
+            out.append((tuple(str(v.aval.dtype) for v in eqn.invars),
+                        eqn.params["precision"]))
+        inner = inside_kernel or eqn.primitive.name == "pallas_call"
+        for sub in eqn.params.values():
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                out.extend(_dots(sub, inner))
+    return out
+
+
+class TestPlanSizedContraction:
+    @pytest.mark.parametrize("G,tile", [
+        (1, 128), (12, 128), (128, 128), (129, 256), (600, GROUP_TILE)])
+    def test_group_tile_from_num_groups(self, G, tile):
+        """The tile is the group count rounded up to whole vregs, at
+        most the parameter; whatever it is, the sums are the XLA limb
+        path's, bit for bit. (n = 1,152 rows: a shape of this test's
+        own, so the build is traced here and the tallies move.)"""
+        assert effective_group_tile(G) == tile
+        assert effective_group_tile(G, 256) == min(tile, 256)
+        n = 1152
+        rng = np.random.default_rng(G)
+        gid = rng.integers(0, G, n).astype(np.int32)
+        sel = rng.random(n) < 0.9
+        vals = SPLIT_VALUES["both_words"](rng, n)
+        w = limb_width(n, n)
+        layout = (("shadow", 0),) + limb_rows(0, 64, w) \
+            + (("count", 0), ("live",))
+        before = {k: t.value("large") for k, t in (
+            ("builds", pg.BUILDS), ("tile", pg.GROUP_TILE_LANES),
+            ("passes", pg.MXU_PASSES))}
+        _, acc_i = large_group_aggregate(
+            gid, sel, (np.where(sel, vals, 0),), (sel,), (), (), G,
+            layout, interpret=True)
+        assert pg.BUILDS.value("large") - before["builds"] == 1
+        assert pg.GROUP_TILE_LANES.value("large") - before["tile"] == tile
+        assert pg.MXU_PASSES.value("large") - before["passes"] == 1
+        acc_i = np.asarray(acc_i)
+        assert acc_i.shape == (len(layout) - 1, G)
+        np.testing.assert_array_equal(
+            _recombine(acc_i, layout, 0),
+            _xla_limb_sums(np.where(sel, vals, 0), gid, G, n))
+        np.testing.assert_array_equal(acc_i[-1], _group_count(gid, sel, G))
+
+    @pytest.mark.parametrize("blk", [BLOCK_ROWS, 1 << 16])
+    @pytest.mark.parametrize("w", [6, 8])
+    def test_one_pass_at_the_worst_case(self, w, blk):
+        """Every row of every block in one group and every limb at its
+        largest (255 or 63: the argument is -1, all ones): the MXU's
+        f32 partial of a block is blk x 255, under 2^24 up to the
+        largest block limb_width allows an 8-bit limb, 2^16."""
+        n, G = 1 << 16, 12
+        assert limb_width(n, n, block_rows=blk, cap=w) == w
+        assert blk * ((1 << w) - 1) < 1 << 24
+        gid = np.full(n, 7, np.int32)
+        sel = np.ones(n, bool)
+        vals = np.full(n, -1, np.int64)
+        layout = limb_rows(0, 64, w) + (("count", 0), ("live",))
+        _, acc_i = large_group_aggregate(
+            gid, sel, (vals,), (sel,), (), (), G, layout,
+            block_rows=blk, interpret=True)
+        acc_i = np.asarray(acc_i)
+        for r, (_, _, shift, width) in enumerate(layout[:-2]):
+            limb = (1 << min(width, 64 - shift)) - 1
+            assert acc_i[r, 7] == n * limb and limb in (255, 63, 15)
+        assert acc_i[-1, 7] == acc_i[-2, 7] == n
+        assert not acc_i[:, np.arange(G) != 7].any()
+        np.testing.assert_array_equal(_recombine(acc_i, layout, 0),
+                                      _xla_limb_sums(vals, gid, G, n))
+
+    def test_a_limb_past_eight_bits_is_refused(self):
+        n = 1024
+        with pytest.raises(AssertionError):
+            large_group_aggregate(
+                np.zeros(n, np.int32), np.ones(n, bool),
+                (np.zeros(n, np.int64),), (), (), (), 12,
+                limb_rows(0, 64, 9) + (("live",),), interpret=True)
+
+    def test_float_sums_keep_f32_precision(self):
+        """Two float sums with a shadow between them and limbs after:
+        the f rows are summed at f32 precision (a bf16 pass would keep
+        8 of these values' 15 bits), each comes back at its layout
+        position, and the shadow follows its source."""
+        n, G = 2048, 12
+        rng = np.random.default_rng(11)
+        gid = rng.integers(0, G, n).astype(np.int32)
+        sel = rng.random(n) < 0.9
+        x = (rng.integers(0, 4096, n) + 0.125).astype(np.float32)
+        y = -(rng.integers(0, 4096, n) + 0.375).astype(np.float32)
+        vals = SPLIT_VALUES["negative"](rng, n)
+        layout = (("f", 0), ("shadow", 0), ("f", 1)) \
+            + limb_rows(0, 64, 8) + (("live",),)
+        acc_f, acc_i = large_group_aggregate(
+            gid, sel, (np.where(sel, vals, 0),), (),
+            (np.where(sel, x, 0), np.where(sel, y, 0)), (), G, layout,
+            block_rows=256, interpret=True)
+        acc_f = np.asarray(acc_f)
+        for r, col in ((0, x), (2, y)):
+            want = np.zeros(G)
+            np.add.at(want, gid[sel], col[sel].astype(np.float64))
+            assert np.abs(want).max() < 1 << 21   # exact in f32
+            np.testing.assert_array_equal(acc_f[r], want)
+        exact = np.zeros(G)
+        np.add.at(exact, gid[sel], vals[sel].astype(np.float64))
+        np.testing.assert_allclose(acc_f[1], exact, rtol=1e-5)
+        np.testing.assert_array_equal(
+            _recombine(np.asarray(acc_i), layout, 0),
+            _group_sum(gid, sel, vals, G))
+
+    def test_the_exact_dot_is_one_bf16_pass(self):
+        """Read the kernel's jaxpr: the exact rows (limbs, counts,
+        liveness, the shadows' pieces) are contracted with bf16
+        operands at default precision into f32, the float-sum rows in
+        a dot of their own at HIGHEST; a layout without float sums
+        has no f32 dot at all."""
+        import jax
+        n, G = 1024, 12
+        gid, sel = np.zeros(n, np.int32), np.ones(n, bool)
+        src, x = np.zeros(n, np.int64), np.zeros(n, np.float32)
+        exact = (("shadow", 0),) + limb_rows(0, 64, 8) \
+            + (("count", 0), ("live",))
+
+        def dots(layout, f_values):
+            return _dots(jax.make_jaxpr(
+                lambda *a: large_group_aggregate(
+                    *a, (), G, layout, interpret=True))(
+                gid, sel, (src,), (sel,), f_values).jaxpr)
+
+        (dtypes, precision), = dots(exact, ())
+        assert dtypes == ("bfloat16", "bfloat16")
+        assert precision in (None, jax.lax.Precision.DEFAULT,
+                             (jax.lax.Precision.DEFAULT,) * 2)
+        both = sorted(dots((("f", 0),) + exact, (x,)))
+        assert [d for d, _ in both] == [("bfloat16", "bfloat16"),
+                                        ("float32", "float32")]
+        assert both[1][1] in (jax.lax.Precision.HIGHEST,
+                              (jax.lax.Precision.HIGHEST,) * 2)
 
 
 # ---------------------------------------------------------------- engine
@@ -619,9 +784,10 @@ class TestQ1ReadsArgumentsOnce:
             return orig(gid, sel, sources, *a, **kw)
 
         monkeypatch.setattr(pgl, "large_group_aggregate", spy)
-        # the limb width of TPC-H SF1 (8,388,608 rows a group at most):
-        # any width under the exactness bound gives the same answer
-        monkeypatch.setattr(pgl, "limb_width", lambda *a, **kw: 8)
+        # the limb width of TPC-H SF10 (this table's own is 8, which
+        # an earlier test's build holds in the jit cache): any width
+        # under the exactness bound gives the same answer
+        monkeypatch.setattr(pgl, "limb_width", lambda *a, **kw: 6)
         s = _local_session(teng)
         want = _q1_rows(teng, s, "off")
         name = "exec.pallas.kernel.operand_bytes"

@@ -118,6 +118,22 @@ class TestPlacementModel:
         kernel = C.large_kernel_bytes(agg, n, params)
         assert 4 * 12 * n <= kernel <= 4 * 12 * n + (1 << 20)
         assert (16 + 7 * 5) * n + kernel < 12 << 30
+        # the accumulator term is the tile the kernel takes for Q1's
+        # twelve groups, 128 lanes an accumulator row, not the tile
+        # parameter's 512
+        from cockroach_tpu.ops.pallas import groupagg_large as pgl
+        lay = C.large_layout(agg.aggs, n, agg.max_group_rows, params)
+        assert C.dense_num_groups(agg) == 12
+        assert pgl.effective_group_tile(12, params.pallas_group_tile) \
+            == 128 < params.pallas_group_tile
+        assert kernel == 4 * n * lay.n_words \
+            + 4 * 128 * (len(lay.f_rows) + len(lay.i_rows))
+        # and the verdict is what it was on either side: resident at
+        # SF10's bucket, not at the next one (12.5 SF), where upload
+        # and operand words alone pass the 12 GiB budget
+        n2 = 1 << 27
+        assert (16 + 7 * 5) * n2 + C.large_kernel_bytes(agg, n2, params) \
+            > (16 + 7 * 5) * n2 + 4 * 12 * n2 > 12 << 30
         # the interpreter's grid budget keeps a CPU run of that size
         # on the scatter path, and the model says so
         assert not C.large_kernel_eligible(
@@ -396,20 +412,25 @@ class TestNarrowLimbs:
         assert len(limbs) >= 6 * (-(-64 // width) - 1)
         assert len(layout) > 64 and {r[3] for r in limbs} == {width}
         assert pg.LIMB_BITS.value("large") - before["bits"] == width
+        # a shadow rides the bf16 pass as three rows
+        shadows = [r for r in layout if r[0] == "shadow"]
+        assert len(shadows) == 6
         assert pg.MATMUL_ROWS.value("large") - before["rows"] \
-            == len(layout)
+            == len(layout) + 2 * len(shadows)
         assert [tuple(int(x) for x in r) for r in got] == want
         # and the scatter path agrees
         off = eng.execute(SUMS_SQL, session=_session(eng, "off")).rows
         assert [tuple(int(x) for x in r) for r in off] == want
 
-    @pytest.mark.parametrize("width", [5, 6])
+    @pytest.mark.parametrize("width", [5, 6, 8])
     @pytest.mark.parametrize("past", [False, True])
     def test_the_sentinel_fires_when_a_sum_passes_int64(
             self, monkeypatch, width, past):
         """One group's sum of column `c` is 2^63 - 2^51 (held) or
         2^63 + 2^51 (wrapped): the statement answers exactly, or is
-        refused. Nothing else in the table is near."""
+        refused. Nothing else in the table is near. The shadow that
+        decides it is summed as three bf16 pieces in the limbs' own
+        MXU pass: 24 significant bits, as the f32 row it replaced."""
         from cockroach_tpu.exec.engine import Engine, EngineError
         from cockroach_tpu.ops.pallas import groupagg_large as pgl
         eng = Engine()
