@@ -377,39 +377,70 @@ def check_groupagg_parity(client, default_rows: dict) -> None:
         client.query("SET pallas_groupagg = auto")
 
 
+def int_minmax_is_exact(seed: int, n: int, groups: int,
+                        interpret: bool) -> bool:
+    """Integer MIN/MAX as the engine routes it (exec/compile.py
+    _pallas_large_partials): the kernel reduces the arithmetic high
+    limb `value >> MM_HI_SHIFT` (|limb| <= 2^23: exact in f32, order-
+    preserving), an XLA fold over the rows holding the winning limb
+    returns the full-width value. Compared bit for bit with ops/agg's
+    folds on seeded int64s across both signs and past 2^24, where a
+    plain f32 MIN/MAX is already wrong."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from cockroach_tpu.exec.compile import MM_HI_SHIFT
+    from cockroach_tpu.ops import agg as aggops
+    from cockroach_tpu.ops.pallas import groupagg_large as pgl
+
+    rng = np.random.default_rng(1000 + seed)
+    gid = jnp.asarray(rng.integers(0, groups, n), jnp.int32)
+    sel = jnp.asarray(rng.random(n) < 0.85)
+    vals = rng.integers(-(1 << 62), 1 << 62, n, dtype=np.int64)
+    small = rng.random(n) < 0.3   # mix in sub-2^24 magnitudes
+    vals[small] = rng.integers(-100, 100, int(small.sum()))
+    d = jnp.asarray(vals)
+    hi = jnp.right_shift(d, jnp.int64(MM_HI_SHIFT))
+    hif = hi.astype(jnp.float32)
+    acc_f, _ = pgl.large_group_aggregate(
+        gid, sel, (), (), (),
+        (jnp.where(sel, hif, jnp.float32(np.inf)),
+         jnp.where(sel, hif, jnp.float32(-np.inf))),
+        num_groups=groups, layout=(("live",),),
+        mm_ops=(pgl.MIN, pgl.MAX), interpret=interpret)
+    live = np.asarray(aggops.group_count(gid, sel, groups)) > 0
+    # no f32 sum rows in this layout, so the MIN/MAX rows lead acc_f
+    for row, fold in ((0, aggops.group_min), (1, aggops.group_max)):
+        ghi = acc_f[row, :].astype(jnp.int64)
+        refine = jnp.logical_and(sel, hi == ghi[gid])
+        got = np.asarray(fold(d, gid, refine, groups))
+        want = np.asarray(fold(d, gid, sel, groups))
+        if not np.array_equal(got[live], want[live]):
+            return False
+    return True
+
+
 def check_kernels(engine, st_before: dict) -> None:
     """The device was not hidden: the large-G kernel was built, by
-    Mosaic; nothing the sweep or the fuzz refused went unreported."""
-    from cockroach_tpu.ops.pallas import autotune, paritygate
-
+    Mosaic, and its integer MIN/MAX slots are exact there."""
     snap = engine.metrics.snapshot()
     built = (snap.get("exec.pallas.kernel.builds.large", 0)
              - st_before.get("exec.pallas.kernel.builds.large", 0))
     st = engine.runtime_status()
     log(f"pallas: builds.large+={built} "
         f"interpret={st['pallas_interpret']} "
-        f"fallbacks={snap.get('exec.pallas.kernel.fallbacks', 0)} "
-        f"autotune_runs={snap.get('exec.autotune.runs', 0)} "
-        f"autotune_s={snap.get('exec.autotune.seconds', 0):.1f} "
-        f"paritygate_checks={snap.get('exec.paritygate.checks', 0)}")
-    backend = st["platform"]
-    tuned = autotune.load_table(st["compile_cache_dir"]).get(backend)
-    gated = paritygate.load_table(st["compile_cache_dir"]).get(backend)
-    log(f"autotune table[{backend}]: {json.dumps(tuned)}")
-    log(f"paritygate table[{backend}]: {json.dumps(gated)}")
-    for cand, why in st["autotune_rejected"].items():
-        log(f"autotune rejected {cand}: {why}")
-    for path, why in st["paritygate_errors"].items():
-        log(f"paritygate fuzz of {path} raised: {why}")
+        f"fallbacks={snap.get('exec.pallas.kernel.fallbacks', 0)}")
     check(built >= 1, "no large-G Pallas kernel was built")
     if st["platform"] == "tpu":
         check(not st["pallas_interpret"],
               "Pallas kernels ran interpreted on the TPU backend")
-    gt, br, cap = autotune.DEFAULT
-    check(f"{gt}x{br}w{cap}" not in st["autotune_rejected"],
-          "the backend refused the shipped default tile")
-    check(not st["paritygate_errors"],
-          "a parity fuzz raised instead of returning a verdict")
+    n, groups = (512, 64) if st["pallas_interpret"] else (4096, 256)
+    for seed in range(3):
+        check(int_minmax_is_exact(seed, n, groups,
+                                  st["pallas_interpret"]),
+              f"int MIN/MAX through the kernel differs from the XLA "
+              f"fold (seed {seed})")
+    log("pallas: int MIN/MAX exact against the XLA folds, 3 seeds")
 
 
 def check_no_staging(mesh, moved: int) -> None:

@@ -15,9 +15,9 @@ racy-global
     the lazy-rebind idiom (``if X is None: X = build()``) is benign.
 
     Regression notes (violations this rule surfaced and this PR fixed):
-    - ops/pallas/autotune.py accumulated sweep wall-time with
-      ``SECONDS[0] += ...`` outside its own ``_LOCK`` — two sessions
-      autotuning different backends concurrently lose increments.
+    - a kernel-tuning module (since removed) accumulated sweep
+      wall-time with ``SECONDS[0] += ...`` outside its own ``_LOCK`` —
+      two sessions sweeping concurrently lose increments.
     - exec/engine.py bumped ``coldstart.PREWARMED += 1`` cross-module
       with no lock; it is now ``coldstart.note_prewarmed()``, a locked
       bump next to the tally it guards.

@@ -13,10 +13,7 @@ Statically: every literal ``session.vars.get("X")`` read reachable
 from ``_prepare_select`` (through resolvable package callees) must
 either flow into the ``key = (...)`` tuple via a traced local
 assignment, or appear in WHITELIST below with the argument for why the
-compiled program is identical across the var's values ("bit-identical
-by construction", the ``pallas_autotune`` tile-param precedent: tile
-points change speed, never results, so two sessions differing only in
-autotune mode can share one compiled program).
+compiled program is identical across the var's values.
 
 The whitelist is itself checked: an entry whose var is no longer read
 anywhere in the prepare closure is reported as drift, so stale
@@ -58,10 +55,6 @@ WHITELIST = {
     "optimizer_sketch_stats": (
         "plan-shaping like `optimizer`: sketch-fed join orders change "
         "the plan tree, captured by the plan fingerprint"),
-    "pallas_autotune": (
-        "tile parameters are perf-only and bit-identical by "
-        "construction across the candidate grid (the documented "
-        "precedent this whitelist generalizes)"),
     "plan_shape_cache": (
         "selects which keytext/psig FORM the key takes; both forms "
         "are self-consistent key elements, so entries cannot collide "

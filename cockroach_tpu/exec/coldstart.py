@@ -15,8 +15,8 @@ accelerator-management frame in PAPERS.md):
    flat directory serves them all. Cluster setting
    `sql.exec.compile_cache.dir = off` disables it. Hit/miss/
    compile-seconds counters come from JAX's monitoring events and
-   surface as `exec.compile.*` metrics. The autotune table, parity
-   table and shapes journal are sidecar files in the same directory.
+   surface as `exec.compile.*` metrics. The shapes journal is a
+   sidecar file in the same directory.
 
 2. **Shape bucket ladder** — `ShapeLadder` generalizes the historical
    "pad row counts to the next power of two" rule into an explicit

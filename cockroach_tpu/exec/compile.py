@@ -53,38 +53,22 @@ class ExecParams:
     # mesh size along axis_name (static: the shuffle's send-buffer
     # shapes depend on it)
     n_shards: int = 1
-    # Session var pallas_groupagg ("auto" | "on" | "off"): route
-    # eligible GROUP BYs through the one-pass Pallas kernels instead
-    # of per-aggregate XLA segment reductions.
-    #   auto (default): per-plan eligibility, exact results only —
-    #     dense large-G plans whose aggregates are counts, `any`
-    #     (rep gather), or int64-limb sums/avgs over INT/DECIMAL ride
-    #     ops/pallas/groupagg_large.py (bit-identical to the XLA
-    #     path); tiny inputs (< AUTO_MIN_ROWS) stay on XLA.
-    #   on: additionally offers the small-G f32 kernel
-    #     (ops/pallas/groupagg.py; approximate float accumulation)
-    #     and admits f32 float sum/avg/min/max into the large kernel.
-    #   off: never — the escape hatch and the bench A/B lever.
-    # pallas_interpret runs the kernels in interpret mode off-TPU
-    # (the engine sets it from the backend).
+    # Session var pallas_groupagg ("auto" | "off"): route eligible
+    # GROUP BYs through the one-pass large-G Pallas kernel
+    # (ops/pallas/groupagg_large.py) instead of per-aggregate XLA
+    # segment reductions.
+    #   auto (default): per-plan eligibility (large_kernel_eligible),
+    #     exact results only — dense plans whose aggregates are
+    #     counts, `any` (rep gather), int64-limb sums/avgs or hi-limb
+    #     MIN/MAX over INT/DECIMAL; bit-identical to the XLA path.
+    #     Tiny inputs (< AUTO_MIN_ROWS) stay on XLA.
+    #   off: never — the XLA path every ineligible plan takes anyway,
+    #     and the oracle of auto == off.
+    # pallas_interpret runs the kernel in interpret mode off-TPU
+    # (the engine sets it from the backend). The tile point is the
+    # kernel module's own constants.
     pallas_groupagg: str = "off"
     pallas_interpret: bool = False
-    # Large-G kernel tile point, normally the shipped
-    # groupagg_large.py constants or the per-backend autotuned winner
-    # (ops/pallas/autotune.py). The group tile is an upper bound: a
-    # build takes pgl.effective_group_tile(num_groups, tile). Any valid
-    # point is bit-identical — limb widths are recomputed from
-    # block_rows via the exactness bound, never past the 8 bits one
-    # bf16 pass holds — so these are perf-only and deliberately NOT
-    # part of the engine's executable-cache key.
-    pallas_group_tile: int = 512
-    pallas_block_rows: int = 4096
-    pallas_limb_cap: int = 8
-    # Kernel paths the parity gate (ops/pallas/paritygate.py) proved
-    # bit-identical to the XLA oracle on this backend: `auto` routing
-    # admits exactly these beyond its always-exact envelope. Perf-only
-    # under the gate's exactness proof, so NOT in the cache key.
-    pallas_exact_paths: tuple = ()
     # Sort+Limit fusion: XLA's variadic sort costs ~20s of compile PER
     # OPERAND beyond 64K rows (measured on v5e; a 5-operand lexsort at
     # 262K compiles ~300s), so ORDER BY ... LIMIT k plans take a
@@ -559,81 +543,6 @@ def _agg_partials(a: BoundAgg, argf, batch, ctx, gid, num_groups,
     raise ExecError(f"aggregate {a.func} unsupported")
 
 
-def _pallas_agg_slots(aggs) -> list | None:
-    """Slot layout for the one-pass Pallas kernel, or None if any
-    aggregate falls outside its f32 envelope (ops/pallas/groupagg.py:
-    counts are exact; value aggregates must be FLOAT-typed)."""
-    from ..ops.pallas import groupagg as pg
-    kinds = {"sum": pg.SUM, "avg": pg.SUM, "min": pg.MIN, "max": pg.MAX}
-    slots = []  # (kernel op, agg index, role: "main" | "cnt")
-    for i, a in enumerate(aggs):
-        if a.distinct:
-            return None  # dedup mask is an XLA-path construct
-        if a.func in ("count_rows", "count"):
-            slots.append((pg.COUNT, i, "main"))
-        elif a.func in kinds:
-            if a.arg is None or a.arg.type.family != Family.FLOAT:
-                return None
-            slots.append((kinds[a.func], i, "main"))
-            # paired count: per-group validity + avg divisor
-            slots.append((pg.COUNT, i, "cnt"))
-        else:
-            return None
-    return slots
-
-
-def _pallas_dense_partials(slots, aggfs, b, ctx, gid, num_groups: int,
-                           axis_name, interpret: bool) -> list:
-    """Compute every aggregate's (data, valid) in ONE kernel pass
-    (Q1-shaped dense GROUP BY: 8 aggregates = 1 HBM read instead of 8
-    segment reductions). Returns aggs_out in aggfs order."""
-    from ..ops.pallas import groupagg as pg
-    ones = jnp.ones((b.n,), jnp.bool_)
-    zerov = jnp.zeros((b.n,), jnp.float32)
-    argdata = {i: argf(ctx) for i, (a, argf) in enumerate(aggfs)
-               if argf is not None}
-    values, masks, ops = [], [], []
-    for op, i, role in slots:
-        if i in argdata:
-            d0, v0 = argdata[i]
-            values.append(zerov if op == pg.COUNT else d0)
-            masks.append(v0)
-        else:  # count_rows: every selected row participates
-            values.append(zerov)
-            masks.append(ones)
-        ops.append(op)
-    acc, cnt = pg.dense_group_aggregate(
-        gid, b.sel, tuple(values), tuple(masks),
-        num_groups=num_groups, ops=tuple(ops), interpret=interpret)
-    if axis_name:
-        # cross-shard merge, column-by-column with the op's collective
-        with jax.named_scope("shard_merge"):
-            cnt = jax.lax.psum(cnt, axis_name)
-            cols = []
-            for j, op in enumerate(ops):
-                c = acc[:, j]
-                if op == pg.MIN:
-                    cols.append(jax.lax.pmin(c, axis_name))
-                elif op == pg.MAX:
-                    cols.append(jax.lax.pmax(c, axis_name))
-                else:
-                    cols.append(jax.lax.psum(c, axis_name))
-            acc = jnp.stack(cols, axis=1)
-    col_of = {(i, role): j for j, (op, i, role) in enumerate(slots)}
-    aggs_out = []
-    for i, (a, argf) in enumerate(aggfs):
-        if a.func in ("count_rows", "count"):
-            d = cnt[:, col_of[(i, "main")]].astype(jnp.int64)
-            aggs_out.append((d, jnp.ones_like(d, dtype=jnp.bool_)))
-            continue
-        d = acc[:, col_of[(i, "main")]].astype(jnp.float64)
-        n_valid = cnt[:, col_of[(i, "cnt")]]
-        if a.func == "avg":
-            d = d / jnp.maximum(n_valid, 1).astype(jnp.float64)
-        aggs_out.append((d, n_valid > 0))
-    return aggs_out
-
-
 # Large-G kernel envelope: the one-hot matmul does O(n * num_groups)
 # MACs, so cap the group domain where the MXU still wins over the
 # scatter ladder (q18's bench-scale o_orderkey span ~262K sits under
@@ -650,61 +559,45 @@ AUTO_MIN_ROWS = 4096
 # would turn a CPU test/oracle run into minutes (measured: a
 # 300K-row / 100K-group GROUP BY costs ~8 minutes interpreted vs
 # seconds on XLA), while the q1/q3/q18 tier-1 shapes stay well
-# under it. Explicit `on` bypasses the cap (forced opt-in), and the
-# real chip never consults it.
+# under it. The real chip never consults it.
 AUTO_INTERPRET_STEPS = 1024
+# arithmetic right-shift putting an int64's order-preserving high limb
+# into f32-exact range for the kernel's MIN/MAX slots: 64 - 40 = 24
+# magnitude bits -> |limb| <= 2^23
+MM_HI_SHIFT = 40
 
 
 def _large_interpret_over_budget(interpret: bool, n: int,
-                                 num_groups: int,
-                                 group_tile: int | None = None,
-                                 block_rows: int | None = None) -> bool:
-    """auto-mode cost check: would the large-G kernel's grid exceed
-    the interpret-execution step budget on this backend? Counts the
-    grid at the plan's actual (possibly autotuned) tile point, the
-    group tile as the kernel sizes it."""
+                                 num_groups: int) -> bool:
+    """Cost check: would the large-G kernel's grid exceed the
+    interpret-execution step budget on this backend?"""
     if not interpret:
         return False
     from ..ops.pallas import groupagg_large as pgl
-    blk = pgl.row_block(n, block_rows or pgl.BLOCK_ROWS)
-    gtiles = -(-num_groups // pgl.effective_group_tile(
-        num_groups, group_tile or pgl.GROUP_TILE))
-    return gtiles * (n // blk) > AUTO_INTERPRET_STEPS
+    gtiles = -(-num_groups // pgl.effective_group_tile(num_groups))
+    return gtiles * (n // pgl.row_block(n)) > AUTO_INTERPRET_STEPS
 
 
-def _pallas_large_ok(aggs, mode: str, exact_paths: tuple = ()) -> bool:
-    """Static (SQL-type) envelope check for the large-G kernel
-    (ops/pallas/groupagg_large.py).
-
-    `auto` admits only aggregates whose kernel results are exact —
-    counts, `any` (representative-row gather), int64-limb sums/avgs
-    over INT/DECIMAL args, and whatever `exact_paths` the parity gate
-    (ops/pallas/paritygate.py) proved bit-identical on this backend
-    (the ordered-int MIN/MAX hi-limb path verifies everywhere; the
-    f32 float sum only on a backend whose fuzz came back clean) — so
-    default routing cannot perturb results. `on` force-admits every
-    path including f32-accumulated float sum/avg/min/max (approximate
-    vs the XLA f64 path, same contract as the small kernel)."""
+def _pallas_large_ok(aggs) -> bool:
+    """Static (SQL-type) envelope of the large-G kernel
+    (ops/pallas/groupagg_large.py): only aggregates whose kernel
+    results are exact, so routing cannot perturb results. Counts,
+    `any` (representative-row gather), int64-limb sums/avgs over
+    INT/DECIMAL arguments, and MIN/MAX over INT/DECIMAL (the kernel
+    reduces the order-preserving high limb, XLA refines the full-width
+    winner over the rows holding it: every value returned is an input
+    value; measured exact on the v5e and on CPU). FLOAT arguments
+    never: an f32 accumulation is not the XLA path's f64."""
     for a in aggs:
         if a.distinct:
             return False  # dedup mask is an XLA-path construct
         if a.func in ("count_rows", "count", "any"):
             continue
-        fam = a.arg.type.family if a.arg is not None else None
-        if a.func in ("sum", "sum_int", "avg"):
-            if fam in (Family.INT, Family.DECIMAL):
-                continue
-            if fam == Family.FLOAT and \
-                    (mode == "on" or "float_sum" in exact_paths):
-                continue
+        if a.func not in ("sum", "sum_int", "avg", "min", "max"):
             return False
-        if a.func in ("min", "max"):
-            if fam in (Family.INT, Family.DECIMAL) and \
-                    (mode == "on" or "int_minmax" in exact_paths):
-                continue
-            if mode == "on" and fam == Family.FLOAT:
-                continue
-        return False
+        if a.arg is None or a.arg.type.family not in (Family.INT,
+                                                      Family.DECIMAL):
+            return False
     return True
 
 
@@ -720,24 +613,18 @@ def dense_num_groups(node: P.Aggregate) -> int:
 def large_kernel_eligible(node: P.Aggregate, n: int,
                           params: "ExecParams") -> bool:
     """Does this Aggregate over an n-row batch compile onto the large-G
-    kernel? Static in the plan and the batch's row count, so the
-    placement model (exec/scanplane.py) asks the question the compile
-    will ask."""
-    mode = params.pallas_groupagg
-    if mode not in ("on", "auto") or node.max_groups <= 0 \
-            or not node.group_by or n % 128:
+    kernel? The one place that decides: static in the plan, the
+    batch's row count, `pallas_groupagg` and `pallas_interpret`, so
+    the placement model (exec/scanplane.py) asks the question the
+    compile asks, with the same inputs."""
+    if params.pallas_groupagg != "auto" or node.max_groups <= 0 \
+            or not node.group_by or n % 128 or n < AUTO_MIN_ROWS:
         return False
     num_groups = dense_num_groups(node)
-    if num_groups <= 64 and mode == "on" \
-            and _pallas_agg_slots(node.aggs) is not None:
-        return False  # the small-G kernel takes it first
     return (num_groups <= LARGE_G_MAX
-            and not (mode == "auto" and n < AUTO_MIN_ROWS)
-            and not (mode == "auto" and _large_interpret_over_budget(
-                params.pallas_interpret, n, num_groups,
-                params.pallas_group_tile, params.pallas_block_rows))
-            and _pallas_large_ok(node.aggs, mode,
-                                 params.pallas_exact_paths))
+            and not _large_interpret_over_budget(
+                params.pallas_interpret, n, num_groups)
+            and _pallas_large_ok(node.aggs))
 
 
 @dataclass
@@ -747,11 +634,9 @@ class LargeLayout:
     anything is traced."""
     w: int                  # limb width of the exact sums
     arg_of: dict            # agg index -> its argument's expr_key
-    # argument -> index of its sel & valid mask / exact-sum source /
-    # f32 sum column
+    # argument -> index of its sel & valid mask / exact-sum source
     mask_of: dict = field(default_factory=dict)
     src_of: dict = field(default_factory=dict)
-    fcol_of: dict = field(default_factory=dict)
     narrow: list = field(default_factory=list)  # source -> one word
     f_rows: list = field(default_factory=list)  # rows summed in f32
     i_rows: list = field(default_factory=list)  # rows summed in i32
@@ -764,25 +649,20 @@ class LargeLayout:
     def n_words(self) -> int:
         """[1, n] 32-bit arrays a build hands the kernel: the group
         ids, the packed mask words (bit 0 is sel), one or two words a
-        source, one f32 column a float sum and a MIN/MAX slot."""
+        source, one f32 column a MIN/MAX slot."""
         return (1 + -(-(1 + len(self.mask_of)) // 32)
                 + sum(1 if nr else 2 for nr in self.narrow)
-                + len(self.fcol_of) + len(self.mm))
+                + len(self.mm))
 
 
-def large_layout(aggs, n: int, max_group_rows: int,
-                 params: "ExecParams") -> LargeLayout:
+def large_layout(aggs, n: int, max_group_rows: int) -> LargeLayout:
     """Plan the large-G kernel's operands and matmul rows for `aggs`
-    over n rows (see _pallas_large_partials, which traces it)."""
-    from ..ops.pallas import groupagg as pg
+    (inside _pallas_large_ok's envelope) over n rows, at the kernel's
+    shipped tile (see _pallas_large_partials, which traces it)."""
     from ..ops.pallas import groupagg_large as pgl
     from ..sql.pushdown import expr_key
-    # the limb width tracks the plan's (possibly autotuned) block_rows
-    # so the f32 block-partial exactness bound holds at that block
     lay = LargeLayout(
-        w=pgl.limb_width(n, max_group_rows,
-                         block_rows=params.pallas_block_rows,
-                         cap=params.pallas_limb_cap),
+        w=pgl.limb_width(n, max_group_rows),
         arg_of={i: expr_key(a.arg) for i, a in enumerate(aggs)
                 if a.arg is not None})
 
@@ -808,12 +688,7 @@ def large_layout(aggs, n: int, max_group_rows: int,
         if a.func == "count":
             continue
         if a.func in ("min", "max"):
-            lay.mm.append((i, pg.MIN if a.func == "min" else pg.MAX))
-            continue
-        if a.arg.type.family == Family.FLOAT:
-            if j not in lay.fcol_of:
-                lay.fcol_of[j] = len(lay.fcol_of)
-                lay.f_rows.append(("f", lay.fcol_of[j]))
+            lay.mm.append((i, pgl.MIN if a.func == "min" else pgl.MAX))
             continue
         # exact int64 sum as w-bit i32 limbs, cut out of the argument's
         # words INSIDE the kernel and recombined by the caller — the
@@ -836,17 +711,16 @@ def large_layout(aggs, n: int, max_group_rows: int,
     return lay
 
 
-def large_kernel_bytes(node: P.Aggregate, n: int,
-                       params: "ExecParams") -> int:
+def large_kernel_bytes(node: P.Aggregate, n: int) -> int:
     """Device bytes the large-G path allocates beside its input, for
     the placement model: the [1, n] words XLA writes for the kernel
     (`exec.pallas.kernel.operand_bytes` is this term) and the kernel's
     accumulator tiles over the padded group domain. The limb, count
     and shadow rows live in VMEM and cost no HBM (PERF.md, PR 26)."""
     from ..ops.pallas import groupagg_large as pgl
-    lay = large_layout(node.aggs, n, node.max_group_rows, params)
+    lay = large_layout(node.aggs, n, node.max_group_rows)
     num_groups = dense_num_groups(node)
-    tile = pgl.effective_group_tile(num_groups, params.pallas_group_tile)
+    tile = pgl.effective_group_tile(num_groups)
     gp = -(-num_groups // tile) * tile
     acc_rows = (max(1, len(lay.f_rows)) + max(1, len(lay.i_rows))
                 + len(lay.mm) + int(lay.want_rep))
@@ -878,12 +752,11 @@ def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
     identity fill (the FD guarantees every shard that has the group
     agrees on the value)."""
     from ..ops.pallas import groupagg_large as pgl
-    from ..ops.pallas import paritygate as _pgate
     n = b.n
     sel = b.sel
-    lay = large_layout([a for a, _ in aggfs], n, max_group_rows, params)
+    lay = large_layout([a for a, _ in aggfs], n, max_group_rows)
     w, arg_of, mask_of, exact = lay.w, lay.arg_of, lay.mask_of, lay.exact
-    fcol_of, f_rows, i_rows = lay.fcol_of, lay.f_rows, lay.i_rows
+    f_rows, i_rows = lay.f_rows, lay.i_rows
     want_rep = lay.want_rep
     # the kernel's operands: each DISTINCT argument evaluated once and
     # masked. The limb, count and shadow rows of the matmul are the
@@ -896,9 +769,7 @@ def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
                 argvals[arg_of[i]] = argf(ctx)
         argdata = {i: argvals[j] for i, j in arg_of.items()}
         for i, (a, _) in enumerate(aggfs):
-            if a.func in ("sum", "sum_int", "avg", "min", "max") \
-                    and a.arg is not None \
-                    and a.arg.type.family in (Family.INT, Family.DECIMAL):
+            if a.func in ("sum", "sum_int", "avg", "min", "max"):
                 # the static check ran on SQL types; re-check the traced
                 # dtype (a cast upstream could hand us floats) — limb
                 # sums and the MIN/MAX hi-limb both need real ints
@@ -912,39 +783,29 @@ def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
             d64 = argvals[j][0].astype(jnp.int64)
             dz = jnp.where(masks[mask_of[j]], d64, jnp.zeros_like(d64))
             sources[k] = dz.astype(jnp.int32) if lay.narrow[k] else dz
-        f_cols = [None] * len(fcol_of)      # the f32 float-sum columns
-        for j, k in fcol_of.items():
-            f_cols[k] = jnp.where(masks[mask_of[j]], argvals[j][0],
-                                  0).astype(jnp.float32)
         mm_cols, mm_ops_l, mm_tags = [], [], []
         for i, op in lay.mm:
             a = aggfs[i][0]
             d0, m = argdata[i][0], masks[mask_of[arg_of[i]]]
             ident = np.float32(np.inf if a.func == "min" else -np.inf)
-            if a.arg.type.family in (Family.INT, Family.DECIMAL):
-                # exact ordered-int path (paritygate "int_minmax"):
-                # the kernel reduces the ARITHMETIC high limb — order-
-                # preserving, |limb| <= 2^23 so f32-exact — and the
-                # full-width winner is refined on XLA in the output
-                # loop below over just the rows holding that limb
-                hi = jnp.right_shift(d0.astype(jnp.int64),
-                                     jnp.int64(_pgate.MM_HI_SHIFT))
-                mm_cols.append(
-                    jnp.where(m, hi.astype(jnp.float32), ident))
-            else:
-                mm_cols.append(
-                    jnp.where(m, d0.astype(jnp.float32), ident))
+            # exact ordered-int MIN/MAX: the kernel reduces the
+            # ARITHMETIC high limb — order-preserving, |limb| <= 2^23
+            # so f32-exact — and the full-width winner is refined on
+            # XLA in the output loop below over just the rows holding
+            # that limb
+            hi = jnp.right_shift(d0.astype(jnp.int64),
+                                 jnp.int64(MM_HI_SHIFT))
+            mm_cols.append(jnp.where(m, hi.astype(jnp.float32), ident))
             mm_ops_l.append(op)
             mm_tags.append(("mm", i))
 
     layout = tuple(f_rows) + tuple(i_rows)
     with jax.named_scope("kernel"):
+        # no float-sum columns: FLOAT arguments are outside the envelope
         acc_f, acc_i = pgl.large_group_aggregate(
-            gid, sel, tuple(sources), tuple(masks), tuple(f_cols),
+            gid, sel, tuple(sources), tuple(masks), (),
             tuple(mm_cols), num_groups=num_groups, layout=layout,
             mm_ops=tuple(mm_ops_l), want_rep=want_rep,
-            group_tile=params.pallas_group_tile,
-            block_rows=params.pallas_block_rows,
             interpret=params.pallas_interpret)
 
     def ps(x):
@@ -994,36 +855,25 @@ def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
                 d = acc_f[mmrow[("mm", i)], :]
                 if axis_name:
                     d = aggops.shard_extreme(d, axis_name, a.func)
-                if a.arg.type.family in (Family.INT, Family.DECIMAL):
-                    # refine the (globally merged) winning hi limb to the
-                    # full-width value with the dtype-preserving XLA fold
-                    # over only the rows that hold it — every survivor is
-                    # an actual input value, so the result is bit-equal to
-                    # the pure-XLA path (shards without the winning limb
-                    # refine an empty mask, whose fold identity loses the
-                    # second pmin/pmax just like an empty-shard group)
-                    d0, v0 = argdata[i]
-                    m = jnp.logical_and(sel, v0)
-                    rowhi = jnp.right_shift(d0.astype(jnp.int64),
-                                            jnp.int64(_pgate.MM_HI_SHIFT))
-                    refine = jnp.logical_and(
-                        m, rowhi == d.astype(jnp.int64)[gid])
-                    fold = aggops.group_min if a.func == "min" \
-                        else aggops.group_max
-                    dref = fold(d0, gid, refine, num_groups)
-                    if axis_name:
-                        dref = aggops.shard_extreme(dref, axis_name,
-                                                    a.func)
-                    aggs_out.append((dref, nonempty))
-                    continue
-                aggs_out.append((d.astype(jnp.float64), nonempty))
-                continue
-            if i not in exact:  # float sum/avg ("on" or promoted)
-                d = ps(acc_f[frow[("f", fcol_of[arg_of[i]])], :]) \
-                    .astype(jnp.float64)
-                if a.func == "avg":
-                    d = d / jnp.maximum(cnt, 1).astype(jnp.float64)
-                aggs_out.append((d, nonempty))
+                # refine the (globally merged) winning hi limb to the
+                # full-width value with the dtype-preserving XLA fold
+                # over only the rows that hold it — every survivor is an
+                # actual input value, so the result is bit-equal to the
+                # pure-XLA path (shards without the winning limb refine
+                # an empty mask, whose fold identity loses the second
+                # pmin/pmax just like an empty-shard group)
+                d0, v0 = argdata[i]
+                m = jnp.logical_and(sel, v0)
+                rowhi = jnp.right_shift(d0.astype(jnp.int64),
+                                        jnp.int64(MM_HI_SHIFT))
+                refine = jnp.logical_and(
+                    m, rowhi == d.astype(jnp.int64)[gid])
+                fold = aggops.group_min if a.func == "min" \
+                    else aggops.group_max
+                dref = fold(d0, gid, refine, num_groups)
+                if axis_name:
+                    dref = aggops.shard_extreme(dref, axis_name, a.func)
+                aggs_out.append((dref, nonempty))
                 continue
             src, k = exact[i]
             total = jnp.zeros(cnt.shape, jnp.int64)
@@ -1137,9 +987,9 @@ def _compile_aggregate(node: P.Aggregate, params: ExecParams) -> CompiledNode:
     axis = params.axis_name
     if axis and node.group_by and not dense:
         if params.pallas_groupagg != "off":
-            # hash-strategy plans are outside every kernel envelope
-            from ..ops.pallas import groupagg as _pg
-            _pg.FALLBACKS.bump("agg")
+            # hash-strategy plans are outside the kernel's envelope
+            from ..ops.pallas import groupagg_large as pgl
+            pgl.FALLBACKS.bump("agg")
         # hash-strategy group ids are shard-local; merge via
         # all_gather of per-slot partial state + re-group (the ICI
         # form of the HashRouter shuffle, colflow/routers.go:425)
@@ -1212,33 +1062,15 @@ def _compile_aggregate(node: P.Aggregate, params: ExecParams) -> CompiledNode:
         return ctx, gid, num_groups, ng, group_cols, b
 
     def _aggregate(rc, b, ctx, gid, num_groups, ng, group_cols):
-        mode = params.pallas_groupagg
-        pslots = None
-        large = False
-        # the one-pass small-G kernel serves dense GROUP BY and
-        # UNGROUPED aggregation alike (Q6 is the num_groups == 1
-        # case); explicit `on` only — its f32 accumulation is
-        # approximate, so `auto` never picks it
-        if (mode == "on" and (dense or not groupfs)
-                and num_groups <= 64 and b.n % 128 == 0):
-            pslots = _pallas_agg_slots([a for a, _ in aggfs])
         # the large-G kernel: dense grouped plans with an engine-known
-        # group bound and an all-exact aggregate envelope under
-        # `auto`; distributed dense plans merge the kernel partials
-        # with collectives inside _pallas_large_partials
-        if pslots is None and large_kernel_eligible(node, b.n, params):
-            large = True
+        # group bound and an all-exact aggregate envelope; distributed
+        # dense plans merge the kernel partials with collectives
+        # inside _pallas_large_partials
+        large = large_kernel_eligible(node, b.n, params)
         overflow = jnp.bool_(False)
         rep_state = None
         large_live = None
-        if pslots is not None:
-            pgid = (gid if gid is not None
-                    else jnp.zeros((b.n,), dtype=jnp.int32))
-            with jax.named_scope("kernel"):
-                aggs_out = _pallas_dense_partials(
-                    pslots, aggfs, b, ctx, pgid, num_groups, axis,
-                    params.pallas_interpret)
-        elif large:
+        if large:
             res = _pallas_large_partials(
                 aggfs, b, ctx, gid, num_groups, node.max_group_rows,
                 axis, params)
@@ -1246,14 +1078,14 @@ def _compile_aggregate(node: P.Aggregate, params: ExecParams) -> CompiledNode:
                 aggs_out, large_live, overflow = res
             else:
                 large = False
-        if pslots is None and not large:
-            if mode != "off":
+        if not large:
+            if params.pallas_groupagg != "off":
                 # an aggregation compiled on the XLA segment path
-                # while the kernels were enabled (outside both
-                # envelopes, or hash-strategy) — trace-time tally,
-                # like BUILDS (exec.pallas.kernel.fallbacks)
-                from ..ops.pallas import groupagg as _pg
-                _pg.FALLBACKS.bump("agg")
+                # while the kernel was enabled (outside its envelope,
+                # or hash-strategy) — trace-time tally, like BUILDS
+                # (exec.pallas.kernel.fallbacks)
+                from ..ops.pallas import groupagg_large as pgl
+                pgl.FALLBACKS.bump("agg")
             if gid is not None and axis is None and any(
                     a.func == "any" and not a.distinct
                     for a, _ in aggfs):

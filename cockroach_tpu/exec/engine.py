@@ -317,8 +317,8 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         from ..utils.stmtdiag import StmtDiagRegistry
         self.stmtdiag = StmtDiagRegistry(metrics=self.metrics)
         # most recent statement's coarse operator profile
-        # (exec/profile.py ProfileSink) — read by bench.py for the
-        # per-query top-operator summary; overwritten per statement
+        # (exec/profile.py ProfileSink), for a per-query top-operator
+        # summary; overwritten per statement
         self.last_profile = None
         self.metrics.counter(
             "exec.profile.statements",
@@ -332,10 +332,6 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         self._compile_cache_dir = coldstart.init_compile_cache(
             self.settings)
         coldstart.register_metrics(self.metrics)
-        from ..ops.pallas import autotune as _tune
-        _tune.register_metrics(self.metrics)
-        from ..ops.pallas import paritygate as _pgate
-        _pgate.register_metrics(self.metrics)
         # device-memory accounting: resident table uploads reserve
         # against the HBM budget BEFORE device_put, so an over-budget
         # upload fails with a quota error naming the knob instead of
@@ -396,60 +392,58 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             "seconds bulk columnar ingest held: encoding checks, "
             "chunking and the chunks' seal-time statistics")
         # TPU-plane visibility: Pallas kernel tallies are trace-time
-        # module counters (ops/pallas/groupagg.py); read live at
+        # module counters (ops/pallas/groupagg_large.py); read live at
         # scrape. All of them count at TRACE time — executions run
         # inside jitted programs and are not host-countable.
-        from ..ops.pallas import groupagg as _ga
+        from ..ops.pallas.groupagg_large import (
+            BUILDS, FALLBACKS, GROUP_TILE_LANES, LIMB_BITS, MATMUL_ROWS,
+            MXU_PASSES, OPERAND_BYTES, ROWS)
         self.metrics.func_counter(
             "exec.pallas.kernel.builds",
-            lambda: _ga.BUILDS.value(),
-            "Pallas group-aggregate kernel traces/builds, all kernels")
-        self.metrics.func_counter(
-            "exec.pallas.kernel.builds.small",
-            lambda: _ga.BUILDS.value("small"),
-            "small-G (unrolled f32) group-aggregate kernel builds")
+            lambda: BUILDS.value(),
+            "Pallas group-aggregate kernel traces/builds")
         self.metrics.func_counter(
             "exec.pallas.kernel.builds.large",
-            lambda: _ga.BUILDS.value("large"),
+            lambda: BUILDS.value("large"),
             "large-G (one-hot matmul) group-aggregate kernel builds")
         self.metrics.func_counter(
             "exec.pallas.kernel.fallbacks",
-            lambda: _ga.FALLBACKS.value(),
+            lambda: FALLBACKS.value(),
             "aggregations compiled on the XLA segment path while "
             "pallas_groupagg was enabled (outside a kernel envelope)")
         self.metrics.func_counter(
             "exec.pallas.kernel.operand_bytes",
-            lambda: _ga.OPERAND_BYTES.value("large"),
+            lambda: OPERAND_BYTES.value("large"),
             "bytes of the HBM arrays handed to the large-G kernel, a "
             "build: the aggregates' arguments as 32-bit words, the "
             "packed masks and the group ids, not the limb rows")
         self.metrics.func_counter(
             "exec.pallas.kernel.limb_bits",
-            lambda: _ga.LIMB_BITS.value("large"),
+            lambda: LIMB_BITS.value("large"),
             "limb width of the large-G kernel's exact int64 sums, "
             "summed over builds: over builds.large, the width the "
             "group-rows bound gave (8 at 2^23 rows, 6 or 5 at 2^26)")
         self.metrics.func_counter(
             "exec.pallas.kernel.matmul_rows",
-            lambda: _ga.MATMUL_ROWS.value("large"),
+            lambda: MATMUL_ROWS.value("large"),
             "rows of the large-G kernel's matmul operands (limb and "
             "count rows, three rows a shadow; built in VMEM), summed "
             "over builds")
         self.metrics.func_counter(
             "exec.pallas.kernel.group_tile",
-            lambda: _ga.GROUP_TILE_LANES.value("large"),
+            lambda: GROUP_TILE_LANES.value("large"),
             "lanes of the group tile the large-G kernel took, summed "
             "over builds: the group count rounded up to 128, at most "
-            "the tile parameter (128 for TPC-H Q1's 12 groups)")
+            "GROUP_TILE's 512 (128 for TPC-H Q1's 12 groups)")
         self.metrics.func_counter(
             "exec.pallas.kernel.mxu_passes",
-            lambda: _ga.MXU_PASSES.value("large"),
+            lambda: MXU_PASSES.value("large"),
             "bf16 MXU passes of the large-G kernel's contraction of "
             "its exact rows, summed over builds (1 a build: limbs of "
             "at most 8 bits, counts and the one-hot are exact in bf16)")
         self.metrics.func_counter(
             "exec.pallas.rows",
-            lambda: _ga.ROWS.value(),
+            lambda: ROWS.value(),
             "rows offered to Pallas group-aggregate kernels at trace "
             "time (per-build input height, not per-execution)")
         # normalized-sort tallies (ops/sortkey.py) — trace-time, like
@@ -768,8 +762,6 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         import jaxlib
 
         from .. import native
-        from ..ops.pallas import autotune as _tune
-        from ..ops.pallas import paritygate as _pgate
         devs = jax.devices()
         mesh_ids = ([int(d.id) for d in self.mesh.devices.flat]
                     if self.mesh is not None else None)
@@ -788,22 +780,8 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             "compile_cache_dir": self._compile_cache_dir,
             "compile_cache_error": coldstart.cache_error(),
             "pallas_interpret": self._pallas_interpret(),
-            "autotune_rejected": dict(_tune.REJECTED),
-            "paritygate_errors": dict(_pgate.ERRORS),
             "native": native.status(),
         }
-
-    def _autotune_mode(self, session) -> str:
-        """Pallas tile-autotune mode: session var `pallas_autotune`
-        overrides the cluster setting (ops/pallas/autotune.py)."""
-        mode = session.vars.get("pallas_autotune", None)
-        if mode is None:
-            try:
-                mode = self.settings.get("sql.exec.pallas.autotune")
-            except Exception:
-                mode = "auto"
-        mode = str(mode).lower()
-        return mode if mode in ("auto", "on", "off") else "auto"
 
     # session vars a journal entry may replay into a prewarm session:
     # exactly the plan-key-changing vars _prepare_select journals —
@@ -1883,7 +1861,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
     def operator_profile(self, sql: str,
                          session: Session | None = None) -> dict:
         """Profile one SELECT's operators via the instrumented eager
-        rerun and return the digest (bench.py records this per
+        rerun and return the digest (a benchmark can record this per
         headline query: top operators by device_seconds + total bytes
         moved). Never touches the statement's real execution path."""
         sess = session or self.session()
@@ -2771,23 +2749,6 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                         else spill.page_rows if spill is not None
                         else 0),
                 vars=jvars)
-            # large-G kernel tile point: the per-backend tuning table
-            # (or shipped constants); perf-only, bit-identical either
-            # way, so deliberately NOT in the cache key above
-            from ..ops.pallas import autotune as _tune
-            interp = self._pallas_interpret()
-            gt, br, limb_cap = _tune.params_for(
-                jax.default_backend(), self._compile_cache_dir,
-                mode=self._autotune_mode(session), interpret=interp) \
-                if pallas != "off" else _tune.DEFAULT
-            # parity-gated promotion: kernel paths measured bit-exact
-            # on this backend widen `auto`'s envelope; perf-only (the
-            # gate proves exactness) so, like the tile point, NOT in
-            # the cache key
-            from ..ops.pallas import paritygate as _pgate
-            exact_paths = _pgate.promoted(
-                jax.default_backend(), self._compile_cache_dir,
-                interp) if pallas == "auto" else ()
             with self.tracer.span("compile"):
                 params = ExecParams(
                     hash_group_capacity=cap,
@@ -2796,11 +2757,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                     n_shards=(self.mesh.devices.size
                               if decision is not None else 1),
                     pallas_groupagg=pallas,
-                    pallas_interpret=interp,
-                    pallas_group_tile=gt,
-                    pallas_block_rows=br,
-                    pallas_limb_cap=limb_cap,
-                    pallas_exact_paths=exact_paths,
+                    pallas_interpret=self._pallas_interpret(),
                     topk_sort=not no_topk,
                     sort_normalized=sortn)
                 if spill is not None and spill.kind == "join":

@@ -28,9 +28,10 @@ planes that already run host-side:
   plane times the REAL execution and ships home as ``flow_profile``
   wire frames (like ``flow_span``) for a node-tagged cluster profile.
 
-Concurrency discipline follows ops/pallas/groupagg.py `_KernelTally`:
-one lock around the op table, per-statement sinks on a thread-local
-(never a shared global), per-flow sinks merged at the gateway.
+Concurrency discipline follows ops/pallas/groupagg_large.py
+`_KernelTally`: one lock around the op table, per-statement sinks on a
+thread-local (never a shared global), per-flow sinks merged at the
+gateway.
 """
 
 from __future__ import annotations

@@ -391,10 +391,10 @@ class ScanPlaneMixin:
         """Device bytes the plan's aggregation allocates beside its
         `padded`-row input, by the path it will compile onto.
 
-        Large-G kernel (a dense GROUP BY inside compile.large_kernel_
-        eligible, asked with the shipped tile and no parity-promoted
-        path): the 32-bit words the kernel is handed and its
-        accumulator tiles (compile.large_kernel_bytes). Measured on
+        Large-G kernel (a dense GROUP BY for which compile.large_
+        kernel_eligible says what the compile will be told): the
+        32-bit words the kernel is handed and its accumulator tiles
+        (compile.large_kernel_bytes). Measured on
         the v5e at TPC-H SF10, 2^26 rows, Q1 (my chip run, PR 28,
         PERF.md): 3.0 GiB of operand words modelled, 3.375 GiB of
         temporaries reserved by the loaded programs beside a 3.19 GiB
@@ -408,32 +408,27 @@ class ScanPlaneMixin:
         reading older than the v5e runs and not repeated there), so a
         table that "fits" can still run out at compile time without
         this term."""
-        from ..ops.pallas import autotune as _tune
         from .compile import large_kernel_bytes, large_kernel_eligible
         agg = _root_aggregate(node)
         if agg is not None:
-            gt, br, limb_cap = _tune.DEFAULT
             # graftlint: waive[plan-key-completeness] the verdict this
             # feeds (`stream`) is a key element, and so is the var
             pallas = session.vars.get("pallas_groupagg", "auto")
             params = ExecParams(
                 pallas_groupagg=self._pallas_mode(pallas),
-                pallas_interpret=self._pallas_interpret(),
-                pallas_group_tile=gt, pallas_block_rows=br,
-                pallas_limb_cap=limb_cap)
+                pallas_interpret=self._pallas_interpret())
             if large_kernel_eligible(agg, padded, params):
-                return large_kernel_bytes(agg, padded, params)
+                return large_kernel_bytes(agg, padded)
         return 16 * _count_aggs(node) * padded
 
     @staticmethod
     def _pallas_mode(pallas) -> str:
-        """A value of session var pallas_groupagg as auto | on | off:
-        legacy bool spellings normalize (True was the old opt-in),
-        anything unrecognized means off."""
-        if isinstance(pallas, bool):
-            pallas = "on" if pallas else "off"
-        pallas = str(pallas).lower()
-        return pallas if pallas in ("auto", "on", "off") else "off"
+        """A value of session var pallas_groupagg as auto | off: the
+        spellings that once opted in further (`on`, True) read as
+        auto, the kernel enabled inside its exact envelope; False and
+        anything unrecognized mean off."""
+        on = str(pallas).lower() in ("auto", "on", "true")
+        return "auto" if on else "off"
 
     def _page_rows(self, session: Session) -> int:
         """Session page size rounded UP to a shape-ladder bucket: page

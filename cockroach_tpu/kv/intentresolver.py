@@ -17,6 +17,7 @@ from __future__ import annotations
 import time
 
 from ..storage.mvcc import TxnStatus
+from .concurrency import Span
 
 MAX_KEY = b"\xff" * 12
 
@@ -66,7 +67,15 @@ class IntentResolver:
                 pending.append((key, meta))
                 continue
             status, commit_ts = d
-            self.store.mvcc.resolve_intent(key, meta, status, commit_ts)
+            # under the key's write latch, like Txn._resolve: the
+            # resolve is a read-modify-write that foreground resolvers
+            # and writers of the same key must not interleave with
+            guard = self.store.latches.acquire([(Span(key), True)])
+            try:
+                self.store.mvcc.resolve_intent(key, meta, status,
+                                               commit_ts)
+            finally:
+                self.store.latches.release(guard)
             n += 1
         self.queue = pending
         self.resolved_total += n

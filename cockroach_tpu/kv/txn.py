@@ -76,8 +76,22 @@ class Txn:
             raise TxnRetryError("conflicting txn still pending")
         commit_ts = rec.commit_ts if rec.status == TxnStatus.COMMITTED \
             else None
-        self.store.mvcc.resolve_intent(err.key, err.txn_meta, rec.status,
-                                       commit_ts)
+        self._resolve(err.key, err.txn_meta, rec.status, commit_ts)
+
+    def _resolve(self, key: bytes, meta: TxnMeta, status: TxnStatus,
+                 commit_ts: Optional[Timestamp] = None) -> None:
+        """Resolve one intent under its key's write latch. MVCC's
+        resolve reads the intent's meta record and then rewrites it, and
+        an intent has several resolvers (its owner at commit, every
+        pusher that met it): unlatched, a second resolver of a commit
+        whose timestamp was pushed finds the provisional version already
+        moved and deletes the committed one, and a resolver that lost
+        the processor between its read and its write removes the meta
+        of the intent the next writer has laid since."""
+        self._with_latch(
+            [(Span(key), True)],
+            lambda: self.store.mvcc.resolve_intent(key, meta, status,
+                                                   commit_ts))
 
     def _with_latch(self, spans, fn):
         guard = self.store.latches.acquire(spans)
@@ -189,9 +203,8 @@ class Txn:
             raise TxnAbortedError(self.meta.id)
         self.finished = True
         for k in self.intent_keys:
-            self.store.mvcc.resolve_intent(k, self.meta,
-                                           TxnStatus.COMMITTED,
-                                           self.meta.write_ts)
+            self._resolve(k, self.meta, TxnStatus.COMMITTED,
+                          self.meta.write_ts)
         # record is only evictable once every intent is resolved:
         # pushers finding an intent of an unknown txn treat it as
         # aborted (recovery), which would be wrong before this point
@@ -207,13 +220,13 @@ class Txn:
         except KeyError:
             pass
         for k in self.intent_keys:
-            self.store.mvcc.resolve_intent(k, self.meta, TxnStatus.ABORTED)
+            self._resolve(k, self.meta, TxnStatus.ABORTED)
         self.store.txns.remove(self.meta.id)
 
     def _restart(self) -> None:
         """Epoch restart: abort-resolve old intents, advance ts."""
         for k in self.intent_keys:
-            self.store.mvcc.resolve_intent(k, self.meta, TxnStatus.ABORTED)
+            self._resolve(k, self.meta, TxnStatus.ABORTED)
         self.intent_keys = []
         self.read_spans = []
         self.meta.epoch += 1

@@ -1,9 +1,8 @@
 """Pallas TPU kernel: one-pass LARGE-G dense grouped aggregation.
 
-The sibling `groupagg.py` kernel Python-unrolls one masked reduction
-per (group, aggregate) pair, which caps the group count at a few
-dozen. This kernel handles the hash-strategy group counts (q3 ~30K,
-q18 ~200K at scale) by tiling the group domain and turning the
+The engine's one grouped-aggregation kernel: it serves dense group
+domains from TPC-H Q1's twelve groups up to LARGE_G_MAX
+(exec/compile.py) by tiling the group domain and turning the
 segment sum into MXU matmuls: for each row block,
 
     one_hot(gid)[blk, G_tile].T @ values[blk, A]  ->  [G_tile, A]
@@ -26,7 +25,7 @@ bf16 MXU pass with f32 accumulation, not the six passes of an f32
 matmul at `Precision.HIGHEST`; only float-sum rows keep such a
 contraction, of their own.
 
-Dtype envelope — wider than the small kernel's f32-only one:
+Dtype envelope:
 
 - f32 value columns accumulate in a f32 [NF, G_tile] tile. A block
   partial is exact for integer-valued columns while
@@ -63,6 +62,7 @@ from __future__ import annotations
 
 import functools
 import math
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -70,9 +70,58 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .groupagg import (  # noqa: F401
-    BUILDS, FALLBACKS, GROUP_TILE_LANES, LANES, LIMB_BITS, MATMUL_ROWS,
-    MAX, MIN, MXU_PASSES, OPERAND_BYTES, ROWS)
+LANES = 128
+
+# op kinds of a MIN/MAX slot
+MIN, MAX = 0, 1
+
+
+class _KernelTally:
+    """Thread-safe per-kernel counter.
+
+    The trace-time tallies are bumped inside jit-traced bodies; the
+    pipelined data plane, per-mesh dispatcher threads and concurrent
+    pgwire sessions can trace simultaneously, so a bare
+    ``global x; x += 1`` read-modify-write races. One lock per tally,
+    keyed by kind (``large`` for the kernel's builds, ``agg`` for an
+    aggregation that fell back) so the engine can expose per-kind and
+    total func-metrics.
+    """
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._counts: dict[str, int] = {}
+
+    def bump(self, kind: str, delta: int = 1) -> None:
+        with self._lock:
+            self._counts[kind] = self._counts.get(kind, 0) + delta
+
+    def value(self, kind: str | None = None) -> int:
+        with self._lock:
+            if kind is None:
+                return sum(self._counts.values())
+            return self._counts.get(kind, 0)
+
+
+# Trace-time tallies, read by the engine's exec.pallas.* func-metrics.
+# large_group_aggregate's body runs once per (shape, static args)
+# jit-cache entry, so they count kernel BUILDS, the honest metric for
+# a jitted kernel (executions happen inside XLA where host counters
+# cannot see them).
+BUILDS = _KernelTally()      # kernel (re)builds
+ROWS = _KernelTally()        # rows offered to the kernel at trace time
+FALLBACKS = _KernelTally()   # aggregations compiled on the XLA segment
+                             # path while the kernel was enabled
+OPERAND_BYTES = _KernelTally()   # bytes of the HBM arrays a build hands
+                                 # the kernel (what XLA writes for it)
+LIMB_BITS = _KernelTally()       # limb width of a build's exact sums,
+                                 # summed over builds (/ builds)
+MATMUL_ROWS = _KernelTally()     # rows of a build's matmul operands,
+                                 # summed over builds
+GROUP_TILE_LANES = _KernelTally()    # lanes of the group tile a build
+                                     # took, summed over builds
+MXU_PASSES = _KernelTally()      # bf16 MXU passes of a build's
+                                 # exact-rows contraction, over builds
 
 # group-domain tile (VMEM accumulator minor dim; multiple of 128
 # lanes): the UPPER bound of the tile a build takes, which is sized by
@@ -94,9 +143,9 @@ MAX_LIMB_BITS = 8
 def effective_group_tile(num_groups: int,
                          group_tile: int = GROUP_TILE) -> int:
     """Lanes of the group tile a build over `num_groups` dense groups
-    takes: the domain rounded up to whole 128-lane vregs, at most the
-    (possibly autotuned) `group_tile`. TPC-H Q1's 12 groups take 128
-    lanes, not 512; a domain past group_tile - 128 sees the parameter."""
+    takes: the domain rounded up to whole 128-lane vregs, at most
+    `group_tile`. TPC-H Q1's 12 groups take 128 lanes, not 512; a
+    domain past group_tile - 128 sees the upper bound."""
     return min(group_tile, -(-max(1, num_groups) // LANES) * LANES)
 
 
@@ -108,22 +157,17 @@ def row_block(n: int, block_rows: int = BLOCK_ROWS) -> int:
 
 
 def limb_width(n: int, max_group_rows: int,
-               block_rows: int = BLOCK_ROWS,
-               cap: int = MAX_LIMB_BITS) -> int:
+               block_rows: int = BLOCK_ROWS) -> int:
     """The widest limb w such that ALL THREE steps stay exact: the
     bf16 operand (w <= 8: a limb is an integer in [0, 255]), the MXU's
     f32 block partial (blk*(2^w-1) < 2^24) and the per-group i32
     running sum (maxg*(2^w-1) < 2^31). Mirrors
-    agg._group_sum_i64_limbs' bound, tightened by the first two.
-    `cap` (a tuning table's, ops/pallas/autotune.py) may only narrow
-    the width below the exactness bound — results stay bit-identical
-    for any cap >= 1, a narrower cap just trades more limb rows for
-    nothing; a cap past 8 reads as 8."""
+    agg._group_sum_i64_limbs' bound, tightened by the first two."""
     blk = row_block(n, block_rows)
     maxg = max_group_rows if max_group_rows and 0 < max_group_rows <= n else n
     maxg = max(1, maxg)
     w = int(math.floor(math.log2((2 ** 31 - 1) / maxg + 1)))
-    w = min(w, 24 - int(math.log2(blk)), MAX_LIMB_BITS, cap)
+    w = min(w, 24 - int(math.log2(blk)), MAX_LIMB_BITS)
     return max(1, w)
 
 

@@ -35,9 +35,9 @@ lane first — each lowers to a <=2-operand XLA sort (key + iota), so
 compile cost no longer grows with the key count. Most ORDER BY lists
 fit ONE lane.
 
-The tallies mirror ops/pallas/groupagg.py: they bump at TRACE time
-(sorts execute inside jitted programs where host counters can't see
-them) and feed the engine's ``exec.sort.*`` func-metrics.
+The tallies mirror ops/pallas/groupagg_large.py: they bump at TRACE
+time (sorts execute inside jitted programs where host counters can't
+see them) and feed the engine's ``exec.sort.*`` func-metrics.
 """
 
 from __future__ import annotations
@@ -49,9 +49,9 @@ import jax.numpy as jnp
 
 
 class _Tally:
-    """Thread-safe per-site counter (see groupagg._KernelTally): traces
-    can run concurrently from dispatcher threads and pgwire sessions,
-    so a bare ``global x; x += 1`` read-modify-write races."""
+    """Thread-safe per-site counter (see groupagg_large._KernelTally):
+    traces can run concurrently from dispatcher threads and pgwire
+    sessions, so a bare ``global x; x += 1`` read-modify-write races."""
 
     def __init__(self):
         self._lock = threading.Lock()

@@ -27,8 +27,8 @@ aren't implemented on the CPU backend``. So:
   within-slice axis rides ICI and the cross-slice axis rides DCN.
 
 The per-host dispatcher process entry point is server/hostd.py; the
-CPU-backed multi-process pytest harness (tests/test_multihost.py) and
-``bench.py multihost_child`` both spawn it.
+CPU-backed multi-process pytest harness (tests/test_multihost.py)
+spawns it.
 """
 
 from __future__ import annotations

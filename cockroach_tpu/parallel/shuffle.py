@@ -40,12 +40,12 @@ from .mesh import SHARD_AXIS
 
 
 class _ByteTally:
-    """Thread-safe trace-time byte counter (the groupagg._KernelTally
-    discipline): bumped inside jit-traced bodies, so it counts the
-    bytes a TRACED exchange moves per shard per execution of that
-    program build — the engine exposes it through the
-    ``exec.movement.*`` family as the shuffle plane's contribution to
-    the unified transfer budget."""
+    """Thread-safe trace-time byte counter (the
+    groupagg_large._KernelTally discipline): bumped inside jit-traced
+    bodies, so it counts the bytes a TRACED exchange moves per shard
+    per execution of that program build — the engine exposes it
+    through the ``exec.movement.*`` family as the shuffle plane's
+    contribution to the unified transfer budget."""
 
     def __init__(self):
         self._lock = threading.Lock()

@@ -15,12 +15,11 @@ coordinator KV store, and then:
 - every other host pumps its transport, serving SetupFlow /
   merge-tree traffic, until the gateway posts the ``done`` key.
 
-The CPU tier-1 harness (tests/test_multihost.py) and
-``bench.py multihost_child`` both spawn this entry point on
-localhost; on a real pod the same command line runs once per host
-with the coordinator pointing at host 0. Fault modes (--fault) let
-the cross-host ladder tests kill a dispatcher or drop a merge link
-deterministically.
+The CPU tier-1 harness (tests/test_multihost.py) spawns this entry
+point on localhost; on a real pod the same command line runs once per
+host with the coordinator pointing at host 0. Fault modes (--fault)
+let the cross-host ladder tests kill a dispatcher or drop a merge
+link deterministically.
 
 ``--elastic`` (round 16) switches to the DYNAMIC pod: no
 jax.distributed, no fixed --num-processes. Host 0 founds the pod

@@ -35,6 +35,7 @@ class Settings:
 
     def __init__(self):
         self._defs: dict[str, _Setting] = {}
+        self._retired: set[str] = set()
         self._values: dict[str, object] = {}
         self._lock = threading.Lock()
         self._watchers: list[Callable[[str, object], None]] = []
@@ -44,7 +45,16 @@ class Settings:
                  validate=None):
         self._defs[name] = _Setting(name, default, kind, description, validate)
 
+    def retire(self, name: str) -> None:
+        """A setting this version no longer has (pkg/settings'
+        retiredSettings): a SET of it, from an older configuration or
+        script, is accepted and does nothing; it holds no value, is
+        not listed, and cannot be read."""
+        self._retired.add(name)
+
     def set(self, name: str, value) -> None:
+        if name in self._retired:
+            return
         d = self._defs.get(name)
         if d is None:
             raise SettingError(f"unknown cluster setting {name!r}")
@@ -79,7 +89,9 @@ class Settings:
             return out
 
     def apply_snapshot(self, snap: dict) -> None:
-        """Adopt a gossiped snapshot from another node."""
+        """Adopt a gossiped snapshot from another node (names this
+        version does not have, a retired one of an older node's
+        included, are passed over)."""
         for k, v in snap.items():
             if k in self._defs:
                 with self._lock:
@@ -140,7 +152,7 @@ def _register_builtins(s: Settings):
                "trace recording in the /debug/tracez ring buffer "
                "(0 disables; sql.trace.txn.enable_threshold analogue)")
     # cold-start elimination (exec/coldstart.py): persistent XLA
-    # compile cache + shape bucket ladder + Pallas tile autotune
+    # compile cache + shape bucket ladder
     s.register("sql.exec.compile_cache.dir", "", str,
                "'off' disables the persistent XLA compile cache; '' "
                "(default) uses $JAX_COMPILATION_CACHE_DIR, else "
@@ -159,11 +171,9 @@ def _register_builtins(s: Settings):
                "(1 = classic pow2 padding; 2/4/8 insert intermediate "
                "buckets: less padding waste, more executables)",
                _pow2)
-    s.register("sql.exec.pallas.autotune", "auto", str,
-               "Pallas tile autotune mode: auto = consult the "
-               "persisted tuning table, tune on first use on real "
-               "TPU; on = force tuning even off-TPU (test hook); "
-               "off = shipped constants")
+    # the large-G kernel's tile is its module's constants since the
+    # autotuner went; benchmark/configs/*.json still SET this to off
+    s.retire("sql.exec.pallas.autotune")
     # multi-tenant front door: sub-mesh dispatch + admission shedding
     s.register("sql.exec.submesh.size", "auto", str,
                "devices per dispatch sub-mesh for eligible distributed "
@@ -259,19 +269,10 @@ class SessionVars:
         "streaming_pipeline": "on",
         "direct_columnar_scans_enabled": True,
         "hash_group_capacity": 1 << 17,
-        # one-pass Pallas GROUP BY kernels. auto (default): per-plan
-        # eligibility, exact-result envelope only (large-G limb-sum
-        # kernel); on: also the small-G f32 kernel + float aggs
-        # (approximate vs the XLA path's f64); off: escape hatch /
-        # bench A/B lever
-        "pallas_groupagg": "auto",   # auto | on | off
-        # Pallas tile autotune (ops/pallas/autotune.py). None defers
-        # to the cluster setting sql.exec.pallas.autotune; auto: use
-        # the persisted per-backend tuning table when present (shipped
-        # constants otherwise); on: run a timed candidate sweep on
-        # first use; off: pin the shipped constants. Tile points are
-        # perf-only — results are bit-identical across the grid.
-        "pallas_autotune": None,     # None | auto | on | off
+        # one-pass large-G Pallas GROUP BY kernel. auto (default):
+        # per-plan eligibility (compile.large_kernel_eligible), exact
+        # results only; off: the XLA path, the oracle of auto == off
+        "pallas_groupagg": "auto",   # auto | off
         # normalized sort keys (ops/sortkey.py): pack the whole
         # ORDER BY / window / distinct key list into uint64 lanes and
         # sort with one stable argsort per lane instead of the

@@ -24,7 +24,7 @@ os.environ.setdefault("JAX_ENABLE_X64", "1")
 import tempfile
 
 # Hermetic cold-start state: the engine keeps its persistent XLA
-# compile cache + autotune/parity tables + shapes journal where
+# compile cache + shapes journal where
 # JAX_COMPILATION_CACHE_DIR says (exec/coldstart.py), and JAX reads
 # that variable at import — so point it at a throwaway session dir
 # BEFORE jax is imported. One dir for the whole run lets later tests

@@ -52,7 +52,16 @@ def test_phases_at_small_scale(smoke, data, capsys):
     for name in smoke.ON_MESH:
         assert f"# mesh[4] {name}: rows=" in out
     assert "lane_hits=" in out and "builds.large+=" in out
+    assert "int MIN/MAX exact" in out
     assert "rows equal one chip's" in out
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_int_minmax_through_the_kernel_is_exact(smoke, seed):
+    # the check chip_smoke.py makes through Mosaic, interpreted here:
+    # why integer MIN/MAX is inside `auto`'s envelope
+    assert smoke.int_minmax_is_exact(seed, n=512, groups=64,
+                                     interpret=True)
 
 
 def test_a_wrong_answer_fails(smoke):

@@ -1,11 +1,11 @@
-"""Cold-start elimination (exec/coldstart.py, ops/pallas/autotune.py).
+"""Cold-start elimination (exec/coldstart.py).
 
 Covers the persistent-compile-cache plumbing (cross-process warm
 start lives in the slow lane), the shape-bucket ladder (parity across
-ladder configs + the executable budget), the Pallas tile autotuner
-(tuned-vs-default parity, corrupt-table fallback), the bounded parse/
-executable cache eviction, and the per-statement compile-vs-execute
-split."""
+ladder configs + the executable budget), the bounded parse/
+executable cache eviction, the per-statement compile-vs-execute
+split, and the retired `sql.exec.pallas.autotune` setting the
+benchmark's configurations still SET."""
 
 import json
 import os
@@ -19,8 +19,7 @@ import pytest
 from cockroach_tpu.exec import coldstart
 from cockroach_tpu.exec.coldstart import ShapeLadder
 from cockroach_tpu.exec.engine import Engine
-from cockroach_tpu.ops.pallas import autotune
-from cockroach_tpu.ops.pallas import groupagg_large as pgl
+from cockroach_tpu.utils.settings import SettingError, Settings
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 
@@ -131,9 +130,7 @@ class TestCompileCachePlumbing:
         eng.execute("SELECT count(*), sum(v) FROM cm WHERE v > 1")
         after = eng.metrics.snapshot()
         for k in ("exec.compile.cache_hit", "exec.compile.cache_miss",
-                  "exec.compile.seconds", "exec.compile.prewarmed",
-                  "exec.autotune.runs", "exec.autotune.table_hit",
-                  "exec.autotune.table_miss"):
+                  "exec.compile.seconds", "exec.compile.prewarmed"):
             assert k in after
         # a fresh per-test cache dir: the statement's programs all
         # missed the persistent cache and paid the backend compiler
@@ -316,153 +313,53 @@ class TestBucketLadderParity:
         assert eng.metrics.snapshot()["sql.plan.cache.hit"] > before
 
 
-# ------------------------------------------------------------- autotune
+# ------------------------------------------------------ retired setting
 
-class TestAutotune:
-    def test_corrupt_table_falls_back(self, tmp_path):
-        root = str(tmp_path)
-        with open(autotune.table_path(root), "w") as f:
-            f.write("{not json at all")
-        assert autotune.params_for("cpu", root, mode="auto",
-                                   interpret=True) == autotune.DEFAULT
+RETIRED = "sql.exec.pallas.autotune"
 
-    def test_stale_version_falls_back(self, tmp_path):
-        root = str(tmp_path)
-        with open(autotune.table_path(root), "w") as f:
-            json.dump({"version": autotune.TABLE_VERSION + 1,
-                       "tables": {"cpu": {"group_tile": 256,
-                                          "block_rows": 512,
-                                          "limb_cap": 22}}}, f)
-        assert autotune.params_for("cpu", root, mode="auto",
-                                   interpret=True) == autotune.DEFAULT
 
-    def test_invalid_entry_falls_back(self, tmp_path):
-        root = str(tmp_path)
-        with open(autotune.table_path(root), "w") as f:
-            json.dump({"version": autotune.TABLE_VERSION,
-                       "tables": {"cpu": {"group_tile": 100,  # !128
-                                          "block_rows": 512,
-                                          "limb_cap": 22}}}, f)
-        assert autotune.params_for("cpu", root, mode="auto",
-                                   interpret=True) == autotune.DEFAULT
+class TestRetiredSetting:
+    """The autotuner went (PR 31); `benchmark/configs/*.json` still set
+    its cluster setting to `off`. As with pkg/settings' retired names,
+    a SET is accepted and does nothing, and the name is not listed."""
 
-    def test_off_never_reads_table(self, tmp_path):
-        root = str(tmp_path)
-        with open(autotune.table_path(root), "w") as f:
-            json.dump({"version": autotune.TABLE_VERSION,
-                       "tables": {"cpu": {"group_tile": 256,
-                                          "block_rows": 512,
-                                          "limb_cap": 22}}}, f)
-        assert autotune.params_for("cpu", root,
-                                   mode="off") == autotune.DEFAULT
+    @pytest.mark.parametrize("value", ["auto", "on", "off"])
+    def test_set_is_accepted_and_inert(self, value):
+        st = Settings()
+        seen = []
+        st.on_change(lambda name, v: seen.append(name))
+        before = st.snapshot()
+        st.set(RETIRED, value)
+        assert st.snapshot() == before and not seen
+        with pytest.raises(SettingError):
+            st.get(RETIRED)     # no value is kept, nothing reads one
 
-    @pytest.mark.parametrize("cap,want", [(22, 8), (16, 8), (8, 8),
-                                          (5, 5)])
-    def test_a_wide_cap_reads_as_eight(self, tmp_path, cap, want):
-        """A table written before the one-pass kernel holds limb caps
-        of 16 or 22: the entry still loads, its tile and block kept,
-        its cap read as the widest limb exact in bf16."""
-        root = str(tmp_path)
-        with open(autotune.table_path(root), "w") as f:
-            json.dump({"version": autotune.TABLE_VERSION,
-                       "tables": {"cpu": {"group_tile": 256,
-                                          "block_rows": 512,
-                                          "limb_cap": cap}}}, f)
-        assert autotune.params_for("cpu", root, mode="auto",
-                                   interpret=True) == (256, 512, want)
-        assert pgl.limb_width(4096, 1, block_rows=512, cap=cap) == want
+    def test_an_unknown_name_still_raises(self):
+        st = Settings()
+        for name in ("sql.exec.pallas.autotune2", "sql.exec.pallas"):
+            with pytest.raises(SettingError, match="unknown"):
+                st.set(name, "off")
 
-    def test_candidates_start_at_the_default(self):
-        assert autotune.CANDIDATES[0] == autotune.DEFAULT \
-            == (pgl.GROUP_TILE, pgl.BLOCK_ROWS, pgl.MAX_LIMB_BITS)
-        # no candidate exists only to narrow limbs, none widens them
-        assert {cap for _, _, cap in autotune.CANDIDATES} \
-            == {pgl.MAX_LIMB_BITS}
-        assert len(set(autotune.CANDIDATES)) == len(autotune.CANDIDATES)
+    def test_snapshots_leave_it_out_and_take_it(self):
+        st = Settings()
+        assert RETIRED not in st.snapshot()
+        # an older node's gossiped snapshot still holds it
+        st.apply_snapshot({RETIRED: "off", "kv.gc.ttl_seconds": 60,
+                           "no.such.setting": 1})
+        assert st.get("kv.gc.ttl_seconds") == 60
+        assert RETIRED not in st.snapshot()
+        assert "no.such.setting" not in st.snapshot()
 
-    def test_sweep_persists_and_reloads(self, tmp_path):
-        root = str(tmp_path / "tune")
-        cands = ((512, 1024, 8), (512, 512, 8))
-        tile = autotune.autotune("cpu", root, interpret=True,
-                                 n=1024, num_groups=256,
-                                 candidates=cands)
-        assert tile in cands
-        assert os.path.exists(autotune.table_path(root))
-        # a fresh lookup (no in-memory hit for this root in "auto"
-        # off-TPU) reads the persisted winner back
-        hit0 = autotune.TABLE.value("hit")
-        assert autotune.params_for("cpu", root, mode="auto",
-                                   interpret=True) == tile
-        assert autotune.TABLE.value("hit") > hit0
-
-    @pytest.mark.parametrize("seed", [0, 1, 2])
-    def test_kernel_tile_parity_fuzzed(self, seed):
-        """Any valid (group_tile, block_rows, limb_cap) point gives
-        bit-identical exact aggregates: limb sums recombine to the
-        same int64s, counts and MIN match the numpy oracle."""
-        import jax.numpy as jnp
-        n, G, bits = 2048, 300, 40
-        rng = np.random.default_rng(seed)
-        gid = rng.integers(0, G, n).astype(np.int32)
-        sel = rng.random(n) < 0.8
-        vals = rng.integers(0, 1 << bits, n).astype(np.int64)
-        oracle_cnt = np.zeros(G, np.int64)
-        np.add.at(oracle_cnt, gid[sel], 1)
-        oracle_sum = np.zeros(G, np.int64)
-        np.add.at(oracle_sum, gid[sel], vals[sel])
-        vf32 = vals.astype(np.float32)
-        for gt, br, cap in ((512, 1024, 22), (256, 512, 12),
-                            (1024, 2048, 22)):
-            w = pgl.limb_width(n, n, block_rows=br, cap=cap)
-            k = -(-bits // w)
-            mm = (jnp.asarray(np.where(sel, vf32, np.float32(np.inf)),
-                              jnp.float32),)
-            _, acc_i = pgl.large_group_aggregate(
-                jnp.asarray(gid), jnp.asarray(sel),
-                (jnp.asarray(np.where(sel, vals, 0)),), (), (), mm,
-                num_groups=G,
-                layout=pgl.limb_rows(0, bits, w) + (("live",),),
-                mm_ops=(pgl.MIN,), want_rep=False, group_tile=gt,
-                block_rows=br, interpret=True)
-            acc_i = np.asarray(acc_i).astype(np.int64)
-            sums = sum(acc_i[j] << np.int64(j * w) for j in range(k))
-            np.testing.assert_array_equal(sums, oracle_sum)
-            np.testing.assert_array_equal(acc_i[k], oracle_cnt)
-
-    def test_engine_tuned_table_matches_defaults(self):
-        """The acceptance parity arm: `pallas_groupagg=auto` with a
-        tuning table present is bit-identical to the shipped
-        constants, and still rides the kernel."""
-        from cockroach_tpu.models import tpch
-        sql = ("SELECT l_orderkey, count(*) AS c, "
-               "sum(l_quantity) AS q FROM lineitem "
-               "GROUP BY l_orderkey")
-
-        def arm(plant_table):
-            eng = Engine()
-            if plant_table:
-                # a non-default point that keeps the interpret-mode
-                # grid under the auto budget at 8192 rows: blk 2048
-                # halves the row blocks, gt 1024 halves the tiles
-                autotune._save(eng._compile_cache_dir, "cpu",
-                               (1024, 2048, 22), {})
-            else:
-                eng.settings.set("sql.exec.pallas.autotune", "off")
-            tpch.load(eng, 0.005, rows=8192, tables=("lineitem",))
-            s = eng.session()
-            s.vars.set("distsql", "off")
-            # builds are counted where the kernel is traced: forget
-            # what another test of this process traced at this shape
-            pgl.large_group_aggregate.clear_cache()
-            before = pgl.BUILDS.value("large")
-            rows = sorted(eng.execute(sql, session=s).rows)
-            return rows, pgl.BUILDS.value("large") - before
-
-        want, built_default = arm(plant_table=False)
-        got, built_tuned = arm(plant_table=True)
-        assert built_default > 0 and built_tuned > 0, \
-            "both arms must ride the large-G kernel"
-        assert got == want
+    def test_the_benchmarks_configurations_still_start(self):
+        import glob
+        configs = sorted(glob.glob(str(REPO / "benchmark/configs/*.json")))
+        assert len(configs) >= 3
+        eng = Engine()
+        for path in configs:
+            with open(path) as f:
+                for name, value in json.load(f)["settings"].items():
+                    eng.settings.set(name, value)
+        eng.execute(f"SET CLUSTER SETTING {RETIRED} = 'off'")
 
 
 # ------------------------------------------------ cross-process (slow)

@@ -48,6 +48,21 @@ def _http_get(node, path: str) -> str:
         return r.read().decode()
 
 
+def _whole_scrape(node, path: str, within: float = 30.0) -> dict:
+    """A ?cluster=1 scrape that reached every peer. The fan-out asks
+    only peers whose replicated liveness record is unexpired at the
+    scraped node's clock and gives each 2 s, so on a loaded host a
+    late heartbeat or reply makes a scrape `partial`, as it should:
+    wait for the next whole one (a renewal), not for one moment's
+    liveness. The last body is returned either way."""
+    deadline = time.time() + within
+    while True:
+        body = json.loads(_http_get(node, path))
+        if not body["partial"] or time.time() > deadline:
+            return body
+        time.sleep(0.2)
+
+
 @pytest.fixture(scope="module")
 def obs():
     oracle = Engine()
@@ -283,8 +298,7 @@ class TestClusterFanout:
         """ISSUE acceptance: /debug/tracez?cluster=1 scraped from a
         node that is NOT the gateway returns the gateway's
         slow-statement entry, node-tagged."""
-        body = json.loads(_http_get(obs["node2"],
-                                    "/debug/tracez?cluster=1"))
+        body = _whole_scrape(obs["node2"], "/debug/tracez?cluster=1")
         assert body["cluster"] is True
         assert body["partial"] is False
         assert sorted(body["nodes"]) == [1, 2, 3]
@@ -298,8 +312,8 @@ class TestClusterFanout:
         arrays; quantiles/means re-derive from the merged values."""
         local = json.loads(_http_get(obs["node"],
                                      "/_status/statements"))
-        merged = json.loads(_http_get(
-            obs["node"], "/_status/statements?cluster=1"))
+        merged = _whole_scrape(obs["node"],
+                               "/_status/statements?cluster=1")
         assert merged["cluster"] is True and merged["partial"] is False
         by_fp = {s["fingerprint"]: s for s in merged["statements"]}
         for s in local["statements"]:

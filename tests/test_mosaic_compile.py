@@ -3,7 +3,7 @@ TPU's own compiler (XLA:TPU and Mosaic) is installed and compiles for
 a topology that is described, not attached. Interpret mode cannot see
 what these see: a contraction Mosaic refuses, a block that passes the
 16 MB of scoped VMEM. Shapes are the benchmark's (TPC-H Q1's layout at
-SF1's 2^23 and SF10's 2^26 rows) and the autotuner's q18-class one.
+SF1's 2^23 and SF10's 2^26 rows) and a q18-class one (4,096 groups).
 Nothing runs, so nothing here is a time or an answer.
 
 The topology is described inside a fixture, never at import: only the
@@ -76,7 +76,7 @@ def test_q1_layout_compiles_at_the_benchmarks_sizes(one_chip, n, w):
 
 def test_q18_class_shape_compiles_at_the_tile_parameter(one_chip):
     """4,096 groups: the tile is the parameter's 512 lanes, with the
-    MIN and REPMIN slots the autotuner's shape has."""
+    MIN and REPMIN slots such a plan has."""
     layout = (("shadow", 0),) + pgl.limb_rows(0, 64, 8) \
         + (("count", 0), ("live",))
     _compile(one_chip, 1 << 22, 4096, layout, n_src=1,

@@ -1,183 +1,171 @@
-"""Pallas kernel tests (interpret mode on CPU; the real-TPU Mosaic
-lowering was validated directly on a v5e chip — see the dtype/layout
-notes in ops/pallas/groupagg.py; off-TPU CI can only run interpret).
+"""Which aggregations take the Pallas kernel, and that taking it never
+changes an answer (interpret mode on CPU; the Mosaic lowering is
+compiled in test_mosaic_compile.py and run by chip_smoke.py).
 
-Oracle: numpy, plus the engine's own XLA path for the integration
-tests (same query with pallas_groupagg on vs off must agree)."""
+The decision lives in one place, compile.large_kernel_eligible: the
+envelope table below pins it per (aggregate, argument family), and the
+engine tests hold `pallas_groupagg = auto` (and the spellings that read
+as auto) to the XLA path's rows, `off` being the oracle."""
 
 import numpy as np
 import pytest
 
-from cockroach_tpu.ops.pallas.groupagg import (COUNT, MAX, MIN, SUM,
-                                               dense_group_aggregate)
+from cockroach_tpu.exec import compile as C
+from cockroach_tpu.ops.pallas import groupagg_large as pgl
+from cockroach_tpu.sql import plan as P
+from cockroach_tpu.sql import types as T
+from cockroach_tpu.sql.bound import BCol, BoundAgg
+
+FAMILIES = {"INT": T.INT8, "DECIMAL": T.SQLType.decimal(12, 2),
+            "FLOAT": T.FLOAT8}
+
+# (aggregate, argument family) -> takes the kernel under `auto`.
+# Counts and `any` never read the argument's value in the kernel;
+# sums, avgs and extremes are exact for integers only.
+ENVELOPE = [("count_rows", None, True)] + [
+    (func, fam, exact or fam != "FLOAT")
+    for func, exact in (("count", True), ("any", True), ("sum", False),
+                        ("avg", False), ("min", False), ("max", False))
+    for fam in FAMILIES]
 
 
-def _data(n=8192, groups=6, seed=0):
-    rng = np.random.default_rng(seed)
-    gid = rng.integers(0, groups, size=n).astype(np.int32)
-    sel = rng.random(n) < 0.8
-    v = rng.normal(size=n).astype(np.float32) * 100
-    m = rng.random(n) < 0.9
-    return gid, sel, v, m
+def _dense_aggregate(func, fam, **kw):
+    """A dense 12-group GROUP BY with one aggregate, as the planner
+    would hand it to compile_plan."""
+    arg = None if fam is None else BCol("t.x", FAMILIES[fam])
+    agg = BoundAgg(func, arg, T.INT8 if arg is None else arg.type, **kw)
+    return P.Aggregate(child=None, group_by=[("g", BCol("t.g", T.INT8))],
+                       aggs=[agg], max_groups=12, group_dims=[11])
 
 
-class TestDenseGroupAggregate:
-    def test_all_ops_match_numpy(self):
-        gid, sel, v, m = _data()
-        acc, cnt = dense_group_aggregate(
-            gid, sel, (v, v, v, v), (m, m, m, m), 6,
-            (COUNT, SUM, MIN, MAX), block_rows=1024, interpret=True)
-        acc, cnt = np.asarray(acc), np.asarray(cnt)
-        eff = sel & m
-        for g in range(6):
-            gm = eff & (gid == g)
-            assert cnt[g, 0] == gm.sum()
-            assert abs(acc[g, 1] - v[gm].sum()) < 1e-2
-            assert acc[g, 2] == pytest.approx(v[gm].min(), rel=1e-6)
-            assert acc[g, 3] == pytest.approx(v[gm].max(), rel=1e-6)
+class TestEnvelope:
+    @pytest.mark.parametrize("func,fam,takes", ENVELOPE)
+    def test_auto_takes_exact_aggregates_only(self, func, fam, takes):
+        node = _dense_aggregate(func, fam)
+        for interpret in (False, True):
+            assert C.large_kernel_eligible(node, 8192, C.ExecParams(
+                pallas_groupagg="auto",
+                pallas_interpret=interpret)) is takes
+        # `off` (ExecParams' own default too) takes nothing
+        assert not C.large_kernel_eligible(
+            node, 8192, C.ExecParams(pallas_groupagg="off"))
+        assert not C.large_kernel_eligible(node, 8192, C.ExecParams())
 
-    def test_empty_group_identities(self):
-        gid, sel, v, m = _data(groups=3)
-        # group 5 never occurs
-        acc, _ = dense_group_aggregate(
-            gid, sel, (v,), (m,), 6, (SUM,), block_rows=1024,
-            interpret=True)
-        assert np.asarray(acc)[5, 0] == 0.0
+    def test_what_else_keeps_a_plan_on_xla(self):
+        auto = C.ExecParams(pallas_groupagg="auto")
+        node = _dense_aggregate("sum", "INT")
+        assert C.large_kernel_eligible(node, 8192, auto)
+        # toy inputs, rows not a whole number of vregs
+        assert not C.large_kernel_eligible(node, C.AUTO_MIN_ROWS - 128,
+                                           auto)
+        assert not C.large_kernel_eligible(node, 8192 + 64, auto)
+        # DISTINCT, hash strategy, no GROUP BY, a domain past the cap
+        assert not C.large_kernel_eligible(
+            _dense_aggregate("sum", "INT", distinct=True), 8192, auto)
+        node.max_groups, node.group_dims = 0, []
+        assert not C.large_kernel_eligible(node, 8192, auto)
+        node = _dense_aggregate("sum", "INT")
+        node.group_by = []
+        assert not C.large_kernel_eligible(node, 8192, auto)
+        node = _dense_aggregate("sum", "INT")
+        node.group_dims = [C.LARGE_G_MAX]
+        assert not C.large_kernel_eligible(node, 1 << 20, auto)
 
-    def test_single_block(self):
-        gid, sel, v, m = _data(n=1024, groups=2)
-        _, cnt = dense_group_aggregate(
-            gid, sel, (v,), (m,), 2, (COUNT,), block_rows=1024,
-            interpret=True)
-        cnt = np.asarray(cnt)
-        eff = sel & m
-        assert cnt[0, 0] == (eff & (gid == 0)).sum()
-        assert cnt[1, 0] == (eff & (gid == 1)).sum()
+    def test_exec_params_carry_two_pallas_fields(self):
+        assert sorted(f for f in C.ExecParams.__dataclass_fields__
+                      if f.startswith("pallas_")) \
+            == ["pallas_groupagg", "pallas_interpret"]
 
-    def test_multi_agg_mixed_masks(self):
-        n = 4096
-        rng = np.random.default_rng(7)
-        gid = rng.integers(0, 4, size=n).astype(np.int32)
-        sel = np.ones(n, bool)
-        v1 = rng.random(n).astype(np.float32)
-        m1 = rng.random(n) < 0.5
-        v2 = (rng.random(n) * 10).astype(np.float32)
-        m2 = np.ones(n, bool)
-        acc, _ = dense_group_aggregate(
-            gid, sel, (v1, v2), (m1, m2), 4, (SUM, MAX),
-            block_rows=2048, interpret=True)
-        acc = np.asarray(acc)
-        for g in range(4):
-            assert abs(acc[g, 0] - v1[m1 & (gid == g)].sum()) < 1e-3
-            assert acc[g, 1] == pytest.approx(
-                v2[(gid == g)].max(), rel=1e-6)
+
+N = 4096    # auto's row floor: the smallest table the kernel takes
+
+
+@pytest.fixture(scope="module")
+def eng():
+    from cockroach_tpu.exec.engine import Engine
+    e = Engine()
+    e.execute("CREATE TABLE px (g STRING NOT NULL, f FLOAT, "
+              "d DECIMAL(10,2))")
+    rng = np.random.default_rng(3)
+    e.store.insert_columns("px", {
+        "g": np.array([f"k{int(g)}" for g in rng.integers(0, 3, N)]),
+        "f": rng.normal(size=N) * 10,
+        "d": rng.integers(0, 10_000, N).astype(np.int64)},
+        e.clock.now())
+    return e
+
+
+def _rows(eng, sql, mode):
+    s = eng.session()
+    s.vars.set("distsql", "off")
+    s.vars.set("pallas_groupagg", mode)
+    return eng.execute(sql, session=s).rows
 
 
 class TestEnginePallasGroupBy:
-    """SET pallas_groupagg='on' routes eligible dense float GROUP BYs
-    through the kernel; results must match the XLA path. Dense strategy
-    requires dict-coded (STRING/BOOL) group keys — the Q1 shape."""
-
-    @pytest.fixture()
-    def eng(self, monkeypatch):
-        from cockroach_tpu.exec import compile as C
-        from cockroach_tpu.exec.engine import Engine
-        calls = []
-        large_calls = []
-        orig = C._pallas_dense_partials
-        monkeypatch.setattr(
-            C, "_pallas_dense_partials",
-            lambda *a, **k: (calls.append(1), orig(*a, **k))[1])
-        orig_l = C._pallas_large_partials
-        monkeypatch.setattr(
-            C, "_pallas_large_partials",
-            lambda *a, **k: (large_calls.append(1), orig_l(*a, **k))[1])
-        e = Engine()
-        e._pallas_calls = calls  # test-only visibility
-        e._pallas_large_calls = large_calls
-        e.execute("CREATE TABLE px (g STRING, f FLOAT, d DECIMAL(10,2))")
-        rng = np.random.default_rng(3)
-        rows = ", ".join(
-            f"('k{int(g)}', {float(f):.6f}, {float(d):.2f})"
-            for g, f, d in zip(rng.integers(0, 3, 200),
-                               rng.normal(size=200) * 10,
-                               rng.random(200) * 100))
-        e.execute(f"INSERT INTO px VALUES {rows}")
-        return e
+    """Dense strategy needs dict-coded (STRING/BOOL) group keys: the
+    Q1 shape."""
 
     SQL = ("SELECT g, count(*) AS c, sum(f) AS s, avg(f) AS a, "
            "min(f) AS lo, max(f) AS hi FROM px "
            "GROUP BY g ORDER BY g")
 
     def test_matches_xla_path(self, eng):
-        s = eng.session()
-        want = eng.execute(self.SQL, session=s).rows
-        # default auto: float aggs are outside the exact envelope and
-        # the table is tiny, so no kernel routed
-        assert not eng._pallas_calls and not eng._pallas_large_calls
-        s.vars.set("pallas_groupagg", "on")
-        got = eng.execute(self.SQL, session=s).rows
-        assert eng._pallas_calls, "kernel gate never fired"
-        assert len(got) == len(want) == 3
-        for rw, rg in zip(want, got):
-            assert rw[0] == rg[0] and rw[1] == rg[1]  # group, count
-            for a, b in zip(rw[2:], rg[2:]):
-                assert float(a) == pytest.approx(float(b), rel=1e-4)
+        # FLOAT aggregates are outside the envelope: every mode runs
+        # the XLA program and gives its rows, bit for bit
+        want = _rows(eng, self.SQL, "off")
+        before = pgl.BUILDS.value("large")
+        assert len(want) == 3
+        for mode in ("auto", "on"):
+            assert _rows(eng, self.SQL, mode) == want
+        assert pgl.BUILDS.value("large") == before
 
     def test_decimal_rides_large_kernel_exactly(self, eng):
-        # DECIMAL sums are outside the SMALL kernel's f32 envelope but
-        # inside the large kernel's int64-limb one: under `on` the
-        # gate must route them there and the results must stay EXACT
-        # (bit-identical int64 fixed-point sums, not f32 approximate)
-        s = eng.session()
+        # DECIMAL sums ride the int64-limb path: the results must be
+        # EXACT (bit-identical int64 fixed-point sums)
         sql = "SELECT g, sum(d) AS s FROM px GROUP BY g ORDER BY g"
-        want = eng.execute(sql, session=s).rows
-        s.vars.set("pallas_groupagg", "on")
-        got = eng.execute(sql, session=s).rows
-        assert not eng._pallas_calls  # small kernel ineligible
-        assert eng._pallas_large_calls, "large kernel never routed"
-        assert got == want  # exact equality: same int64 fixed-point sums
+        want = _rows(eng, sql, "off")
+        before = pgl.BUILDS.value("large")
+        got = _rows(eng, sql, "auto")
+        assert pgl.BUILDS.value("large") > before, \
+            "large kernel never routed"
+        assert got == want
+
+    @pytest.mark.parametrize("spelling", ["on", "true"])
+    def test_old_opt_ins_read_as_auto(self, eng, spelling):
+        """`SET pallas_groupagg = on` (and the legacy True) name the
+        `auto` program: the same plan-cache key, so the statement is a
+        hit on what `auto` compiled, not a third program."""
+        sql = "SELECT g, sum(d) AS s, count(d) AS c FROM px GROUP BY g"
+        s = eng.session()
+        s.vars.set("distsql", "off")
+        want = eng.execute(sql, session=s).rows      # auto, the default
+        eng.execute(f"SET pallas_groupagg = {spelling}", session=s)
+        assert eng._pallas_mode(s.vars.get("pallas_groupagg")) == "auto"
+        before = eng.metrics.snapshot()
+        assert eng.execute(sql, session=s).rows == want
+        after = eng.metrics.snapshot()
+        assert after["sql.plan.cache.hit"] == before["sql.plan.cache.hit"] + 1
+        assert after["sql.plan.cache.miss"] == before["sql.plan.cache.miss"]
 
 
 class TestUngroupedPallas:
-    """The one-pass kernel also serves ungrouped aggregation
-    (num_groups == 1) — the Q6 shape. The monkeypatched counter
-    asserts the kernel really fired (a silent fallback to XLA would
-    make result comparison vacuous)."""
+    """Ungrouped aggregation (num_groups == 1, the Q6 shape) has no
+    kernel: `auto` is the XLA program."""
 
-    @pytest.fixture()
-    def ueng(self, monkeypatch):
-        from cockroach_tpu.exec import compile as C
+    def test_matches_xla(self, eng):
+        q = ("SELECT count(*), avg(f), min(f), max(f), sum(d) FROM px "
+             "WHERE d >= 5000")
+        before = pgl.BUILDS.value("large")
+        assert _rows(eng, q, "auto") == _rows(eng, q, "off")
+        assert pgl.BUILDS.value("large") == before
+
+    def test_q6_shape(self):
         from cockroach_tpu.exec.engine import Engine
-        calls = []
-        orig = C._pallas_dense_partials
-        monkeypatch.setattr(
-            C, "_pallas_dense_partials",
-            lambda *a, **k: (calls.append(1), orig(*a, **k))[1])
-        e = Engine()
-        e._pallas_calls = calls
-        return e
-
-    def test_matches_xla(self, ueng):
-        e = ueng
-        e.execute("CREATE TABLE t (a INT, f FLOAT)")
-        e.execute("INSERT INTO t VALUES " + ",".join(
-            f"({i},{i / 7})" for i in range(256)))
-        s = e.session()
-        s.vars.set("pallas_groupagg", "on")
-        q = ("SELECT count(*), avg(f), min(f), max(f) FROM t "
-             "WHERE a >= 128")
-        r_p = e.execute(q, s).rows[0]
-        assert e._pallas_calls, "ungrouped kernel gate never fired"
-        r_x = e.execute(q).rows[0]
-        assert all(abs(a - b) < 1e-4 for a, b in zip(r_p, r_x))
-
-    def test_q6_shape(self, ueng):
         from cockroach_tpu.models import tpch
-        e = ueng
-        tpch.load(e, sf=0.01, rows=8192)
+        e = Engine()
+        tpch.load(e, sf=0.01, rows=8192, tables=("lineitem",))
         want = tpch.ref_q6(tpch.gen_lineitem(0.01, rows=8192))
-        s = e.session()
-        s.vars.set("pallas_groupagg", "on")
-        got = e.execute(tpch.Q6, s).rows[0][0]
-        assert abs(got - want) < max(1e-4 * abs(want), 1e-4)
+        got = _rows(e, tpch.Q6, "auto")
+        assert got == _rows(e, tpch.Q6, "off")
+        assert abs(got[0][0] - want) < max(1e-4 * abs(want), 1e-4)

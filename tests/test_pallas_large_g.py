@@ -13,8 +13,9 @@ Three layers:
 3. engine-level eligibility + parity: q18's inner GROUP BY rides the
    large kernel under the default ``auto`` mode, a sparse packed
    composite key does NOT (hash strategy -> fallback tally), the
-   ``auto`` arm is bit-exact vs ``off``, and the compiled HLO of the
-   auto arm carries no aggregation scatters.
+   ``auto`` arm is bit-exact vs ``off`` (integer MIN/MAX included),
+   the placement model asks the compile's own question, and the
+   compiled HLO of the auto arm carries no aggregation scatters.
 """
 
 import threading
@@ -22,11 +23,10 @@ import threading
 import numpy as np
 import pytest
 
-from cockroach_tpu.ops.pallas import groupagg as pg
-from cockroach_tpu.ops.pallas.groupagg import MAX, MIN, _KernelTally
+from cockroach_tpu.ops.pallas import groupagg_large as pg
 from cockroach_tpu.ops.pallas.groupagg_large import (
-    BLOCK_ROWS, GROUP_TILE, effective_group_tile, large_group_aggregate,
-    limb_rows, limb_width, row_block)
+    BLOCK_ROWS, GROUP_TILE, MAX, MIN, _KernelTally, effective_group_tile,
+    large_group_aggregate, limb_rows, limb_width, row_block)
 
 
 # ---------------------------------------------------------------- helpers
@@ -120,9 +120,6 @@ class TestLimbWidth:
         # blk = 2^16 is the largest block an 8-bit limb allows
         assert limb_width(1 << 16, 1, block_rows=1 << 16) == 8
         assert limb_width(1 << 17, 1, block_rows=1 << 17) == 7
-        # a table's cap may only narrow; one past 8 reads as 8
-        assert limb_width(4096, 1, cap=22) == 8
-        assert limb_width(4096, 1, cap=5) == 5
 
 
 class TestKernelTally:
@@ -351,8 +348,9 @@ class TestLargeKernelParity:
                                           _group_count(gid, m, G))
 
     def test_float_sum_column(self):
-        # an f32 column ("on", or a promoted float_sum) is copied into
-        # the operand beside the rows the kernel derives
+        # an f32 column (a capability no plan reaches: FLOAT arguments
+        # are outside the engine's envelope) is copied into the operand
+        # beside the rows the kernel derives
         n, G = 1024, 96
         rng = np.random.default_rng(6)
         gid = rng.integers(0, G, n).astype(np.int32)
@@ -469,7 +467,7 @@ class TestPlanSizedContraction:
         f32 partial of a block is blk x 255, under 2^24 up to the
         largest block limb_width allows an 8-bit limb, 2^16."""
         n, G = 1 << 16, 12
-        assert limb_width(n, n, block_rows=blk, cap=w) == w
+        assert w <= limb_width(n, n, block_rows=blk) == 8
         assert blk * ((1 << w) - 1) < 1 << 24
         gid = np.full(n, 7, np.int32)
         sel = np.ones(n, bool)
@@ -643,34 +641,41 @@ class TestEngineEligibility:
     def test_metrics_exported(self, teng):
         snap = teng.metrics.snapshot()
         for want in ("exec.pallas.kernel.builds",
-                     "exec.pallas.kernel.builds.small",
                      "exec.pallas.kernel.builds.large",
                      "exec.pallas.kernel.fallbacks",
                      "exec.pallas.rows"):
             assert want in snap
 
 
-class TestParityGatePromotion:
-    """The fuzzed parity gate (ops/pallas/paritygate.py) promotes
-    measured-exact kernel paths into `auto`; everything else stays
-    `on`-gated. auto == off bit-parity is the invariant throughout."""
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_kernel_tile_parity_fuzzed(seed):
+    """Any valid (group_tile, block_rows) point gives bit-identical
+    exact aggregates: limb sums recombine to the same int64s, counts
+    match the numpy oracle. (The engine runs the module's constants;
+    the points are a function's arguments, not a knob.)"""
+    n, G, bits = 2048, 300, 40
+    rng = np.random.default_rng(seed)
+    gid = rng.integers(0, G, n).astype(np.int32)
+    sel = rng.random(n) < 0.8
+    vals = rng.integers(0, 1 << bits, n).astype(np.int64)
+    mm = (np.where(sel, vals.astype(np.float32), np.float32(np.inf)),)
+    for gt, br in ((512, 1024), (256, 512), (1024, 2048), (128, 256)):
+        w = limb_width(n, n, block_rows=br)
+        layout = limb_rows(0, bits, w) + (("live",),)
+        _, acc_i = large_group_aggregate(
+            gid, sel, (np.where(sel, vals, 0),), (), (), mm,
+            num_groups=G, layout=layout, mm_ops=(MIN,), group_tile=gt,
+            block_rows=br, interpret=True)
+        acc_i = np.asarray(acc_i)
+        np.testing.assert_array_equal(_recombine(acc_i, layout, 0),
+                                      _group_sum(gid, sel, vals, G))
+        np.testing.assert_array_equal(acc_i[-1], _group_count(gid, sel, G))
 
-    def test_gate_promotes_int_minmax_not_float_sum(self, tmp_path):
-        from cockroach_tpu.ops.pallas import paritygate as pgate
-        got = pgate.fuzz("cpu", str(tmp_path), interpret=True)
-        assert "int_minmax" in got, \
-            "hi-limb MIN/MAX + XLA refinement must fuzz bit-exact"
-        assert "float_sum" not in got, \
-            "f32 accumulation cannot bit-match the f64 oracle"
-        # verdict persisted in the autotune-style backend table
-        assert pgate.load_table(str(tmp_path))["cpu"]["exact"] == \
-            ["int_minmax"]
 
-    def test_corrupt_table_demotes_everything(self, tmp_path):
-        from cockroach_tpu.ops.pallas import paritygate as pgate
-        with open(pgate.table_path(str(tmp_path)), "w") as f:
-            f.write("{not json")
-        assert pgate.load_table(str(tmp_path)) == {}
+class TestIntMinMaxAndPlacement:
+    """Integer MIN/MAX rides the kernel under `auto`, bit for bit; and
+    the placement model (exec/scanplane.py) calls the predicate the
+    compile calls, with the same inputs."""
 
     def test_int_minmax_rides_kernel_under_auto_bit_exact(self, teng):
         # adjacent giant int64 values: a plain f32 kernel MIN/MAX
@@ -702,13 +707,52 @@ class TestParityGatePromotion:
         m = gk == g0
         assert got[0][1:] == (int(v[m].min()), int(v[m].max()))
 
-    def test_paritygate_metrics_exported(self, teng):
-        snap = teng.metrics.snapshot()
-        for want in ("exec.paritygate.checks",
-                     "exec.paritygate.seconds",
-                     "exec.paritygate.table_hit",
-                     "exec.paritygate.table_miss"):
-            assert want in snap
+    @pytest.mark.parametrize("shape,takes", [
+        ("q1", True), ("int_minmax", True), ("float_sum", False),
+        ("hash", False)])
+    def test_placement_agrees_with_compile(self, teng, shape, takes):
+        """scanplane's model prices the kernel's words exactly when
+        the program compile_plan builds bumps `builds.large`."""
+        from cockroach_tpu.exec import compile as C
+        from cockroach_tpu.exec.stmtutil import (_count_aggs,
+                                                 _root_aggregate)
+        from cockroach_tpu.models import tpch
+        n = N_ROWS
+        rng = np.random.default_rng(5)
+        teng.execute(f"CREATE TABLE pl_{shape} (g INT8 NOT NULL, "
+                     "h INT8 NOT NULL, v INT8, f FLOAT)")
+        wide = shape == "hash"      # two wide-span keys: hash strategy
+        teng.store.insert_columns(f"pl_{shape}", {
+            "g": rng.integers(0, 10 ** 9 if wide else 48, n),
+            "h": rng.integers(0, 10 ** 9 if wide else 2, n),
+            "v": rng.integers(-(1 << 50), 1 << 50, n),
+            "f": rng.random(n)}, teng.clock.now())
+        sql = {
+            "q1": tpch.Q1.replace("count(*) AS count_order",
+                                  "count(*) AS count_order, count(*) AS c4"),
+            "int_minmax": f"SELECT g, min(v), max(v), sum(v) "
+                          f"FROM pl_{shape} GROUP BY g",
+            "float_sum": f"SELECT g, sum(f), count(*) FROM pl_{shape} "
+                         f"GROUP BY g",
+            "hash": f"SELECT g, h, sum(v) FROM pl_{shape} GROUP BY g, h",
+        }[shape]
+        s = _local_session(teng)
+        node, _ = teng._plan(teng._parse_cached(sql), s)
+        agg = _root_aggregate(node)
+        scatter = 16 * _count_aggs(node) * n
+        modelled = teng._agg_temp_bytes(node, s, n)
+        if takes:
+            assert modelled == C.large_kernel_bytes(agg, n) < scatter
+        else:
+            assert modelled == scatter
+        # builds are counted where the kernel is traced: forget what
+        # another test of this process traced at this shape
+        large_group_aggregate.clear_cache()
+        before = pg.BUILDS.value("large")
+        teng.execute(sql, session=s)
+        assert (pg.BUILDS.value("large") > before) is takes
+        s.vars.set("pallas_groupagg", "off")
+        assert teng._agg_temp_bytes(node, s, n) == scatter
 
 
 class TestNoScatterHLO:
@@ -833,10 +877,7 @@ class TestOperandHLO:
         from cockroach_tpu.exec.engine import Engine
         from cockroach_tpu.models import tpch
         s = _local_session(teng)
-        s.vars.set("pallas_autotune", "off")
-        # an `auto` run first: the parity gate's verdicts are then in
-        # memory and nothing probes the kernel with interpret off
-        _q1_rows(teng, s, "auto")
+        s.vars.set("pallas_groupagg", "auto")
         monkeypatch.setattr(Engine, "_pallas_interpret",
                             staticmethod(lambda: False))
         sql = tpch.Q1.replace("count(*) AS count_order",

@@ -84,7 +84,12 @@ def _frame(typ: bytes, body: bytes = b"") -> bytes:
 
 
 def _recv_all(sock, timeout=15.0) -> bytes:
-    """Everything the server sends until it closes the connection."""
+    """Everything the server sends until it closes the connection. A
+    server that closes with bytes of ours unread in its buffer (a
+    FATAL startup reply: the script's next packet may or may not have
+    arrived yet) resets the connection where it would have sent FIN;
+    what it wrote before is still delivered first, so a reset ends the
+    stream like a close."""
     sock.settimeout(timeout)
     chunks = []
     while True:
@@ -92,6 +97,8 @@ def _recv_all(sock, timeout=15.0) -> bytes:
             b = sock.recv(1 << 16)
         except (socket.timeout, TimeoutError):
             raise AssertionError("server did not close the connection")
+        except ConnectionResetError:
+            b = b""
         if not b:
             return b"".join(chunks)
         chunks.append(b)

@@ -8,7 +8,7 @@ argument is 13 or 11 limbs and the matmul operand passes 64 rows."""
 import numpy as np
 import pytest
 
-from cockroach_tpu.ops.pallas import groupagg as pg
+from cockroach_tpu.ops.pallas import groupagg_large as pg
 from cockroach_tpu.storage import chunkstats
 from cockroach_tpu.storage.columnstore import ColumnStore, Dictionary
 
@@ -115,24 +115,22 @@ class TestPlacementModel:
         params = C.ExecParams(pallas_groupagg="auto",
                               pallas_interpret=False)
         assert C.large_kernel_eligible(agg, n, params)
-        kernel = C.large_kernel_bytes(agg, n, params)
+        kernel = C.large_kernel_bytes(agg, n)
         assert 4 * 12 * n <= kernel <= 4 * 12 * n + (1 << 20)
         assert (16 + 7 * 5) * n + kernel < 12 << 30
         # the accumulator term is the tile the kernel takes for Q1's
-        # twelve groups, 128 lanes an accumulator row, not the tile
-        # parameter's 512
-        from cockroach_tpu.ops.pallas import groupagg_large as pgl
-        lay = C.large_layout(agg.aggs, n, agg.max_group_rows, params)
+        # twelve groups, 128 lanes an accumulator row, not the tile's
+        # upper bound of 512
+        lay = C.large_layout(agg.aggs, n, agg.max_group_rows)
         assert C.dense_num_groups(agg) == 12
-        assert pgl.effective_group_tile(12, params.pallas_group_tile) \
-            == 128 < params.pallas_group_tile
+        assert pg.effective_group_tile(12) == 128 < pg.GROUP_TILE
         assert kernel == 4 * n * lay.n_words \
             + 4 * 128 * (len(lay.f_rows) + len(lay.i_rows))
         # and the verdict is what it was on either side: resident at
         # SF10's bucket, not at the next one (12.5 SF), where upload
         # and operand words alone pass the 12 GiB budget
         n2 = 1 << 27
-        assert (16 + 7 * 5) * n2 + C.large_kernel_bytes(agg, n2, params) \
+        assert (16 + 7 * 5) * n2 + C.large_kernel_bytes(agg, n2) \
             > (16 + 7 * 5) * n2 + 4 * 12 * n2 > 12 << 30
         # the interpreter's grid budget keeps a CPU run of that size
         # on the scatter path, and the model says so
@@ -364,7 +362,7 @@ class TestNarrowLimbs:
         assert pgl.limb_width(1 << 23, 0) == 8      # SF1, for contrast
         node, _ = teng._plan(teng._parse_cached(tpch.Q1), _session(teng))
         lay = C.large_layout(_root_aggregate(node).aggs, n,
-                             max_group_rows, C.ExecParams())
+                             max_group_rows)
         assert lay.w == width and lay.n_words == 12
         assert len(lay.f_rows) + len(lay.i_rows) == rows
         per_arg = -(-64 // width)

@@ -331,7 +331,7 @@ CLEAN_LOCKED_GLOBAL = """
 """
 
 CLEAN_TALLY_GLOBAL = """
-    from ..ops.pallas.groupagg import _KernelTally
+    from ..ops.pallas.groupagg_large import _KernelTally
 
     RUNS = _KernelTally()
 
