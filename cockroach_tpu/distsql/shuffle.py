@@ -400,7 +400,7 @@ def _decompose_join(node: P.PlanNode) -> ShuffleGraph:
         if isinstance(n, P.Aggregate):
             group_by = [(nm, rewrite(e)) for nm, e in n.group_by]
             aggs = [BoundAgg(a.func, rewrite(a.arg), a.type, a.distinct,
-                             a.arg_max_abs, a.arg_nonneg) for a in n.aggs]
+                             a.arg_bits, a.arg_nonneg) for a in n.aggs]
             strings = any(_is_dict_type(getattr(e, "type", None))
                           for _, e in group_by)
             return P.Aggregate(

@@ -402,14 +402,49 @@ def _agg_output(group_cols, aggs_out, live, itemfs, havingf,
                               jnp.broadcast_to(ht_ovf, (num_groups,)))
     return out
 
+
+# exact SUM / AVG aggregates (INT / DECIMAL arguments) compiled with
+# ("proved") and without ("unproved") a value-range proof, whatever
+# strategy they then take: the engine's exec.agg.range_proof.*
+RANGE_PROOFS = sortkey._Tally()
+
+
+def _compile_agg_args(aggs) -> list:
+    """(aggregate, its compiled argument or None) for an Aggregate's
+    list, tallying the exact sums among them by proof."""
+    for a in aggs:
+        if a.func in ("sum", "sum_int", "avg") and a.arg is not None \
+                and a.arg.type.family in (Family.INT, Family.DECIMAL):
+            RANGE_PROOFS.bump(
+                "proved" if _proven_bits(a) else "unproved")
+    return [(a, compile_expr(a.arg) if a.arg is not None else None)
+            for a in aggs]
+
+
+def _proven_bits(a: BoundAgg) -> int:
+    """Bits the plan proved an exact sum's argument to fit, never
+    negative (Engine._prove_agg_arg_ranges); 0 where nothing is
+    proven."""
+    return a.arg_bits if a.arg_nonneg else 0
+
+
+def _sum_cannot_wrap(a: BoundAgg, rows: int) -> bool:
+    """Is an int64 sum of at most `rows` values of a's argument proven
+    inside int64 by the plan alone? rows x 2^bits < 2^62, the run-time
+    gate's own bound on rows x max|value|, in Python integers: then no
+    overflow sentinel is compiled for it."""
+    bits = _proven_bits(a)
+    return bits > 0 and (rows << bits) < (1 << 62)
+
+
 def _agg_partials(a: BoundAgg, argf, batch, ctx, gid, num_groups,
                   axis_name=None, max_group_rows=0, rep_state=None,
-                  sort_mode="off"):
+                  sort_mode="off", n_shards=1):
     """Compute one aggregate's per-group arrays: (data, valid).
 
-    With axis_name set, partials merge across mesh shards with the
-    collective from AggSpec.merge_ops — the ICI replacement for the
-    reference's final-stage gRPC shuffle (SURVEY.md §A.4)."""
+    With axis_name set, partials merge across the n_shards mesh shards
+    with the collective from AggSpec.merge_ops — the ICI replacement
+    for the reference's final-stage gRPC shuffle (SURVEY.md §A.4)."""
     grouped = gid is not None
 
     def psum(x):
@@ -471,13 +506,15 @@ def _agg_partials(a: BoundAgg, argf, batch, ctx, gid, num_groups,
             d = aggops.group_sum(d0, gid, mask, num_groups,
                                  acc_dtype=acc,
                                  max_group_rows=max_group_rows,
-                                 arg_max_abs=a.arg_max_abs,
-                                 arg_nonneg=a.arg_nonneg)
+                                 arg_bits=_proven_bits(a))
         else:
             d = aggops.masked_sum(d0, mask, acc_dtype=acc)[None]
         d = psum(d)
         overflow = None
-        if acc == jnp.int64:
+        rows = d0.shape[0] * n_shards   # the rows one sum can take in
+        if grouped and 0 < max_group_rows < rows:
+            rows = max_group_rows
+        if acc == jnp.int64 and not _sum_cannot_wrap(a, rows):
             # int64 keeps decimal sums exact through the SF100 target,
             # but a large-enough scan wraps silently. The overflow
             # gate: a cheap global bound (rows x max|value|, one fast
@@ -655,10 +692,15 @@ class LargeLayout:
                 + len(self.mm))
 
 
-def large_layout(aggs, n: int, max_group_rows: int) -> LargeLayout:
+def large_layout(aggs, n: int, max_group_rows: int,
+                 n_shards: int = 1) -> LargeLayout:
     """Plan the large-G kernel's operands and matmul rows for `aggs`
-    (inside _pallas_large_ok's envelope) over n rows, at the kernel's
-    shipped tile (see _pallas_large_partials, which traces it)."""
+    (inside _pallas_large_ok's envelope) over n rows on each of
+    n_shards shards, at the kernel's shipped tile (see
+    _pallas_large_partials, which traces it). An argument the plan
+    proved narrow (BoundAgg.arg_bits) is sized by its bits: one word
+    under 32, the limbs that cover them, and no shadow row where a
+    group's sum provably stays inside int64."""
     from ..ops.pallas import groupagg_large as pgl
     from ..sql.pushdown import expr_key
     lay = LargeLayout(
@@ -671,9 +713,12 @@ def large_layout(aggs, n: int, max_group_rows: int) -> LargeLayout:
             rows.append(row)
 
     def sum_bits(a):    # the bits an exact sum's argument can hold
-        if a.arg_nonneg and a.arg_max_abs:
-            return max(1, int(a.arg_max_abs).bit_length())
-        return 64
+        return _proven_bits(a) or 64
+
+    # the rows one group's sum can take in, over every shard
+    group_rows = n * n_shards
+    if 0 < max_group_rows < group_rows:
+        group_rows = max_group_rows
 
     for i, a in enumerate(aggs):
         if a.func == "count_rows":
@@ -706,7 +751,8 @@ def large_layout(aggs, n: int, max_group_rows: int) -> LargeLayout:
         lay.exact[i] = (src, -(-bits // lay.w))
         for row in pgl.limb_rows(src, bits, lay.w):
             add(lay.i_rows, row)
-        add(lay.f_rows, ("shadow", src))  # feeds the overflow sentinel
+        if not _sum_cannot_wrap(a, group_rows):
+            add(lay.f_rows, ("shadow", src))  # the overflow sentinel's
     lay.i_rows.append(("live",))  # group liveness
     return lay
 
@@ -754,7 +800,8 @@ def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
     from ..ops.pallas import groupagg_large as pgl
     n = b.n
     sel = b.sel
-    lay = large_layout([a for a, _ in aggfs], n, max_group_rows)
+    lay = large_layout([a for a, _ in aggfs], n, max_group_rows,
+                       params.n_shards if axis_name else 1)
     w, arg_of, mask_of, exact = lay.w, lay.arg_of, lay.mask_of, lay.exact
     f_rows, i_rows = lay.f_rows, lay.i_rows
     want_rep = lay.want_rep
@@ -780,9 +827,17 @@ def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
             masks[k] = jnp.logical_and(sel, argvals[j][1])
         sources = [None] * len(lay.src_of)  # the masked exact-sum args
         for j, k in lay.src_of.items():
+            if lay.narrow[k]:
+                # proven 0 <= v < 2^31: its low word is the value, so it
+                # is cut to one word first and masked there, and no
+                # 64-bit select or high word is written for the kernel
+                d32 = argvals[j][0].astype(jnp.int32)
+                sources[k] = jnp.where(masks[mask_of[j]], d32,
+                                       jnp.zeros_like(d32))
+                continue
             d64 = argvals[j][0].astype(jnp.int64)
-            dz = jnp.where(masks[mask_of[j]], d64, jnp.zeros_like(d64))
-            sources[k] = dz.astype(jnp.int32) if lay.narrow[k] else dz
+            sources[k] = jnp.where(masks[mask_of[j]], d64,
+                                   jnp.zeros_like(d64))
         mm_cols, mm_ops_l, mm_tags = [], [], []
         for i, op in lay.mm:
             a = aggfs[i][0]
@@ -806,7 +861,9 @@ def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
             gid, sel, tuple(sources), tuple(masks), (),
             tuple(mm_cols), num_groups=num_groups, layout=layout,
             mm_ops=tuple(mm_ops_l), want_rep=want_rep,
-            interpret=params.pallas_interpret)
+            interpret=params.pallas_interpret,
+            proved_sums=sum(_proven_bits(aggfs[i][0]) > 0
+                            for i in exact))
 
     def ps(x):
         return aggops.shard_sum(x, axis_name) if axis_name else x
@@ -886,19 +943,24 @@ def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
             # global bound proves most scans cannot wrap, else compare
             # the f32 shadow. Tolerance 1e-2 (vs the f64 shadow's 1e-3)
             # absorbs block-sequential f32 accumulation noise; a real
-            # int64 wrap is ~2^64 off, far beyond either.
-            d0, v0 = argdata[i]
-            m = jnp.logical_and(sel, v0)
-            dz64 = jnp.where(m, d0, jnp.zeros_like(d0)).astype(jnp.float64)
-            # psum makes the bound global: every shard agrees
-            cannot = ps(jnp.float64(n) * jnp.max(jnp.abs(dz64))) \
-                < jnp.float64(2 ** 62)
-            sh = ps(acc_f[frow[("shadow", src)], :]).astype(jnp.float64)
-            err = jnp.abs(total.astype(jnp.float64) - sh)
-            tol = jnp.maximum(jnp.abs(sh) * 1e-2, 1e12)
-            overflow = jnp.logical_or(
-                overflow,
-                jnp.logical_and(jnp.logical_not(cannot), jnp.any(err > tol)))
+            # int64 wrap is ~2^64 off, far beyond either. Where the plan
+            # proved the sum inside int64 the layout has no shadow row,
+            # and neither the bound nor the comparison is traced.
+            if ("shadow", src) in frow:
+                d0, v0 = argdata[i]
+                m = jnp.logical_and(sel, v0)
+                dz64 = jnp.where(m, d0, jnp.zeros_like(d0)) \
+                    .astype(jnp.float64)
+                # psum makes the bound global: every shard agrees
+                cannot = ps(jnp.float64(n) * jnp.max(jnp.abs(dz64))) \
+                    < jnp.float64(2 ** 62)
+                sh = ps(acc_f[frow[("shadow", src)], :]) \
+                    .astype(jnp.float64)
+                err = jnp.abs(total.astype(jnp.float64) - sh)
+                tol = jnp.maximum(jnp.abs(sh) * 1e-2, 1e12)
+                overflow = jnp.logical_or(
+                    overflow, jnp.logical_and(jnp.logical_not(cannot),
+                                              jnp.any(err > tol)))
             if a.func == "avg":
                 scale = (10.0 ** a.arg.type.scale
                          if a.arg.type.family == Family.DECIMAL else 1.0)
@@ -977,8 +1039,7 @@ def _compile_aggregate(node: P.Aggregate, params: ExecParams) -> CompiledNode:
             # by sum/min/max merges; distagg.analyze refuses these
             # plans, so this is a belt-and-braces guard
             raise ExecError("DISTINCT aggregates cannot run distributed")
-    aggfs = [(a, compile_expr(a.arg) if a.arg is not None else None)
-             for a in node.aggs]
+    aggfs = _compile_agg_args(node.aggs)
     itemfs = [(name, compile_expr(e)) for name, e in node.items]
     havingf = compile_expr(node.having) if node.having is not None else None
     dense = node.max_groups > 0
@@ -1098,7 +1159,9 @@ def _compile_aggregate(node: P.Aggregate, params: ExecParams) -> CompiledNode:
                                               num_groups, axis,
                                               node.max_group_rows,
                                               rep_state,
-                                              params.sort_normalized)
+                                              params.sort_normalized,
+                                              params.n_shards if axis
+                                              else 1)
                 aggs_out.append((d, v))
                 if ovf is not None:
                     overflow = jnp.logical_or(overflow, ovf)
@@ -1441,9 +1504,11 @@ def _agg_page_state(a: BoundAgg, argf, batch, ctx, gid, num_groups,
         acc = jnp.float64 if _is_float_agg_arg(a) else jnp.int64
         d = (aggops.group_sum(d0, gid, mask, num_groups, acc_dtype=acc,
                               max_group_rows=max_group_rows,
-                              arg_max_abs=a.arg_max_abs,
-                              arg_nonneg=a.arg_nonneg)
+                              arg_bits=_proven_bits(a))
              if grouped else aggops.masked_sum(d0, mask, acc_dtype=acc)[None])
+        if acc == jnp.int64 and _sum_cannot_wrap(a, d0.shape[0]):
+            # proven by the plan: the gate below, decided before trace
+            return (d, cnt, d.astype(jnp.float64))
         if acc == jnp.int64:
             # same gate as _agg_partials: when this page's rows*max
             # bound proves its partial cannot wrap, its int64 sum cast
@@ -1575,8 +1640,7 @@ def compile_streaming(node: P.PlanNode, params: ExecParams,
             raise ExecError("DISTINCT aggregates cannot stream")
     childf = compile_plan(agg.child, params)
     groupfs = [(name, compile_expr(e)) for name, e in agg.group_by]
-    aggfs = [(a, compile_expr(a.arg) if a.arg is not None else None)
-             for a in agg.aggs]
+    aggfs = _compile_agg_args(agg.aggs)
     itemfs = [(name, compile_expr(e)) for name, e in agg.items]
     havingf = compile_expr(agg.having) if agg.having is not None else None
     dims = list(agg.group_dims)

@@ -52,8 +52,8 @@ from ..utils.mon import BytesMonitor, MemoryQuotaError
 from ..utils.settings import SessionVars, Settings
 from . import coldstart
 from . import movement
-from .compile import (ExecParams, RunContext, can_stream, compile_plan,
-                      compile_streaming)
+from .compile import (RANGE_PROOFS, ExecParams, RunContext, can_stream,
+                      compile_plan, compile_streaming)
 from .planparam import parameterize, plan_fingerprint, shape_text
 from .expr import ExprContext, compile_expr
 from .stream import extract_zone_preds
@@ -397,7 +397,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         # inside jitted programs and are not host-countable.
         from ..ops.pallas.groupagg_large import (
             BUILDS, FALLBACKS, GROUP_TILE_LANES, LIMB_BITS, MATMUL_ROWS,
-            MXU_PASSES, OPERAND_BYTES, ROWS)
+            MXU_PASSES, OPERAND_BYTES, OPERAND_WORDS, PROVED_SUMS, ROWS)
         self.metrics.func_counter(
             "exec.pallas.kernel.builds",
             lambda: BUILDS.value(),
@@ -441,6 +441,33 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             "bf16 MXU passes of the large-G kernel's contraction of "
             "its exact rows, summed over builds (1 a build: limbs of "
             "at most 8 bits, counts and the one-hot are exact in bf16)")
+        self.metrics.func_counter(
+            "exec.pallas.kernel.operand_words",
+            lambda: OPERAND_WORDS.value("large"),
+            "[1, n] 32-bit arrays handed to the large-G kernel, summed "
+            "over builds: the group ids, the packed mask words, one "
+            "word a source the plan proved under 2^31 and two a "
+            "source it did not, one a MIN/MAX slot (TPC-H Q1: 8, with "
+            "no proof 12)")
+        self.metrics.func_counter(
+            "exec.pallas.kernel.proved_sums",
+            lambda: PROVED_SUMS.value("large"),
+            "exact sums and avgs of the large-G kernel's builds whose "
+            "argument carried a value-range proof (BoundAgg.arg_bits), "
+            "summed over builds (TPC-H Q1: all 7)")
+        self.metrics.func_counter(
+            "exec.agg.range_proof.proved",
+            lambda: RANGE_PROOFS.value("proved"),
+            "exact SUM / AVG aggregates over INT / DECIMAL compiled "
+            "with a value-range proof of their argument (non-negative, "
+            "so many bits: sql/valuerange.py over the store's column "
+            "ranges), every aggregation strategy")
+        self.metrics.func_counter(
+            "exec.agg.range_proof.unproved",
+            lambda: RANGE_PROOFS.value("unproved"),
+            "exact SUM / AVG aggregates over INT / DECIMAL compiled "
+            "with no such proof: 64-bit words, every limb, the "
+            "run-time overflow gate")
         self.metrics.func_counter(
             "exec.pallas.rows",
             lambda: ROWS.value(),
@@ -2038,6 +2065,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                    != "off"),
             trace=trace)
         result = planner.plan_select(stmt)
+        self._prove_agg_arg_ranges(result[0], session)
         if not for_explain:
             self._count_plan_source(result[0], cv)
         return result
@@ -3135,7 +3163,6 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                         k += overlay.get(table, 0)
                         if k > 0:
                             n.max_group_rows = k
-                self._bound_agg_value_ranges(n, overlay)
                 walk(n.child)
                 return
             for attr in ("child", "left", "right"):
@@ -3207,44 +3234,74 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         # prepare loop so the device upload dtype matches the scan
         return narrow_by_alias
 
-    def _bound_agg_value_ranges(self, agg, overlay: dict) -> None:
-        """Attach stored-column value bounds to plain-column int64 SUM
-        aggregates (BoundAgg.arg_max_abs/arg_nonneg): a SUM over a
-        proven-non-negative narrow column (quantities, scaled prices)
-        needs i32 limb coverage for bits(max) only — ONE scatter
-        instead of three (ops/agg.py _group_sum_i64_limbs)."""
-        from ..sql.bound import BCol
-        from ..sql.types import Family
+    def _prove_agg_arg_ranges(self, node, session: Session) -> None:
+        """Attach a value-range proof to every exact SUM / AVG whose
+        INT / DECIMAL argument is arithmetic over stored columns and
+        constants (BoundAgg.arg_nonneg / arg_bits): interval
+        arithmetic (sql/valuerange.py) from the store's all-versions
+        column ranges, which already feed narrow32_cols and the direct
+        join tables. A proven non-negative argument travels to the
+        large-G kernel as the words and limbs its bits need (a 13-bit
+        quantity: one word, two 8-bit limbs, not two words and eight),
+        rides bits/w i32 scatters on the XLA path
+        (ops/agg.py _group_sum_i64_limbs), and where rows x 2^bits
+        stays under 2^62 its sum provably cannot wrap, so no overflow
+        sentinel is compiled. A table this txn has written proves
+        nothing (its buffered rows are not in the store's ranges), nor
+        does any other expression: those aggregates compile as they
+        always did. A write that widens a range past the proven bits
+        moves the table's generation, and the statement is planned
+        and proven again."""
+        from ..sql.valuerange import expr_int_range, nonneg_bits
 
-        colmap = {}
+        written = ({tb for tb, _ in session.effects}
+                   if session.txn is not None else set())
 
-        def scans(n):
-            if isinstance(n, P.Scan):
-                for bname, sname in n.columns.items():
-                    colmap[bname] = (n.table, sname)
-                return
+        def prove(agg):
+            stored = {}     # batch column -> (table, stored column)
+            ranges = {}     # batch column -> its [lo, hi], asked once
+
+            def scans(n):
+                if isinstance(n, P.Scan):
+                    for bname, sname in n.columns.items():
+                        stored[bname] = (n.table, sname)
+                    return
+                for attr in ("child", "left", "right"):
+                    c = getattr(n, attr, None)
+                    if c is not None:
+                        scans(c)
+
+            def col_range(name):
+                if name not in ranges:
+                    ranges[name] = stored_range(stored.get(name))
+                return ranges[name]
+
+            def stored_range(hit):
+                if hit is None or hit[0] in written:
+                    return None
+                try:
+                    rng = self.store.key_int_range(*hit)
+                except (KeyError, TypeError):   # no integer zone map
+                    return None
+                return None if rng is None else (rng[0], rng[1])
+
+            scans(agg.child)
+            for a in agg.aggs:
+                if a.func in ("sum", "sum_int", "avg") \
+                        and a.arg is not None:
+                    a.arg_bits = nonneg_bits(
+                        expr_int_range(a.arg, col_range))
+                    a.arg_nonneg = a.arg_bits > 0
+
+        def walk(n):
+            if isinstance(n, P.Aggregate):
+                prove(n)
             for attr in ("child", "left", "right"):
                 c = getattr(n, attr, None)
                 if c is not None:
-                    scans(c)
+                    walk(c)
 
-        scans(agg.child)
-        for a in agg.aggs:
-            if a.func not in ("sum", "sum_int") \
-                    or not isinstance(a.arg, BCol):
-                continue
-            if a.arg.type.family not in (Family.INT, Family.DECIMAL):
-                continue
-            hit = colmap.get(a.arg.name)
-            if hit is None or overlay.get(hit[0], 0):
-                continue
-            rng = self.store.key_int_range(hit[0], hit[1])
-            if rng is None:
-                continue
-            lo, hi, _n = rng
-            if lo >= 0 and hi > 0:
-                a.arg_nonneg = True
-                a.arg_max_abs = int(hi)
+        walk(node)
 
     def _check_join_builds(self, node, read_ts: Timestamp,
                            overlay: set = frozenset()) -> None:

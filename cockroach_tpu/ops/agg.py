@@ -108,8 +108,9 @@ def group_any_via_rep(data, valid, rep, nonempty):
 
 
 def group_sum(data, group_ids, mask, num_groups: int, acc_dtype=None,
-              max_group_rows: int = 0, arg_max_abs: int = 0,
-              arg_nonneg: bool = False):
+              max_group_rows: int = 0, arg_bits: int = 0):
+    """Per-group sum. arg_bits > 0: every unmasked value is proven
+    non-negative and under 2^arg_bits (BoundAgg.arg_bits)."""
     d = data.astype(acc_dtype) if acc_dtype is not None else data
     if num_groups <= UNROLL_GROUPS:
         z = jnp.zeros_like(d)
@@ -122,12 +123,12 @@ def group_sum(data, group_ids, mask, num_groups: int, acc_dtype=None,
     gid = jnp.where(mask, group_ids, 0)
     if d.dtype == jnp.int64:
         return _group_sum_i64_limbs(d, gid, num_groups, max_group_rows,
-                                    arg_max_abs if arg_nonneg else 0)
+                                    arg_bits)
     return jax.ops.segment_sum(d, gid, num_segments=num_groups)
 
 
 def _group_sum_i64_limbs(d, gid, num_groups: int,
-                         max_group_rows: int, max_abs: int = 0):
+                         max_group_rows: int, arg_bits: int = 0):
     """Exact int64 group sum via limb-decomposed INT32 scatters.
 
     64-bit scatter-adds are software-emulated on TPU (measured ~250ms
@@ -145,17 +146,14 @@ def _group_sum_i64_limbs(d, gid, num_groups: int,
         else max(int(d.shape[0]), 1)
     w = int(np.floor(np.log2((2.0 ** 31 - 1) / maxg + 1)))
     w = max(1, min(22, w))
-    # engine-proven NON-NEGATIVE values need only bits(max_abs) limb
+    # engine-proven NON-NEGATIVE values need only arg_bits of limb
     # coverage: a 13-bit quantity column's exact sum is ONE i32
     # scatter. (Negative values need all 64 bits — their two's-
-    # complement high limbs are non-zero.)
-    bits = 64
-    if max_abs > 0:
-        bits = min(64, max(1, int(max_abs).bit_length()))
-        # a group sum can need up to log2(maxg) carry bits beyond the
-        # value width; the reconstruction below only sees limb sums,
-        # which carry them exactly, so `bits` only bounds which limbs
-        # can be non-zero
+    # complement high limbs are non-zero.) A group sum can need up to
+    # log2(maxg) carry bits beyond the value width; the reconstruction
+    # below only sees limb sums, which carry them exactly, so `bits`
+    # only bounds which limbs can be non-zero
+    bits = min(64, arg_bits) if arg_bits > 0 else 64
     k = -(-bits // w)
     m = (1 << w) - 1
     total = jnp.zeros(num_groups, jnp.int64)

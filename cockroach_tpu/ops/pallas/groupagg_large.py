@@ -122,6 +122,11 @@ GROUP_TILE_LANES = _KernelTally()    # lanes of the group tile a build
                                      # took, summed over builds
 MXU_PASSES = _KernelTally()      # bf16 MXU passes of a build's
                                  # exact-rows contraction, over builds
+OPERAND_WORDS = _KernelTally()   # [1, n] 32-bit arrays a build hands
+                                 # the kernel, summed over builds
+PROVED_SUMS = _KernelTally()     # a build's exact sums and avgs whose
+                                 # argument the plan proved narrow
+                                 # (BoundAgg.arg_bits), over builds
 
 # group-domain tile (VMEM accumulator minor dim; multiple of 128
 # lanes): the UPPER bound of the tile a build takes, which is sized by
@@ -342,14 +347,15 @@ def _kernel(gid_ref, *refs, i_rows: tuple, split: tuple, f_rows: tuple,
 
 @functools.partial(jax.jit, static_argnames=(
     "num_groups", "layout", "mm_ops", "want_rep", "group_tile",
-    "block_rows", "interpret"))
+    "block_rows", "interpret", "proved_sums"))
 def large_group_aggregate(gid, sel, sources: tuple, masks: tuple,
                           f_values: tuple, mm_values: tuple,
                           num_groups: int, layout: tuple,
                           mm_ops: tuple = (), want_rep: bool = False,
                           group_tile: int = GROUP_TILE,
                           block_rows: int = BLOCK_ROWS,
-                          interpret: bool = False):
+                          interpret: bool = False,
+                          proved_sums: int = 0):
     """One-pass large-G grouped aggregation.
 
     gid: int32[n] dense ids (0..num_groups-1); rows outside [0, G)
@@ -375,6 +381,9 @@ def large_group_aggregate(gid, sel, sources: tuple, masks: tuple,
       effective_group_tile(num_groups, group_tile) lanes.
     - mm_values/mm_ops: MIN/MAX slots, pre-masked to their +/-inf
       identities.
+    - proved_sums: for the PROVED_SUMS tally alone, which counts where
+      builds are counted: the caller's sums and avgs whose layout
+      rows were sized by a value-range proof.
 
     Returns (f32[NF, num_groups], i32[NI, num_groups]): the layout's
     f rows then the MIN/MAX rows (NF >= 1), its i rows then, with
@@ -448,6 +457,8 @@ def large_group_aggregate(gid, sel, sources: tuple, masks: tuple,
     # a count row past the masks handed in would read a zero bit
     assert all(r[1] < len(masks) for r in layout if r[0] == "count")
     OPERAND_BYTES.bump("large", sum(a.nbytes for a in args))
+    OPERAND_WORDS.bump("large", len(args))
+    PROVED_SUMS.bump("large", proved_sums)
     MATMUL_ROWS.bump("large", n_x + len(f_rows))
     LIMB_BITS.bump("large", max((r[3] for r in layout if r[0] == "limb"),
                                 default=0))

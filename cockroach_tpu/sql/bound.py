@@ -201,11 +201,14 @@ class BoundAgg:
     arg: Optional[BExpr]
     type: SQLType = None
     distinct: bool = False
-    # engine-measured bound on |arg| over the scanned table (0 =
-    # unknown), valid only with arg_nonneg; lets an exact int64 group
-    # SUM of a narrow column (quantities, scaled prices) ride ONE i32
-    # scatter instead of 3 (ops/agg.py _group_sum_i64_limbs)
-    arg_max_abs: int = 0
+    # the engine's value-range proof of an exact SUM / AVG argument
+    # (sql/valuerange.py over the store's zone-map ranges): it is
+    # never negative and fits arg_bits bits (0 = nothing proven). The
+    # bit length and not the maximum, so the plan (these fields are in
+    # its fingerprint) changes only when a value crosses a power of
+    # two. Sizes the sum's words, limbs and overflow sentinel
+    # (exec/compile.py large_layout, ops/agg.py _group_sum_i64_limbs)
+    arg_bits: int = 0
     arg_nonneg: bool = False
 
 

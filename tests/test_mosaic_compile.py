@@ -41,8 +41,21 @@ def _q1_layout(w):
     return layout + tuple(("count", k) for k in range(5)) + (("live",),)
 
 
+Q1_BITS = (13, 24, 4, 30, 37)
+
+
+def _q1_proven_layout(w, shadows=()):
+    """Q1's layout as the plan's value-range proofs size it (PR 32):
+    arguments of 13, 24, 4, 30 and 37 bits, a shadow only for the
+    sources named (20 limb rows and none at SF10's width 6)."""
+    layout = tuple(("shadow", s) for s in shadows)
+    for s, bits in enumerate(Q1_BITS):
+        layout += pgl.limb_rows(s, bits, w)
+    return layout + tuple(("count", k) for k in range(5)) + (("live",),)
+
+
 def _compile(one_chip, n, num_groups, layout, n_src, n_f=0, mm_ops=(),
-             want_rep=False, **kw):
+             want_rep=False, src_dtypes=None, **kw):
     def spec(dtype):
         return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
 
@@ -58,7 +71,8 @@ def _compile(one_chip, n, num_groups, layout, n_src, n_f=0, mm_ops=(),
     with jax.enable_x64(True):
         compiled = jax.jit(fn).trace(
             spec(jnp.int32), spec(jnp.bool_),
-            tuple(spec(jnp.int64) for _ in range(n_src)),
+            tuple(spec(dt) for dt in
+                  (src_dtypes or (jnp.int64,) * n_src)),
             tuple(spec(jnp.bool_) for _ in range(n_masks)),
             tuple(spec(jnp.float32) for _ in range(n_f)),
             tuple(spec(jnp.float32) for _ in mm_ops)).lower().compile()
@@ -72,6 +86,24 @@ def test_q1_layout_compiles_at_the_benchmarks_sizes(one_chip, n, w):
     default 4,096 rows a step: Q1 at SF1 (61 matmul rows) and at SF10
     (76), thirteen [1, n] operands."""
     _compile(one_chip, n, 12, _q1_layout(w), n_src=5)
+
+
+@pytest.mark.parametrize("n,w,shadows", [
+    (1 << 23, 8, ()), (1 << 26, 6, ()), (1 << 26, 5, (4,))])
+def test_q1_proven_layout_compiles_at_the_benchmarks_sizes(one_chip, n, w,
+                                                           shadows):
+    """What the served Q1 hands the kernel since the plan proves its
+    arguments' ranges: four one-word sources and one of two words,
+    eight [1, n] operands, 21 matmul rows at SF1, 26 at SF10, and at
+    width 5 with `charge`'s shadow kept (no group bound) 32; with no
+    shadow at all the f accumulator is one unused row."""
+    compiled = _compile(
+        one_chip, n, 12, _q1_proven_layout(w, shadows), n_src=5,
+        src_dtypes=tuple(jnp.int32 if b < 32 else jnp.int64
+                         for b in Q1_BITS))
+    call, = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    assert call.count(f"s32[1,{n}]") == 8
 
 
 def test_q18_class_shape_compiles_at_the_tile_parameter(one_chip):

@@ -811,9 +811,18 @@ class TestQ1ReadsArgumentsOnce:
     def test_auto_matches_off_bit_for_bit(self, teng):
         s = _local_session(teng)
         want = _q1_rows(teng, s, "off")
-        before = pg.BUILDS.value("large")
+        tallies = (pg.BUILDS, pg.PROVED_SUMS, pg.OPERAND_WORDS,
+                   pg.MATMUL_ROWS)
+        before = [t.value("large") for t in tallies]
         got = _q1_rows(teng, s, "auto")
-        assert pg.BUILDS.value("large") > before, "Q1 missed the kernel"
+        builds, proved, words, rows = (
+            t.value("large") - b for t, b in zip(tallies, before))
+        assert builds == 1, "Q1 missed the kernel"
+        # with the plan's value-range proofs on: all four sums and
+        # three avgs proven, eight operand words, and at this table's
+        # limb width of 8 fifteen limb rows, five counts, liveness and
+        # no shadow
+        assert (proved, words, rows) == (7, 8, 21)
         _assert_q1_equal(got, want)
 
     def test_operand_bytes_exported_and_small(self, teng, monkeypatch):
@@ -845,9 +854,10 @@ class TestQ1ReadsArgumentsOnce:
         # l_quantity and l_extendedprice serve a sum and an avg each
         assert n_src == 5
         handed = teng.metrics.snapshot()[name] - before
-        # gid, one packed mask word, two words an argument
-        assert handed == (2 + 2 * n_src) * 4 * n
-        assert handed < 4 * n_mat * n / 4
+        # gid, one packed mask word, one word an argument the plan
+        # proved under 2^31 (four of them), two for `charge` (37 bits)
+        assert handed == (2 + 4 + 2) * 4 * n
+        assert handed < 4 * n_mat * n / 2
 
     def test_overflow_sentinel_still_raises(self, teng):
         from cockroach_tpu.exec.engine import EngineError
@@ -868,8 +878,9 @@ class TestQ1ReadsArgumentsOnce:
 class TestOperandHLO:
     """Beside TestNoScatterHLO: Q1's program as the TPU would get it
     (lowered for that platform, Mosaic kernel and all) holds no
-    row-major operand matrix, and the custom call reads a dozen
-    [1, n] rows."""
+    row-major operand matrix, and the custom call reads eight
+    [1, n] rows (a dozen before the plan proved four of the five
+    arguments into one word each)."""
 
     def test_q1_has_no_operand_matrix(self, teng, monkeypatch):
         import re
@@ -895,8 +906,10 @@ class TestOperandHLO:
         assert len(calls) == 1
         operands = re.findall(rf"tensor<1x{n}x[a-z0-9]+>",
                               calls[0].split("->")[0])
-        # five distinct arguments, their validities packed in one word
-        assert 0 < len(operands) <= 2 * 5 + 1 + 2
+        # the group ids, five validities packed in one word, four
+        # one-word arguments and `charge` in two
+        assert len(operands) == 1 + 1 + 4 + 2
+        assert sum("xi32>" in o for o in operands) == 8
         assert not re.search(rf"tensor<\d+x{n}x", calls[0].replace(
             f"tensor<1x{n}x", ""))
 
@@ -917,10 +930,12 @@ class TestMeshParity:
         one = _q1_rows(eng, local, "auto")
         _assert_q1_equal(one, want)
         dist = eng.session()
-        before = pg.BUILDS.value("large")
+        before = pg.BUILDS.value("large"), pg.PROVED_SUMS.value("large")
         got = _q1_rows(eng, dist, "auto")
-        assert pg.BUILDS.value("large") > before, \
+        assert pg.BUILDS.value("large") > before[0], \
             "the sharded Q1 missed the kernel"
+        # the shards' kernels are sized by the same proofs
+        assert pg.PROVED_SUMS.value("large") - before[1] == 7
         # sharded against one device, both through the kernel: the
         # same limb sums and counts, so the avgs agree to the bit too
         assert got == one

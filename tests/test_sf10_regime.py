@@ -5,6 +5,9 @@ ingest against the value-by-value path it replaced, and the large-G
 kernel at the limb widths 2^26 rows give it (5 and 6), where a 64-bit
 argument is 13 or 11 limbs and the matmul operand passes 64 rows."""
 
+import copy
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -23,6 +26,12 @@ def teng():
     e = Engine()
     tpch.load(e, SF, rows=N_ROWS, tables=("lineitem",))
     return e
+
+
+def _without_proofs(aggs):
+    """The aggregates as a plan with no value-range proof has them."""
+    return [dataclasses.replace(a, arg_bits=0, arg_nonneg=False)
+            for a in aggs]
 
 
 def _session(eng, pallas="auto"):
@@ -46,9 +55,10 @@ def budget(teng):
 # Q1 reads seven columns: three int32 (4 + 1 validity byte a row) and
 # four int64 proven to fit int32, beside the two MVCC int64s
 Q1_UPLOAD = (16 + 7 * 5) * BUCKET
-# the kernel path: the group ids, one packed mask word and five 64-bit
-# arguments as two words each, and the accumulator tiles
-Q1_KERNEL_WORDS = 4 * 12 * BUCKET
+# the kernel path: the group ids, one packed mask word, four arguments
+# the plan proves under 2^31 as one word each and `charge` (37 bits) as
+# two, and the accumulator tiles (twelve words with no proof: PR 32)
+Q1_KERNEL_WORDS = 4 * 8 * BUCKET
 # the scatter path: 16 bytes a row an aggregate, eight aggregates
 Q1_SCATTER = 16 * 8 * BUCKET
 
@@ -103,8 +113,9 @@ class TestPlacementModel:
     def test_q1_at_sf10_is_resident_on_a_v5e(self, teng):
         """The model at the real size, from the plan alone: Q1 over a
         2^26-row bucket on the kernel path is 3.19 GiB of upload and
-        3.0 GiB of operand words, inside the 12 GiB budget; the
-        scatter term read 8 GiB for the same plan."""
+        2.0 GiB of operand words (3.0 before the plan proved four of
+        its five arguments into one word), inside the 12 GiB budget;
+        the scatter term read 8 GiB for the same plan."""
         from cockroach_tpu.exec import compile as C
         from cockroach_tpu.exec.stmtutil import _root_aggregate
         from cockroach_tpu.models import tpch
@@ -116,7 +127,7 @@ class TestPlacementModel:
                               pallas_interpret=False)
         assert C.large_kernel_eligible(agg, n, params)
         kernel = C.large_kernel_bytes(agg, n)
-        assert 4 * 12 * n <= kernel <= 4 * 12 * n + (1 << 20)
+        assert 4 * 8 * n <= kernel <= 4 * 8 * n + (1 << 20)
         assert (16 + 7 * 5) * n + kernel < 12 << 30
         # the accumulator term is the tile the kernel takes for Q1's
         # twelve groups, 128 lanes an accumulator row, not the tile's
@@ -125,13 +136,16 @@ class TestPlacementModel:
         assert C.dense_num_groups(agg) == 12
         assert pg.effective_group_tile(12) == 128 < pg.GROUP_TILE
         assert kernel == 4 * n * lay.n_words \
-            + 4 * 128 * (len(lay.f_rows) + len(lay.i_rows))
-        # and the verdict is what it was on either side: resident at
-        # SF10's bucket, not at the next one (12.5 SF), where upload
-        # and operand words alone pass the 12 GiB budget
+            + 4 * 128 * (max(1, len(lay.f_rows)) + len(lay.i_rows))
+        # at the next bucket (12.5 SF) the twelve words of a plan with
+        # no proof pass the 12 GiB budget beside the upload, as they
+        # always did; the eight of the proven plan stay under it
         n2 = 1 << 27
-        assert (16 + 7 * 5) * n2 + C.large_kernel_bytes(agg, n2) \
-            > (16 + 7 * 5) * n2 + 4 * 12 * n2 > 12 << 30
+        bare = copy.copy(agg)
+        bare.aggs = _without_proofs(agg.aggs)
+        assert (16 + 7 * 5) * n2 + C.large_kernel_bytes(bare, n2) \
+            > (16 + 7 * 5) * n2 + 4 * 12 * n2 > 12 << 30 \
+            > (16 + 7 * 5) * n2 + C.large_kernel_bytes(agg, n2)
         # the interpreter's grid budget keeps a CPU run of that size
         # on the scatter path, and the model says so
         assert not C.large_kernel_eligible(
@@ -342,31 +356,62 @@ def _python_sums(cols):
 
 
 class TestNarrowLimbs:
-    @pytest.mark.parametrize("max_group_rows,width,rows", [
-        (29_000_000, 6, 66),    # Engine._bound_agg_group_rows' bound
-        (0, 5, 76),             # the bound unknown: a group may be all
+    @pytest.mark.parametrize("n,max_group_rows,width,proven,limbs,rows", [
+        # SF10's bucket under Engine._bound_agg_group_rows' bound: the
+        # proven bits 13, 24, 4, 30 and 37 are 3 + 4 + 1 + 5 + 7 limbs
+        # of 6, and no group of 29 M rows x 2^37 can pass int64
+        (1 << 26, 29_000_000, 6, True, 20, 26),
+        # the bound unknown: a group may be all 2^26 rows, limbs of 5,
+        # and 2^26 x 2^37 = 2^63 keeps `charge` its shadow row
+        (1 << 26, 0, 5, True, 23, 30),
+        # SF1's bucket: limbs of 8, 2 + 3 + 1 + 4 + 5
+        (1 << 23, 0, 8, True, 15, 21),
+        # with no proof every argument is 64 bits and has its shadow:
+        # 11 (13, 8) limbs each, what every plan carried before PR 32
+        (1 << 26, 29_000_000, 6, False, 55, 66),
+        (1 << 26, 0, 5, False, 65, 76),
+        (1 << 23, 0, 8, False, 40, 51),
     ])
-    def test_q1_layout_at_two_to_the_26(self, teng, max_group_rows,
-                                        width, rows):
-        """Q1's operand plan at SF10's row bucket, from the plan alone:
-        the i32 accumulator bound gives limbs of 6 bits (5 without the
-        exact group bound), a 64-bit argument is 11 (13) of them, and
-        the matmul operand is 66 (76) rows, past the 64 it padded to
-        at SF1."""
+    def test_q1_layout_at_two_to_the_26(self, teng, n, max_group_rows,
+                                        width, proven, limbs, rows):
+        """Q1's operand plan from the plan alone, at SF10's row bucket
+        and at SF1's: the i32 accumulator bound gives the limb width,
+        the plan's value-range proofs the limbs an argument needs,
+        the words it travels as and whether it keeps a shadow row."""
         from cockroach_tpu.exec import compile as C
         from cockroach_tpu.exec.stmtutil import _root_aggregate
         from cockroach_tpu.models import tpch
         from cockroach_tpu.ops.pallas import groupagg_large as pgl
-        n = 1 << 26
         assert pgl.limb_width(n, max_group_rows) == width
-        assert pgl.limb_width(1 << 23, 0) == 8      # SF1, for contrast
         node, _ = teng._plan(teng._parse_cached(tpch.Q1), _session(teng))
-        lay = C.large_layout(_root_aggregate(node).aggs, n,
-                             max_group_rows)
-        assert lay.w == width and lay.n_words == 12
+        aggs = _root_aggregate(node).aggs
+        assert [a.arg_bits for a in aggs] == [13, 24, 30, 37, 13, 24, 4, 0]
+        if not proven:
+            aggs = _without_proofs(aggs)
+        lay = C.large_layout(aggs, n, max_group_rows)
+        assert lay.w == width
+        assert lay.n_words == (8 if proven else 12)
+        assert lay.narrow == ([True, True, True, False, True] if proven
+                              else [False] * 5)
+        assert sum(r[0] == "limb" for r in lay.i_rows) == limbs
         assert len(lay.f_rows) + len(lay.i_rows) == rows
-        per_arg = -(-64 // width)
-        assert {k for _, k in lay.exact.values()} == {per_arg}
+        if proven:
+            # a shadow only where rows-a-group x 2^bits can reach 2^62
+            charge = lay.src_of[lay.arg_of[3]]
+            assert lay.f_rows == ([] if max_group_rows or n < 1 << 26
+                                  else [("shadow", charge)])
+            assert {i: k for i, (_, k) in lay.exact.items()} == {
+                i: -(-a.arg_bits // width)
+                for i, a in enumerate(aggs) if a.arg_bits}
+        else:
+            assert len(lay.f_rows) == 5
+            assert {k for _, k in lay.exact.values()} == {-(-64 // width)}
+        # over four shards a group can hold four times a shard's rows:
+        # 2^24 x 2^37 stays under 2^62, 2^25 x 2^37 is 2^62
+        if proven and n == 1 << 23:
+            assert C.large_layout(aggs, n // 2, 0, n_shards=4).f_rows == []
+            assert C.large_layout(aggs, n, 0, n_shards=4).f_rows \
+                == [("shadow", charge)]
 
     @pytest.mark.parametrize("width", [5, 6])
     def test_sums_digit_for_digit_past_64_rows(self, monkeypatch, width):
