@@ -1,0 +1,12 @@
+"""Device milliseconds a statement of SSB flight 3 (Q3.1-Q3.4: three joins,
+grouped by customer and supplier nation (5,408 dense groups) or city
+(504,008, over the dense bound) and year): the mean over the flight's
+classes of each class's median in the one-session trace slice
+(`trace/per_class/<class>/device_ms`). Over the classes the slice held:
+a round longer than the slice leaves some out."""
+
+import ssb_flights
+
+
+def read(ctx):
+    return ssb_flights.mean_device_ms(ctx, "f3")

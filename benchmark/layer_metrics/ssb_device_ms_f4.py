@@ -1,0 +1,12 @@
+"""Device milliseconds a statement of SSB flight 4 (Q4.1-Q4.3: four joins,
+profit grouped by 208, 5,408 and 2,010,008 (over the dense bound)
+groups): the mean over the flight's classes of each class's median in
+the one-session trace slice (`trace/per_class/<class>/device_ms`). Over
+the classes the slice held: a round longer than the slice leaves some
+out."""
+
+import ssb_flights
+
+
+def read(ctx):
+    return ssb_flights.mean_device_ms(ctx, "f4")

@@ -1,0 +1,18 @@
+"""Integer reference of ssb_q1_2.sql (SSB Q1.2: a month)."""
+
+import ssbref
+
+COLUMNS = ["int"]
+TABLES = ("lineorder", "date")
+
+
+def reference(tables, p):
+    lo, _ = tables["lineorder"]
+    m = (ssbref.star(tables, date=ssbref.equal(
+        tables, "date", "d_yearmonthnum", p["yearmonthnum"]))
+         & (lo["lo_discount"] >= p["discount_lo"])
+         & (lo["lo_discount"] <= p["discount_hi"])
+         & (lo["lo_quantity"] >= p["quantity_lo"])
+         & (lo["lo_quantity"] <= p["quantity_hi"]))
+    revenue = lo["lo_extendedprice"][m] * lo["lo_discount"][m]
+    return [[int(revenue.sum())]]

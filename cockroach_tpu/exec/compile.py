@@ -98,6 +98,37 @@ class ExecParams:
     # jitted hot path never carries a sink, so profiled and
     # unprofiled statements run the identical compiled program.
     profile: object = None
+    # what the plan's joins are traced over (JoinStats): the engine
+    # hands one to a compile and keeps it beside the executable, so a
+    # dispatch can count exec.join.* for its statement
+    join_stats: object = None
+
+
+class JoinStats:
+    """Trace-time facts of one compiled plan's hash joins: for each,
+    the rows its probe side and its build side are traced over (static
+    shapes: after any Compact beneath). A join takes its slot when it
+    is compiled and fills it when it is traced; a retrace overwrites
+    the slot with the same numbers."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._rows: list = []   # slot -> (probe rows, build rows)
+        # (joins, probe rows, build rows) of the plan: summed when a
+        # join is traced, read (one reference) by every dispatch
+        self.totals: tuple = (0, 0, 0)
+
+    def slot(self) -> int:
+        with self._lock:
+            self._rows.append((0, 0))
+            return len(self._rows) - 1
+
+    def note(self, slot: int, probe_rows: int, build_rows: int) -> None:
+        with self._lock:
+            self._rows[slot] = (int(probe_rows), int(build_rows))
+            self.totals = (len(self._rows),
+                           sum(p for p, _ in self._rows),
+                           sum(b for _, b in self._rows))
 
 
 class RunContext:
@@ -239,10 +270,14 @@ def _compile_plan(node: P.PlanNode, params: ExecParams,
         leftf = compile_plan(node.left, params)
         rightf = compile_plan(node.right, params)
         jn = node
+        stats = params.join_stats
+        slot = stats.slot() if stats is not None else None
 
         def run_join(rc):
             lb = leftf(rc)
             rb = rightf(rc)
+            if stats is not None:
+                stats.note(slot, lb.n, rb.n)
             return hash_join(lb, rb, jn.left_keys, jn.right_keys,
                              jn.payload, jn.join_type,
                              expand=jn.expand, direct=jn.direct,
@@ -318,6 +353,38 @@ def _compile_scan(node: P.Scan, params: ExecParams) -> CompiledNode:
 # aggregation
 # ---------------------------------------------------------------------------
 
+def _compact_block_rows(n: int, frac: float, block: int) -> int:
+    """Rows a `block`-row segment of an n-row batch keeps under
+    compact_batch: block * frac rounded up to 128 lanes, or the whole
+    block where the batch is too small, ragged, or would not shrink."""
+    if n < 2 * block or n % block:
+        return block
+    kb = max(128, int(block * frac))
+    return min(((kb + 127) // 128) * 128, block)
+
+
+def plan_rows(node: P.PlanNode, scan_rows: dict):
+    """Rows of the batch `node` hands its parent (a static shape), from
+    the plan and its scans' padded row counts {alias: rows}; None
+    where the plan does not say (a nested Aggregate's group count, a
+    Limit). What an Aggregate's strategy and a probe's width follow
+    from before anything is traced."""
+    if isinstance(node, P.Scan):
+        return scan_rows.get(node.alias)
+    if isinstance(node, P.HashJoin):
+        n = plan_rows(node.left, scan_rows)
+        return None if n is None else n * node.expand
+    if isinstance(node, P.Compact):
+        n = plan_rows(node.child, scan_rows)
+        if n is None:
+            return None
+        kb = _compact_block_rows(n, node.frac, node.block)
+        return n if kb == node.block else n // node.block * kb
+    if isinstance(node, (P.Filter, P.Project, P.Window, P.Sort)):
+        return plan_rows(node.child, scan_rows)
+    return None
+
+
 def compact_batch(b: ColumnBatch, frac: float,
                   block: int = 32768) -> ColumnBatch:
     """Pack selected rows to the front of a batch `frac` the size.
@@ -338,13 +405,10 @@ def compact_batch(b: ColumnBatch, frac: float,
     index first; the scatter path happens to be stable) — the engine
     only compacts under aggregation."""
     n = int(b.sel.shape[0])
-    if n < 2 * block or n % block:
+    kb = _compact_block_rows(n, frac, block)
+    if kb == block:
         return b
     nb = n // block
-    kb = max(128, int(block * frac))
-    kb = ((kb + 127) // 128) * 128
-    if kb >= block:
-        return b
     sel = b.sel
     if jax.default_backend() != "tpu":
         s = sel.reshape(nb, block)
@@ -372,6 +436,12 @@ def compact_batch(b: ColumnBatch, frac: float,
     cols = {}
     valid = {}
     for name in b.names:
+        if name == "__compact_overflow":
+            # a Compact further down the spine (the joins between
+            # carry its flag through): rows it dropped never reach
+            # this one, so its overflow is this batch's too
+            overflow = jnp.logical_or(overflow, jnp.any(b.col(name)))
+            continue
         cols[name] = jnp.take(b.col(name), flat, axis=0)
         valid[name] = jnp.take(b.col_valid(name), flat, axis=0)
     out = ColumnBatch.from_dict(cols, valid, sel=live)
@@ -407,6 +477,11 @@ def _agg_output(group_cols, aggs_out, live, itemfs, havingf,
 # ("proved") and without ("unproved") a value-range proof, whatever
 # strategy they then take: the engine's exec.agg.range_proof.*
 RANGE_PROOFS = sortkey._Tally()
+
+
+# one tally a compiled Aggregate, by the strategy its trace took
+# (aggregate_strategy): the engine's exec.agg.strategy.*
+AGG_STRATEGY = sortkey._Tally()
 
 
 def _compile_agg_args(aggs) -> list:
@@ -662,6 +737,21 @@ def large_kernel_eligible(node: P.Aggregate, n: int,
             and not _large_interpret_over_budget(
                 params.pallas_interpret, n, num_groups)
             and _pallas_large_ok(node.aggs))
+
+
+def aggregate_strategy(node: P.Aggregate, n: int,
+                       params: "ExecParams") -> str:
+    """How this Aggregate over an n-row batch computes its groups,
+    from the plan alone: `scalar` (no GROUP BY: masked reductions),
+    `kernel` (a dense group domain on the large-G Pallas kernel),
+    `dense` (a dense domain on XLA's segment sums) or `hash` (a domain
+    the planner could not bound: ops/hashtable.py's while-loop table,
+    segment sums over its slots)."""
+    if not node.group_by:
+        return "scalar"
+    if node.max_groups <= 0:
+        return "hash"
+    return "kernel" if large_kernel_eligible(node, n, params) else "dense"
 
 
 @dataclass
@@ -1127,19 +1217,20 @@ def _compile_aggregate(node: P.Aggregate, params: ExecParams) -> CompiledNode:
         # group bound and an all-exact aggregate envelope; distributed
         # dense plans merge the kernel partials with collectives
         # inside _pallas_large_partials
-        large = large_kernel_eligible(node, b.n, params)
+        strategy = aggregate_strategy(node, b.n, params)
         overflow = jnp.bool_(False)
         rep_state = None
         large_live = None
-        if large:
+        if strategy == "kernel":
             res = _pallas_large_partials(
                 aggfs, b, ctx, gid, num_groups, node.max_group_rows,
                 axis, params)
             if res is not None:
                 aggs_out, large_live, overflow = res
-            else:
-                large = False
-        if not large:
+            else:   # a traced argument outside the kernel's envelope
+                strategy = "dense"
+        AGG_STRATEGY.bump(strategy)
+        if strategy != "kernel":
             if params.pallas_groupagg != "off":
                 # an aggregation compiled on the XLA segment path
                 # while the kernel was enabled (outside its envelope,
@@ -1429,15 +1520,57 @@ def limit_batch(b: ColumnBatch, limit, offset) -> ColumnBatch:
     return b.with_sel(keep)
 
 
+# Slots of a hash-strategy Aggregate's output that a Sort above it
+# orders where the engine estimates far fewer groups (P.Sort.prefix,
+# Engine._size_hash_sorts): the table hands its groups over as a dense
+# prefix of hash_group_capacity slots (hashtable.group_ids numbers them
+# 0..ng-1), 2^17 by default, and XLA:TPU takes 65-80 s to compile a
+# sort of 2^15 rows or more against 8 s for 2^13 (measured for a
+# described v5e: a stable argsort of u64[n]; SSB's four hash-strategy
+# statements were 37 to 75 s of cold compile each, nearly all of it
+# this sort, for a few hundred live groups)
+HASH_SORT_PREFIX = 1 << 13
+
+
+def _dense_prefix(b: ColumnBatch, k: int) -> ColumnBatch:
+    """The first k rows of a batch whose selected rows are a prefix,
+    flagged __topk_inexact where one is selected past them (the
+    estimate was low): the engine then replans with the whole sort, as
+    it does when a top-k cut crosses a tie (TopKInexact -> no_topk),
+    and remembers to for that plan."""
+    cut = jnp.any(b.sel[k:])
+    head = ColumnBatch(tuple(d[:k] for d in b.data),
+                       tuple(v[:k] for v in b.valid), b.sel[:k], b.names)
+    return head.with_column("__topk_inexact",
+                            jnp.broadcast_to(cut, (k,)))
+
+
+def sort_prefix(node: P.Sort, params: ExecParams) -> int:
+    """Leading slots this Sort orders, 0 = its whole input: the plan's
+    prefix, on one device, over a hash-strategy Aggregate (its HAVING
+    thins the prefix, no more), unless the engine asked for the whole
+    sort (no_topk)."""
+    child = node.child
+    if params.topk_sort and params.axis_name is None \
+            and isinstance(child, P.Aggregate) and child.group_by \
+            and child.max_groups <= 0:
+        return node.prefix
+    return 0
+
+
 def _compile_sort(node: P.Sort, params: ExecParams,
                   meta: P.OutputMeta | None) -> CompiledNode:
     childf = compile_plan(node.child, params, meta)
     rank_tables = _sort_rank_tables(node.keys, meta)
     keys = list(node.keys)
     mode = params.sort_normalized
+    prefix = sort_prefix(node, params)
 
     def run_sort(rc: RunContext) -> ColumnBatch:
-        return sort_batch(childf(rc), keys, rank_tables, mode)
+        b = childf(rc)
+        if 0 < prefix < b.n:
+            b = _dense_prefix(b, prefix)
+        return sort_batch(b, keys, rank_tables, mode)
     return run_sort
 
 
@@ -1768,6 +1901,7 @@ def _compile_hash_dist_aggregate(node: P.Aggregate, params: ExecParams,
 
     def run(rc: RunContext) -> ColumnBatch:
         b = childf(rc)
+        AGG_STRATEGY.bump("hash")
         ctx = _ctx_of(b)
         keycols = []
         gdata = []  # (name, data, valid) of each group-key expression

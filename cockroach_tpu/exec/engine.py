@@ -52,14 +52,16 @@ from ..utils.mon import BytesMonitor, MemoryQuotaError
 from ..utils.settings import SessionVars, Settings
 from . import coldstart
 from . import movement
-from .compile import (RANGE_PROOFS, ExecParams, RunContext, can_stream,
-                      compile_plan, compile_streaming)
+from .compile import (AGG_STRATEGY, RANGE_PROOFS, ExecParams, JoinStats,
+                      RunContext, aggregate_strategy, can_stream,
+                      compile_plan, compile_streaming, plan_rows)
 from .planparam import parameterize, plan_fingerprint, shape_text
 from .expr import ExprContext, compile_expr
 from .stream import extract_zone_preds
 from .session import (CompactOverflow, EngineError, HashCapacityExceeded,
                       Prepared, Result, Session)
-from .stmtutil import (_StreamFns, _RerunPrepared, _host_sort, _count_aggs,
+from .stmtutil import (_StreamFns, _RerunPrepared, _has_prefix_sort,
+                      _host_sort, _count_aggs,
                       _collect_scan_columns, _collect_scans,
                       _contains_func, _decode_column,
                       _decode_scalar, _decode_storage_value,
@@ -269,6 +271,10 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         # evicting its own shapes
         from .tenantcache import TenantLRU
         self._exec_cache: TenantLRU = TenantLRU(self._EXEC_CACHE_MAX)
+        # plan-cache keys of prefix sorts (_size_hash_sorts) that met
+        # more groups than their prefix holds: prepared with the whole
+        # sort from then on
+        self._whole_sorts: set = set()
         self._parse_cache: TenantLRU = TenantLRU(
             self._PARSE_CACHE_MAX,
             on_evict=lambda k: self._plain_memo.discard(k))
@@ -364,7 +370,14 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                  "with a dispatch (scalars, gather indices)"),
                 (_ob.H2D_BYTES, "bytes of those host values"),
                 (_ob.D2H_CALLS, "device-to-host result transfers"),
-                (_ob.D2H_BYTES, "bytes those transfers moved")):
+                (_ob.D2H_BYTES, "bytes those transfers moved"),
+                (_ob.JOINS, "hash joins in the plans of the statements "
+                 "dispatched as one program, a dispatch"),
+                (_ob.JOIN_BUILD_ROWS, "rows of those joins' build sides "
+                 "(the padded batch each build is traced over)"),
+                (_ob.JOIN_PROBE_ROWS, "rows those joins' probes are "
+                 "traced over, after any Compact beneath: 2^23 a join "
+                 "while a probe runs over the full-width fact batch")):
             self.metrics.func_counter(tally.name, tally.value, help_)
         # the placement verdict of each prepare (resident | stream |
         # spill | distributed), and the largest working set the
@@ -468,6 +481,21 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             "exact SUM / AVG aggregates over INT / DECIMAL compiled "
             "with no such proof: 64-bit words, every limb, the "
             "run-time overflow gate")
+        for kind, how in (
+                ("kernel", "a dense group domain on the large-G Pallas "
+                 "kernel"),
+                ("dense", "a dense group domain on XLA's segment sums "
+                 "(outside the kernel's envelope, or pallas_groupagg "
+                 "off)"),
+                ("hash", "a group domain the planner could not bound: "
+                 "the while-loop hash table, segment sums over its "
+                 "slots"),
+                ("scalar", "no GROUP BY: masked reductions")):
+            self.metrics.func_counter(
+                "exec.agg.strategy." + kind,
+                lambda kind=kind: AGG_STRATEGY.value(kind),
+                "Aggregates compiled, by the strategy their trace "
+                f"took (compile.aggregate_strategy): {how}")
         self.metrics.func_counter(
             "exec.pallas.rows",
             lambda: ROWS.value(),
@@ -2066,6 +2094,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             trace=trace)
         result = planner.plan_select(stmt)
         self._prove_agg_arg_ranges(result[0], session)
+        self._size_hash_sorts(result[0])
         if not for_explain:
             self._count_plan_source(result[0], cv)
         return result
@@ -2749,8 +2778,23 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         key = (keytext, tuple(sorted(shapes)), decision is not None,
                stream, spill, cap, pallas, sortn, plan_fp, no_topk,
                no_compact, psig)
+        # a prefix Sort whose estimate proved low (Prepared.run noted
+        # its key): straight to the whole sort, not through the
+        # sentinel and a second program on every execution
+        prefix_key = None
+        if not no_topk and decision is None \
+                and _has_prefix_sort(node):
+            if key in self._whole_sorts:
+                no_topk = True
+                key = (keytext, tuple(sorted(shapes)),
+                       decision is not None, stream, spill, cap, pallas,
+                       sortn, plan_fp, no_topk, no_compact, psig)
+            else:
+                prefix_key = key
         cached = self._exec_cache.get(key)
         self.tracer.tag(plan_cache="hit" if cached else "miss")
+        if self.tracer.recording():
+            self.tracer.tag(**self._plan_shape_tags(node, scans, pallas))
         self.metrics.counter(
             "sql.plan.cache.hit" if cached else "sql.plan.cache.miss",
             "compiled-plan cache lookups, by outcome").inc()
@@ -2787,7 +2831,11 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                     pallas_groupagg=pallas,
                     pallas_interpret=self._pallas_interpret(),
                     topk_sort=not no_topk,
-                    sort_normalized=sortn)
+                    sort_normalized=sortn,
+                    join_stats=JoinStats())
+                # beside the executable in the cache: a hit finds what
+                # the miss's trace noted
+                meta.join_stats = params.join_stats
                 if spill is not None and spill.kind == "join":
                     # the spill-join probes with the UNCHANGED
                     # streaming page program: each probe row lands in
@@ -2870,7 +2918,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                                         if spill is not None
                                         and spill.build_alias else None),
                             joinfilter=jf_specs,
-                            params=pvals)
+                            params=pvals, prefix_key=prefix_key)
         # alias -> table map (composed CTE execution patches temp
         # aliases' scan batches per run, exec/ctecompose.py)
         prepared.scan_tables = dict(scan_aliases)
@@ -3303,6 +3351,74 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
 
         walk(node)
 
+    def _size_hash_sorts(self, node) -> None:
+        """Give a Sort right above a hash-strategy Aggregate the prefix
+        it orders (P.Sort.prefix, compile.HASH_SORT_PREFIX) where the
+        Aggregate's estimated group count is at most half of it;
+        otherwise, and where nothing is known of a key, the Sort
+        orders all hash_group_capacity slots as it always did. An
+        estimate that proves low raises the top-k sentinel once
+        (_whole_sorts)."""
+        from .compile import HASH_SORT_PREFIX
+
+        def walk(n):
+            if isinstance(n, P.Sort) and isinstance(n.child, P.Aggregate) \
+                    and n.child.group_by and n.child.max_groups <= 0:
+                est = self._estimate_groups(n.child)
+                if est is not None and 2 * est <= HASH_SORT_PREFIX:
+                    n.prefix = HASH_SORT_PREFIX
+            for attr in ("child", "left", "right"):
+                c = getattr(n, attr, None)
+                if c is not None:
+                    walk(c)
+
+        walk(node)
+
+    def _estimate_groups(self, agg) -> float | None:
+        """Estimated number of groups of an Aggregate whose keys are
+        stored columns: the product over the keys of the distinct
+        values of each (a dictionary's length; an integer column's
+        range or row count, whichever is less) times the share of its
+        table that the scan's pushed filter keeps (_estimate_scan_
+        selectivity: SSB Q3.2's `c_nation = 'UNITED STATES'` leaves 250
+        / 25 cities), at least one a key. None where a key is not a
+        stored column of a scan beneath, or its column has no
+        range."""
+        from ..sql.bound import BCol
+        scans = {}
+
+        def collect(n):
+            if isinstance(n, P.Scan):
+                for bname in n.columns:
+                    scans[bname] = n
+                return
+            for attr in ("child", "left", "right"):
+                c = getattr(n, attr, None)
+                if c is not None:
+                    collect(c)
+
+        collect(agg.child)
+        est = 1.0
+        for _, e in agg.group_by:
+            sc = scans.get(e.name) if isinstance(e, BCol) else None
+            if sc is None:
+                return None
+            stored = sc.columns[e.name]
+            try:
+                if e.type.uses_dictionary:
+                    d = self.store.table(sc.table).dictionaries.get(stored)
+                    ndv = len(d.values) if d is not None else None
+                else:
+                    r = self.store.key_int_range(sc.table, stored)
+                    ndv = None if r is None else min(r[1] - r[0] + 1, r[2])
+            except (KeyError, TypeError):
+                return None
+            if not ndv:
+                return None
+            sel = self._estimate_scan_selectivity(sc)
+            est *= max(1.0, ndv * (sel if sel is not None else 1.0))
+        return est
+
     def _check_join_builds(self, node, read_ts: Timestamp,
                            overlay: set = frozenset()) -> None:
         """The device hash join gathers ONE build row per probe key
@@ -3513,8 +3629,13 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         int-family range/equality conjuncts contribute; every other
         conjunct can only shrink the true selectivity further, so the
         estimate stays an UPPER bound — safe for sizing capacity."""
+        return self._estimate_pred_selectivity(scan, scan.filter)
+
+    def _estimate_pred_selectivity(self, scan, pred) -> float | None:
+        """_estimate_scan_selectivity of one predicate over the scan's
+        columns: a conjunction, whose OR conjuncts are estimated arm by
+        arm."""
         from ..sql.bound import BBin, BCol, BConst, BDictLookup, BInList
-        pred = scan.filter
         if pred is None:
             return None
         cons: dict[str, list] = {}
@@ -3534,6 +3655,16 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             if isinstance(e, BBin) and e.op == "and":
                 walk(e.left)
                 walk(e.right)
+                return
+            if isinstance(e, BBin) and e.op == "or":
+                # a disjunction keeps at most the sum of what its arms
+                # keep (SSB's `c_city = 'UNITED KI1' or c_city =
+                # 'UNITED KI5'`: 2 / 250); an arm nothing is known of
+                # leaves the whole conjunct unknown
+                arms = [self._estimate_pred_selectivity(scan, x)
+                        for x in (e.left, e.right)]
+                if None not in arms:
+                    dict_fracs.append(min(1.0, sum(arms)))
                 return
             if isinstance(e, BDictLookup) and isinstance(e.expr, BCol):
                 # precomputed dictionary predicate (LIKE / ordered
@@ -3626,6 +3757,33 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             got = True
         return est if got else None
 
+    def _plan_shape_tags(self, node, scans: dict, pallas: str) -> dict:
+        """The `plan` span's `joins` (hash joins in the plan) and `agg`
+        (compile.aggregate_strategy of its outermost Aggregate, `none`
+        without one), from the plan and its scans' shapes alone."""
+        from ..sql import plan as P
+
+        def nodes(n):
+            yield n
+            for attr in ("child", "left", "right"):
+                c = getattr(n, attr, None)
+                if c is not None:
+                    yield from nodes(c)
+
+        joins, agg = 0, None
+        for n in nodes(node):
+            joins += isinstance(n, P.HashJoin)
+            if agg is None and isinstance(n, P.Aggregate):
+                agg = n
+        strategy = "none"
+        if agg is not None:
+            rows = plan_rows(agg.child,
+                             {a: b.n for a, b in scans.items()})
+            strategy = aggregate_strategy(agg, rows or 0, ExecParams(
+                pallas_groupagg=pallas,
+                pallas_interpret=self._pallas_interpret()))
+        return {"joins": joins, "agg": strategy}
+
     def _compact_frac(self, est: float) -> float:
         # 4x headroom over the uniform estimate absorbs moderate
         # per-block skew; worse skew trips the sentinel and the
@@ -3652,9 +3810,11 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         work left to shrink. Expanding joins (duplicate build keys)
         bound the wrap point — their output length breaks the est
         bookkeeping above, but the spine below them still compacts,
-        so the K-way copy runs over the packed width. Project and
-        Window stop the walk (fresh columns would drop the sentinel /
-        order matters)."""
+        so the K-way copy runs over the packed width. A spine may be
+        wrapped again further up, where the estimate has fallen far
+        enough to shrink the packed batch eight-fold once more.
+        Project and Window stop the walk (fresh columns would drop the
+        sentinel / order matters)."""
         from ..sql import plan as P
 
         def build_sel(jn) -> float:
@@ -3665,20 +3825,29 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 return e if e is not None else 1.0
             return 1.0
 
-        # (node, est, wrapped_below, joins_below)
+        def wrap(n, est, width):
+            """(Compact over n, its share of the scan's rows) for a
+            batch `width` of the scan's rows wide that `est` of them
+            survive into."""
+            frac = self._compact_frac(est / width)
+            return P.Compact(n, frac=frac), width * frac
+
+        # (node, est, width, joins_below): est the share of the scan's
+        # rows that survive, width the share the batch holds after the
+        # Compacts beneath (1.0 none yet; 0.0 nothing above may wrap)
         def spine(n, joins_above, agg_scatters):
             if isinstance(n, P.Filter):
-                c, est, wrapped, jb = spine(n.child, joins_above,
-                                            agg_scatters)
+                c, est, width, jb = spine(n.child, joins_above,
+                                          agg_scatters)
                 n.child = c
-                return n, est, wrapped, jb
+                return n, est, width, jb
             if isinstance(n, P.Scan):
                 est = self._estimate_scan_selectivity(n)
                 est = est if est is not None else 1.0
                 if est <= self.COMPACT_MAX_EST and joins_above > 0:
-                    return (P.Compact(n, frac=self._compact_frac(est)),
-                            est, True, 0)
-                return n, est, False, 0
+                    c, width = wrap(n, est, 1.0)
+                    return c, est, width, 0
+                return n, est, 1.0, 0
             if isinstance(n, P.HashJoin):
                 if n.expand != 1:
                     # output width is expand*input, which breaks the
@@ -3686,23 +3855,32 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                     # — but the probe spine BELOW still benefits: a
                     # selective join under the expansion compacts,
                     # and the K-way copy then multiplies the packed
-                    # width instead of the full batch. Report wrapped
-                    # so nothing above tries to compact the expanded
+                    # width instead of the full batch. Width 0 so
+                    # nothing above tries to compact the expanded
                     # output.
                     c, _, _, jb = spine(n.left, joins_above + 1,
                                         agg_scatters)
                     n.left = c
-                    return n, 1.0, True, jb + 1
-                c, left_est, wrapped, jb = spine(
+                    return n, 1.0, 0.0, jb + 1
+                c, left_est, width, jb = spine(
                     n.left, joins_above + 1, agg_scatters)
                 n.left = c
                 est = left_est * build_sel(n)
-                if not wrapped and est <= self.COMPACT_MAX_EST \
+                # the first Compact of a spine where an eighth or less
+                # survives; another only where it shrinks the packed
+                # batch eight-fold again (at four times the estimate:
+                # SSB Q3.2 keeps 0.04 after the customer join and
+                # 0.0016 after the supplier's, and the hash table
+                # above ran over the 1.3 M rows of the first Compact,
+                # two or three 0.43 s passes by the seed's collisions)
+                bar = (self.COMPACT_MAX_EST if width == 1.0
+                       else self.COMPACT_MAX_EST / 4)
+                if width and est <= bar * width \
                         and (joins_above > 0 or agg_scatters):
-                    return (P.Compact(n, frac=self._compact_frac(est)),
-                            est, True, jb + 1)
-                return n, est, wrapped, jb + 1
-            return n, 1.0, False, 0
+                    c2, width = wrap(n, est, width)
+                    return c2, est, width, jb + 1
+                return n, est, width, jb + 1
+            return n, 1.0, 1.0, 0
 
         def walk(n):
             if isinstance(n, P.Aggregate):
@@ -3743,6 +3921,11 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         upstream Filters consume their bits."""
         from ..sql.bound import referenced_columns
 
+        # the re-probe joins made here: one whose whole payload a
+        # Compact further up defers again has nothing left to carry
+        # (its rows matched at the join below) and is dropped
+        reprobes: set[int] = set()
+
         def pull_up(compact):
             used: set[str] = set()
             deferred: list = []
@@ -3770,6 +3953,9 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                                 payload=defer, join_type="inner",
                                 expand=1, direct=n.direct,
                                 pack_payload=[]))
+                            reprobes.add(id(deferred[-1]))
+                            if id(n) in reprobes and not n.payload:
+                                return descend(n.left)
                     used.update(n.payload)
                     n.left = descend(n.left)
                     return n

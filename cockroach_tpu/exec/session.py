@@ -12,7 +12,8 @@ from typing import Optional
 import numpy as np
 
 from ..kv.txn import Txn
-from ..ops.batch import H2D_BYTES, H2D_CALLS, PROGRAMS, ColumnBatch
+from ..ops.batch import (H2D_BYTES, H2D_CALLS, JOIN_BUILD_ROWS,
+                         JOIN_PROBE_ROWS, JOINS, PROGRAMS, ColumnBatch)
 from ..sql import ast
 from ..storage.hlc import Timestamp
 from ..utils.settings import SessionVars
@@ -141,6 +142,10 @@ class Prepared:
     # literal values, riding each dispatch as runtime scalars into the
     # shared parameterized executable; () = unparameterized
     params: tuple = ()
+    # the plan-cache key of a program whose Sort orders a prefix of a
+    # hash Aggregate's slots (Engine._size_hash_sorts), None otherwise:
+    # what run() tells the engine if more groups were live
+    prefix_key: Optional[tuple] = None
 
     def _refresh(self) -> "Prepared":
         cur = tuple((t, self.engine.store.table(t).generation)
@@ -160,6 +165,7 @@ class Prepared:
         self.spill, self.spill_cols = p.spill, p.spill_cols
         self.joinfilter = p.joinfilter
         self.params = p.params
+        self.prefix_key = p.prefix_key
         self.as_of = p.as_of  # keep guard + execution timestamps
         # consistent (interval forms re-resolve on refresh)
 
@@ -209,8 +215,16 @@ class Prepared:
             H2D_CALLS.inc(3 + len(self.params))
             H2D_BYTES.inc(16 + sum(int(getattr(v, "nbytes", 8))
                                    for v in self.params))
-            return self.jfn(self.scans, tsv, np.int32(nparts),
-                            np.int32(pid), self.params)
+            out = self.jfn(self.scans, tsv, np.int32(nparts),
+                           np.int32(pid), self.params)
+            stats = getattr(self.meta, "join_stats", None)
+            # after the call: a first dispatch traces inside it
+            if stats is not None and stats.totals[0]:
+                joins, probe_rows, build_rows = stats.totals
+                JOINS.inc(joins)
+                JOIN_PROBE_ROWS.inc(probe_rows)
+                JOIN_BUILD_ROWS.inc(build_rows)
+            return out
         # paged execution through the prefetch pipeline: a bounded
         # background worker assembles+uploads page i+1 while the
         # device computes page i, and zone-pruned pages never move
@@ -342,12 +356,23 @@ class Prepared:
                     self.stmt, self.session, self.sql_text,
                     no_compact=True).run(read_ts)
         except TopKInexact:
-            # primary-key ties crossed the top-k candidate cut:
-            # replan with the full (slow-to-compile, always-exact)
-            # device sort
-            return self.engine._prepare_select(
-                self.stmt, self.session, self.sql_text,
-                no_topk=True).run(read_ts)
+            # primary-key ties crossed the top-k candidate cut, or a
+            # prefix sort met more groups than it holds: replan with
+            # the full (slow-to-compile, always-exact) device sort.
+            # The second is a fact of the plan and not of this
+            # execution's parameters, so the engine and this handle
+            # keep the whole sort from here on
+            whole = self.engine._prepare_select(
+                self.stmt, self.session, self.sql_text, no_topk=True)
+            if self.prefix_key is not None:
+                self.engine._whole_sorts.add(self.prefix_key)
+                self.engine.metrics.counter(
+                    "exec.sort.prefix_short",
+                    "prefix sorts over a hash Aggregate that met more "
+                    "groups than their prefix holds: the plan keeps "
+                    "the whole sort from then on").inc()
+                self._adopt(whole)
+            return whole.run(read_ts)
         except CompactOverflow:
             # the stats-estimated selectivity undershot: replan with
             # the full-width masked pipeline (always exact)

@@ -68,6 +68,16 @@ def _root_aggregate(node: P.PlanNode):
     return n if isinstance(n, P.Aggregate) else None
 
 
+def _has_prefix_sort(node: P.PlanNode) -> bool:
+    """Does a Sort of the plan order a prefix of its input
+    (P.Sort.prefix, set by Engine._size_hash_sorts)?"""
+    if isinstance(node, P.Sort) and node.prefix:
+        return True
+    return any(_has_prefix_sort(c) for c in
+               (getattr(node, a, None) for a in ("child", "left", "right"))
+               if c is not None)
+
+
 def _count_aggs(node: P.PlanNode) -> int:
     """Aggregate-function count of the plan's root aggregate (for the
     streaming working-set estimate)."""

@@ -35,6 +35,11 @@ H2D_CALLS = Counter("exec.transfer.h2d.calls")
 H2D_BYTES = Counter("exec.transfer.h2d.bytes")
 D2H_CALLS = Counter("exec.transfer.d2h.calls")
 D2H_BYTES = Counter("exec.transfer.d2h.bytes")
+# what a dispatched statement's joins run over (exec/compile.py
+# JoinStats: trace-time shapes, counted once a dispatch)
+JOINS = Counter("exec.join.joins")
+JOIN_BUILD_ROWS = Counter("exec.join.build_rows")
+JOIN_PROBE_ROWS = Counter("exec.join.probe_rows")
 
 
 @jax.tree_util.register_pytree_node_class

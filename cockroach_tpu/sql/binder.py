@@ -225,6 +225,8 @@ class Binder:
             return BUnary("-", o, o.type)
         if isinstance(e, ast.Between):
             x = self.bind(e.expr)
+            if x.type.family == Family.STRING:
+                return self.bind_string_between(e, x)
             lo = self.coerce(self.bind(e.lo), x.type)
             hi = self.coerce(self.bind(e.hi), x.type)
             x, lo, hi = self._align3(x, lo, hi)
@@ -713,6 +715,22 @@ class Binder:
                 ">": np.greater, ">=": np.greater_equal}[op]
         table = pyop(vals.astype(str), lit)
         return BDictLookup(col, np.asarray(table, dtype=bool), BOOL)
+
+    def bind_string_between(self, e: ast.Between, x: BExpr) -> BExpr:
+        """`s BETWEEN 'a' AND 'b'` over a dictionary column is one
+        lookup table (SSB Q2.2's brand range); any other string BETWEEN
+        is its two comparisons."""
+        lo, hi = self.bind(e.lo), self.bind(e.hi)
+        d = self._dict_of(x)
+        if d is not None and all(isinstance(c, BConst)
+                                 and isinstance(c.value, str)
+                                 for c in (lo, hi)):
+            vals = np.asarray(d.values, dtype=object).astype(str)
+            table = (vals >= lo.value) & (vals <= hi.value)
+            return BDictLookup(x, table != e.negated, BOOL)
+        both = ast.BinOp("and", ast.BinOp(">=", e.expr, e.lo),
+                         ast.BinOp("<=", e.expr, e.hi))
+        return self.bind(ast.UnaryOp("not", both) if e.negated else both)
 
     def bind_like(self, e: ast.BinOp) -> BExpr:
         col = self.bind(e.left)

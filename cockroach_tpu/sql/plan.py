@@ -126,6 +126,10 @@ class Window(PlanNode):
 class Sort(PlanNode):
     child: PlanNode
     keys: list[tuple[str, bool]] = field(default_factory=list)  # (col, desc)
+    # leading slots of the child's batch that are sorted, 0 = all: set
+    # by the engine over a hash-strategy Aggregate whose estimated
+    # group count fits well inside them (Engine._size_hash_sorts)
+    prefix: int = 0
 
 
 @dataclass
@@ -150,6 +154,9 @@ class OutputMeta:
     # alias -> access-path description chosen by the memo's scan
     # costing ("primary eq(l_orderkey) rows≈3" / "full rows≈6001215")
     access_paths: dict = field(default_factory=dict)
+    # exec/compile.py JoinStats of the compiled plan, kept beside the
+    # executable in the plan cache: what exec.join.* counts a dispatch
+    join_stats: object = None
 
 
 def plan_tree_repr(node: PlanNode, indent: int = 0,

@@ -336,3 +336,8 @@ class Tracer:
 
     def tag(self, **tags) -> None:
         tag(**tags)
+
+    def recording(self) -> bool:
+        """Is a span open on this thread (would a tag land)? Lets a
+        caller skip computing tags nothing records."""
+        return current_span() is not None

@@ -85,7 +85,8 @@ class TestSSB:
     def test_q2_1_matches_oracle(self, loaded):
         eng, data = loaded
         r = eng.execute(ssbmod.Q2_1)
-        got = [(y, b, int(rev)) for y, b, rev in r.rows]
+        # the paper's column order: sum(lo_revenue), d_year, p_brand1
+        got = [(int(rev), y, b) for rev, y, b in r.rows]
         want = ssbmod.ref_q2_1(data["lineorder"], data["dims"])
         assert got == want
 
