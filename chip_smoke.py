@@ -274,9 +274,10 @@ class UploadLedger:
                     + sum(v.nbytes for v in b.valid) + b.sel.nbytes)
             copies = (len(b.sel.sharding.device_set)
                       if k[2] == "replicated" else 1)
-            # the metric leaves out sel and the two MVCC columns' valid
-            # masks (3 bytes a row), which are made on the device
-            want += (held - 3 * b.n) * copies
+            # the metric leaves out sel and the four MVCC word columns'
+            # valid masks (one array on the device, a byte a row each
+            # as counted here), which are made on the device
+            want += (held - 5 * b.n) * copies
         log(f"{what}: table_uploads+={n} upload_bytes+={nbytes} "
             f"new_resident={[k[:3] for k in new]}")
         # a superset upload evicts the subset it replaces within one

@@ -7,9 +7,13 @@ no-aliasing-upload
     page assembly buffers, so an aliased device array silently reads
     the NEXT page's bytes (the PR 3 corruption: exec/stream.py now
     documents the exact trap at its ``_batch_views`` site). ``jnp.array``
-    always copies. Sites that convert provably fresh, never-reused
-    buffers (e.g. the result of ``np.concatenate``) carry explicit
-    waivers; everything else must copy.
+    copies, but not before it returns (the copy is a program dispatched
+    asynchronously over the handed-over buffer: PR 34 found pages
+    corrupted behind it), so it protects a buffer that is written
+    LATER, never one rewritten at once: reused page buffers are gone
+    from exec/stream.py for that reason. Sites that convert provably
+    fresh, never-reused buffers (e.g. the result of ``np.concatenate``)
+    carry explicit waivers; everything else must copy.
 
     Regression note (this PR's sweep): exec/expr.py uploaded statement
     parameters and dictionary-gather LUTs with ``jnp.asarray`` — the

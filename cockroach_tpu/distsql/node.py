@@ -34,7 +34,8 @@ from cockroach_tpu.distsql.physical import (RAW, UNION,
                                             merge_partials, split)
 from cockroach_tpu.exec.compile import ExecParams, RunContext, compile_plan
 from cockroach_tpu.exec import profile as _prof
-from cockroach_tpu.ops.batch import ColumnBatch
+from cockroach_tpu.ops.batch import (MAX_TS, ColumnBatch,
+                                     const_mvcc_words, read_ts_words)
 from cockroach_tpu.sql import parser
 from cockroach_tpu.sql.planner import Planner, PlanError
 from cockroach_tpu.utils import tracing
@@ -121,10 +122,8 @@ def _arrays_to_batch(chunks, columns, string_cols, shared_dict):
         sel = np.ones(total, dtype=bool)
         for c in string_cols:
             data[c] = shared_dict.encode_array(data[c].astype(str))
-    n = len(sel)
-    data["_mvcc_ts"] = np.zeros(n, dtype=np.int64)
-    data["_mvcc_del"] = np.full(n, np.iinfo(np.int64).max,
-                                dtype=np.int64)
+    # the pseudo-table's rows are visible at every read timestamp
+    data.update(const_mvcc_words(len(sel), 0, MAX_TS))
     # graftlint: waive[no-aliasing-upload] data/vmask/sel are fresh
     # np.concatenate/np.zeros buffers built above; no later writes
     return ColumnBatch.from_dict(
@@ -448,8 +447,9 @@ class DistSQLNode:
                         or alias in builds:
                     raise
                 paged = (alias, tbl)
-        read_ts = jnp.int64(spec.read_ts if spec.read_ts is not None
-                            else eng.clock.now().to_int())
+        read_ts = read_ts_words(
+            spec.read_ts if spec.read_ts is not None
+            else eng.clock.now().to_int())
         if paged is not None:
             return self._paged_local(spec, runf, scans, paged,
                                      read_ts, sink=sink), stage
@@ -1020,8 +1020,9 @@ class DistSQLNode:
         self._patch_probe_join(stage.plan, scans)
         runf = compile_plan(stage.plan,
                             ExecParams(profile=st.psink))
-        read_ts = jnp.int64(spec.read_ts if spec.read_ts is not None
-                            else eng.clock.now().to_int())
+        read_ts = read_ts_words(
+            spec.read_ts if spec.read_ts is not None
+            else eng.clock.now().to_int())
         if st.psink is None:
             return runf(RunContext(scans, read_ts))
         t0 = _time.monotonic()
@@ -1803,10 +1804,10 @@ class Gateway:
         runf = compile_plan(stage.final, ExecParams(profile=gsink),
                             meta)
         if gsink is None:
-            out = runf(RunContext({UNION: union}, jnp.int64(read_ts)))
+            out = runf(RunContext({UNION: union}, read_ts_words(read_ts)))
         else:
             t0 = _time.monotonic()
-            out = runf(RunContext({UNION: union}, jnp.int64(read_ts)))
+            out = runf(RunContext({UNION: union}, read_ts_words(read_ts)))
             gsink.wall_s += _time.monotonic() - t0
         return eng._materialize(out, meta)
 
@@ -1866,10 +1867,10 @@ class Gateway:
         runf = compile_plan(graph.final, ExecParams(profile=gsink),
                             meta)
         if gsink is None:
-            out = runf(RunContext({UNION: union}, jnp.int64(read_ts)))
+            out = runf(RunContext({UNION: union}, read_ts_words(read_ts)))
         else:
             t0 = _time.monotonic()
-            out = runf(RunContext({UNION: union}, jnp.int64(read_ts)))
+            out = runf(RunContext({UNION: union}, read_ts_words(read_ts)))
             gsink.wall_s += _time.monotonic() - t0
         return eng._materialize(out, meta)
 
@@ -2053,7 +2054,7 @@ class Gateway:
         raw_union, raw_dicts = self._union_batch(
             raw, stage.raw_columns, stage.raw_strings)
         runf = compile_plan(stage.raw_merge, ExecParams())
-        out = runf(RunContext({RAW: raw_union}, jnp.int64(read_ts)))
+        out = runf(RunContext({RAW: raw_union}, read_ts_words(read_ts)))
         host = {n: np.asarray(d) for n, d in zip(out.names, out.data)}
         sel = np.asarray(out.sel).astype(bool)
         for flag in ("__sum_overflow", "__ht_overflow"):
@@ -2115,11 +2116,8 @@ class Gateway:
                 d = Dictionary()
                 data[c] = d.encode_array(data[c].astype(str))
                 merged[c] = d
-        n = len(sel)
-        # MVCC columns for the pseudo-table scan: always visible
-        data["_mvcc_ts"] = np.zeros(n, dtype=np.int64)
-        data["_mvcc_del"] = np.full(n, np.iinfo(np.int64).max,
-                                    dtype=np.int64)
+        # MVCC words for the pseudo-table scan: always visible
+        data.update(const_mvcc_words(len(sel), 0, MAX_TS))
         # graftlint: waive[no-aliasing-upload] data/vmask/sel are fresh
         # np.concatenate/np.zeros buffers built above; no later writes
         batch = ColumnBatch.from_dict(

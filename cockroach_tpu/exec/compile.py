@@ -29,7 +29,7 @@ import numpy as np
 from ..ops import agg as aggops
 from ..ops import hashtable
 from ..ops import sortkey
-from ..ops.batch import ColumnBatch
+from ..ops.batch import ColumnBatch, mvcc_live
 from ..ops.join import hash_join
 from ..sql import plan as P
 from ..sql.bound import BoundAgg
@@ -133,6 +133,10 @@ class JoinStats:
 
 class RunContext:
     """Per-execution inputs to the compiled program.
+
+    read_ts is the statement's read timestamp as two 32-bit words
+    (ops/batch.py read_ts_words), compared with the scans' MVCC word
+    columns.
 
     nparts/pid (dynamic scalars) drive the hash-partitioned spill
     recursion: a hash-strategy GROUP BY keeps only rows with
@@ -324,9 +328,7 @@ def _compile_scan(node: P.Scan, params: ExecParams) -> CompiledNode:
         # MVCC visibility: mvcc_ts <= read_ts < mvcc_del, fused with the
         # scan (storage/columnstore.py docstring; the reference pays a
         # per-KV decode here, pebble_mvcc_scanner.go:384)
-        ts = raw.col("_mvcc_ts")
-        dl = raw.col("_mvcc_del")
-        live = jnp.logical_and(ts <= rc.read_ts, rc.read_ts < dl)
+        live = mvcc_live(raw, rc.read_ts)
         cols, valid = {}, {}
         for bname, sname in colmap.items():
             d = raw.col(sname)

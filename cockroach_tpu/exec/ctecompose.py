@@ -37,10 +37,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.batch import _pow2
+from ..ops.batch import (MAX_TS, MVCC_COLUMNS, MVCC_DEL_HI, MVCC_DEL_LO,
+                         MVCC_TS_HI, MVCC_TS_LO, NEVER_TS, _pow2,
+                         read_ts_words, ts_words)
 from .session import SENTINEL_COLUMNS as _SENTINELS
-
-_DEAD_TS = np.int64(2 ** 62)
 
 
 def make_glue(template, cname_to_oname: dict, dict_clip: dict,
@@ -66,15 +66,20 @@ def make_glue(template, cname_to_oname: dict, dict_clip: dict,
         (idx,) = jnp.nonzero(sel, size=w2, fill_value=n)
         row_ok = idx < n
         idx_c = jnp.minimum(idx, n - 1).astype(jnp.int32)
+        # a gathered row was created at 1 and never deleted, a slot
+        # past the live count is never visible: the MVCC words
+        # (ops/batch.py) of (1, MAX_TS) and (NEVER_TS, MAX_TS)
+        (live_hi, live_lo), (dead_hi, dead_lo) = \
+            ts_words(1), ts_words(NEVER_TS)
+        del_hi, del_lo = ts_words(MAX_TS)
+        mvcc = {MVCC_TS_HI: jnp.where(row_ok, live_hi, dead_hi),
+                MVCC_TS_LO: jnp.where(row_ok, live_lo, dead_lo),
+                MVCC_DEL_HI: jnp.full((w2,), del_hi),
+                MVCC_DEL_LO: jnp.full((w2,), del_lo)}
         cols, valid = {}, {}
         for nm in names:
-            if nm == "_mvcc_ts":
-                cols[nm] = jnp.where(row_ok, jnp.int64(1),
-                                     jnp.int64(_DEAD_TS))
-                continue
-            if nm == "_mvcc_del":
-                cols[nm] = jnp.full((w2,), np.int64(2 ** 63 - 1),
-                                    jnp.int64)
+            if nm in mvcc:
+                cols[nm] = mvcc[nm]
                 continue
             oname = cname_to_oname[nm]
             d = jnp.take(b.col(oname), idx_c, axis=0)
@@ -128,7 +133,7 @@ class ComposedCTE:
         can pipeline several dispatches before syncing."""
         eng = self.engine
         ts = read_ts or eng._read_ts(self.session)
-        tsv = np.int64(ts.to_int())
+        tsv = read_ts_words(ts.to_int())
         one, zero = np.int32(1), np.int32(0)
         scans = dict(self.main.scans)
         flags = []
@@ -208,7 +213,7 @@ def build_composition(engine, session, capture) -> ComposedCTE | None:
                 return None
             if any(nm not in cname_to_oname
                    for nm in template.names
-                   if nm not in ("_mvcc_ts", "_mvcc_del")):
+                   if nm not in MVCC_COLUMNS):
                 return None
             if w2 != template.n:
                 return None  # shape drift vs main's compiled input

@@ -32,7 +32,7 @@ import numpy as np
 from ..kv.concurrency import (Span, TxnAbortedError, TxnRetryError)
 from ..kv.txn import DB as KVDB
 from ..kv.txn import KVStore, Txn
-from ..ops.batch import ColumnBatch
+from ..ops.batch import SCAN_WIDE_ARGS, ColumnBatch, read_ts_words
 from ..parallel import mesh as meshmod
 from ..parallel.distagg import analyze as dist_analyze
 from ..parallel.distagg import make_distributed_fn, queued_collective_call
@@ -377,7 +377,12 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                  "(the padded batch each build is traced over)"),
                 (_ob.JOIN_PROBE_ROWS, "rows those joins' probes are "
                  "traced over, after any Compact beneath: 2^23 a join "
-                 "while a probe runs over the full-width fact batch")):
+                 "while a probe runs over the full-width fact batch"),
+                (SCAN_WIDE_ARGS, "row-length arrays of a 64-bit element "
+                 "type among the scan batches of the statements "
+                 "prepared: each is a split pass over every row of "
+                 "every execution on a TPU (a data column past 32 "
+                 "bits; never the MVCC pair, which travels as words)")):
             self.metrics.func_counter(tally.name, tally.value, help_)
         # the placement verdict of each prepare (resident | stream |
         # spill | distributed), and the largest working set the
@@ -1809,8 +1814,8 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                             ExecParams(row_hook=hook, profile=sink))
         t0 = _time.monotonic()
         with _prof.active(sink, fine=True):
-            runf(RunContext(scans,
-                            jnp.int64(self.clock.now().to_int())))
+            runf(RunContext(
+                scans, read_ts_words(self.clock.now().to_int())))
         return actual, sink, _time.monotonic() - t0
 
     def _diag_bundle(self, stmt, session: Session, sql_text: str,
@@ -2718,6 +2723,8 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             return self._prepare_select(
                 sel, session, sql_text, no_memo=no_memo,
                 no_topk=no_topk, no_compact=no_compact, no_dist=True)
+        SCAN_WIDE_ARGS.inc(sum(d.dtype.itemsize == 8
+                               for b in scans.values() for d in b.data))
 
         cap = int(session.vars.get("hash_group_capacity", 1 << 17))
         pallas = session.vars.get("pallas_groupagg", "auto")

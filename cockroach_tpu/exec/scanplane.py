@@ -14,7 +14,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.batch import ColumnBatch
+from ..ops.batch import (MVCC_COLUMNS, NEVER_TS, ColumnBatch,
+                         alloc_mvcc_words, fill_mvcc_words,
+                         put_mvcc_words, read_ts_words)
 from ..parallel import mesh as meshmod
 from ..parallel.distagg import analyze as dist_analyze
 from ..parallel.distagg import make_distributed_fn, queued_collective_call
@@ -63,15 +65,15 @@ _SENTINEL_PAIRS = tuple((n, _SENTINEL_EXCS[n]) for n in SENTINEL_COLUMNS)
 def _own_columns(b: ColumnBatch, cols: frozenset | None) -> ColumnBatch:
     """The batch a scan of `cols` is handed when a resident copy with
     more columns serves it (_device_lookup_locked): its own columns and
-    the MVCC pair, in the table's order, a view. A program's arguments
+    the MVCC words, in the table's order, a view. A program's arguments
     then do not depend on what other statements made resident, so a
     wider upload neither retraces nor recompiles it (SSB's flight 4
     reads a superset of flights 2 and 3: seven programs were compiled
     twice in every cold set-up)."""
-    if cols is None or len(b.names) <= len(cols) + 2:
+    if cols is None or len(b.names) <= len(cols) + len(MVCC_COLUMNS):
         return b
     return b.project([n for n in b.names
-                      if n in cols or n.startswith("_mvcc_")])
+                      if n in cols or n in MVCC_COLUMNS])
 
 
 class ScanPlaneMixin:
@@ -144,7 +146,7 @@ class ScanPlaneMixin:
             jfn, meta = cached
 
         ts = read_ts or self._read_ts(prep.session)
-        tsv = np.int64(ts.to_int())
+        tsv = read_ts_words(ts.to_int())
 
         def run_pid(fn, scans, np_enc: int, pid_enc: int) -> list:
             out = fn(scans, tsv, np.int32(np_enc), np.int32(pid_enc))
@@ -691,7 +693,7 @@ class ScanPlaneMixin:
         charge 4+1 bytes per row, not the stored 8+1."""
         n = td.row_count
         padded = self._row_bucket(n)
-        total = 16 * padded  # the two MVCC int64 columns
+        total = 16 * padded  # the MVCC pair: four 32-bit word columns
         for col in td.schema.columns:
             if cols is not None and col.name not in cols:
                 continue
@@ -1151,7 +1153,8 @@ class ScanPlaneMixin:
                            narrow: frozenset = frozenset(),
                            sharding=None) -> ColumnBatch:
         """Concatenate chunks, pad to a power-of-two row bucket, and
-        upload as a device-resident ColumnBatch with MVCC columns.
+        upload as a device-resident ColumnBatch with the MVCC word
+        columns.
         With ``prune`` set, only those stored columns upload (the scan
         projection; HBM is the scarce resource the reference's
         needed-columns fetch logic protects, cfetcher.go:668).
@@ -1163,7 +1166,7 @@ class ScanPlaneMixin:
         n = sum(c.n for c in chunks)
         padded = self._row_bucket(n)
 
-        def gather(parts, dtype, fill=0) -> np.ndarray:
+        def gather(parts, dtype) -> np.ndarray:
             """The chunks' arrays in one padded array of `dtype`, each
             written once into its place: a 2^26-row column is held
             once on the host, not as a concatenation, a cast and a
@@ -1173,7 +1176,7 @@ class ScanPlaneMixin:
             for part in parts:
                 out[at:at + len(part)] = part
                 at += len(part)
-            out[at:] = fill
+            out[at:] = 0
             return out
 
         for col in td.schema.columns:
@@ -1188,10 +1191,15 @@ class ScanPlaneMixin:
                 # all-valid masks regenerate on device (ones) for free
                 # instead of paying PCIe for a constant
                 valid[cn] = gather([c.valid[cn] for c in chunks], bool)
-        # padding rows are never visible: created at +inf
-        cols["_mvcc_ts"] = gather([c.mvcc_ts for c in chunks], np.int64,
-                                  fill=np.int64(2**62))
-        cols["_mvcc_del"] = gather([c.mvcc_del for c in chunks], np.int64)
+        # the MVCC pair as 32-bit words (ops/batch.py), each chunk's
+        # written once into its place; padding rows are never visible
+        words = alloc_mvcc_words(padded)
+        at = 0
+        for c in chunks:
+            put_mvcc_words(words, at, c.mvcc_ts, c.mvcc_del)
+            at += c.n
+        fill_mvcc_words(words, at, padded, NEVER_TS, 0)
+        cols.update(words)
         # cols/valid hold fresh arrays built
         # above, with no later writes. All-valid masks and sel are
         # created in place under the same sharding, so nothing of the
@@ -1200,10 +1208,14 @@ class ScanPlaneMixin:
         def ones():
             return jnp.ones((padded,), jnp.bool_, device=sharding)
 
+        # no scan reads a word column's validity: the four share one
+        # mask, so the pair costs no more of HBM in words than in int64
+        words_valid = ones()
         return ColumnBatch.from_dict(
             {k: jax.device_put(v, sharding) for k, v in cols.items()},
             {k: (jax.device_put(valid[k], sharding) if k in valid
-                 else ones()) for k in cols},
+                 else words_valid if k in words else ones())
+             for k in cols},
             sel=ones())
 
     def _overlay_batch(self, name: str, effects: list,

@@ -13,7 +13,8 @@ import numpy as np
 
 from ..kv.txn import Txn
 from ..ops.batch import (H2D_BYTES, H2D_CALLS, JOIN_BUILD_ROWS,
-                         JOIN_PROBE_ROWS, JOINS, PROGRAMS, ColumnBatch)
+                         JOIN_PROBE_ROWS, JOINS, PROGRAMS, ColumnBatch,
+                         read_ts_words)
 from ..sql import ast
 from ..storage.hlc import Timestamp
 from ..utils.settings import SessionVars
@@ -169,7 +170,7 @@ class Prepared:
         self.as_of = p.as_of  # keep guard + execution timestamps
         # consistent (interval forms re-resolve on refresh)
 
-    def _join_filters(self, tsv) -> tuple:
+    def _join_filters(self, read_ts: int) -> tuple:
         """Derive this dispatch's semi-join filters (join-induced
         data skipping, exec/joinfilter.py). ``SET join_filter =
         auto|on|off``: off is the bench A/B arm, on lifts auto's
@@ -185,7 +186,7 @@ class Prepared:
         from . import joinfilter as jf
         out = []
         for spec in self.joinfilter:
-            f = jf.derive(self.engine, spec, int(tsv), mode)
+            f = jf.derive(self.engine, spec, read_ts, mode)
             if f is not None:
                 out.append(f)
         return tuple(out)
@@ -197,16 +198,18 @@ class Prepared:
             self._adopt(p)
         ts = read_ts or self.as_of or \
             self.engine._read_ts(self.session)
-        # np scalar: a jnp.int64() upload would cost a blocking
-        # host->device round trip before the query even dispatches.
-        tsv = np.int64(ts.to_int())
+        rts = ts.to_int()
         if self.spill is not None:
             if self.spill.kind != "join":
                 raise EngineError(
                     "spill-sort statements materialize host-side; "
                     "use Prepared.run()")
             from .spill import run_spill_join
-            return run_spill_join(self.engine, self, tsv)
+            return run_spill_join(self.engine, self, rts)
+        # a host array of two 32-bit words, as the scans compare it
+        # (ops/batch.py): a jnp upload would cost a blocking
+        # host->device round trip before the query even dispatches
+        tsv = read_ts_words(rts)
         if self.stream is None:
             # one program; its scalar arguments are host-to-device
             # transfers of their own (the read timestamp, the two
@@ -239,13 +242,13 @@ class Prepared:
         pipeline = self.session.vars.get("streaming_pipeline",
                                          "on") != "off"
         zpreds = self.stream_zone
-        filters = self._join_filters(tsv)
+        filters = self._join_filters(rts)
         if filters:
             from .joinfilter import zone_pred
             zpreds = zpreds + tuple(zone_pred(f) for f in filters)
         pages = self.engine._stream_pages(
             tname, self.stream_cols, page_rows,
-            zone_preds=zpreds, pipeline=pipeline, read_ts=int(tsv))
+            zone_preds=zpreds, pipeline=pipeline, read_ts=rts)
         try:
             for page in pages:
                 scans[_alias] = page
@@ -273,7 +276,8 @@ class Prepared:
         exercises, so the traced program is exactly the one real
         dispatches reuse."""
         import jax
-        tsv = np.int64(self.engine._read_ts(self.session).to_int())
+        tsv = read_ts_words(
+            self.engine._read_ts(self.session).to_int())
         scans = dict(self.scans)
         if self.spill is not None and self.spill.kind == "join":
             sp = self.spill
@@ -320,8 +324,7 @@ class Prepared:
             ts = read_ts or self.as_of or \
                 self.engine._read_ts(self.session)
             with tracer.span("dispatch"):
-                return run_spill_sort(self.engine, self,
-                                      np.int64(ts.to_int()))
+                return run_spill_sort(self.engine, self, ts.to_int())
         from ..parallel.distagg import CollectiveFault
         try:
             with tracer.span("dispatch"):

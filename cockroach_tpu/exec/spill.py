@@ -55,7 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import sortkey
-from ..ops.batch import ColumnBatch, pull_arrays
+from ..ops.batch import ColumnBatch, pull_arrays, read_ts_words
 from ..ops.join import radix_partition_ids
 from ..sql import plan as P
 from .compile import (ExecError, RunContext, _normalized_lanes,
@@ -179,10 +179,11 @@ def _partition_indices(pids: np.ndarray, nparts: int) -> list:
 # partitioned external hash join
 # ---------------------------------------------------------------------------
 
-def run_spill_join(engine, prep, tsv) -> ColumnBatch:
-    """Execute a spill-join Prepared: sweep the partitions, combining
-    per-(partition, page) aggregate partials, and return the device
-    result batch (Prepared.run materializes it like any other).
+def run_spill_join(engine, prep, read_ts: int) -> ColumnBatch:
+    """Execute a spill-join Prepared at the int timestamp `read_ts`:
+    sweep the partitions, combining per-(partition, page) aggregate
+    partials, and return the device result batch (Prepared.run
+    materializes it like any other).
 
     Correctness rests on two invariants: (a) equal join keys hash to
     the same partition on both sides, so every device match the
@@ -194,6 +195,7 @@ def run_spill_join(engine, prep, tsv) -> ColumnBatch:
     duplicate chain shares its partition."""
     sp: SpillPlan = prep.spill
     fns = prep.jfn
+    tsv = read_ts_words(read_ts)
     m_parts, m_bytes, m_rounds, m_overlap = _spill_metrics(
         engine.metrics)
     m_rounds.inc()
@@ -214,7 +216,7 @@ def run_spill_join(engine, prep, tsv) -> ColumnBatch:
     # semi-join filter prunes non-matching rows from the partition
     # index arrays before any gather/upload (inner/semi only — those
     # rows would be dropped by the join on device anyway)
-    filters = prep._join_filters(tsv)
+    filters = prep._join_filters(read_ts)
     if filters:
         keep = None
         for f in filters:
@@ -365,13 +367,14 @@ def compile_spill_sort(node: P.PlanNode, params, meta):
     return run_fn
 
 
-def run_spill_sort(engine, prep, tsv):
-    """Execute a spill-sort Prepared host-side and return a decoded
-    Result (there is no single device output batch to hand back:
-    the merge happens on the host)."""
+def run_spill_sort(engine, prep, read_ts: int):
+    """Execute a spill-sort Prepared host-side at the int timestamp
+    `read_ts` and return a decoded Result (there is no single device
+    output batch to hand back: the merge happens on the host)."""
     from .session import Result
     sp: SpillPlan = prep.spill
     meta = prep.meta
+    tsv = read_ts_words(read_ts)
     names = list(meta.names)
     m_parts, m_bytes, m_rounds, m_overlap = _spill_metrics(
         engine.metrics)
@@ -380,7 +383,7 @@ def run_spill_sort(engine, prep, tsv):
     src = engine._page_source(sp.table, prep.stream_cols,
                               sp.page_rows,
                               zone_preds=prep.stream_zone,
-                              read_ts=int(tsv))
+                              read_ts=read_ts)
     busy = [0.0]
 
     def feed():

@@ -11,7 +11,7 @@ by overlapping those two (PAPERS.md); this module supplies the
 overlap:
 
   PageSource     one-time setup per execution (sealed chunk snapshot,
-                 prefix offsets, preallocated per-column buffers),
+                 prefix offsets, zone-pred column wiring),
                  then O(log chunks) page addressing instead of an
                  O(chunks) rescan per column per page.
   ZonePred       per-chunk min/max/null-count summaries (storage
@@ -40,13 +40,11 @@ from dataclasses import dataclass
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.batch import ColumnBatch
+from ..ops.batch import (MVCC_COLUMNS, NEVER_TS, ColumnBatch,
+                         alloc_mvcc_words, const_mvcc_words,
+                         fill_mvcc_words, put_mvcc_words)
 from ..sql import bound as B
 from ..sql import plan as P
-
-# padding rows are never visible: created at +inf (matches
-# scanplane._batch_from_chunks)
-NEVER_TS = np.int64(2 ** 62)
 
 PREFETCH_DEPTH = 2
 
@@ -251,11 +249,21 @@ def _find_chain(node, alias):
 class PageSource:
     """Assembles fixed-shape host pages from a sealed chunk snapshot.
 
-    Setup (chunk snapshot, prefix offsets, zone-pred column wiring,
-    buffer allocation) happens once per execution; per page the chunk
-    span is a binary search over the prefix array and each column is
-    one in-place fill of a preallocated buffer — no concatenate+pad
-    double allocation, no per-page chunk-list rescan."""
+    Setup (chunk snapshot, prefix offsets, zone-pred column wiring)
+    happens once per execution; per page the chunk span is a binary
+    search over the prefix array and each column is one fill of a
+    buffer allocated for that page — no concatenate+pad double
+    allocation, no per-page chunk-list rescan.
+
+    A page's host buffers are its own and are never written again
+    once the page is handed over; one set reused for every page is
+    not safe behind any upload call. jnp.array does not own a copy
+    when it returns: it hands the numpy buffer to the device (aliased
+    zero-copy on the CPU backend, a transfer in flight on a TPU) and
+    copies in a program dispatched asynchronously, so a prefetch
+    worker's refill for page i+1 races the copy of page i and a
+    streamed aggregate now and then sums the next page's rows
+    (PERF.md PR 34)."""
 
     def __init__(self, td, cols, page_rows: int, zone_preds=(),
                  metrics=None, read_ts=None):
@@ -308,20 +316,23 @@ class PageSource:
             self._m_mv_bytes = metrics.counter(
                 "exec.skip.mvcc.bytes",
                 "host->device bytes avoided by MVCC window skipping")
-        # one preallocated buffer set, reused for every page: the
-        # upload goes through jnp.array (copy=True), which owns its
-        # copy before returning, so refilling the host buffers can
-        # never corrupt a page already handed to the device.
-        # jnp.asarray would NOT be safe here — on the CPU backend it
-        # zero-copy aliases suitably-aligned numpy buffers.
-        self._bufs = self._alloc()
 
-    def _alloc(self):
-        bufs = {cn: np.empty(self.page_rows, dtype=dt)
+    def _alloc(self, n: int) -> dict:
+        """One page's unwritten host buffers, n rows."""
+        bufs = {cn: np.empty(n, dtype=dt)
                 for cn, dt in self.dtypes.items()}
-        bufs["_mvcc_ts"] = np.empty(self.page_rows, dtype=np.int64)
-        bufs["_mvcc_del"] = np.empty(self.page_rows, dtype=np.int64)
+        bufs.update(alloc_mvcc_words(n))
         return bufs
+
+    def _upload(self, bufs: dict, vmap: dict) -> ColumnBatch:
+        """The device batch of one assembled page."""
+        return ColumnBatch.from_dict(
+            # graftlint: waive[no-aliasing-upload] bufs and vmap are
+            # this page's own arrays (_alloc, and the np.ones vbufs of
+            # _assemble/_gather_into); nothing writes them after this
+            {cn: jnp.asarray(bufs[cn])
+             for cn in (*self.names, *MVCC_COLUMNS)},
+            {cn: jnp.asarray(v) for cn, v in vmap.items()})
 
     def _page_zone_ok(self, i0: int, i1: int) -> bool:
         ok, _ = self._page_verdict(i0, i1)
@@ -417,7 +428,7 @@ class PageSource:
             start = end
 
     def _assemble(self, start: int, end: int, i0: int, i1: int):
-        bufs = self._bufs
+        bufs = self._alloc(self.page_rows)
         n = end - start
         vmap: dict[str, np.ndarray] = {}
         for cn in self.names:
@@ -440,23 +451,16 @@ class PageSource:
             if any_invalid:
                 vbuf[n:] = False
                 vmap[cn] = vbuf
-        mts, mdl = bufs["_mvcc_ts"], bufs["_mvcc_del"]
         for ci in range(i0, i1):
             c = self.chunks[ci]
             coff = int(self.offs[ci])
             lo, hi = max(start - coff, 0), min(end - coff, c.n)
-            dst = coff + lo - start
-            mts[dst:dst + hi - lo] = c.mvcc_ts[lo:hi]
-            mdl[dst:dst + hi - lo] = c.mvcc_del[lo:hi]
-        mts[n:] = NEVER_TS
-        mdl[n:] = 0
-        batch = ColumnBatch.from_dict(
-            {cn: jnp.array(bufs[cn])  # copy=True: see __init__
-             for cn in (*self.names, "_mvcc_ts", "_mvcc_del")},
-            # graftlint: waive[no-aliasing-upload] every vmap value is a
-            # vbuf np.ones freshly allocated by _gather_into/_assemble
-            # for this page; nothing writes it after this conversion
-            {cn: jnp.asarray(v) for cn, v in vmap.items()})
+            put_mvcc_words(bufs, coff + lo - start,
+                           c.mvcc_ts[lo:hi], c.mvcc_del[lo:hi])
+        # padding rows are never visible (as scanplane.
+        # _batch_from_chunks pads a resident upload)
+        fill_mvcc_words(bufs, n, self.page_rows, NEVER_TS, 0)
+        batch = self._upload(bufs, vmap)
         if self._m_pages is not None:
             self._m_pages.inc()
             self._m_bytes.inc(self.page_bytes)
@@ -496,12 +500,9 @@ class PageSource:
             if vbuf is not None:
                 vbuf[n:n_pad] = False
                 vmap[cn] = vbuf
-        mts, mdl = bufs["_mvcc_ts"], bufs["_mvcc_del"]
-        for c, loc, s, e in runs:
-            mts[s:e] = c.mvcc_ts[loc]
-            mdl[s:e] = c.mvcc_del[loc]
-        mts[n:n_pad] = NEVER_TS
-        mdl[n:n_pad] = 0
+        for c, loc, s, _e in runs:
+            put_mvcc_words(bufs, s, c.mvcc_ts[loc], c.mvcc_del[loc])
+        fill_mvcc_words(bufs, n, n_pad, NEVER_TS, 0)
         return vmap
 
     def gather_batch(self, idx: np.ndarray, n_pad: int):
@@ -511,33 +512,15 @@ class PageSource:
         bucket — exec/coldstart.ShapeLadder, the same ladder resident
         uploads and streamed pages use — so a single XLA program
         serves the whole partition sweep)."""
-        bufs = {cn: np.empty(n_pad, dtype=dt)
-                for cn, dt in self.dtypes.items()}
-        bufs["_mvcc_ts"] = np.empty(n_pad, dtype=np.int64)
-        bufs["_mvcc_del"] = np.empty(n_pad, dtype=np.int64)
-        vmap = self._gather_into(bufs, idx, n_pad)
-        return ColumnBatch.from_dict(
-            {cn: jnp.array(bufs[cn])  # copy=True: see __init__
-             for cn in (*self.names, "_mvcc_ts", "_mvcc_del")},
-            # graftlint: waive[no-aliasing-upload] every vmap value is a
-            # vbuf np.ones freshly allocated by _gather_into/_assemble
-            # for this page; nothing writes it after this conversion
-            {cn: jnp.asarray(v) for cn, v in vmap.items()})
+        bufs = self._alloc(n_pad)
+        return self._upload(bufs, self._gather_into(bufs, idx, n_pad))
 
     def gather_pages(self, idx: np.ndarray):
         """Yield page_rows-shaped device pages of the rows at ascending
-        global indices ``idx`` (a spill-join probe partition), reusing
-        the preallocated buffer set like pages()."""
+        global indices ``idx`` (a spill-join probe partition)."""
         for start in range(0, len(idx), self.page_rows):
-            sl = idx[start:start + self.page_rows]
-            vmap = self._gather_into(self._bufs, sl, self.page_rows)
-            yield ColumnBatch.from_dict(
-                {cn: jnp.array(self._bufs[cn])
-                 for cn in (*self.names, "_mvcc_ts", "_mvcc_del")},
-                # graftlint: waive[no-aliasing-upload] vmap values are
-                # per-call np.ones buffers (only self._bufs is reused,
-                # and those go through the jnp.array copy above)
-                {cn: jnp.asarray(v) for cn, v in vmap.items()})
+            yield self.gather_batch(idx[start:start + self.page_rows],
+                                    self.page_rows)
 
     def empty_page(self):
         """A page of only never-visible padding rows: runs the page
@@ -546,12 +529,11 @@ class PageSource:
         result)."""
         cols = {cn: np.zeros(self.page_rows, dtype=dt)
                 for cn, dt in self.dtypes.items()}
-        cols["_mvcc_ts"] = np.full(self.page_rows, NEVER_TS,
-                                   dtype=np.int64)
-        cols["_mvcc_del"] = np.zeros(self.page_rows, dtype=np.int64)
+        cols.update(const_mvcc_words(self.page_rows, NEVER_TS, 0))
         return ColumnBatch.from_dict(
             # graftlint: waive[no-aliasing-upload] cols are np.zeros/
-            # np.full allocated three lines up, never written again
+            # const_mvcc_words buffers allocated just above, never
+            # written again
             {cn: jnp.asarray(v) for cn, v in cols.items()}, {})
 
 

@@ -40,6 +40,10 @@ D2H_BYTES = Counter("exec.transfer.d2h.bytes")
 JOINS = Counter("exec.join.joins")
 JOIN_BUILD_ROWS = Counter("exec.join.build_rows")
 JOIN_PROBE_ROWS = Counter("exec.join.probe_rows")
+# row-length arguments of a 64-bit element type among the scan batches
+# of a prepared statement (Engine._prepare_select): each is an
+# X64SplitHigh/Low pass over every row of every execution on a TPU
+SCAN_WIDE_ARGS = Counter("exec.scan.wide_args")
 
 
 @jax.tree_util.register_pytree_node_class
@@ -163,6 +167,100 @@ class ColumnBatch:
 
     def __repr__(self) -> str:
         return f"ColumnBatch(n={self.n}, cols={list(self.names)})"
+
+
+# -- the MVCC pair on the device ---------------------------------------------
+#
+# The store keeps a row version's two timestamps as int64
+# (storage/columnstore.py). The TPU has no 64-bit integers: an s64[n]
+# argument is split by two custom calls that fuse with nothing, 24 B a
+# row moved to prepare a compare of 16 (33 % of the device in
+# tpch_sf10_scan.scan1, PERF.md PR 34). So a device batch holds each
+# timestamp as two 32-bit word columns, written so on the host: the
+# high word signed, the low word unsigned, 1-D and contiguous (a minor
+# dimension of 2 pads to 128 lanes). Every producer of a scan batch
+# writes them through the host half below, the one consumer
+# (exec/compile.py _compile_scan) compares them through mvcc_live, and
+# the statement's read timestamp travels as read_ts_words.
+
+MVCC_TS_HI, MVCC_TS_LO = "_mvcc_ts_hi", "_mvcc_ts_lo"
+MVCC_DEL_HI, MVCC_DEL_LO = "_mvcc_del_hi", "_mvcc_del_lo"
+MVCC_COLUMNS = (MVCC_TS_HI, MVCC_TS_LO, MVCC_DEL_HI, MVCC_DEL_LO)
+# a padding row is created at NEVER_TS, past every read timestamp; a
+# row made on the way (a DistSQL pseudo-table's, a composed CTE's) is
+# never deleted: MAX_TS. Stored rows carry the store's own values.
+NEVER_TS = 2 ** 62
+MAX_TS = 2 ** 63 - 1
+
+
+def ts_words(ts: int) -> tuple[np.int32, np.uint32]:
+    """(high word, low word) of one int64 timestamp."""
+    ts = int(ts)
+    if not -2 ** 63 <= ts < 2 ** 63:
+        raise OverflowError(f"timestamp {ts} is not an int64")
+    return np.int32(ts >> 32), np.uint32(ts & 0xFFFFFFFF)
+
+
+def read_ts_words(ts: int) -> np.ndarray:
+    """A statement's read timestamp as a program takes it: uint32[2],
+    the high word's bits then the low word. One host value, so one
+    transfer a dispatch, and no 64-bit scalar for the TPU to split."""
+    hi, lo = ts_words(ts)
+    return np.array([hi.view(np.uint32), lo], dtype=np.uint32)
+
+
+def alloc_mvcc_words(n: int) -> dict[str, np.ndarray]:
+    """Unwritten host buffers of the four word columns for n rows."""
+    return {MVCC_TS_HI: np.empty(n, np.int32),
+            MVCC_TS_LO: np.empty(n, np.uint32),
+            MVCC_DEL_HI: np.empty(n, np.int32),
+            MVCC_DEL_LO: np.empty(n, np.uint32)}
+
+
+def put_mvcc_words(bufs: Mapping[str, np.ndarray], at: int,
+                   ts: np.ndarray, dl: np.ndarray) -> None:
+    """Write the words of the int64 timestamps `ts` / `dl` (a chunk's
+    mvcc_ts / mvcc_del, or a slice of them) into rows [at, at + len)
+    of the word buffers: each word straight into its place, no int64
+    copy of the column in between."""
+    for hi, lo, src in ((MVCC_TS_HI, MVCC_TS_LO, ts),
+                        (MVCC_DEL_HI, MVCC_DEL_LO, dl)):
+        src = np.asarray(src, dtype=np.int64)
+        end = at + len(src)
+        np.right_shift(src, 32, out=bufs[hi][at:end], casting="unsafe")
+        np.bitwise_and(src, 0xFFFFFFFF, out=bufs[lo][at:end],
+                       casting="unsafe")
+
+
+def fill_mvcc_words(bufs: Mapping[str, np.ndarray], start: int,
+                    stop: int, ts: int, dl: int) -> None:
+    """Rows [start, stop) of the word buffers all created at `ts` and
+    deleted at `dl`: (NEVER_TS, 0) is padding no statement sees,
+    (0, MAX_TS) a row every statement sees."""
+    for hi, lo, v in ((MVCC_TS_HI, MVCC_TS_LO, ts),
+                      (MVCC_DEL_HI, MVCC_DEL_LO, dl)):
+        bufs[hi][start:stop], bufs[lo][start:stop] = ts_words(v)
+
+
+def const_mvcc_words(n: int, ts: int, dl: int) -> dict[str, np.ndarray]:
+    """The four word columns of n rows with one (ts, dl)."""
+    bufs = alloc_mvcc_words(n)
+    fill_mvcc_words(bufs, 0, n, ts, dl)
+    return bufs
+
+
+def mvcc_live(raw: ColumnBatch, read_ts) -> jnp.ndarray:
+    """Snapshot visibility of each row of a scan batch at `read_ts`
+    (read_ts_words): mvcc_ts <= read_ts < mvcc_del, the same bit as
+    the int64 compare for every int64 triple. High words compare
+    signed, low words unsigned."""
+    r_hi = jax.lax.bitcast_convert_type(read_ts[0], jnp.int32)
+    r_lo = read_ts[1]
+    t_hi, t_lo = raw.col(MVCC_TS_HI), raw.col(MVCC_TS_LO)
+    d_hi, d_lo = raw.col(MVCC_DEL_HI), raw.col(MVCC_DEL_LO)
+    created = (t_hi < r_hi) | ((t_hi == r_hi) & (t_lo <= r_lo))
+    not_deleted = (r_hi < d_hi) | ((r_hi == d_hi) & (r_lo < d_lo))
+    return created & not_deleted
 
 
 # -- single-transfer device->host pulls -------------------------------------

@@ -16,6 +16,11 @@ A scan AS OF timestamp T selects ``_mvcc_ts <= T < _mvcc_del`` — a pure
 mask kernel that runs on device beside the WHERE clause, so MVCC
 visibility filtering costs one compare+and per row (SURVEY.md §7
 "MVCC visibility filtering on device": resolved in favor of on-device).
+The int64 form is the host store's alone: a device batch holds each
+timestamp as two 32-bit word columns (``_mvcc_ts_hi`` / ``_mvcc_ts_lo``,
+``_mvcc_del_hi`` / ``_mvcc_del_lo``), split on the host at upload, and
+the scan compares words (ops/batch.py ``mvcc_live``: the TPU has no
+64-bit integers and would split an int64 column on every execution).
 
 Updates/deletes write tombstones (set _mvcc_del) and appended new
 versions; chunks are sealed at `chunk_rows` and never mutated except

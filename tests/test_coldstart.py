@@ -88,28 +88,29 @@ class TestCompileCachePlumbing:
         with pytest.raises(RuntimeError, match="after jax was imported"):
             Engine()
 
-    def test_unset_env_uses_checkout_dir(self):
-        default = coldstart.checkout_cache_dir()
-        assert default == str(REPO / ".jax_cache")
-        existed = os.path.exists(default)
+    def test_unset_env_uses_checkout_dir(self, tmp_path):
+        assert coldstart.checkout_cache_dir() == str(REPO / ".jax_cache")
+        # the child runs from a checkout of its own, the package linked
+        # into tmp_path: in the real one its .jax_cache, present while
+        # the child lives, fails the hermetic check (conftest) of
+        # whatever the other workers finish meanwhile
+        (tmp_path / "cockroach_tpu").symlink_to(
+            REPO / "cockroach_tpu", target_is_directory=True)
+        default = str(tmp_path / ".jax_cache")
         env = {k: v for k, v in os.environ.items()
-               if k != "JAX_COMPILATION_CACHE_DIR"}
+               if k not in ("JAX_COMPILATION_CACHE_DIR", "PYTHONPATH")}
         code = ("import jax\n"
                 "from cockroach_tpu.exec.engine import Engine\n"
                 "eng = Engine()\n"
                 "assert eng._compile_cache_dir == "
                 "jax.config.jax_compilation_cache_dir\n"
                 "print(eng._compile_cache_dir)\n")
-        try:
-            out = subprocess.run(
-                [sys.executable, "-c", code], env=env, cwd=str(REPO),
-                capture_output=True, text=True, timeout=300)
-            assert out.returncode == 0, out.stderr[-2000:]
-            assert out.stdout.strip().splitlines()[-1] == default
-        finally:
-            if not existed:
-                import shutil
-                shutil.rmtree(default, ignore_errors=True)
+        out = subprocess.run(
+            [sys.executable, "-c", code], env=env, cwd=str(tmp_path),
+            capture_output=True, text=True, timeout=300)
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert out.stdout.strip().splitlines()[-1] == default
+        assert os.path.isdir(default)
 
     def test_setting_keeps_only_off(self):
         from cockroach_tpu.utils.settings import SettingError

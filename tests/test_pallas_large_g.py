@@ -23,6 +23,7 @@ import threading
 import numpy as np
 import pytest
 
+from cockroach_tpu.ops.batch import read_ts_words
 from cockroach_tpu.ops.pallas import groupagg_large as pg
 from cockroach_tpu.ops.pallas.groupagg_large import (
     BLOCK_ROWS, GROUP_TILE, MAX, MIN, _KernelTally, effective_group_tile,
@@ -764,7 +765,7 @@ class TestNoScatterHLO:
         s = _local_session(eng)
         s.vars.set("pallas_groupagg", mode)
         p = eng.prepare(PARITY_SQL, session=s)
-        tsv = np.int64(eng._read_ts(s).to_int())
+        tsv = read_ts_words(eng._read_ts(s).to_int())
         return p.jfn.lower(p.scans, tsv, np.int32(1),
                            np.int32(0)).as_text()
 
@@ -894,7 +895,7 @@ class TestOperandHLO:
         sql = tpch.Q1.replace("count(*) AS count_order",
                               "count(*) AS count_order, count(*) AS c3")
         p = teng.prepare(sql, session=s)
-        tsv = np.int64(teng._read_ts(s).to_int())
+        tsv = read_ts_words(teng._read_ts(s).to_int())
         text = p.jfn.trace(p.scans, tsv, np.int32(1), np.int32(0),
                            p.params) \
             .lower(lowering_platforms=("tpu",)).as_text()

@@ -31,7 +31,7 @@ from cockroach_tpu.exec import compile as C
 from cockroach_tpu.ops import sortkey as sk
 from cockroach_tpu.ops import window as W
 from cockroach_tpu.ops.agg import distinct_first_mask
-from cockroach_tpu.ops.batch import ColumnBatch
+from cockroach_tpu.ops.batch import ColumnBatch, read_ts_words
 from cockroach_tpu.ops.join import _dup_chain
 
 I64 = np.iinfo(np.int64)
@@ -397,7 +397,7 @@ class TestEngineAB:
     def _lowered(self, eng, mode):
         s = _sess(eng, mode)
         p = eng.prepare(ORDER_SQL, session=s)
-        tsv = np.int64(eng._read_ts(s).to_int())
+        tsv = read_ts_words(eng._read_ts(s).to_int())
         return p.jfn.lower(p.scans, tsv, np.int32(1),
                            np.int32(0)).as_text()
 

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from cockroach_tpu.exec.engine import Engine
+from cockroach_tpu.ops.batch import read_ts_words
 from cockroach_tpu.parallel import distagg
 from cockroach_tpu.rpc.context import FaultInjector
 from cockroach_tpu.utils.metric import MetricRegistry
@@ -239,7 +240,7 @@ class TestResidentHloUnchanged:
             eng._parse_cached(JOIN_Q), sess, JOIN_Q)
         sess.vars.set("spill", "off")
         assert p_off.spill is None and p_auto.spill is None
-        tsv = np.int64(0)
+        tsv = read_ts_words(0)
         hlo_off = p_off.jfn.lower(p_off.scans, tsv, np.int32(1),
                                   np.int32(0)).as_text()
         hlo_auto = p_auto.jfn.lower(p_auto.scans, tsv, np.int32(1),

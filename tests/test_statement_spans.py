@@ -17,6 +17,7 @@ import pytest
 
 from cockroach_tpu.exec.engine import Engine
 from cockroach_tpu.models import tpch
+from cockroach_tpu.ops.batch import read_ts_words
 from cockroach_tpu.server import pgwire
 from cockroach_tpu.server.miniclient import MiniClient
 from cockroach_tpu.server.node import Node, NodeConfig
@@ -379,7 +380,7 @@ class TestOperatorScopes:
         # every device, and its jfn then routes; SET distsql = off
         # keeps the single-device executable)
         return prep.jfn.lower(
-            prep.scans, np.int64(0), np.int32(1), np.int32(0),
+            prep.scans, read_ts_words(0), np.int32(1), np.int32(0),
             prep.params).as_text(debug_info=True)
 
     @staticmethod

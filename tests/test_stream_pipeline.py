@@ -5,13 +5,14 @@ against unskipped / unpipelined execution."""
 import threading
 import time
 
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from cockroach_tpu.exec.engine import Engine
 from cockroach_tpu.exec.stream import (PageSource, ZonePred,
                                        extract_zone_preds, prefetch)
+from cockroach_tpu.ops.batch import (MVCC_TS_HI, MVCC_TS_LO, NEVER_TS,
+                                     ts_words)
 
 
 # ---------------------------------------------------------------------------
@@ -92,15 +93,27 @@ class TestPrefetch:
         assert H.n == 6  # one wait per item + the done marker
 
 
-def test_jnp_array_copies_reused_buffers():
-    """The upload-safety invariant PageSource relies on: jnp.array
-    (copy=True) must never alias the reusable host buffer. (jnp.asarray
-    DOES alias suitably-aligned buffers on the CPU backend — that was a
-    real corruption under the 8-device test config.)"""
-    buf = np.arange(4096, dtype=np.int64)
-    d = jnp.array(buf)
-    buf[:] = -1
-    assert int(d[0]) == 0 and int(d[-1]) == 4095
+def test_a_page_is_not_rewritten_by_the_next():
+    """A page's host buffers are its own. They were one set reused for
+    every page behind jnp.array, which does not own a copy when it
+    returns (the numpy buffer aliased zero-copy on the CPU backend,
+    the copy a program dispatched asynchronously): at 2^16 rows a
+    page read the next page's rows about one time in five, and a
+    streamed aggregate summed them."""
+    rows, pages = 1 << 16, 6
+    eng = Engine(mesh=None)
+    eng.execute("CREATE TABLE big (k INT8 NOT NULL PRIMARY KEY, v INT8)")
+    k = np.arange(rows * pages, dtype=np.int64)
+    eng.store.insert_columns("big", {"k": k, "v": k % 97},
+                             eng.clock.now())
+    td = eng.store.table("big")
+    for _ in range(4):
+        got = list(PageSource(td, frozenset({"k", "v"}), rows).pages())
+        assert len(got) == pages
+        for i, page in enumerate(got):
+            want = k[i * rows:(i + 1) * rows]
+            assert (np.asarray(page.col("k")) == want).all(), i
+            assert (np.asarray(page.col("v")) == want % 97).all(), i
 
 
 # ---------------------------------------------------------------------------
@@ -284,5 +297,7 @@ class TestPageSource:
         td = ceng.store.table("t")
         src = PageSource(td, frozenset({"k"}), 256)
         p = src.empty_page()
-        assert int(np.asarray(p.col("_mvcc_ts")).min()) == 2 ** 62
+        hi, lo = ts_words(NEVER_TS)
+        assert (np.asarray(p.col(MVCC_TS_HI)) == hi).all()
+        assert (np.asarray(p.col(MVCC_TS_LO)) == lo).all()
         assert p.n == 256

@@ -16,6 +16,7 @@ import pytest
 
 from cockroach_tpu.exec.engine import Engine
 from cockroach_tpu.models import tpch
+from cockroach_tpu.ops.batch import MVCC_COLUMNS
 
 ROWS = 50_000
 
@@ -145,9 +146,9 @@ def test_column_pruning_uploads_only_needed():
     from cockroach_tpu.sql import parser as pr
     p = eng._prepare_select(pr.parse(tpch.Q6), eng.session(), tpch.Q6)
     b = p.scans["lineitem"]
-    # Q6 touches 4 lineitem columns; batch adds the 2 MVCC columns
-    assert len(b.names) <= 6, b.names
-    assert "_mvcc_ts" in b.names
+    # Q6 touches 4 lineitem columns; batch adds the 4 MVCC word columns
+    assert len(b.names) <= 8, b.names
+    assert set(MVCC_COLUMNS) <= set(b.names)
     # untouched wide columns (e.g. comment-ish/string cols) not uploaded
     assert "l_orderkey" not in b.names
 
