@@ -1,0 +1,9 @@
+"""Client latency of the semi- and anti-join classes (Q4, Q21, Q22): the
+mean over the kind's classes of each class's median in the window
+(`client/class_median_ms`)."""
+
+import nested_classes
+
+
+def read(ctx):
+    return nested_classes.mean_client_ms(ctx, "semi_anti")

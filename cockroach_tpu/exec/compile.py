@@ -253,7 +253,7 @@ def _compile_plan(node: P.PlanNode, params: ExecParams,
 
         def run_project(rc):
             b = childf(rc)
-            ctx = _ctx_of(b)
+            ctx = _ctx_of(b, params=rc.params)
             cols, valid = {}, {}
             for name, f in items:
                 d, v = f(ctx)
@@ -282,12 +282,18 @@ def _compile_plan(node: P.PlanNode, params: ExecParams,
             rb = rightf(rc)
             if stats is not None:
                 stats.note(slot, lb.n, rb.n)
-            return hash_join(lb, rb, jn.left_keys, jn.right_keys,
-                             jn.payload, jn.join_type,
-                             expand=jn.expand, direct=jn.direct,
-                             pack_payload=jn.pack_payload,
-                             sort_normalized=params.sort_normalized)
+            JOIN_KINDS.bump(jn.join_type)
+            out = hash_join(lb, rb, jn.left_keys, jn.right_keys,
+                            jn.payload, jn.join_type,
+                            expand=jn.expand, direct=jn.direct,
+                            pack_payload=jn.pack_payload,
+                            sort_normalized=params.sort_normalized)
+            # a build side that is a plan of its own (a Derived) can
+            # raise a sentinel; only payload columns cross the join
+            return _carry_sentinels(out, rb)
         return run_join
+    if isinstance(node, P.Derived):
+        return _compile_derived(node, params)
     if isinstance(node, P.Compact):
         childf = compile_plan(node.child, params)
         frac, block = node.frac, node.block
@@ -314,6 +320,46 @@ def _compile_plan(node: P.PlanNode, params: ExecParams,
             return limit_batch(childf(rc), lim, off)
         return run_limit
     raise ExecError(f"cannot compile plan node {node!r}")
+
+
+def _carry_sentinels(out: ColumnBatch, src: ColumnBatch) -> ColumnBatch:
+    """OR every sentinel flag `src` raises into `out`'s (a batch that
+    does not carry src's columns: a join's output for its build side,
+    a Derived's for its child)."""
+    from .session import SENTINEL_COLUMNS
+    for name in SENTINEL_COLUMNS:
+        if not src.has(name):
+            continue
+        flag = jnp.any(src.col(name))
+        if out.has(name):
+            flag = jnp.logical_or(flag, jnp.any(out.col(name)))
+        out = out.with_column(name, jnp.broadcast_to(flag, (out.n,)))
+    return out
+
+
+def _compile_derived(node: P.Derived, params: ExecParams) -> CompiledNode:
+    """A derived table in place: the child's output under the alias's
+    batch names, then what the outer planner pushed onto it as it
+    pushes onto a Scan (computed join keys, single-table conjuncts)."""
+    childf = compile_plan(node.child, params)
+    colmap = dict(node.columns)  # batch name -> child output name
+    predf = compile_expr(node.filter) if node.filter is not None else None
+    computedf = [(n, compile_expr(e)) for n, e in node.computed]
+
+    def run_derived(rc: RunContext) -> ColumnBatch:
+        b = childf(rc)
+        out = ColumnBatch.from_dict(
+            {bn: b.col(src) for bn, src in colmap.items()},
+            {bn: b.col_valid(src) for bn, src in colmap.items()},
+            sel=b.sel)
+        for name, f in computedf:
+            d, v = f(_ctx_of(out, params=rc.params))
+            out = out.with_column(name, d, v)
+        if predf is not None:
+            pv = predf(_ctx_of(out, params=rc.params))
+            out = out.and_sel(jnp.logical_and(pv[0], pv[1]))
+        return _carry_sentinels(out, b)
+    return run_derived
 
 
 def _compile_scan(node: P.Scan, params: ExecParams) -> CompiledNode:
@@ -345,7 +391,7 @@ def _compile_scan(node: P.Scan, params: ExecParams) -> CompiledNode:
             pv = predf(_ctx_of(b, params=rc.params))
             b = b.and_sel(jnp.logical_and(pv[0], pv[1]))
         for cname, cf in computedf:
-            d, v = cf(_ctx_of(b))
+            d, v = cf(_ctx_of(b, params=rc.params))
             b = b.with_column(cname, d, v)
         return b
     return run_scan
@@ -481,6 +527,10 @@ def _agg_output(group_cols, aggs_out, live, itemfs, havingf,
 RANGE_PROOFS = sortkey._Tally()
 
 
+# one tally a compiled HashJoin, by its join type (inner, left, semi,
+# anti): the engine's exec.join.kind.*
+JOIN_KINDS = sortkey._Tally()
+
 # one tally a compiled Aggregate, by the strategy its trace took
 # (aggregate_strategy): the engine's exec.agg.strategy.*
 AGG_STRATEGY = sortkey._Tally()
@@ -499,9 +549,9 @@ def _compile_agg_args(aggs) -> list:
 
 
 def _proven_bits(a: BoundAgg) -> int:
-    """Bits the plan proved an exact sum's argument to fit, never
-    negative (Engine._prove_agg_arg_ranges); 0 where nothing is
-    proven."""
+    """Bits the plan proved the argument of an exact sum, a min or a
+    max to fit, never negative (Engine._prove_agg_arg_ranges); 0
+    where nothing is proven."""
     return a.arg_bits if a.arg_nonneg else 0
 
 
@@ -626,6 +676,21 @@ def _agg_partials(a: BoundAgg, argf, batch, ctx, gid, num_groups,
     if a.func == "avg":
         scale = (10.0 ** a.arg.type.scale
                  if a.arg.type.family == Family.DECIMAL else 1.0)
+        if grouped and axis_name is None and d0.dtype == jnp.int64 \
+                and _sum_cannot_wrap(a, d0.shape[0]):
+            # an INT / DECIMAL argument whose sum the plan proved
+            # inside int64: the exact sum on 32-bit limb scatters,
+            # divided once a group, not a float scatter-add of every
+            # row (a float segment sum over 2^23 rows into 200,001
+            # groups measured 942 ms on a v5e where a 32-bit one takes
+            # 60: TPC-H Q17's avg(l_quantity) by part, PR 35)
+            s = aggops.group_sum(d0, gid, mask, num_groups,
+                                 acc_dtype=jnp.int64,
+                                 max_group_rows=max_group_rows,
+                                 arg_bits=_proven_bits(a))
+            d = (s.astype(jnp.float64) / scale
+                 / jnp.maximum(cnt, 1).astype(jnp.float64))
+            return d, nonempty, None
         df = d0.astype(jnp.float64) / scale
         if grouped:
             s = aggops.group_sum(df, gid, mask, num_groups)
@@ -642,6 +707,17 @@ def _agg_partials(a: BoundAgg, argf, batch, ctx, gid, num_groups,
         else:
             d = aggops.masked_max(d0, mask)[None]
         return pmax(d), nonempty, None
+    if a.func in ("min", "max") and grouped \
+            and 0 < _proven_bits(a) <= 31 and d0.dtype == jnp.int64:
+        # an argument the plan proved non-negative under 2^31 (a key,
+        # a quantity: Engine._prove_agg_arg_ranges): the segment
+        # extreme scatters in 32 bits, where a 64-bit one is emulated
+        # on the TPU (TPC-H Q21's min / max of l_suppkey over 1.5 M
+        # groups), and the winner widens back
+        fold = aggops.group_min if a.func == "min" else aggops.group_max
+        d = fold(d0.astype(jnp.int32), gid, mask,
+                 num_groups).astype(jnp.int64)
+        return (pmin if a.func == "min" else pmax)(d), nonempty, None
     if a.func == "min":
         if grouped:
             d = aggops.group_min(d0, gid, mask, num_groups)
@@ -662,6 +738,19 @@ def _agg_partials(a: BoundAgg, argf, batch, ctx, gid, num_groups,
 # scatter ladder (q18's bench-scale o_orderkey span ~262K sits under
 # this; beyond it the XLA segment path remains).
 LARGE_G_MAX = 1 << 19
+# Inside that envelope, where the kernel is the faster of the two dense
+# strategies. It builds a one-hot of every row against every lane of
+# every group tile, n x padded groups elements, at about 1e12 elements
+# a second on a v5e: TPC-H Q1's 2^26 rows x 128 lanes in 9.1 ms (PR
+# 34), Q13's 2^21 orders x 150,016 customers in 0.31 s and Q17's 2^23
+# lines x 200,192 parts in 1.7 s (PR 35). XLA's segment sums pay one
+# 32-bit scatter a slot, about 7 ns a row each (14 ms over 2 M rows,
+# ops/agg.py), whatever the group count. So the kernel takes a domain
+# of at most this many padded groups for each scatter the XLA path
+# would make (one an aggregate and the liveness count): SSB's 8,008
+# groups under one sum stay on it, 150,000 customers under one count
+# do not.
+LARGE_G_PER_SCATTER = 7000
 # Under `auto`, inputs smaller than this stay on XLA: kernel launch +
 # padding overhead beats nothing at toy sizes, and the logic-test
 # corpus stays byte-for-byte on its established path.
@@ -736,6 +825,7 @@ def large_kernel_eligible(node: P.Aggregate, n: int,
         return False
     num_groups = dense_num_groups(node)
     return (num_groups <= LARGE_G_MAX
+            and num_groups <= LARGE_G_PER_SCATTER * (len(node.aggs) + 1)
             and not _large_interpret_over_budget(
                 params.pallas_interpret, n, num_groups)
             and _pallas_large_ok(node.aggs))
@@ -1083,7 +1173,7 @@ def _compile_window(node: P.Window, params: ExecParams) -> CompiledNode:
 
     def run_window(rc: RunContext) -> ColumnBatch:
         b = childf(rc)
-        ctx = _ctx_of(b)
+        ctx = _ctx_of(b, params=rc.params)
         for i, (w, argf, partfs, orderfs) in enumerate(specs):
             parts = [pf(ctx) for pf in partfs]
             orders = []
@@ -1117,7 +1207,7 @@ def _compile_window(node: P.Window, params: ExecParams) -> CompiledNode:
                 d, v = W.window_agg(w.func, order, seg_start, peer_start,
                                     sel_s, ad, av, framed)
             b = b.with_column(f"__win{i}", d, v)
-            ctx = _ctx_of(b)
+            ctx = _ctx_of(b, params=rc.params)
         return b
     return run_window
 
@@ -1159,7 +1249,7 @@ def _compile_aggregate(node: P.Aggregate, params: ExecParams) -> CompiledNode:
         return _aggregate(rc, b, ctx, gid, num_groups, ng, group_cols)
 
     def _group_keys(rc, b):
-        ctx = _ctx_of(b)
+        ctx = _ctx_of(b, params=rc.params)
         group_cols = {}  # name -> ([G] data, [G] valid)
         ng = None
 
@@ -1788,7 +1878,7 @@ def compile_streaming(node: P.PlanNode, params: ExecParams,
 
     def page_fn(rc: RunContext) -> tuple:
         b = childf(rc)
-        ctx = _ctx_of(b)
+        ctx = _ctx_of(b, params=rc.params)
         if not groupfs:
             gid = None
         else:
@@ -1904,7 +1994,7 @@ def _compile_hash_dist_aggregate(node: P.Aggregate, params: ExecParams,
     def run(rc: RunContext) -> ColumnBatch:
         b = childf(rc)
         AGG_STRATEGY.bump("hash")
-        ctx = _ctx_of(b)
+        ctx = _ctx_of(b, params=rc.params)
         keycols = []
         gdata = []  # (name, data, valid) of each group-key expression
         for name, gf in groupfs:

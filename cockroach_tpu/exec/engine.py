@@ -39,9 +39,9 @@ from ..parallel.distagg import make_distributed_fn, queued_collective_call
 from ..parallel.mesh import SHARD_AXIS
 from ..sql import ast, parser
 from ..sql import plan as P
-from ..sql.binder import Binder, ColumnBinding, Scope
+from ..sql.binder import Binder, BindError, ColumnBinding, Scope
 from ..sql.bound import BConst
-from ..sql.planner import CatalogView, PlanError, Planner
+from ..sql.planner import CatalogView, NotInPlace, PlanError, Planner
 from ..sql.rowenc import ROWID
 from ..sql.types import ColumnSchema, Family, TableSchema
 from ..storage import keys as K
@@ -52,13 +52,17 @@ from ..utils.mon import BytesMonitor, MemoryQuotaError
 from ..utils.settings import SessionVars, Settings
 from . import coldstart
 from . import movement
-from .compile import (AGG_STRATEGY, RANGE_PROOFS, ExecParams, JoinStats,
+from .compile import (AGG_STRATEGY, JOIN_KINDS, RANGE_PROOFS, ExecParams,
+                      JoinStats,
                       RunContext, aggregate_strategy, can_stream,
                       compile_plan, compile_streaming, plan_rows)
-from .planparam import parameterize, plan_fingerprint, shape_text
+from .planparam import (SubqueryValue, inline_subquery_args,
+                        param_signature, parameterize, plan_fingerprint,
+                        shape_text)
 from .expr import ExprContext, compile_expr
 from .stream import extract_zone_preds
-from .session import (CompactOverflow, EngineError, HashCapacityExceeded,
+from .session import (subquery_const,
+                      CompactOverflow, EngineError, HashCapacityExceeded,
                       Prepared, Result, Session)
 from .stmtutil import (_StreamFns, _RerunPrepared, _has_prefix_sort,
                       _host_sort, _count_aggs,
@@ -72,6 +76,21 @@ from .stmtutil import (_StreamFns, _RerunPrepared, _has_prefix_sort,
 
 EPOCH_DATE = datetime.date(1970, 1, 1)
 EPOCH_DT = datetime.datetime(1970, 1, 1)
+
+
+def _find_scan_column(node, bname: str):
+    """(table, stored column) of the Scan beneath `node` that makes the
+    batch column `bname`, or None."""
+    if isinstance(node, P.Scan):
+        stored = node.columns.get(bname)
+        return None if stored is None else (node.table, stored)
+    for attr in ("child", "left", "right"):
+        c = getattr(node, attr, None)
+        if c is not None:
+            hit = _find_scan_column(c, bname)
+            if hit is not None:
+                return hit
+    return None
 
 
 from .constraints import ConstraintMixin  # noqa: E402
@@ -277,7 +296,8 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         self._whole_sorts: set = set()
         self._parse_cache: TenantLRU = TenantLRU(
             self._PARSE_CACHE_MAX,
-            on_evict=lambda k: self._plain_memo.discard(k))
+            on_evict=lambda k: (self._plain_memo.discard(k),
+                                self._temps_memo.discard(k)))
         # the executing statement's tenant, published per-thread
         # between admission acquire/release so cache puts deep in the
         # dispatch stack can attribute entries without plumbing
@@ -287,6 +307,11 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         # (round-4 advisor, low: an in-place annotation on a shared
         # node is a latent cross-thread race under the read gate)
         self._plain_memo: set[str] = set()
+        # SELECT texts whose derived tables cannot be planned in place
+        # (NotInPlace, found on their first execution): they take
+        # _exec_with_temps without asking the planner again. Kept in
+        # step with the parse cache, as _plain_memo is
+        self._temps_memo: set[str] = set()
         # per-table secondary-index descriptors, cached off the catalog
         # (invalidated by index DDL; a fresh engine lazily reloads)
         self._index_defs: dict[str, list] = {}
@@ -501,6 +526,37 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 lambda kind=kind: AGG_STRATEGY.value(kind),
                 "Aggregates compiled, by the strategy their trace "
                 f"took (compile.aggregate_strategy): {how}")
+        for kind in ("inner", "left", "semi", "anti"):
+            self.metrics.func_counter(
+                "exec.join.kind." + kind,
+                lambda kind=kind: JOIN_KINDS.value(kind),
+                "hash joins traced, by join type (one tally a traced "
+                "join, beside exec.join.joins a dispatch): semi and "
+                "anti are what EXISTS / NOT EXISTS with equality "
+                "correlations unnest into on one device")
+        self._m_subquery = {
+            k: self.metrics.counter(
+                "exec.subquery." + k,
+                "results of uncorrelated scalar subqueries, counted a "
+                "dispatched program, by how they reached it: args "
+                "(read at the dispatch's timestamp by the subquery's "
+                "own prepared statement and passed beside the lifted "
+                "literals, planparam.SubqueryArg) or inlined "
+                "(constants of the plan, read when it was prepared: "
+                "another program for other rows)")
+            for k in ("args", "inlined")}
+        self._m_subquery_seconds = self.metrics.histogram(
+            "exec.subquery.seconds",
+            "seconds a `subquery` span took: one subquery's prepared "
+            "statement run for one dispatch of the statement that "
+            "takes its result as an argument")
+        self._m_decorrelate = {
+            k: self.metrics.counter(
+                "exec.decorrelate." + k,
+                "subqueries unnested at prepare (sql/decorrelate.py): "
+                "exists counts EXISTS / NOT EXISTS, scalar a "
+                "correlated scalar subquery")
+            for k in ("exists", "scalar")}
         self.metrics.func_counter(
             "exec.pallas.rows",
             lambda: ROWS.value(),
@@ -1036,6 +1092,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             # path, exec/oltplane.py)
             self._parse_cache.clear()
             self._plain_memo.clear()
+            self._temps_memo.clear()
             self._lane_shapes.clear()
             self._lane_mirrors.clear()
         if self.cluster is not None:
@@ -2063,7 +2120,8 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
 
     # -- SELECT --------------------------------------------------------------
     def _plan(self, stmt, session, for_explain: bool = False,
-              no_memo: bool = False, trace=None):
+              no_memo: bool = False, trace=None,
+              subquery_slots: list | None = None):
         if not isinstance(stmt, ast.Select):
             raise EngineError("can only EXPLAIN SELECT")
         # AS OF pins the whole statement: now() and plan-time
@@ -2096,7 +2154,13 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             volatile_fold_ok=for_explain,
             rules=(session.vars.get("optimizer_rules", "on")
                    != "off"),
-            trace=trace)
+            trace=trace,
+            # a caller that dispatches what it plans keeps the
+            # subqueries' prepared statements in this list
+            subquery_arg=(None if subquery_slots is None else
+                          lambda sel: self._prepare_subquery(
+                              _propagate_as_of(sel, stmt), session,
+                              subquery_slots)))
         result = planner.plan_select(stmt)
         self._prove_agg_arg_ranges(result[0], session)
         self._size_hash_sorts(result[0])
@@ -2185,11 +2249,38 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         return v
 
     # -- subqueries / CTEs ---------------------------------------------------
+    def _prepare_subquery(self, sel, session: Session, slots: list):
+        """Prepare an uncorrelated scalar subquery as a statement of
+        its own and append it to `slots`: (slot, result type) for the
+        binder's BSubqueryArg, or None for a shape that only
+        _eval_subquery serves (set operations, CTEs and derived
+        tables, a table-free SELECT, paged and spilled plans, a
+        transaction's overlay). The statement that holds the argument
+        runs it at each dispatch, at the dispatch's read timestamp
+        (Prepared.subquery_params)."""
+        if not isinstance(sel, ast.Select) or session.txn is not None \
+                or session.effects or self._cte_capture is not None:
+            return None
+        sel = self._decorrelate(
+            self._expand_views(sel),
+            inline=self._plans_in_place(sel, session))
+        if sel.ctes or self._has_derived(sel) or sel.table is None:
+            return None
+        prep = self._prepare_select(sel, session, f"(subquery {sel!r})")
+        if prep.stream is not None or prep.spill is not None:
+            return None
+        if len(prep.meta.types) != 1:
+            raise BindError("scalar subquery must return one column")
+        slots.append(prep)
+        return len(slots) - 1, prep.meta.types[0]
+
     def _eval_subquery(self, sel: ast.Select, session: Session,
                        limit_one: bool = False):
-        """Execute an expression subquery before the main statement
-        (the reference's planTop.subqueryPlans, sql/subquery.go) and
-        hand (rows, types) back to the binder for constant inlining."""
+        """Execute an expression subquery while the main statement is
+        bound (the reference's planTop.subqueryPlans, sql/subquery.go)
+        and hand (rows, types) back to the binder, which writes them
+        into the plan as constants: EXISTS, IN (SELECT ...), and the
+        scalar subqueries _prepare_subquery does not take."""
         import copy
         if limit_one and sel.limit is None:
             sel = copy.copy(sel)
@@ -2197,12 +2288,18 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         res = self._exec_select(sel, session, f"(subquery {sel!r})")
         return res.rows, res.types
 
-    def _decorrelate(self, sel: ast.Select) -> ast.Select:
+    def _decorrelate(self, sel: ast.Select,
+                     inline: bool = False) -> ast.Select:
         """Unnest correlated (NOT) EXISTS and correlated scalar
         subqueries into grouped LEFT JOINs (sql/decorrelate.py; the
-        opt/norm/decorrelate.go analogue)."""
+        opt/norm/decorrelate.go analogue). `inline`: the statement
+        runs as one program on one device (_plans_in_place), so an
+        EXISTS / NOT EXISTS whose correlations are all equalities
+        becomes a SEMI / ANTI join of the subquery's table, a count
+        beneath an outer join is pushed below it (eager_count), and
+        derived tables' bodies are unnested too."""
         from ..sql.decorrelate import (decorrelate_exists,
-                                       decorrelate_scalar)
+                                       decorrelate_scalar, eager_count)
 
         from ..sql.types import Family
 
@@ -2217,8 +2314,50 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 return sch.column(col).type.uses_dictionary
             except KeyError:
                 return True   # unknown: refuse the min/max trick
-        sel = decorrelate_exists(sel, columns_of, is_string_col)
-        return decorrelate_scalar(sel, columns_of)
+        # a SEMI / ANTI join reads the subquery's table as a build
+        # side of the statement's own program; on a mesh the statement
+        # keeps the grouped LEFT JOIN the distributed planner knows
+        join_ok = ((lambda table, alias: table in self.store.tables)
+                   if inline else None)
+        applied: list = []
+        out = decorrelate_exists(sel, columns_of, is_string_col,
+                                 join_ok=join_ok, applied=applied)
+        out = decorrelate_scalar(out, columns_of, applied=applied)
+        for kind in applied:
+            self._m_decorrelate[kind].inc()
+        if inline:
+            out = eager_count(out, columns_of)
+        if inline and self._has_derived(out):
+            # a derived table's body is a statement of its own: its
+            # subqueries unnest in it (q22's custsale)
+            import copy
+            if out is sel:
+                out = copy.copy(sel)
+            out.joins = [copy.copy(j) for j in out.joins]
+            for holder in [out] + out.joins:    # each has a .table
+                ref = holder.table
+                if ref is not None and isinstance(ref.subquery,
+                                                  ast.Select):
+                    holder.table = ast.TableRef(
+                        ref.name, ref.alias,
+                        self._decorrelate(ref.subquery, inline=True))
+        return out
+
+    def _plans_in_place(self, sel, session: Session) -> bool:
+        """Does this statement run as one program on one device, so
+        that its subqueries' tables can join it (SEMI / ANTI) and its
+        derived tables be planned in place? Not on a mesh that may
+        distribute it (the distributed planner keeps grouped LEFT
+        JOINs over temps), not inside a transaction's overlay, not
+        while a composed-CTE capture records temps."""
+        if not isinstance(sel, ast.Select) or sel.ctes:
+            return False
+        if session.txn is not None or session.effects \
+                or self._cte_capture is not None \
+                or getattr(session, "_cte_depth", 0):
+            return False
+        return (self.mesh is None or self.mesh.size <= 1
+                or session.vars.get("distsql", "auto") == "off")
 
     @staticmethod
     def _has_derived(sel: ast.Select) -> bool:
@@ -2630,7 +2769,11 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         for td in self.store.tables.values():
             if td.open_ts:
                 self.store.seal(td.schema.name)
-        node, meta = self._plan(sel, session, no_memo=no_memo)
+        # the statement's uncorrelated scalar subqueries, each a
+        # prepared statement of its own (_prepare_subquery)
+        subs: list = []
+        node, meta = self._plan(sel, session, no_memo=no_memo,
+                                subquery_slots=subs)
 
         scan_aliases = _collect_scans(node)
         scan_cols = _collect_scan_columns(node)
@@ -2763,9 +2906,22 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         psc = str(session.vars.get("plan_shape_cache", "auto")).lower()
         if psc != "off" and stream is None and spill is None \
                 and not overlay and self._cte_capture is None:
-            pnode, vals = parameterize(node)
+            pnode, vals = parameterize(
+                node, tables=decision is None
+                and self._reads_large_dictionary(scan_aliases, scan_cols))
             if vals is not None:
                 node, pvals = pnode, vals
+        inlined = meta.subqueries
+        if subs:
+            # a subquery's result that no filter holds, or of a plan
+            # that is not parameterized: read now, at this prepare's
+            # timestamp, and written into the plan (the form every
+            # subquery had before: another program for other rows)
+            def const_of(arg):
+                return subquery_const(subs[arg.slot].run(read_ts))
+
+            node, n = inline_subquery_args(node, const_of)
+            inlined += n
         if pvals:
             # literals left the plan, so they must leave the key text
             # too; the structural fingerprint below is what rejects a
@@ -2774,14 +2930,17 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             keytext = shape_text(sql_text)
             plan_fp = plan_fingerprint(node)
         else:
-            # plan fingerprint: subquery results are inlined into the
-            # plan as constants, so two preparations of the SAME
-            # sql_text can compile DIFFERENT programs when underlying
-            # data moved — sql_text alone would hand back a stale
-            # compiled constant
+            # plan fingerprint: what a subquery returned while the
+            # statement was bound (an IN list, an EXISTS, a scalar no
+            # filter holds) is a constant of the plan, so two
+            # preparations of the SAME sql_text can compile DIFFERENT
+            # programs when underlying data moved — sql_text alone
+            # would hand back a stale compiled constant. (A scalar
+            # subquery in a filter is an argument, above, and leaves
+            # the key as the literals do.)
             keytext = sql_text
             plan_fp = hash(repr(node))
-        psig = tuple(str(v.dtype) for v in pvals)
+        psig = param_signature(pvals)
         key = (keytext, tuple(sorted(shapes)), decision is not None,
                stream, spill, cap, pallas, sortn, plan_fp, no_topk,
                no_compact, psig)
@@ -2925,7 +3084,12 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                                         if spill is not None
                                         and spill.build_alias else None),
                             joinfilter=jf_specs,
-                            params=pvals, prefix_key=prefix_key)
+                            params=pvals, prefix_key=prefix_key,
+                            subqueries=tuple(
+                                (i, subs[v.slot]) for i, v in
+                                enumerate(pvals)
+                                if isinstance(v, SubqueryValue)),
+                            inlined_subqueries=inlined)
         # alias -> table map (composed CTE execution patches temp
         # aliases' scan batches per run, exec/ctecompose.py)
         prepared.scan_tables = dict(scan_aliases)
@@ -2962,8 +3126,10 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                      sql_text: str) -> Result:
         if isinstance(sel, ast.SetOp):
             return self._exec_setop(sel, session, sql_text)
+        inline = self._plans_in_place(sel, session)
         if sql_text not in self._plain_memo:
-            sel2 = self._decorrelate(self._expand_views(sel))
+            sel2 = self._decorrelate(self._expand_views(sel),
+                                     inline=inline)
             if sel2 is sel and sql_text and \
                     sql_text.lower().count("select") == 1:
                 # memoize BY TEXT so hot OLTP statements skip both
@@ -2977,6 +3143,22 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 # guard fixes). DDL invalidates with the parse cache.
                 self._plain_memo.add(sql_text)
             sel = sel2
+        if inline and not sel.ctes and self._has_derived(sel):
+            # derived tables planned in place (plan.Derived): one
+            # program, nothing materialized on the host, nothing in it
+            # measured from a temp's rows. A shape the planner cannot
+            # place (a derived build side joined on other columns than
+            # its GROUP BY key, a body of CTEs or set operations) says
+            # so once, by NotInPlace; its text is remembered, and
+            # takes the temps below from then on, as before
+            if sql_text not in self._temps_memo:
+                try:
+                    prep = self._prepare_select(sel, session, sql_text)
+                except NotInPlace:
+                    if sql_text:
+                        self._temps_memo.add(sql_text)
+                else:
+                    return prep.run()
         if sel.ctes or self._has_derived(sel):
             return self._exec_with_temps(sel, session, sql_text)
         if sel.table is None:
@@ -3290,7 +3472,8 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         return narrow_by_alias
 
     def _prove_agg_arg_ranges(self, node, session: Session) -> None:
-        """Attach a value-range proof to every exact SUM / AVG whose
+        """Attach a value-range proof to every exact SUM / AVG (and
+        MIN / MAX, whose scatter then runs in 32 bits) whose
         INT / DECIMAL argument is arithmetic over stored columns and
         constants (BoundAgg.arg_nonneg / arg_bits): interval
         arithmetic (sql/valuerange.py) from the store's all-versions
@@ -3342,8 +3525,10 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
 
             scans(agg.child)
             for a in agg.aggs:
-                if a.func in ("sum", "sum_int", "avg") \
-                        and a.arg is not None:
+                if a.func in ("sum", "sum_int", "avg", "min", "max") \
+                        and a.arg is not None \
+                        and a.arg.type.family in (Family.INT,
+                                                  Family.DECIMAL):
                     a.arg_bits = nonneg_bits(
                         expr_int_range(a.arg, col_range))
                     a.arg_nonneg = a.arg_bits > 0
@@ -3374,12 +3559,38 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 est = self._estimate_groups(n.child)
                 if est is not None and 2 * est <= HASH_SORT_PREFIX:
                     n.prefix = HASH_SORT_PREFIX
+                elif est is None and self._groups_by_aggregates(n.child):
+                    n.prefix = HASH_SORT_PREFIX
             for attr in ("child", "left", "right"):
                 c = getattr(n, attr, None)
                 if c is not None:
                     walk(c)
 
         walk(node)
+
+    @staticmethod
+    def _groups_by_aggregates(agg) -> bool:
+        """Is every key of this Aggregate an aggregate's result of a
+        derived table planned beneath it (TPC-H Q13 groups customers
+        by their count of orders: an aggregate of an aggregate)? Such
+        a key has no stored column to estimate from, and takes few
+        values as a rule: a histogram's bars are far fewer than what
+        it counts. The Sort above then orders the prefix; if more
+        groups are live the sentinel falls back to the whole sort
+        once, as for an estimate that proved low."""
+        from ..sql.bound import BCol
+        n = agg.child
+        while isinstance(n, (P.Filter, P.Compact, P.Project)):
+            n = n.child
+        if not isinstance(n, P.Derived) \
+                or not isinstance(n.child, P.Aggregate):
+            return False
+        grouped = {name for name, e in n.child.items
+                   if isinstance(e, BCol)
+                   and e.name in dict(n.child.group_by)}
+        return all(isinstance(e, BCol) and e.name in n.columns
+                   and n.columns[e.name] not in grouped
+                   for _, e in agg.group_by)
 
     def _estimate_groups(self, agg) -> float | None:
         """Estimated number of groups of an Aggregate whose keys are
@@ -3438,8 +3649,20 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
 
         def walk(n):
             if isinstance(n, P.HashJoin):
-                if n.join_type in ("inner", "left"):
+                if isinstance(n.right, P.Derived):
+                    self._check_derived_build(n)
+                elif n.join_type in ("inner", "left"):
                     self._check_one_build(n, read_ts, overlay)
+                elif isinstance(n.right, P.Scan):
+                    # SEMI / ANTI: a probe row asks whether ANY build
+                    # row has its key, so duplicate keys need neither
+                    # a check nor an expansion; a dense key domain
+                    # still takes the direct-address table
+                    stored = [n.right.columns.get(k)
+                              for k in n.right_keys]
+                    if all(c is not None for c in stored):
+                        self._maybe_direct_join(n, n.right, stored,
+                                                read_ts, overlay)
                 walk(n.left)
                 walk(n.right)
                 return
@@ -3449,6 +3672,47 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                     walk(c)
 
         walk(node)
+
+    def _check_derived_build(self, join) -> None:
+        """A join whose build side is a derived table planned in place
+        (plan.Derived). Nothing of it is stored, so uniqueness and the
+        key domain come from its plan: keys that are all the GROUP BY
+        columns of its Aggregate are unique, and a single key that is
+        a group column read off a stored integer column spans no more
+        than that column (Engine.store.key_int_range of the BASE
+        table: the same whatever rows the sub-select keeps, so the
+        program does not follow the data). Anything else refuses the
+        in-place plan (NotInPlace), and the statement takes temps."""
+        from ..sql.bound import BCol
+        d = join.right
+        n = d.child
+        while isinstance(n, (P.Project, P.Sort, P.Limit, P.Filter)):
+            if isinstance(n, P.Project) and not all(
+                    isinstance(e, BCol) for _, e in n.items):
+                break
+            n = n.child
+        out_of = {}
+        if isinstance(n, P.Aggregate) and n.group_by:
+            groups = dict(n.group_by)           # "g0:name" -> expr
+            for name, e in n.items:
+                if isinstance(e, BCol) and e.name in groups:
+                    out_of[name] = groups[e.name]
+        keys = [out_of.get(d.columns.get(k)) for k in join.right_keys]
+        unique = (isinstance(n, P.Aggregate) and None not in keys
+                  and len(keys) == len(n.group_by))
+        if not unique and join.join_type in ("inner", "left"):
+            raise NotInPlace(
+                f"derived table {d.alias!r} is joined on columns that "
+                "are not its GROUP BY key: it cannot be a build side "
+                "in place")
+        join.expand = 1
+        join.direct = None
+        if len(keys) == 1 and isinstance(keys[0], BCol):
+            src = _find_scan_column(n, keys[0].name)
+            if src is not None:
+                r = self.store.key_int_range(*src)
+                if r is not None:
+                    join.direct = self._direct_slots(*r)
 
     def _check_one_build(self, join, read_ts: Timestamp,
                          overlay: set) -> None:
@@ -3513,6 +3777,22 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
     # packed composite keys size the table by the SPAN PRODUCT
     MAX_PACKED_JOIN_SLOTS = 1 << 27
 
+    def _direct_slots(self, lo: int, hi: int, n_all: int):
+        """(base, slots) of the direct-address table of a single
+        integer key spanning [lo, hi] over n_all rows, or None where
+        the span is too sparse or too wide. Density is a MEMORY
+        question, not a perf one: the build is a single scatter over
+        the table regardless of sparsity, and a sparse table still
+        beats the ~100x-slower while-loop hash probe. SSB's date
+        dimension (YYYYMMDD ints: ~2.5K keys over a ~60K span) is the
+        canonical sparse-but-small case round 2's 4x-density guard
+        wrongly sent to the hash path."""
+        span = hi - lo + 1
+        if span <= max(256 * n_all, 4096) \
+                and span + 1 <= self.MAX_DIRECT_JOIN_SLOTS:
+            return (lo, span + 1)
+        return None
+
     def _maybe_direct_join(self, join, b, stored, read_ts,
                            overlay: set) -> None:
         """Direct-address the join when the single build key is
@@ -3537,16 +3817,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             ranges.append((lo, hi - lo + 1))
         if len(ranges) == 1:
             lo, span = ranges[0]
-            # density is a MEMORY question, not a perf one: the build
-            # is a single scatter over the table regardless of
-            # sparsity, and a sparse table still beats the
-            # ~100x-slower while-loop hash probe. SSB's date dimension
-            # (YYYYMMDD ints: ~2.5K keys over a ~60K span) is the
-            # canonical sparse-but-small case round 2's 4x-density
-            # guard wrongly sent to the hash path.
-            if span <= max(256 * n_all, 4096) \
-                    and span + 1 <= self.MAX_DIRECT_JOIN_SLOTS:
-                join.direct = (lo, span + 1)
+            join.direct = self._direct_slots(lo, lo + span - 1, n_all)
             return
         # composite keys (q9's partsupp (ps_partkey, ps_suppkey)):
         # mixed-radix-pack the components; the span PRODUCT sizes the
@@ -3764,6 +4035,23 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             got = True
         return est if got else None
 
+    def _reads_large_dictionary(self, scan_aliases: dict,
+                                scan_cols: dict) -> bool:
+        """Does a scan of the plan read a string column whose
+        dictionary is past planparam's table length? Only then can the
+        plan hold a table worth lifting (a table is indexed by a
+        column's codes), and only then is the plan walked for one: a
+        statement is prepared on every execution, and `.scan`'s are
+        five milliseconds of host path each."""
+        from .planparam import _TABLE_MIN
+        for alias, tname in scan_aliases.items():
+            dicts = self.store.table(tname).dictionaries
+            for col in scan_cols.get(alias, ()):
+                d = dicts.get(col)
+                if d is not None and len(d.values) > _TABLE_MIN:
+                    return True
+        return False
+
     def _plan_shape_tags(self, node, scans: dict, pallas: str) -> dict:
         """The `plan` span's `joins` (hash joins in the plan) and `agg`
         (compile.aggregate_strategy of its outermost Aggregate, `none`
@@ -3778,8 +4066,11 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                     yield from nodes(c)
 
         joins, agg = 0, None
+        kinds = {"semi": 0, "anti": 0, "left": 0}
         for n in nodes(node):
             joins += isinstance(n, P.HashJoin)
+            if isinstance(n, P.HashJoin) and n.join_type in kinds:
+                kinds[n.join_type] += 1
             if agg is None and isinstance(n, P.Aggregate):
                 agg = n
         strategy = "none"
@@ -3789,7 +4080,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             strategy = aggregate_strategy(agg, rows or 0, ExecParams(
                 pallas_groupagg=pallas,
                 pallas_interpret=self._pallas_interpret()))
-        return {"joins": joins, "agg": strategy}
+        return {"joins": joins, "agg": strategy, **kinds}
 
     def _compact_frac(self, est: float) -> float:
         # 4x headroom over the uniform estimate absorbs moderate

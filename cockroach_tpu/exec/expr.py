@@ -23,6 +23,7 @@ import numpy as np
 from ..ops import kernels as K
 from ..sql.bound import (BAggRef, BBetween, BBin, BCase, BCast, BCoalesce,
                          BCol, BConst, BDictGather, BDictLookup, BDictRemap,
+                         BTableParam,
                          BExpr, BExtract, BFunc, BInList, BIsNull, BParam,
                          BUnary, BWinRef)
 from ..sql.types import Family, SQLType
@@ -66,11 +67,18 @@ def compile_expr(e: BExpr) -> CompiledExpr:
         return f_const
 
     if isinstance(e, BParam):
-        idx, pty = e.index, e.type
+        idx, pty, nullable = e.index, e.type, e.nullable
 
         def f_param(ctx):
             # runtime scalar (statement-shape plan cache): same dtype
             # and broadcast semantics as the baked f_const above
+            if nullable:
+                # a subquery's result: (value, is-not-NULL)
+                v, ok = ctx.params[idx]
+                return (jnp.broadcast_to(
+                            jnp.array(v, dtype=_np_dtype(pty)), (ctx.n,)),
+                        jnp.broadcast_to(
+                            jnp.array(ok, dtype=jnp.bool_), (ctx.n,)))
             v = jnp.array(ctx.params[idx], dtype=_np_dtype(pty))
             d = jnp.broadcast_to(v, (ctx.n,))
             return d, jnp.ones((ctx.n,), dtype=jnp.bool_)
@@ -233,6 +241,21 @@ def compile_expr(e: BExpr) -> CompiledExpr:
 
     if isinstance(e, BFunc):
         return _compile_func(e)
+
+    if isinstance(e, (BDictGather, BDictLookup)) \
+            and isinstance(e.table, BTableParam):
+        # the table of a large dictionary, lifted out of the plan
+        # (exec/planparam.py): a runtime argument, one gather
+        xf = compile_expr(e.expr)
+        tp, np_ = e.table, getattr(e, "null_table", None)
+
+        def f_table_param(ctx):
+            d, v = xf(ctx)
+            codes = jnp.clip(d, 0, tp.size - 1)
+            if np_ is not None:
+                v = v & ctx.params[np_.index][codes]
+            return ctx.params[tp.index][codes], v
+        return f_table_param
 
     if isinstance(e, BDictGather):
         xf = compile_expr(e.expr)

@@ -9,12 +9,26 @@ same parameterized q3/q6 with different dates/quantities share ONE
 ``_exec_cache`` entry instead of each paying a trace (the reference's
 plan cache keyed on the statement fingerprint, pkg/sql/plan_cache).
 
-Conservative by construction: only constants inside ``Filter.pred`` /
-``Scan.filter`` comparison spines are lifted — anything that shapes
-the compiled program stays baked and keeps the plan fingerprint
-distinct, so a shape-changing literal (LIMIT, Compact.frac derived
-from selectivity, dictionary masks, function args read at compile
-time) misses the cache instead of sharing a wrong executable.
+What is lifted, and nothing else:
+
+- literals: constants inside ``Filter.pred`` / ``Scan.filter`` /
+  ``Derived.filter`` comparison spines. Conservative by construction:
+  anything that shapes the compiled program stays baked and keeps the
+  plan fingerprint distinct, so a shape-changing literal (LIMIT,
+  Compact.frac derived from selectivity, dictionary masks, function
+  args read at compile time) misses the cache instead of sharing a
+  wrong executable;
+- the result of an uncorrelated scalar subquery that stands in such a
+  spine (``BSubqueryArg`` -> a nullable ``BParam``; its place among the
+  values holds a ``SubqueryValue`` until ``Prepared.subquery_params``
+  runs the subquery, at every dispatch). What a subquery returns never
+  shapes the program, so the plan's cache key no longer follows the
+  data. One left anywhere else is read once and written into the plan
+  (``inline_subquery_args``), as every subquery's result was before;
+- the tables of large dictionaries (``lift_tables``: what a LIKE or a
+  substring makes of every value of a column's dictionary), where the
+  engine asks for them: arguments padded to a power of two, so the
+  program knows their length and not their content.
 """
 
 from __future__ import annotations
@@ -27,7 +41,7 @@ import numpy as np
 
 from ..sql import bound as B
 from ..sql import plan as P
-from ..sql.types import Family
+from ..sql.types import Family, SQLType
 
 # Literal families whose physical scalars can ride as runtime args.
 # STRING (and ARRAY/JSON) predicates are host-pre-evaluated into
@@ -35,6 +49,42 @@ from ..sql.types import Family
 # constants often fold control flow.
 _ELIGIBLE = (Family.INT, Family.DECIMAL, Family.DATE, Family.TIMESTAMP,
              Family.FLOAT)
+
+# The scalar result of an uncorrelated expression subquery (TPC-H
+# Q22's `c_acctbal > (SELECT avg(c_acctbal) ...)`), which the binder
+# leaves unread (sql/bound.py): found in a filter it is lifted here
+# beside the literals, so it is an argument of the compiled program
+# and not a constant of it, and one program serves whatever rows the
+# subquery reads. benchmark/generators/tpch_full.py asks for this name.
+SubqueryArg = B.BSubqueryArg
+
+
+@dataclasses.dataclass(frozen=True)
+class SubqueryValue:
+    """Where a lifted SubqueryArg's value will stand among a
+    statement's runtime arguments: the pair (value, is-not-NULL) that
+    Prepared reads at each dispatch from the prepared subquery `slot`
+    (exec/session.py). Before that it holds the place."""
+    slot: int
+    type: SQLType
+
+    def pair(self, value):
+        """The argument for a physical `value`, None for NULL."""
+        dt = self.type.np_dtype
+        return (np.asarray(0 if value is None else value, dtype=dt),
+                np.bool_(value is not None))
+
+
+def param_signature(values) -> tuple:
+    """What of the runtime arguments the compiled program is traced
+    for: each one's dtype and shape, never its value."""
+    out = []
+    for v in values:
+        if isinstance(v, SubqueryValue):
+            out.append(f"{np.dtype(v.type.np_dtype)}?")
+        else:
+            out.append(f"{v.dtype}{list(v.shape) or ''}")
+    return tuple(out)
 
 # Bound on lifted literals per statement: each becomes one extra jit
 # argument; a pathological filter should fall back to text keying.
@@ -63,6 +113,13 @@ class _Lifter:
         self.values.append(v)
         return B.BParam(len(self.values) - 1, e.type)
 
+    def arg(self, e: B.BSubqueryArg):
+        if e.type.family not in _ELIGIBLE \
+                or len(self.values) >= _MAX_PARAMS:
+            return e    # the engine reads it now, into the plan
+        self.values.append(SubqueryValue(e.slot, e.type))
+        return B.BParam(len(self.values) - 1, e.type, nullable=True)
+
     def expr(self, e):
         """Rewrite the comparison spine of a predicate. Recursion is a
         whitelist — BBin/BUnary/BBetween — because other nodes read
@@ -70,6 +127,12 @@ class _Lifter:
         digits, BInList value lists, dictionary tables)."""
         if _eligible_const(e):
             return self.const(e)
+        if isinstance(e, B.BSubqueryArg):
+            return self.arg(e)
+        if isinstance(e, B.BCast) and isinstance(e.expr, B.BSubqueryArg):
+            # a subquery's result compared in another type than its own
+            x = self.arg(e.expr)
+            return e if x is e.expr else dataclasses.replace(e, expr=x)
         if isinstance(e, B.BBin):
             l, r = self.expr(e.left), self.expr(e.right)
             if l is not e.left or r is not e.right:
@@ -93,6 +156,12 @@ class _Lifter:
                 return n
             f = self.expr(n.filter)
             return n if f is n.filter else dataclasses.replace(n, filter=f)
+        if isinstance(n, P.Derived):
+            c = self.node(n.child)
+            f = self.expr(n.filter) if n.filter is not None else None
+            if c is n.child and f is n.filter:
+                return n
+            return dataclasses.replace(n, child=c, filter=f)
         if isinstance(n, P.Filter):
             c = self.node(n.child)
             p = self.expr(n.pred) if n.pred is not None else None
@@ -111,17 +180,132 @@ class _Lifter:
         return n  # unknown node: leave baked (conservative)
 
 
-def parameterize(node):
-    """Lift eligible filter literals out of ``node``.
+# tables of dictionaries past this length leave the plan; shorter ones
+# stay baked, where exec/expr.py turns them into one-hot matmuls
+_TABLE_MIN = 512
 
-    Returns ``(parameterized_node, values)`` — values is a tuple of np
-    scalars positionally matching the BParam indices — or
-    ``(node, None)`` when nothing was lifted (or too much would be)."""
+
+# what lift_tables' walk does not open: a plan is prepared on every
+# execution, and a column type alone has six fields
+_LEAVES = (str, int, float, bool, type(None), np.generic, np.ndarray,
+           SQLType, frozenset, B.BCol, B.BConst, B.BParam)
+
+
+def _rewrite(node, replace):
+    """`node` with every object `replace` answers for swapped for its
+    answer (`replace(o)` is None for one it leaves alone, and what it
+    replaces is not opened). Walks every expression of a plan by
+    dataclass field, copying only what changes."""
+    def walk(o):
+        if isinstance(o, _LEAVES):
+            return o        # most of a plan's fields: keep the walk cheap
+        new = replace(o)
+        if new is not None:
+            return new
+        if dataclasses.is_dataclass(o) and not isinstance(o, type):
+            changes = {}
+            for f in dataclasses.fields(o):
+                v = getattr(o, f.name)
+                w = walk(v)
+                if w is not v:
+                    changes[f.name] = w
+            return dataclasses.replace(o, **changes) if changes else o
+        if isinstance(o, (list, tuple)):
+            out = [walk(v) for v in o]
+            if all(a is b for a, b in zip(out, o)):
+                return o
+            return type(o)(out)
+        if isinstance(o, dict):
+            out = {k: walk(v) for k, v in o.items()}
+            if all(out[k] is o[k] for k in o):
+                return o
+            return out
+        return o
+
+    return walk(node)
+
+
+def lift_tables(node, values: list):
+    """Replace the tables of large dictionaries in `node`'s expressions
+    (BDictLookup masks, BDictGather value and null tables) by
+    BTableParams, appending each table, padded to a power of two, to
+    `values` (the runtime arguments, after the lifted scalars).
+
+    A table is what a predicate or a function makes of every value of
+    a column's dictionary: the rows' own data. Baked into the program
+    it makes the program the data's (another load of the table,
+    another program, compiled cold); as an argument it leaves a
+    program that only knows the table's power-of-two length. Every
+    expression of the plan is walked (a table may stand in a projected
+    or grouped expression, TPC-H Q22's substring of c_phone)."""
+    memo: dict = {}
+
+    def table(t):
+        t = np.asarray(t)
+        if t.ndim != 1 or t.shape[0] <= _TABLE_MIN:
+            return None
+        key = id(t)
+        if key not in memo:
+            size = 1 << (int(t.shape[0]) - 1).bit_length()
+            padded = np.zeros((size,), dtype=t.dtype)
+            padded[:t.shape[0]] = t
+            values.append(padded)
+            memo[key] = (B.BTableParam(len(values) - 1, size), t)
+        return memo[key][0]
+
+    def replace(o):
+        if not isinstance(o, (B.BDictLookup, B.BDictGather)) \
+                or isinstance(o.table, B.BTableParam):
+            return None
+        tp = table(o.table)
+        if tp is None:
+            return None
+        changes = {"expr": _rewrite(o.expr, replace), "table": tp}
+        nt = getattr(o, "null_table", None)
+        if nt is not None:
+            changes["null_table"] = table(np.asarray(nt, dtype=bool))
+        return dataclasses.replace(o, **changes)
+
+    return _rewrite(node, replace)
+
+
+def parameterize(node, tables: bool = True):
+    """Lift eligible filter literals, and the filters' SubqueryArgs,
+    out of ``node``.
+
+    Returns ``(parameterized_node, values)`` — values is a tuple
+    positionally matching the BParam indices: np scalars, and a
+    SubqueryValue where a subquery's result will stand — or
+    ``(node, None)`` when nothing was lifted. With `tables` the tables
+    of large dictionaries follow the scalars (lift_tables); a
+    distributed plan keeps them baked (its arguments are replicated
+    scalars)."""
     lf = _Lifter()
     out = lf.node(node)
-    if lf.overflow or not lf.values:
+    if lf.overflow:
+        out, lf = node, _Lifter()
+    values = list(lf.values)
+    if tables:
+        out = lift_tables(out, values)
+    if not values:
         return node, None
-    return out, tuple(lf.values)
+    return out, tuple(values)
+
+
+def inline_subquery_args(node, const_of):
+    """`node` with every SubqueryArg left in it replaced by
+    ``const_of(arg)``, a BConst: the ones no filter held, or all of
+    them where the plan is not parameterized. Returns (node, how many
+    were replaced)."""
+    found = []
+
+    def replace(o):
+        if isinstance(o, B.BSubqueryArg):
+            found.append(o)
+            return const_of(o)
+        return None
+
+    return _rewrite(node, replace), len(found)
 
 
 def plan_fingerprint(node) -> str:

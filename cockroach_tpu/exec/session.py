@@ -6,6 +6,7 @@ module's docstring for the overall execution model."""
 
 
 import datetime
+import time
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -49,6 +50,17 @@ class CompactOverflow(EngineError):
 # by one of them.
 SENTINEL_COLUMNS = ("__ht_overflow", "__sum_overflow",
                     "__topk_inexact", "__compact_overflow")
+
+
+def subquery_const(res: "Result"):
+    """The BConst a scalar subquery's decoded result stands for, in
+    its column's physical form (NULL for no row)."""
+    from ..sql.binder import Binder, BindError
+    if len(res.rows) > 1:
+        raise BindError("more than one row returned by a subquery used "
+                        "as an expression")
+    return Binder._subquery_const(
+        res.rows[0][0] if res.rows else None, res.types[0])
 
 
 @dataclass
@@ -147,6 +159,47 @@ class Prepared:
     # hash Aggregate's slots (Engine._size_hash_sorts), None otherwise:
     # what run() tells the engine if more groups were live
     prefix_key: Optional[tuple] = None
+    # the statement's uncorrelated scalar subqueries that are
+    # arguments of its program: (index into params, the subquery's own
+    # Prepared). params holds a planparam.SubqueryValue there; each
+    # dispatch runs the subquery at its own read timestamp and passes
+    # what it returned (subquery_params)
+    subqueries: tuple = ()
+    # subquery results that are constants of the plan instead, read
+    # when it was bound (engine counter exec.subquery.inlined)
+    inlined_subqueries: int = 0
+
+    # (read timestamp, subquery_params' result) of the last dispatch
+    _subquery_memo: Optional[tuple] = None
+
+    def subquery_params(self, ts: Timestamp) -> tuple:
+        """This dispatch's runtime arguments: params with every
+        subquery's place filled by what the subquery returns at `ts`,
+        the statement's own read timestamp, so both read one snapshot.
+        What was read is kept for as long as the next dispatch reads
+        at the same timestamp (AS OF SYSTEM TIME, the partitions of
+        one execution) and no longer. One `subquery` span each (tags
+        `rows`; `cache`: hit where the value was kept, else miss),
+        opened on the caller's side of `dispatch`, never beneath it."""
+        if not self.subqueries:
+            return self.params
+        tracer = self.engine.tracer
+        memo, key = self._subquery_memo, ts.to_int()
+        vals = list(self.params)
+        for i, sub in self.subqueries:
+            with tracer.span("subquery"):
+                if memo is not None and memo[0] == key:
+                    vals[i] = memo[1][i]
+                    tracer.tag(rows=int(vals[i][1]), cache="hit")
+                    continue
+                t0 = time.perf_counter()
+                res = sub.run(ts)
+                tracer.tag(rows=len(res.rows), cache="miss")
+                self.engine._m_subquery_seconds.observe(
+                    time.perf_counter() - t0)
+            vals[i] = vals[i].pair(subquery_const(res).value)
+        self._subquery_memo = (key, tuple(vals))
+        return self._subquery_memo[1]
 
     def _refresh(self) -> "Prepared":
         cur = tuple((t, self.engine.store.table(t).generation)
@@ -166,6 +219,9 @@ class Prepared:
         self.spill, self.spill_cols = p.spill, p.spill_cols
         self.joinfilter = p.joinfilter
         self.params = p.params
+        self.subqueries = p.subqueries
+        self.inlined_subqueries = p.inlined_subqueries
+        self._subquery_memo = None
         self.prefix_key = p.prefix_key
         self.as_of = p.as_of  # keep guard + execution timestamps
         # consistent (interval forms re-resolve on refresh)
@@ -192,13 +248,24 @@ class Prepared:
         return tuple(out)
 
     def dispatch(self, read_ts: Optional[Timestamp] = None,
-                 nparts: int = 1, pid: int = 0) -> ColumnBatch:
+                 nparts: int = 1, pid: int = 0,
+                 params: Optional[tuple] = None) -> ColumnBatch:
+        """`params`: subquery_params(read_ts) where the caller has
+        read them (run(), outside its `dispatch` span); read here
+        otherwise."""
         p = self._refresh()
         if p is not self:
             self._adopt(p)
+            params = None
         ts = read_ts or self.as_of or \
             self.engine._read_ts(self.session)
         rts = ts.to_int()
+        if params is None:
+            params = self.subquery_params(ts)
+        if self.subqueries or self.inlined_subqueries:
+            self.engine._m_subquery["args"].inc(len(self.subqueries))
+            self.engine._m_subquery["inlined"].inc(
+                self.inlined_subqueries)
         if self.spill is not None:
             if self.spill.kind != "join":
                 raise EngineError(
@@ -215,11 +282,11 @@ class Prepared:
             # transfers of their own (the read timestamp, the two
             # partition scalars, each stripped literal)
             PROGRAMS.inc()
-            H2D_CALLS.inc(3 + len(self.params))
+            H2D_CALLS.inc(3 + len(params))
             H2D_BYTES.inc(16 + sum(int(getattr(v, "nbytes", 8))
-                                   for v in self.params))
+                                   for v in params))
             out = self.jfn(self.scans, tsv, np.int32(nparts),
-                           np.int32(pid), self.params)
+                           np.int32(pid), params)
             stats = getattr(self.meta, "join_stats", None)
             # after the call: a first dispatch traces inside it
             if stats is not None and stats.totals[0]:
@@ -326,9 +393,16 @@ class Prepared:
             with tracer.span("dispatch"):
                 return run_spill_sort(self.engine, self, ts.to_int())
         from ..parallel.distagg import CollectiveFault
+        params = None
+        if self.subqueries:
+            # the subqueries run beside `dispatch`, not beneath it,
+            # at the timestamp the statement itself will read at
+            read_ts = read_ts or self.as_of or \
+                self.engine._read_ts(self.session)
+            params = self.subquery_params(read_ts)
         try:
             with tracer.span("dispatch"):
-                out = self.dispatch(read_ts)
+                out = self.dispatch(read_ts, params=params)
             with tracer.span("materialize"):
                 return self.engine._materialize(out, self.meta)
         except CollectiveFault:

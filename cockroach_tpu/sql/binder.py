@@ -30,7 +30,7 @@ from . import datum as dtm
 from .bound import (BAggRef, BBetween, BBin, BCase, BCast, BCoalesce, BCol,
                     BConst, BDictGather, BDictLookup, BDictRemap, BExpr,
                     BExtract, BFunc, BInList, BIsNull, BoundAgg,
-                    BoundWindow, BUnary, BWinRef)
+                    BoundWindow, BSubqueryArg, BUnary, BWinRef)
 from .types import (BOOL, DATE, FLOAT8, INT8, INTERVAL, STRING, TIMESTAMP,
                     Family, SQLType, common_numeric_type)
 
@@ -54,11 +54,18 @@ class ColumnBinding:
 class Scope:
     """In-scope tables: alias -> {col -> ColumnBinding}."""
     tables: dict[str, dict[str, ColumnBinding]] = field(default_factory=dict)
+    # aliases whose columns resolve only when qualified and never
+    # expand under `*`: the build side of a SEMI / ANTI join, which
+    # tests rows and contributes no column
+    hidden: set = field(default_factory=set)
 
-    def add_table(self, alias: str, cols: dict[str, ColumnBinding]):
+    def add_table(self, alias: str, cols: dict[str, ColumnBinding],
+                  hidden: bool = False):
         if alias in self.tables:
             raise BindError(f"duplicate table alias {alias!r}")
         self.tables[alias] = cols
+        if hidden:
+            self.hidden.add(alias)
 
     def resolve(self, name: str, qualifier: Optional[str]) -> ColumnBinding:
         if qualifier is not None:
@@ -69,7 +76,8 @@ class Scope:
             if b is None:
                 raise BindError(f"column {name!r} not in {qualifier!r}")
             return b
-        hits = [t[name] for t in self.tables.values() if name in t]
+        hits = [t[name] for a, t in self.tables.items()
+                if name in t and a not in self.hidden]
         if not hits:
             raise BindError(f"unknown column {name!r}")
         if len(hits) > 1:
@@ -78,8 +86,9 @@ class Scope:
 
     def all_columns(self) -> list[ColumnBinding]:
         out = []
-        for t in self.tables.values():
-            out.extend(t.values())
+        for a, t in self.tables.items():
+            if a not in self.hidden:
+                out.extend(t.values())
         return out
 
 
@@ -170,7 +179,7 @@ class Binder:
     def __init__(self, scope: Scope, subquery_eval=None,
                  now_micros: Optional[int] = None,
                  sequence_ops=None, volatile_fold_ok: bool = True,
-                 dict_folds: bool = True):
+                 dict_folds: bool = True, subquery_arg=None):
         self.scope = scope
         # dict_folds=False: a string literal absent from the column's
         # dictionary binds to an impossible code (-1) compare instead
@@ -187,6 +196,16 @@ class Binder:
         # runs planTop.subqueryPlans first, sql/subquery.go); None when
         # the caller cannot execute (pure-binder contexts)
         self.subquery_eval = subquery_eval
+        # subquery_arg(ast.Select) -> (slot, SQLType) | None: prepares
+        # an uncorrelated scalar subquery as a statement of its own,
+        # to be run at every dispatch (BSubqueryArg); None where the
+        # caller keeps no such list, or for a shape it cannot prepare,
+        # and the subquery is then executed here and now
+        self.subquery_arg = subquery_arg
+        # expression subqueries this binder executed, whose results
+        # are constants of what it bound (the planner sums them into
+        # OutputMeta.subqueries)
+        self.subqueries_run = 0
         # statement timestamp in unix micros for now()/current_date
         self.now_micros = now_micros
         # sequence_ops(fn, seq_name, arg) -> int: volatile sequence
@@ -268,6 +287,14 @@ class Binder:
                 raise BindError("SUBSTRING binding failed")
             return out
         if isinstance(e, ast.Subquery):
+            if self.subquery_arg is not None:
+                try:
+                    arg = self.subquery_arg(e.select)
+                except BindError as err:
+                    raise BindError("correlated subqueries not "
+                                    f"supported ({err})") from err
+                if arg is not None:
+                    return BSubqueryArg(*arg)
             rows, types = self._run_subquery(e.select)
             if len(types) != 1:
                 raise BindError("scalar subquery must return one column")
@@ -303,6 +330,7 @@ class Binder:
     def _run_subquery(self, sel: ast.Select, limit_one: bool = False):
         if self.subquery_eval is None:
             raise BindError("subqueries not supported in this context")
+        self.subqueries_run += 1
         try:
             return self.subquery_eval(sel, limit_one)
         except BindError as e:
@@ -311,7 +339,8 @@ class Binder:
             raise BindError(
                 f"correlated subqueries not supported ({e})") from e
 
-    def _subquery_const(self, val, ty: SQLType) -> BConst:
+    @staticmethod
+    def _subquery_const(val, ty: SQLType) -> BConst:
         """Re-encode a decoded subquery result value to physical form."""
         if val is None:
             return BConst(None, SQLType.unknown())
@@ -749,8 +778,9 @@ class Binder:
         d = self._dict_of(col)
         if d is None:
             raise BindError("LIKE on non-dictionary column")
-        table = np.fromiter((rx.match(v) is not None for v in d.values),
-                            dtype=bool, count=len(d.values))
+        table = d.derived(("like", pat.value), lambda values: np.fromiter(
+            (rx.match(v) is not None for v in values),
+            dtype=bool, count=len(values)))
         return BDictLookup(col, table, BOOL)
 
     # -- datum types (ARRAY / JSONB) over dictionaries ------------------------

@@ -27,6 +27,22 @@ class BConst(BExpr):
 
 
 @dataclass
+class BSubqueryArg(BExpr):
+    """The scalar an uncorrelated expression subquery will return: not
+    read yet when the statement is bound and planned, so nothing that
+    plans can take it for a constant. `slot` names the subquery's own
+    prepared statement in the list the engine keeps for this plan.
+    Where exec/planparam.py finds it in a filter it becomes a nullable
+    BParam, and the subquery runs at every dispatch, at the dispatch's
+    read timestamp: the compiled program is the same whatever rows the
+    subquery reads. Anywhere else the engine reads it once, when the
+    statement is prepared, and writes the value into the plan as a
+    BConst (engine counters exec.subquery.{args,inlined})."""
+    slot: int
+    type: SQLType = None
+
+
+@dataclass
 class BParam(BExpr):
     """Runtime statement parameter i — a literal the statement-shape
     plan cache (exec/planparam.py) stripped out of the plan so
@@ -34,9 +50,26 @@ class BParam(BExpr):
     broadcast of ``ctx.params[index]`` (exec/expr.py); the value rides
     the dispatch as a replicated runtime scalar instead of baking into
     the trace. ``repr`` deliberately shows index+type only, so the
-    parameterized plan's fingerprint is literal-independent."""
+    parameterized plan's fingerprint is literal-independent. A
+    `nullable` one (a subquery's result) rides as a pair: the value
+    and whether it is not NULL."""
     index: int
     type: SQLType = None
+    nullable: bool = False
+
+
+@dataclass
+class BTableParam:
+    """Where a dictionary table stood in a BDictLookup / BDictGather
+    (its `table` or `null_table`): runtime parameter `index`, an array
+    of `size` entries (the table padded to a power of two with entries
+    no code reaches). exec/planparam.py lifts the tables of large
+    dictionaries out of the plan this way: what a LIKE or a substring
+    makes of a 1.5 M-value dictionary is data, so it is an argument of
+    the compiled program and not a constant in it, and the program is
+    the same for every load of the table."""
+    index: int
+    size: int
 
 
 @dataclass

@@ -707,7 +707,8 @@ def _bind_string_builtin(binder, name: str, args: list) -> BExpr | None:
             return BConst(None, STRING)  # strict: NULL arg -> NULL
         fn = _STR_TO_STR[name]
         return _dict_transform(binder, name, x,
-                               lambda s: fn(s, *cvals))
+                               lambda s: fn(s, *cvals),
+                               cache_key=(name, tuple(cvals)))
     if name in _STR_TO_VAL:
         fn, ty = _STR_TO_VAL[name]
         x, consts = args[0], args[1:]
@@ -733,9 +734,11 @@ def _bind_string_builtin(binder, name: str, args: list) -> BExpr | None:
     return None
 
 
-def _dict_transform(binder, name, x, fn) -> BExpr:
+def _dict_transform(binder, name, x, fn, cache_key=None) -> BExpr:
     """string->string builtin: build an output dictionary by mapping the
-    input dictionary through fn; the device op is a code remap gather."""
+    input dictionary through fn; the device op is a code remap gather.
+    With `cache_key` (the builtin and its constant arguments) the map
+    is kept on the input dictionary while that does not grow."""
     from ..storage.columnstore import Dictionary
     if isinstance(x, BConst):
         if x.value is None:
@@ -750,10 +753,14 @@ def _dict_transform(binder, name, x, fn) -> BExpr:
     d = binder._dict_of(x)
     if d is None:
         raise BuiltinError(f"{name} on non-dictionary column")
-    out = Dictionary()
+    def build(values):
+        out = Dictionary()
+        return np.fromiter((out.encode(fn(v)) for v in values),
+                           dtype=np.int64, count=len(values)), out
+
     try:
-        codes = np.fromiter((out.encode(fn(v)) for v in d.values),
-                            dtype=np.int64, count=len(d.values))
+        codes, out = (build(d.values) if cache_key is None
+                      else d.derived(cache_key, build))
     except re.error as exc:
         # user-supplied malformed regexp (regexp_replace): a clean
         # bind error, not a traceback mid-dictionary-map

@@ -39,7 +39,12 @@ from dataclasses import replace
 
 from . import ast
 
-_counter = itertools.count()
+# Names of what one decorrelate_* call adds to a statement number from
+# 0 in that call (an itertools.count it makes and hands down): the same
+# statement text unnests to the same aliases every time it is prepared,
+# so its plan, and with it the compiled program's cache key, does not
+# change from one execution to the next (a process-wide count made
+# every execution a new program).
 
 
 def _conjuncts(e):
@@ -167,7 +172,8 @@ def _walk_subqueries(e, visit):
                     _walk_subqueries(x, visit)
 
 
-def decorrelate_scalar(sel: ast.Select, columns_of) -> ast.Select:
+def decorrelate_scalar(sel: ast.Select, columns_of,
+                       applied=None) -> ast.Select:
     """Rewrite correlated scalar subqueries in sel's SELECT items and
     WHERE into grouped LEFT JOINs (TPC-H q2/q17/q20/q22 shapes):
 
@@ -201,14 +207,18 @@ def decorrelate_scalar(sel: ast.Select, columns_of) -> ast.Select:
 
     sel = copy.deepcopy(sel)
     new_joins = []
+    names = itertools.count()
 
     def visit(sub, setter):
-        out = _rewrite_scalar(sub.select, outer_aliases, columns_of)
+        out = _rewrite_scalar(sub.select, outer_aliases, columns_of,
+                              names)
         if out is None:
             return
         join, repl = out
         new_joins.append(join)
         setter(repl)
+        if applied is not None:
+            applied.append("scalar")
 
     for item in sel.items:
         _walk_subqueries(item, visit)
@@ -220,7 +230,8 @@ def decorrelate_scalar(sel: ast.Select, columns_of) -> ast.Select:
     return sel
 
 
-def _rewrite_scalar(sub: ast.Select, outer_aliases: set, columns_of):
+def _rewrite_scalar(sub: ast.Select, outer_aliases: set, columns_of,
+                    names):
     """One correlated scalar subquery -> (JoinClause, replacement
     expr), or None. The subquery may itself join several tables
     (TPC-H q2's min-supplycost over partsupp x supplier x nation x
@@ -249,8 +260,10 @@ def _rewrite_scalar(sub: ast.Select, outer_aliases: set, columns_of):
             return None
         inner_aliases.add(j.table.alias or j.table.name)
         inner_cols |= cols
-    if inner_aliases & outer_aliases:
-        return None
+    # an inner alias that repeats an outer one (q17 as the spec prints
+    # it: lineitem inside and out) shadows it, as SQL scoping says:
+    # _side resolves such a reference to the inner table, and the
+    # derived select is a scope of its own
     for j in sub.joins:
         if j.on is not None and _side(j.on, inner_aliases, inner_cols,
                                       outer_aliases) != "inner":
@@ -281,7 +294,7 @@ def _rewrite_scalar(sub: ast.Select, outer_aliases: set, columns_of):
     if not eq_corr:
         return None  # uncorrelated: the binder inlines it already
 
-    dn = f"__sc{next(_counter)}"
+    dn = f"__sc{next(names)}"
     items = []
     group_by = []
     on_parts = []
@@ -318,7 +331,8 @@ def _match_exists(c):
 
 
 def decorrelate_exists(sel: ast.Select, columns_of,
-                       is_string_col=None) -> ast.Select:
+                       is_string_col=None, join_ok=None,
+                       applied=None) -> ast.Select:
     """Rewrite rewritable (NOT) EXISTS conjuncts of sel.where;
     non-rewritable ones are left alone (and fail later with the
     existing 'correlated subqueries not supported' error).
@@ -330,6 +344,7 @@ def decorrelate_exists(sel: ast.Select, columns_of,
     across tables)."""
     if sel.where is None or sel.table is None:
         return sel
+    names = itertools.count()
     outer_aliases = set()
     if sel.table is not None:
         outer_aliases.add(sel.table.alias or sel.table.name)
@@ -344,13 +359,17 @@ def decorrelate_exists(sel: ast.Select, columns_of,
         rewritten = None
         if ex is not None and ex.select is not None:
             rewritten = _rewrite_one(ex.select, negated, outer_aliases,
-                                     columns_of, is_string_col)
+                                     columns_of, is_string_col, join_ok,
+                                     names)
         if rewritten is None:
             new_conjs.append(c)
             continue
         join, pred = rewritten
         new_joins.append(join)
-        new_conjs.append(pred)
+        if pred is not None:
+            new_conjs.append(pred)
+        if applied is not None:
+            applied.append("exists")
         changed = True
     if not changed:
         return sel
@@ -358,8 +377,50 @@ def decorrelate_exists(sel: ast.Select, columns_of,
                    joins=list(sel.joins) + new_joins)
 
 
+def _qualify(e, alias: str, inner_cols: set, new_alias: str):
+    """A copy of e with every reference to the inner table (qualified
+    by `alias`, or bare and one of its columns) qualified by
+    `new_alias`. e holds no subquery (_side saw none)."""
+    import copy
+    import dataclasses
+    e = copy.deepcopy(e)
+
+    def walk(x):
+        if isinstance(x, ast.ColumnRef):
+            if x.table == alias or (x.table is None
+                                    and x.name in inner_cols):
+                x.table = new_alias
+            return
+        if isinstance(x, (list, tuple)):
+            for v in x:
+                walk(v)
+            return
+        if dataclasses.is_dataclass(x) and not isinstance(x, type):
+            for f in dataclasses.fields(x):
+                v = getattr(x, f.name)
+                if isinstance(v, (ast.Expr, list, tuple)):
+                    walk(v)
+    walk(e)
+    return e
+
+
+def _semi_join(table: str, alias: str, inner_cols: set, eq_corr: list,
+               residual: list, negated: bool, names) -> ast.JoinClause:
+    """JOIN <table> of type semi / anti ON <inner col = outer expr ...>
+    AND <the subquery's own conjuncts>, the inner references qualified
+    so that they cannot be taken for the outer tables' columns."""
+    new_alias = f"__semi{next(names)}_{alias}"
+    on = [ast.BinOp("=", ast.ColumnRef(icol.name, new_alias), oexpr)
+          for icol, oexpr in eq_corr]
+    on += [_qualify(p, alias, inner_cols, new_alias) for p in residual]
+    return ast.JoinClause(table=ast.TableRef(table, alias=new_alias),
+                          join_type="anti" if negated else "semi",
+                          on=_and_all(on))
+
+
 def _rewrite_one(sub: ast.Select, negated: bool, outer_aliases: set,
-                 columns_of, is_string_col=None):
+                 columns_of, is_string_col=None, join_ok=None,
+                 names=None):
     """One EXISTS subquery -> (JoinClause, replacement predicate),
     or None if the shape is not rewritable."""
     if sub.table is None or sub.table.subquery is not None or \
@@ -368,7 +429,7 @@ def _rewrite_one(sub: ast.Select, negated: bool, outer_aliases: set,
         return None
     inner_alias = sub.table.alias or sub.table.name
     inner_cols = columns_of(sub.table.name)
-    if inner_cols is None or inner_alias in outer_aliases:
+    if inner_cols is None:
         return None
 
     eq_corr = []    # (inner ColumnRef, outer expr)
@@ -399,7 +460,16 @@ def _rewrite_one(sub: ast.Select, negated: bool, outer_aliases: set,
             is_string_col(sub.table.name, neq_corr[0][0].name):
         return None
 
-    dn = f"__exists{next(_counter)}"
+    if not neq_corr and join_ok is not None and join_ok(
+            sub.table.name, inner_alias):
+        # equality correlations alone: the subquery's table joins in
+        # as a SEMI (EXISTS) or ANTI (NOT EXISTS) build side, its own
+        # conjuncts the build's filter. No aggregate and no second
+        # row a match: the join keeps or drops the outer row
+        return _semi_join(sub.table.name, inner_alias, inner_cols,
+                          eq_corr, residual, negated, names), None
+
+    dn = f"__exists{next(names)}"
     items = []
     group_by = []
     on_parts = []
@@ -451,3 +521,99 @@ def _rewrite_one(sub: ast.Select, negated: bool, outer_aliases: set,
     all_same = ast.BinOp("and", ast.BinOp("=", mn, s_out),
                          ast.BinOp("=", mx, s_out))
     return join, ast.BinOp("or", no_match, all_same)
+
+
+def eager_count(sel: ast.Select, columns_of) -> ast.Select:
+    """Push a count below an outer join (eager aggregation, Yan and
+    Larson): TPC-H Q13's
+
+        SELECT t.g, count(b.c) FROM t LEFT JOIN b
+               ON t.k = b.k AND <b's own conjuncts> GROUP BY t.g
+
+    becomes t LEFT JOIN (SELECT k AS __k0, count(c) AS __c0 FROM b
+    WHERE <b's conjuncts> GROUP BY k) d ON d.__k0 = t.k, with the
+    count rewritten to coalesce(sum(d.__c0), 0). Each row of t then
+    meets at most one row of d: no duplicate-keyed build side, so no
+    expansion of the probe by the largest number of b rows a key has,
+    a number measured from the data that made the compiled program
+    the data's. The sum over the groups of t.g is the same count
+    whether or not t.g is unique. Returns sel itself where the shape
+    is another."""
+    if sel.table is None or sel.table.subquery is not None \
+            or len(sel.joins) != 1 or sel.where is not None \
+            or sel.having is not None or sel.distinct or sel.ctes \
+            or not sel.group_by:
+        return sel
+    j = sel.joins[0]
+    if j.join_type != "left" or j.table.subquery is not None \
+            or j.on is None:
+        return sel
+    t_alias = sel.table.alias or sel.table.name
+    b_alias = j.table.alias or j.table.name
+    t_cols, b_cols = columns_of(sel.table.name), columns_of(j.table.name)
+    if t_cols is None or b_cols is None or t_alias == b_alias \
+            or set(t_cols) & set(b_cols):
+        return sel      # bare names must tell the two tables apart
+
+    def side(e):
+        return _side(e, b_alias, set(b_cols), {t_alias})
+
+    if any(not isinstance(g, ast.ColumnRef) or side(g) != "outer"
+           for g in sel.group_by):
+        return sel
+    counts = []
+    for it in sel.items:
+        e = it.expr
+        if isinstance(e, ast.FuncCall) and e.name == "count" \
+                and not e.star and not getattr(e, "distinct", False) \
+                and len(e.args) == 1 \
+                and isinstance(e.args[0], ast.ColumnRef) \
+                and side(e.args[0]) == "inner":
+            counts.append(it)
+        elif it.star or not isinstance(e, ast.ColumnRef) \
+                or side(e) != "outer":
+            return sel
+    if not counts:
+        return sel
+    keys, residual = [], []
+    for p in _conjuncts(j.on):
+        if side(p) == "inner":
+            residual.append(p)
+            continue
+        if isinstance(p, ast.BinOp) and p.op == "=":
+            l, r = side(p.left), side(p.right)
+            if l == "inner" and r == "outer" \
+                    and isinstance(p.left, ast.ColumnRef):
+                keys.append((p.left, p.right))
+                continue
+            if r == "inner" and l == "outer" \
+                    and isinstance(p.right, ast.ColumnRef):
+                keys.append((p.right, p.left))
+                continue
+        return sel
+    if not keys:
+        return sel
+    dn = "__eager0"
+    items, group_by, on = [], [], []
+    for i, (bcol, texpr) in enumerate(keys):
+        items.append(ast.SelectItem(bcol, alias=f"__k{i}"))
+        group_by.append(bcol)
+        on.append(ast.BinOp("=", ast.ColumnRef(f"__k{i}", dn), texpr))
+    new_items = []
+    for it in sel.items:
+        if not any(it is c for c in counts):
+            new_items.append(it)
+            continue
+        name = f"__c{len(items) - len(keys)}"
+        items.append(ast.SelectItem(it.expr, alias=name))
+        new_items.append(ast.SelectItem(
+            ast.FuncCall("coalesce", [
+                ast.FuncCall("sum", [ast.ColumnRef(name, dn)]),
+                ast.Literal(0)]),
+            alias=it.alias or "count"))
+    derived = ast.Select(items=items, table=j.table,
+                         where=_and_all(residual), group_by=group_by)
+    join = ast.JoinClause(
+        table=ast.TableRef(dn, alias=dn, subquery=derived),
+        join_type="left", on=_and_all(on))
+    return replace(sel, items=new_items, joins=[join])

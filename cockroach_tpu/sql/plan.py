@@ -40,6 +40,27 @@ class Scan(PlanNode):
 
 
 @dataclass
+class Derived(PlanNode):
+    """A derived table (FROM (SELECT ...) AS alias, or the grouped
+    sub-select a correlated subquery unnests into) planned in place:
+    the sub-select's plan is `child`, and this node is to the outer
+    plan what a Scan is, the alias's source of columns. Its output
+    renames the child's columns to the alias's batch names; `filter`
+    and `computed` are the outer planner's pushed conjuncts and
+    computed join keys, as on a Scan. Nothing of it is stored, so
+    nothing about it is measured from data: a program with a Derived
+    depends on the base tables' statistics alone."""
+    child: PlanNode
+    alias: str
+    # batch column name ("alias.col") -> the child's output name
+    columns: dict[str, str] = field(default_factory=dict)
+    filter: Optional[BExpr] = None
+    computed: list[tuple[str, BExpr]] = field(default_factory=list)
+    # for messages and the catalog's misses; never a stored table
+    table: str = ""
+
+
+@dataclass
 class Filter(PlanNode):
     child: PlanNode
     pred: BExpr = None
@@ -157,6 +178,10 @@ class OutputMeta:
     # exec/compile.py JoinStats of the compiled plan, kept beside the
     # executable in the plan cache: what exec.join.* counts a dispatch
     join_stats: object = None
+    # expression subqueries executed while the statement was bound,
+    # whose results are constants of the plan (Binder.subqueries_run,
+    # derived tables' bodies included)
+    subqueries: int = 0
 
 
 def plan_tree_repr(node: PlanNode, indent: int = 0,
@@ -202,6 +227,9 @@ def plan_tree_repr(node: PlanNode, indent: int = 0,
     if isinstance(node, Scan):
         f = f" filter={node.filter!r}" if node.filter is not None else ""
         return f"{pad}Scan {node.table} as {node.alias}{f}{ann()}\n"
+    if isinstance(node, Derived):
+        f = f" filter={node.filter!r}" if node.filter is not None else ""
+        return f"{pad}Derived as {node.alias}{f}{ann()}\n" + child(node.child)
     if isinstance(node, Filter):
         return f"{pad}Filter {node.pred!r}{ann()}\n" + child(node.child)
     if isinstance(node, HashJoin):
@@ -254,7 +282,7 @@ def _prune_impl(root: PlanNode):
     needed: set[str] = set()
 
     def collect(n: PlanNode):
-        if isinstance(n, Scan):
+        if isinstance(n, (Scan, Derived)):
             if n.filter is not None:
                 needed.update(referenced_columns(n.filter))
             for _, e in n.computed:

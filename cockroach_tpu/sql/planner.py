@@ -30,6 +30,14 @@ class PlanError(Exception):
     pass
 
 
+class NotInPlace(PlanError):
+    """A derived table that cannot stand in the outer plan where a Scan
+    would (plan.Derived): the statement is right, and the engine
+    materializes the table instead (Engine._exec_with_temps). Raised
+    for nothing else, so that a user's plan or bind error is never
+    taken for it."""
+
+
 class CatalogView:
     """What the planner needs from the catalog: schema + dictionaries
     + table statistics (exact row counts; ANALYZE-computed distincts
@@ -87,6 +95,44 @@ def and_all(conjuncts: list[BExpr]) -> BExpr:
     return out
 
 
+def _prefix_aliases(sel: ast.Select, prefix: str) -> ast.Select:
+    """A copy of a derived table's SELECT whose own FROM aliases start
+    with `prefix`, references to them requalified. Bare references
+    need nothing: they resolve in the sub-select's own scope. Nested
+    SELECTs (a derived table's body, an expression subquery) are
+    scopes of their own and are left as they are."""
+    import copy
+    import dataclasses
+    sel = copy.deepcopy(sel)
+    refs = ([sel.table] if sel.table is not None else []) \
+        + [j.table for j in sel.joins]
+    renamed = {}
+    for r in refs:
+        old = r.alias or r.name
+        renamed[old] = prefix + old
+        r.alias = prefix + old
+
+    def walk(x):
+        if isinstance(x, ast.ColumnRef):
+            if x.table in renamed:
+                x.table = renamed[x.table]
+            return
+        if isinstance(x, ast.Select):
+            return
+        if isinstance(x, (list, tuple)):
+            for v in x:
+                walk(v)
+            return
+        if dataclasses.is_dataclass(x) and not isinstance(x, type):
+            for f in dataclasses.fields(x):
+                walk(getattr(x, f.name))
+
+    for f in dataclasses.fields(sel):
+        if f.name not in ("table", "ctes"):
+            walk(getattr(sel, f.name))
+    return sel
+
+
 class Planner:
     # tables beyond this use the greedy orderer (2^n memo groups)
     MEMO_MAX_TABLES = 12
@@ -95,7 +141,7 @@ class Planner:
                  now_micros=None, sequence_ops=None,
                  use_memo: bool = True, volatile_fold_ok: bool = True,
                  dict_folds: bool = True, rules: bool = True,
-                 trace=None):
+                 trace=None, subquery_arg=None):
         self.catalog = catalog
         # False: dictionary-content-dependent constant folds disabled
         # so plan structure is shard-independent (distsql/shuffle.py)
@@ -113,6 +159,7 @@ class Planner:
         # timestamp for now()/current_date + sequence builtins
         # (binder.py)
         self.subquery_eval = subquery_eval
+        self.subquery_arg = subquery_arg
         self.now_micros = now_micros
         self.sequence_ops = sequence_ops
         self.use_memo = use_memo
@@ -401,7 +448,45 @@ class Planner:
         scans: dict[str, plan.Scan] = {}
         join_specs: list[ast.JoinClause] = list(sel.joins)
 
-        def add_table(tref: ast.TableRef):
+        derived_subqueries: list = []
+
+        def add_derived(tref: ast.TableRef, hidden: bool):
+            """FROM (SELECT ...) AS alias, planned in place: the
+            sub-select's plan stands where a Scan would, under the
+            alias's batch names. Its tables' aliases are prefixed with
+            this alias, so that no two scans of one program share one."""
+            alias = tref.alias or tref.name
+            if not isinstance(tref.subquery, ast.Select) \
+                    or tref.subquery.ctes:
+                raise NotInPlace(
+                    f"derived table {alias!r} is a set operation or "
+                    "has CTEs: it is materialized, not planned in place")
+            sub = Planner(self.catalog, subquery_eval=self.subquery_eval,
+                          now_micros=self.now_micros,
+                          sequence_ops=self.sequence_ops,
+                          use_memo=self.use_memo,
+                          volatile_fold_ok=self.volatile_fold_ok,
+                          dict_folds=self.dict_folds, rules=self.rules_on,
+                          trace=self._trace,
+                          subquery_arg=self.subquery_arg)
+            subnode, submeta = sub.plan_select(
+                _prefix_aliases(tref.subquery, alias + "$"))
+            derived_subqueries.append(submeta.subqueries)
+            cols, colmap = {}, {}
+            for name, ty in zip(submeta.names, submeta.types):
+                bname = f"{alias}.{name}"
+                cols[name] = ColumnBinding(
+                    bname, ty, submeta.dictionaries.get(name))
+                colmap[bname] = name
+            scope.add_table(alias, cols, hidden=hidden)
+            tname = f"(derived {alias})"
+            tables.append((alias, tname))
+            scans[alias] = plan.Derived(subnode, alias, colmap,
+                                        table=tname)
+
+        def add_table(tref: ast.TableRef, hidden: bool = False):
+            if tref.subquery is not None:
+                return add_derived(tref, hidden)
             alias = tref.alias or tref.name
             schema = self.catalog.schema(tref.name)
             dicts = self.catalog.dictionaries.get(tref.name, {})
@@ -411,19 +496,20 @@ class Planner:
                 bname = f"{alias}.{c.name}"
                 cols[c.name] = ColumnBinding(bname, c.type, dicts.get(c.name))
                 colmap[bname] = c.name
-            scope.add_table(alias, cols)
+            scope.add_table(alias, cols, hidden=hidden)
             tables.append((alias, tref.name))
             scans[alias] = plan.Scan(tref.name, alias, colmap)
 
         add_table(sel.table)
         for j in join_specs:
-            add_table(j.table)
+            add_table(j.table, hidden=j.join_type in ("semi", "anti"))
 
         binder = Binder(scope, subquery_eval=self.subquery_eval,
                         now_micros=self.now_micros,
                         sequence_ops=self.sequence_ops,
                         volatile_fold_ok=self.volatile_fold_ok,
-                        dict_folds=self.dict_folds)
+                        dict_folds=self.dict_folds,
+                        subquery_arg=self.subquery_arg)
 
         # ---- gather predicates ---------------------------------------------
         conjuncts: list[BExpr] = []
@@ -527,7 +613,8 @@ class Planner:
         if ordered and not all(jt in ("inner", "cross")
                                for _, jt, _ in ordered):
             inners = [e for e in ordered if e[1] in ("inner", "cross")]
-            lefts = [e for e in ordered if e[1] == "left"]
+            lefts = [e for e in ordered
+                     if e[1] in ("left", "semi", "anti")]
             if len(inners) + len(lefts) == len(ordered) and lefts:
                 inner_aliases = {tables[0][0]} | {e[0] for e in inners}
                 left_aliases = {e[0] for e in lefts}
@@ -635,7 +722,8 @@ class Planner:
         for alias, jt, on_conj in ordered:
             # LEFT JOIN must not consume WHERE conjuncts as join keys —
             # ON and WHERE have different outer-join semantics
-            pool = on_conj + (remaining_conjuncts if jt != "left" else [])
+            outer_like = jt in ("left", "semi", "anti")
+            pool = on_conj + ([] if outer_like else remaining_conjuncts)
             lk, rk, used = extract_equi_keys(pool, joined, alias)
             if lk and jt == "cross":
                 # comma-join with equality predicates in WHERE -> hash join
@@ -650,22 +738,23 @@ class Planner:
             residual = [c for c in on_conj if c not in used]
             build = scans[alias]
             build_local = []
-            if jt == "left":
+            if outer_like:
                 # residual ON conjuncts on the build side filter which
-                # rows can MATCH (NULL-extension still happens) — push
+                # rows can MATCH (NULL-extension still happens; a SEMI
+                # or ANTI join tests the rows that are left) — push
                 # into the build scan; cross-side residuals would need
                 # per-pair evaluation inside the join
                 both_sided = [c for c in residual if tables_of(c) != {alias}]
                 if both_sided:
                     raise PlanError(
-                        "LEFT JOIN ON conditions across both sides "
-                        "(beyond equality keys) not supported yet")
+                        f"{jt.upper()} JOIN ON conditions across both "
+                        "sides (beyond equality keys) not supported yet")
                 build_local = residual
                 residual = []
             # build-side single-table WHERE conjuncts push into the build
             # scan (for LEFT joins, WHERE stays above the join: filtering
             # the build scan would wrongly null-extend filtered matches)
-            if jt != "left":
+            if not outer_like:
                 wl = [c for c in remaining_conjuncts
                       if tables_of(c) == {alias}]
                 for c in wl:
@@ -678,6 +767,8 @@ class Planner:
             payload = [b.batch_name for b in scope.tables[alias].values()]
             pack = [b.batch_name for b in scope.tables[alias].values()
                     if b.dictionary is not None]
+            if jt in ("semi", "anti"):
+                payload, pack = [], []   # tests rows, carries none
             node = plan.HashJoin(node, build, lk, rk, payload, jt,
                                  pack_payload=pack)
             joined.add(alias)
@@ -712,6 +803,8 @@ class Planner:
         for it in sel.items:
             if it.star:
                 for alias, _ in tables:
+                    if alias in scope.hidden:
+                        continue
                     for colname, b in scope.tables[alias].items():
                         items.append((uniq(colname),
                                       ast.ColumnRef(colname, alias)))
@@ -892,6 +985,7 @@ class Planner:
         meta.rule_trace = trace
         meta.access_paths = dict(self.access_paths)
         meta.memo = self.last_memo
+        meta.subqueries = binder.subqueries_run + sum(derived_subqueries)
         return node, meta
 
     MAX_INT_GROUP_SPAN = 1 << 12

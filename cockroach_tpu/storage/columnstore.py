@@ -67,6 +67,23 @@ class Dictionary:
             self._codes = codes
         return self._codes
 
+    def derived(self, key, build):
+        """What `build(values)` makes of this dictionary's values (a
+        LIKE mask, a substring's code map), kept for as long as no
+        value is added: a dictionary only grows, so its length says
+        whether what was made still covers it. A statement that is
+        prepared on every execution would otherwise walk a 1.5 M-entry
+        dictionary (TPC-H Q13's o_comment) every time."""
+        cache = self.__dict__.setdefault("_derived", {})
+        hit = cache.get(key)
+        if hit is not None and hit[0] == len(self.values):
+            return hit[1]
+        out = build(self.values)
+        if len(cache) >= 64:
+            cache.pop(next(iter(cache)))
+        cache[key] = (len(self.values), out)
+        return out
+
     def encode(self, v: str) -> int:
         c = self.codes.get(v)
         if c is None:
