@@ -396,7 +396,7 @@ def _decompose_join(node: P.PlanNode) -> ShuffleGraph:
             return P.Project(rebuild(n.child),
                              [(nm, rewrite(e)) for nm, e in n.items])
         if isinstance(n, P.Compact):
-            return P.Compact(rebuild(n.child), n.frac, n.block)
+            return P.Compact(rebuild(n.child), n.frac, n.block, n.narrow)
         if isinstance(n, P.Aggregate):
             group_by = [(nm, rewrite(e)) for nm, e in n.group_by]
             aggs = [BoundAgg(a.func, rewrite(a.arg), a.type, a.distinct,

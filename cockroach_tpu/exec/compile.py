@@ -31,6 +31,7 @@ from ..ops import hashtable
 from ..ops import sortkey
 from ..ops.batch import ColumnBatch, mvcc_live
 from ..ops.join import hash_join
+from ..ops.pallas import compact as pallas_compact
 from ..sql import plan as P
 from ..sql.bound import BoundAgg
 from ..sql.types import Family
@@ -64,11 +65,14 @@ class ExecParams:
     #     Tiny inputs (< AUTO_MIN_ROWS) stay on XLA.
     #   off: never — the XLA path every ineligible plan takes anyway,
     #     and the oracle of auto == off.
-    # pallas_interpret runs the kernel in interpret mode off-TPU
-    # (the engine sets it from the backend). The tile point is the
-    # kernel module's own constants.
+    # pallas_interpret runs the Pallas kernels (that one and a
+    # Compact's, ops/pallas/compact.py) in interpret mode: by default
+    # wherever the backend is not the TPU, as the engine sets it; a
+    # Compact can stand in any plan, so the default has to run. The
+    # tile point is the kernel module's own constants.
     pallas_groupagg: str = "off"
-    pallas_interpret: bool = False
+    pallas_interpret: bool = field(
+        default_factory=lambda: jax.default_backend() != "tpu")
     # Sort+Limit fusion: XLA's variadic sort costs ~20s of compile PER
     # OPERAND beyond 64K rows (measured on v5e; a 5-operand lexsort at
     # 262K compiles ~300s), so ORDER BY ... LIMIT k plans take a
@@ -296,10 +300,11 @@ def _compile_plan(node: P.PlanNode, params: ExecParams,
         return _compile_derived(node, params)
     if isinstance(node, P.Compact):
         childf = compile_plan(node.child, params)
-        frac, block = node.frac, node.block
+        frac, block, narrow = node.frac, node.block, node.narrow
 
         def run_compact(rc):
-            return compact_batch(childf(rc), frac, block)
+            return compact_batch(childf(rc), frac, block, narrow,
+                                 interpret=params.pallas_interpret)
         return run_compact
     if isinstance(node, P.Aggregate):
         return _compile_aggregate(node, params)
@@ -404,8 +409,9 @@ def _compile_scan(node: P.Scan, params: ExecParams) -> CompiledNode:
 def _compact_block_rows(n: int, frac: float, block: int) -> int:
     """Rows a `block`-row segment of an n-row batch keeps under
     compact_batch: block * frac rounded up to 128 lanes, or the whole
-    block where the batch is too small, ragged, or would not shrink."""
-    if n < 2 * block or n % block:
+    block where the batch is too small, ragged, or would not shrink (or
+    the block is not whole kernel tiles)."""
+    if n < 2 * block or n % block or block % pallas_compact.BLOCK_QUANTUM:
         return block
     kb = max(128, int(block * frac))
     return min(((kb + 127) // 128) * 128, block)
@@ -433,65 +439,114 @@ def plan_rows(node: P.PlanNode, scan_rows: dict):
     return None
 
 
-def compact_batch(b: ColumnBatch, frac: float,
-                  block: int = 32768) -> ColumnBatch:
+def _as_words(x, narrow: bool) -> tuple:
+    """A column as the 32-bit arrays the pack kernel moves (Mosaic has
+    no 64-bit lanes): a 64-bit integer's low word alone where the plan
+    proves its values within int32 (`narrow`), else its low and high
+    word (the u32 halves XLA:TPU itself keeps of one: nothing is
+    converted), a 32-bit value as it is, a narrower integer or a bool
+    widened."""
+    if x.dtype.itemsize == 8:
+        lo = x.astype(jnp.uint32)
+        return (lo,) if narrow else (lo, (x >> 32).astype(jnp.uint32))
+    if x.dtype.itemsize == 4:
+        return (x,)
+    return (x.astype(jnp.int32),)
+
+
+def _from_words(words, dtype):
+    """_as_words undone, over the packed arrays: a column of element
+    type `dtype`."""
+    if len(words) == 2:
+        lo, hi = words
+        return (hi.astype(dtype) << 32) | lo.astype(dtype)
+    if dtype.itemsize == 8:     # a narrow column's low word: its sign
+        return words[0].astype(jnp.int32).astype(dtype)
+    return words[0].astype(dtype)
+
+
+# what the traced Compacts ran over (static shapes, one tally a trace):
+# the engine's exec.compact.*
+COMPACTS = sortkey._Tally()
+
+
+def compact_batch(b: ColumnBatch, frac: float, block: int = 32768,
+                  narrow: frozenset = frozenset(),
+                  interpret: bool = False) -> ColumnBatch:
     """Pack selected rows to the front of a batch `frac` the size.
 
     Blocked: each `block`-row segment keeps its first block*frac
     selected rows, and every downstream per-row op (join probe
-    gathers, CASE math, agg partials) then runs at frac width. Two
-    pack strategies by backend: on TPU, top_k over (sel ? index : -1)
-    — measured on a v5e, ~1/3 the cost of the full-width gather it
-    replaces at 8.4M rows; elsewhere, cumsum-rank + scatter into a
-    (kb+1)-slot frame per block — XLA's CPU top_k costs ~3x the
-    scatter (measured at 2^18), inverting the v5e tradeoff.
+    gathers, CASE math, agg partials) then runs at frac width. One
+    body on every backend, the displacement network of
+    ops/pallas/compact.py (interpreted off the TPU): a survivor moves
+    left by the unselected rows before it, one bit of that count a
+    step, so nothing is sorted and nothing gathered. `route` lays the
+    network out from the selection mask once; each column then goes
+    through it by itself (a 64-bit column as its two words), so the
+    columns the statement never reads cost nothing once XLA has
+    dropped them; its validity mask goes beside it as an 8-bit word.
+    A 64-bit column named in `narrow` (P.Compact.narrow: the store
+    proves its values within int32) goes as its low word alone.
     A segment with more selected rows than its capacity sets the
     __compact_overflow sentinel; results would be missing rows, so
     the engine rechecks it at materialize time and replans without
     compaction (same pattern as __ht_overflow / __topk_inexact).
-    Relative row order is NOT preserved on the top_k path (largest
-    index first; the scatter path happens to be stable) — the engine
-    only compacts under aggregation."""
+    Rows keep their order inside a block (ascending; behind a
+    block's survivors come unselected rows that repeat its first
+    one). Nothing may depend on it: the engine only compacts under
+    an aggregation or a Project-rooted spine."""
     n = int(b.sel.shape[0])
     kb = _compact_block_rows(n, frac, block)
+    names = [c for c in b.names if c != "__compact_overflow"]
+    COMPACTS.bump("compacts")
+    COMPACTS.bump("rows_in", n)
+    COMPACTS.bump("rows_out", n if kb == block else n // block * kb)
+    COMPACTS.bump("columns", len(names))
     if kb == block:
         return b
     nb = n // block
-    sel = b.sel
-    if jax.default_backend() != "tpu":
-        s = sel.reshape(nb, block)
-        pos = jnp.cumsum(s.astype(jnp.int32), axis=1) - 1
-        overflow = jnp.any(pos[:, -1] + 1 > kb)
-        base = (jnp.arange(nb, dtype=jnp.int32) * (kb + 1))[:, None]
-        # beyond-capacity and unselected rows both land in the extra
-        # slot kb, which the [:kb] slice below discards
-        dst = (jnp.where(jnp.logical_and(s, pos < kb), pos, kb)
-               + base).reshape(-1)
-        scat = jnp.full((nb * (kb + 1),), -1, jnp.int32).at[dst].set(
-            jax.lax.iota(jnp.int32, n), mode="drop")
-        flat = scat.reshape(nb, kb + 1)[:, :kb].reshape(-1)
-        live = flat >= 0
-        flat = jnp.maximum(flat, 0)
-    else:
-        score = jnp.where(sel, jax.lax.iota(jnp.int32, n),
-                          jnp.int32(-1)).reshape(nb, block)
-        top, idx = jax.lax.top_k(score, kb)
-        live = (top >= 0).reshape(-1)
-        base = (jnp.arange(nb, dtype=jnp.int32) * block)[:, None]
-        flat = (idx.astype(jnp.int32) + base).reshape(-1)
-        overflow = jnp.any(
-            jnp.sum(sel.reshape(nb, block), axis=1) > kb)
-    cols = {}
-    valid = {}
-    for name in b.names:
-        if name == "__compact_overflow":
-            # a Compact further down the spine (the joins between
-            # carry its flag through): rows it dropped never reach
-            # this one, so its overflow is this batch's too
-            overflow = jnp.logical_or(overflow, jnp.any(b.col(name)))
-            continue
-        cols[name] = jnp.take(b.col(name), flat, axis=0)
-        valid[name] = jnp.take(b.col_valid(name), flat, axis=0)
+
+    def tiled(x):
+        return pallas_compact.tiled(x, block)
+
+    routing, count = pallas_compact.route(tiled(b.sel), interpret=interpret)
+    overflow = jnp.any(count > kb)
+    if b.has("__compact_overflow"):
+        # a Compact further down the spine (the joins between carry
+        # its flag through): rows it dropped never reach this one, so
+        # its overflow is this batch's too
+        overflow = jnp.logical_or(
+            overflow, jnp.any(b.col("__compact_overflow")))
+
+    def packed(words):
+        return pallas_compact.pack(routing, tuple(words), kb=kb,
+                                   interpret=interpret)
+
+    rows = None
+    cols, valid = {}, {}
+    for c in names:
+        x = b.col(c)
+        # a column's validity mask goes with its words, in the same
+        # call: what XLA drops of an unread column then takes the mask,
+        # and whatever computed it (a payload's probe gather), along
+        words = (tiled(b.col_valid(c)).astype(jnp.int8),)
+        if x.dtype == jnp.float64:
+            # XLA:TPU has no rewrite of a float64's bits into 32-bit
+            # words (bitcast-convert is unimplemented there): such a
+            # column's rows are fetched by their packed row numbers
+            if rows is None:
+                rows, = packed((tiled(jax.lax.iota(jnp.int32, n)),))
+            cols[c] = jnp.take(x, rows, axis=0)
+        else:
+            words = _as_words(tiled(x), c in narrow) + words
+        *words, mask = packed(words)
+        if words:
+            cols[c] = _from_words(words, x.dtype)
+        valid[c] = mask != 0
+    # a block's survivors are its first `count` rows
+    live = (jax.lax.broadcasted_iota(jnp.int32, (nb, kb), 1)
+            < count[:, None]).reshape(-1)
     out = ColumnBatch.from_dict(cols, valid, sel=live)
     return out.with_column(
         "__compact_overflow",

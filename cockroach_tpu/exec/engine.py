@@ -52,10 +52,10 @@ from ..utils.mon import BytesMonitor, MemoryQuotaError
 from ..utils.settings import SessionVars, Settings
 from . import coldstart
 from . import movement
-from .compile import (AGG_STRATEGY, JOIN_KINDS, RANGE_PROOFS, ExecParams,
-                      JoinStats,
-                      RunContext, aggregate_strategy, can_stream,
-                      compile_plan, compile_streaming, plan_rows)
+from .compile import (AGG_STRATEGY, COMPACTS, JOIN_KINDS, RANGE_PROOFS,
+                      ExecParams, JoinStats, RunContext,
+                      aggregate_strategy, can_stream, compile_plan,
+                      compile_streaming, plan_rows)
 from .planparam import (SubqueryValue, inline_subquery_args,
                         param_signature, parameterize, plan_fingerprint,
                         shape_text)
@@ -534,6 +534,21 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 "join, beside exec.join.joins a dispatch): semi and "
                 "anti are what EXISTS / NOT EXISTS with equality "
                 "correlations unnest into on one device")
+        for kind, what in (
+                ("compacts", "Compact nodes traced"),
+                ("rows_in", "rows of the batches they were handed"),
+                ("rows_out", "rows of the batches they handed on (a "
+                 "Compact over a batch too small or too ragged to "
+                 "shrink hands it on as it is)"),
+                ("columns", "columns those batches held (each goes "
+                 "through the network by itself, and XLA drops the "
+                 "ones the statement never reads)")):
+            self.metrics.func_counter(
+                "exec.compact." + kind,
+                lambda kind=kind: COMPACTS.value(kind),
+                f"{what}: one tally a traced Compact, static shapes "
+                "(compile.compact_batch, the displacement network of "
+                "ops/pallas/compact.py)")
         self._m_subquery = {
             k: self.metrics.counter(
                 "exec.subquery." + k,
@@ -2891,7 +2906,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             # aggregation pack their survivors before join probes /
             # agg partials run (see compile.compact_batch). Gated off
             # under streaming (the sentinel cannot ride page state)
-            # and distributed plans (per-shard top_k + psum merges
+            # and distributed plans (a per-shard pack + psum merges
             # would need sentinel plumbing through collectives)
             node = self._insert_compaction(node)
         # statement-shape plan cache: lift filter literals out of the
@@ -4053,9 +4068,10 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         return False
 
     def _plan_shape_tags(self, node, scans: dict, pallas: str) -> dict:
-        """The `plan` span's `joins` (hash joins in the plan) and `agg`
-        (compile.aggregate_strategy of its outermost Aggregate, `none`
-        without one), from the plan and its scans' shapes alone."""
+        """The `plan` span's `joins` (hash joins in the plan), `compacts`
+        (its Compact nodes) and `agg` (compile.aggregate_strategy of
+        its outermost Aggregate, `none` without one), from the plan
+        and its scans' shapes alone."""
         from ..sql import plan as P
 
         def nodes(n):
@@ -4065,10 +4081,11 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 if c is not None:
                     yield from nodes(c)
 
-        joins, agg = 0, None
+        joins, compacts, agg = 0, 0, None
         kinds = {"semi": 0, "anti": 0, "left": 0}
         for n in nodes(node):
             joins += isinstance(n, P.HashJoin)
+            compacts += isinstance(n, P.Compact)
             if isinstance(n, P.HashJoin) and n.join_type in kinds:
                 kinds[n.join_type] += 1
             if agg is None and isinstance(n, P.Aggregate):
@@ -4080,7 +4097,8 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             strategy = aggregate_strategy(agg, rows or 0, ExecParams(
                 pallas_groupagg=pallas,
                 pallas_interpret=self._pallas_interpret()))
-        return {"joins": joins, "agg": strategy, **kinds}
+        return {"joins": joins, "compacts": compacts, "agg": strategy,
+                **kinds}
 
     def _compact_frac(self, est: float) -> float:
         # 4x headroom over the uniform estimate absorbs moderate
@@ -4112,7 +4130,12 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         wrapped again further up, where the estimate has fallen far
         enough to shrink the packed batch eight-fold once more.
         Project and Window stop the walk (fresh columns would drop the
-        sentinel / order matters)."""
+        sentinel / order matters). A Compact keeps a block's rows in
+        their order but interleaves filler between blocks, and what
+        sits above one must not read an order out of it. Each Compact
+        is told which 64-bit columns of its batch are stored columns
+        the store proves within int32 (`narrow`): those travel as one
+        word."""
         from ..sql import plan as P
 
         def build_sel(jn) -> float:
@@ -4123,12 +4146,29 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 return e if e is not None else 1.0
             return 1.0
 
+        def narrow(n) -> set:
+            """Batch columns of the spine under `n` that are stored
+            columns proven within int32 (narrow32_cols): a scan's
+            columns keep their names through filters, joins (as
+            probe columns or payload) and Compacts; anything else
+            renames or computes, and proves nothing."""
+            if isinstance(n, P.Scan):
+                fits = self.narrow32_cols(
+                    n.table, frozenset(n.columns.values()))
+                return {bn for bn, sn in n.columns.items() if sn in fits}
+            if isinstance(n, (P.Filter, P.Compact)):
+                return narrow(n.child)
+            if isinstance(n, P.HashJoin):
+                return narrow(n.left) | narrow(n.right)
+            return set()
+
         def wrap(n, est, width):
             """(Compact over n, its share of the scan's rows) for a
             batch `width` of the scan's rows wide that `est` of them
             survive into."""
             frac = self._compact_frac(est / width)
-            return P.Compact(n, frac=frac), width * frac
+            return P.Compact(n, frac=frac,
+                             narrow=frozenset(narrow(n))), width * frac
 
         # (node, est, width, joins_below): est the share of the scan's
         # rows that survive, width the share the batch holds after the

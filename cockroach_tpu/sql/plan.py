@@ -91,18 +91,25 @@ class HashJoin(PlanNode):
 
 @dataclass
 class Compact(PlanNode):
-    """Pack selected rows into a smaller batch (blocked top_k over the
-    selection mask). Inserted by the engine above low-selectivity
-    scans/filters feeding aggregation: every downstream per-row op —
-    join probe gathers above all — then runs at ``frac`` of the batch
-    instead of full width with masked lanes. The TPU analogue of the
-    reference's selection vectors (coldata.Batch sel), which its
-    operators consume implicitly; XLA needs the compaction to be an
-    explicit op. Per-block capacity overflow raises the
-    __compact_overflow sentinel and the engine replans uncompacted."""
+    """Pack selected rows into a smaller batch (block by block, a
+    log-step displacement network laid out from the selection mask:
+    ops/pallas/compact.py; rows keep their order inside a block, and
+    nothing may depend on it). Inserted by the engine above
+    low-selectivity scans/filters feeding aggregation: every
+    downstream per-row op — join probe gathers above all — then runs
+    at ``frac`` of the batch instead of full width with masked lanes.
+    The TPU analogue of the reference's selection vectors
+    (coldata.Batch sel), which its operators consume implicitly; XLA
+    needs the compaction to be an explicit op. Per-block capacity
+    overflow raises the __compact_overflow sentinel and the engine
+    replans uncompacted."""
     child: PlanNode
     frac: float = 0.125     # per-block capacity fraction
     block: int = 32768
+    # 64-bit columns of the batch whose values the store proves within
+    # int32 (Engine.narrow32_cols over the scans beneath): each goes
+    # through the network as one word, not two
+    narrow: frozenset = frozenset()
 
 
 @dataclass

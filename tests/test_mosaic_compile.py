@@ -1,4 +1,5 @@
-"""The large-G kernel compiled for the v5e here, without a chip: the
+"""The large-G kernel and a Compact's two kernels (ops/pallas/compact.py,
+through compile.compact_batch) compiled for the v5e here, without a chip: the
 TPU's own compiler (XLA:TPU and Mosaic) is installed and compiles for
 a topology that is described, not attached. Interpret mode cannot see
 what these see: a contraction Mosaic refuses, a block that passes the
@@ -121,3 +122,41 @@ def test_float_sums_beside_the_bf16_pass_compile(one_chip):
     layout = (("f", 0), ("shadow", 0), ("f", 1)) \
         + pgl.limb_rows(0, 64, 8) + (("count", 0), ("live",))
     _compile(one_chip, 1 << 20, 300, layout, n_src=1, n_f=2)
+
+
+# -- a Compact's displacement network ----------------------------------------
+
+@pytest.mark.parametrize("n,frac", [(1 << 23, 0.16), (1 << 23, 0.0508),
+                                    (1343488, 0.04)])
+def test_compact_batch_compiles_at_the_benchmarks_shapes(one_chip, n, frac):
+    """The body the cells run, through Mosaic: SSB's first Compact at
+    SF1 (2^23 rows, 5,248 of a block's 32,768 kept: 41 tile rows, not
+    a multiple of 8), Q14's (1,664), and a second Compact over the
+    first one's 1,343,488 rows; a 64-bit column as two words, a bool,
+    a float, a float64 (gathered by packed row numbers: XLA:TPU cannot
+    split one), a nullable column, and an inner Compact's flag."""
+    from cockroach_tpu.exec.compile import compact_batch, plan_rows
+    from cockroach_tpu.ops.batch import ColumnBatch
+    from cockroach_tpu.sql import plan as P
+
+    def spec(dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+
+    def fn(sel, key, price, flag, ratio, wide, nulls, inner):
+        b = ColumnBatch.from_dict(
+            {"key": key.astype(jnp.int64), "price": price, "flag": flag,
+             "ratio": ratio, "wide": wide, "__compact_overflow": inner},
+            {"price": nulls}, sel=sel)
+        return compact_batch(b, frac)
+
+    with jax.enable_x64(True):
+        compiled = jax.jit(fn).trace(
+            spec(jnp.bool_), spec(jnp.int32), spec(jnp.int64),
+            spec(jnp.bool_), spec(jnp.float32), spec(jnp.float64),
+            spec(jnp.bool_), spec(jnp.bool_)).lower().compile()
+    text = compiled.as_text()
+    # route, five columns (each with its validity mask), the float64's
+    # row numbers
+    assert text.count('custom_call_target="tpu_custom_call"') == 7
+    out = plan_rows(P.Compact(P.Scan("t", "t"), frac=frac), {"t": n})
+    assert out < n and f"[{out}]" in text

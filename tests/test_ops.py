@@ -355,3 +355,203 @@ class TestCompaction:
         fk2 = g.integers(0, dim_n, rows)
         want = int(wdim[fk2[d2 < 7]].sum())
         assert got == [(want,)]
+
+
+# -- compact_batch: the displacement network, the body the chip runs ---------
+
+def _compact_case(density, block, nb, kb, seed):
+    """A selection mask by name: how many rows of each block survive."""
+    rng = np.random.default_rng(seed)
+    sel = np.zeros((nb, block), bool)
+    per_block = {"none": 0, "sparse": kb // 5, "exactly_kb": kb,
+                 "over_kb": kb + 1 + kb // 3, "all": block}[density]
+    for i in range(nb):
+        sel[i, rng.choice(block, per_block, replace=False)] = True
+    if density == "over_kb":
+        sel[1:] = False                 # one block over, the rest empty
+        sel[1, :7] = True
+    return sel.reshape(-1)
+
+
+@pytest.mark.parametrize("inner_flag", [False, True],
+                         ids=["alone", "inner_overflow"])
+@pytest.mark.parametrize("block", [1024, 32768])
+@pytest.mark.parametrize("density", ["none", "sparse", "exactly_kb",
+                                     "over_kb", "all"])
+def test_compact_batch_equals_the_plain_reference(density, block,
+                                                  inner_flag):
+    """Every block's survivors, first kb of them, in ascending order,
+    of every column kind, against numpy's x[sel][:kb]; the overflow
+    flag where a block holds more than kb or an inner Compact said
+    so."""
+    from cockroach_tpu.exec.compile import (_compact_block_rows,
+                                            compact_batch)
+    nb, frac = 3, 0.25
+    n = nb * block
+    kb = _compact_block_rows(n, frac, block)
+    assert kb == block // 4
+    sel = _compact_case(density, block, nb, kb, seed=block + len(density))
+    rng = np.random.default_rng(5)
+    cols = {
+        "row": np.arange(n, dtype=np.int32),
+        "i32": rng.integers(-2**31, 2**31, n).astype(np.int32),
+        "i64": rng.integers(-2**62, 2**62, n),
+        # a 64-bit column the plan proves within int32: one word
+        "i64_narrow": rng.integers(-2**31 + 1, 2**31 - 1, n),
+        "flag": rng.random(n) < 0.5,
+        "f32": rng.random(n).astype(np.float32),
+        "f64": rng.random(n),       # fetched by packed row numbers
+        "nullable": rng.integers(0, 1000, n),
+    }
+    nulls = rng.random(n) < 0.7
+    b = ColumnBatch.from_dict(
+        {k: jnp.asarray(v) for k, v in cols.items()},
+        {"nullable": jnp.asarray(nulls)}, sel=jnp.asarray(sel))
+    if inner_flag:
+        b = b.with_column("__compact_overflow", jnp.ones((n,), bool))
+    out = compact_batch(b, frac, block, frozenset({"i64_narrow"}),
+                        interpret=True)
+    assert out.n == nb * kb
+    osel = np.asarray(out.sel).reshape(nb, kb)
+    for name, x in cols.items():
+        got = np.asarray(out.col(name))
+        assert got.dtype == x.dtype
+        got = got.reshape(nb, kb)
+        gotv = np.asarray(out.col_valid(name)).reshape(nb, kb)
+        for i in range(nb):
+            s = sel[i * block:(i + 1) * block]
+            want = x[i * block:(i + 1) * block][s][:kb]
+            k = len(want)
+            assert osel[i, :k].all() and not osel[i, k:].any()
+            np.testing.assert_array_equal(got[i, :k], want)
+            wantv = (nulls[i * block:(i + 1) * block][s][:kb]
+                     if name == "nullable" else np.ones(k, bool))
+            np.testing.assert_array_equal(gotv[i, :k], wantv)
+            # behind the survivors one row repeats, the block's first:
+            # one address a block for what reads unselected rows too
+            assert (got[i, k:] == got[i, 0]).all()
+    rows = np.asarray(out.col("row")).reshape(nb, kb)
+    for i in range(nb):
+        k = int(osel[i].sum())
+        assert (np.diff(rows[i, :k]) > 0).all()     # ascending
+    flag = np.asarray(out.col("__compact_overflow"))
+    assert flag.all() == flag.any() == (density in ("over_kb", "all")
+                                        or inner_flag)
+
+
+def test_compact_counters_and_the_plan_span_tag_read_the_plan():
+    """exec.compact.* and the `plan` span's `compacts`: a Q3-shaped
+    join holds one Compact, a Q6-shaped scan none, a spine wrapped
+    again two."""
+    from cockroach_tpu.exec.engine import Engine
+    from cockroach_tpu.utils import tracing
+
+    block = 32768
+    n = 16 * block
+    eng = Engine()
+    eng.execute("CREATE TABLE f (k1 INT8 NOT NULL, k2 INT8 NOT NULL, "
+                "g INT8 NOT NULL, v INT8 NOT NULL)")
+    ids = np.arange(1, 1025, dtype=np.int64)
+    for t in ("d1", "d2"):
+        eng.execute(f"CREATE TABLE {t} (id INT8 PRIMARY KEY, "
+                    "a INT8 NOT NULL)")
+        eng.store.insert_columns(t, {"id": ids, "a": ids % 16},
+                                 eng.clock.now())
+    rng = np.random.default_rng(37)
+    f = {c: rng.integers(1, 1025, n) for c in ("k1", "k2")}
+    f["g"] = rng.integers(0, 100, n)
+    f["v"] = rng.integers(1, 1000, n)
+    eng.store.insert_columns("f", f, eng.clock.now())
+    for t in ("f", "d1", "d2"):
+        eng.execute(f"ANALYZE {t}")
+    s = eng.session()
+    s.vars.set("distsql", "off")
+    keep1, keep2 = f["k1"] % 16 == 0, f["k2"] % 16 == 0
+
+    def counts():
+        snap = eng.metrics.snapshot()
+        return [snap[f"exec.compact.{k}"]
+                for k in ("compacts", "rows_in", "rows_out", "columns")]
+
+    cases = [
+        ("select g, sum(v) from f, d1 where k1 = d1.id and d1.a = 0 "
+         "group by g order by g", keep1, [(n, n // 4)]),
+        ("select sum(v) from f where g < 5",
+         int(f["v"][f["g"] < 5].sum()), []),
+        ("select g, sum(v) from f, d1, d2 where k1 = d1.id and "
+         "k2 = d2.id and d1.a = 0 and d2.a = 0 group by g order by g",
+         keep1 & keep2, [(n, n // 4), (n // 4, n // 64)]),
+    ]
+    for sql, want, shapes in cases:
+        before = counts()
+        tracing.start_collector()
+        try:
+            got = eng.execute(sql, session=s).rows
+        finally:
+            roots = tracing.stop_collector()
+
+        def plans(span):
+            if span.name == "plan":
+                yield span.tags
+            for c in span.children:
+                yield from plans(c)
+
+        tags = [t for r in roots for t in plans(r)]
+        assert [t["compacts"] for t in tags] == [len(shapes)], sql
+        d = [a - b for a, b in zip(counts(), before)]
+        assert d[:3] == [len(shapes), sum(a for a, _ in shapes),
+                         sum(b for _, b in shapes)], sql
+        assert (d[3] > 0) == bool(shapes)
+        if isinstance(want, np.ndarray):
+            want = [(int(x), int(f["v"][want & (f["g"] == x)].sum()))
+                    for x in np.unique(f["g"][want])]
+            assert [tuple(r) for r in got] == want
+        else:
+            assert got == [(want,)]
+
+
+def test_a_compact_carries_proven_int32_columns_as_one_word():
+    """P.Compact.narrow: the stored columns beneath whose all-versions
+    range fits int32 (a probe column, a payload); a column past 2^31
+    keeps both words, and both kinds sum exactly."""
+    from cockroach_tpu.exec.engine import Engine
+    from cockroach_tpu.sql import parser
+    from cockroach_tpu.sql import plan as P
+
+    n = 1 << 17
+    eng = Engine()
+    eng.execute("CREATE TABLE f (k INT8 NOT NULL, g INT8 NOT NULL, "
+                "small INT8 NOT NULL, big INT8 NOT NULL)")
+    eng.execute("CREATE TABLE d (id INT8 PRIMARY KEY, a INT8 NOT NULL, "
+                "w INT8 NOT NULL)")
+    ids = np.arange(1, 1025, dtype=np.int64)
+    eng.store.insert_columns("d", {"id": ids, "a": ids % 16,
+                                   "w": -ids}, eng.clock.now())
+    rng = np.random.default_rng(3)
+    f = {"k": rng.integers(1, 1025, n), "g": rng.integers(0, 100, n),
+         "small": rng.integers(-2**31 + 2, 2**31 - 2, n),
+         "big": rng.integers(-2**40, 2**40, n)}
+    eng.store.insert_columns("f", f, eng.clock.now())
+    for t in ("f", "d"):
+        eng.execute(f"ANALYZE {t}")
+    s = eng.session()
+    s.vars.set("distsql", "off")
+    sql = ("select g, sum(small), sum(big), sum(w) from f, d "
+           "where k = d.id and d.a = 0 group by g order by g")
+    node, _ = eng._plan(parser.parse(sql), s)
+    eng._check_join_builds(node, eng._read_ts(s), {})
+    node = eng._insert_compaction(node)
+    compacts = []
+    while node is not None:
+        if isinstance(node, P.Compact):
+            compacts.append(node)
+        node = getattr(node, "child", None) or getattr(node, "left", None)
+    assert len(compacts) == 1
+    narrow = compacts[0].narrow
+    assert {"f.small", "f.g", "f.k"} <= narrow and "f.big" not in narrow
+    keep = f["k"] % 16 == 0
+    want = [(int(x), int(f["small"][keep & (f["g"] == x)].sum()),
+             int(f["big"][keep & (f["g"] == x)].sum()),
+             int(-f["k"][keep & (f["g"] == x)].sum()))
+            for x in np.unique(f["g"][keep])]
+    assert [tuple(r) for r in eng.execute(sql, session=s).rows] == want
