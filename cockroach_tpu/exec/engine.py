@@ -47,7 +47,9 @@ from ..sql.types import ColumnSchema, Family, TableSchema
 from ..storage import keys as K
 from ..storage.columnstore import MAX_TS_INT, Chunk, ColumnStore
 from ..storage.hlc import Clock, Timestamp
-from ..utils.metric import MetricRegistry
+from ..utils import tracing as _trc
+from ..utils.metric import (MetricRegistry, process_status,
+                            register_process_metrics)
 from ..utils.mon import BytesMonitor, MemoryQuotaError
 from ..utils.settings import SessionVars, Settings
 from . import coldstart
@@ -341,22 +343,29 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         # lock SELECTs race the resident-table map otherwise)
         self._device_lock = threading.RLock()
         self.metrics = MetricRegistry()
+        register_process_metrics(self.metrics)
         # statement diagnostics (utils/stmtdiag.py): armed fingerprints
         # capture a JSON bundle on their next execution; bundles serve
         # at /_status/stmtdiag/<id> and inline via EXPLAIN ANALYZE
         # (DEBUG)
         from ..utils.stmtdiag import StmtDiagRegistry
         self.stmtdiag = StmtDiagRegistry(metrics=self.metrics)
-        # most recent statement's coarse operator profile
-        # (exec/profile.py ProfileSink), for a per-query top-operator
-        # summary; overwritten per statement
-        self.last_profile = None
-        self.metrics.counter(
+        # what every statement counts, held: a look-up by formatted
+        # name is a lock and a dict probe a statement each
+        self._m_profile_statements = self.metrics.counter(
             "exec.profile.statements",
             "statements executed with an active profile sink")
-        self.metrics.counter(
+        self._m_profile_operators = self.metrics.counter(
             "exec.profile.operators",
             "operator entries recorded into profile sinks")
+        self._m_exec_latency = self.metrics.histogram(
+            "sql.exec.latency", "statement execution latency (s)")
+        self._m_stmt_count: dict = {}   # statement type -> its counter
+        self._m_plan_cache = {
+            hit: self.metrics.counter(
+                "sql.plan.cache.hit" if hit else "sql.plan.cache.miss",
+                "compiled-plan cache lookups, by outcome")
+            for hit in (True, False)}
         # cold-start elimination (exec/coldstart.py): persistent XLA
         # compile cache so a restarted process deserializes instead of
         # recompiling; None when disabled or the backend/dir refuses
@@ -763,12 +772,11 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         Dispatcher objects stay registered — a later dispatch through a
         cached closure respawns its thread (parallel/distagg.py)."""
         from ..parallel.distagg import shutdown_dispatchers
-        # profiling lifecycle: drop armed diagnostics requests,
-        # retained bundles, and the last statement's sink — a closed
-        # engine must leak no profiling state (sinks hold no threads;
-        # per-statement sinks die with their statement's thread-local)
+        # profiling lifecycle: drop armed diagnostics requests and
+        # retained bundles — a closed engine must leak no profiling
+        # state (sinks hold no threads; per-statement sinks die with
+        # their statement's thread-local)
         self.stmtdiag.clear()
-        self.last_profile = None
         self.drop_device_cache()
         if self.mesh is not None:
             shutdown_dispatchers(self.mesh)
@@ -801,7 +809,6 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
 
     def _parse_cached(self, sql: str):
         import copy
-        from ..utils import tracing as _trc
         with _trc.span("parse") as sp:
             hit = self._parse_cache.get(sql)
             if sp is not None:
@@ -912,6 +919,10 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             "compile_cache_error": coldstart.cache_error(),
             "pallas_interpret": self._pallas_interpret(),
             "native": native.status(),
+            # the process's own cost (utils/metric.py): CPU seconds of
+            # the process and of its Python threads by role, the
+            # collector's pauses by generation
+            "process": process_status(),
         }
 
     # session vars a journal entry may replay into a prewarm session:
@@ -1017,6 +1028,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         # tables when they can be enumerated: a multi-tenant analytic
         # statement over other tables neither stalls the OLTP lane nor
         # forces its deferred publish (round-18 group-commit lane).
+        _trc.stage("route")
         tables = self._stmt_tables(stmt)
         with self._lane_sync:
             # atomic with lane commits: after this block, any lane
@@ -1119,6 +1131,10 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             with self._stmt_lock:
                 self._sync_scan_plane(stmt)
         import time as _time
+        # the observability plane's own time, marked on whatever span
+        # is open around the statement's (the served root): `setup`
+        # up to the statement's span, `account` from its close
+        _trc.stage("setup")
         t0 = _time.monotonic()
         prio = session.vars.get("admission_priority", "normal")
         # tenant identity for the fair queue: application_name when the
@@ -1150,12 +1166,13 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 "sql.trace.slow_statement.threshold"))
         except Exception:
             slow_thresh = 0.0
-        from ..utils import tracing as _trc
         from ..utils.sqlstats import fingerprint as _fp
         from . import profile as _prof
         # statement diagnostics (utils/stmtdiag.py): an armed
         # fingerprint captures a bundle on THIS execution, which needs
-        # a trace recording and a before-snapshot of the metric plane
+        # a trace recording and a before-snapshot of the metric plane.
+        # The fingerprint is computed here once (three regex passes
+        # over the text) and handed to every reader below
         fp = _fp(sql_text) if sql_text else type(stmt).__name__
         diag_req = (self.stmtdiag.should_capture(fp)
                     if sql_text else None)
@@ -1233,21 +1250,24 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                     (outer if served else rec).tags.setdefault(
                         "fingerprint", fp)
                 res = _run()
+            _trc.stage("account")
             if not capture:
                 rec = None      # read by no sink below
             elif tracing:
                 session.trace.append(rec)
-            self.metrics.counter(
-                f"sql.{type(stmt).__name__.lower()}.count",
-                "statements executed, by type").inc()
+            m_count = self._m_stmt_count.get(type(stmt))
+            if m_count is None:
+                m_count = self._m_stmt_count[type(stmt)] = \
+                    self.metrics.counter(
+                        f"sql.{type(stmt).__name__.lower()}.count",
+                        "statements executed, by type")
+            m_count.inc()
             dt = _time.monotonic() - t0
-            self.metrics.histogram(
-                "sql.exec.latency",
-                "statement execution latency (s)").observe(dt)
+            self._m_exec_latency.observe(dt)
             if sql_text:
-                self.sqlstats.record(sql_text, dt,
-                                     max(len(res.rows), res.row_count),
-                                     compile_s=compile_s)
+                self.sqlstats.record_fp(fp, dt,
+                                        max(len(res.rows), res.row_count),
+                                        compile_s=compile_s)
             # device-execute seconds: the statement's wall time net of
             # its XLA compile bill (utils/devstats.py)
             device_s = max(0.0, dt - compile_s)
@@ -1262,17 +1282,10 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                     rows=max(len(res.rows), res.row_count),
                     hbm_bytes=self.devstats.hbm_bytes(),
                     stall_s=psink.total_stall_seconds())
-                self.metrics.counter(
-                    "exec.profile.statements",
-                    "statements executed with an active profile "
-                    "sink").inc()
+                self._m_profile_statements.inc()
                 n_ops = len(psink.entries())
                 if n_ops:
-                    self.metrics.counter(
-                        "exec.profile.operators",
-                        "operator entries recorded into profile "
-                        "sinks").inc(n_ops)
-                self.last_profile = psink
+                    self._m_profile_operators.inc(n_ops)
             if rec is not None and slow_thresh > 0 \
                     and dt >= slow_thresh:
                 # tenant-attributable slow traces: application_name +
@@ -1307,8 +1320,8 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 # request pending for the next matching execution
                 self.stmtdiag.rearm(fp, diag_req)
             if sql_text:
-                self.sqlstats.record(
-                    sql_text, _time.monotonic() - t0, 0, failed=True,
+                self.sqlstats.record_fp(
+                    fp, _time.monotonic() - t0, 0, failed=True,
                     compile_s=coldstart.thread_compile_seconds() - c0)
             if psink is not None:
                 self.sqlstats.record_tenant(
@@ -1326,7 +1339,6 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
 
     def _dispatch_locked(self, stmt, session, sql_text: str,
                          shared: bool) -> Result:
-        from ..utils import tracing as _trc
         lock = self._stmt_lock
         # the wait for the statement gate, up to the lock held
         with _trc.span("gate", shared=shared):
@@ -1334,6 +1346,11 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 lock.acquire_read()
             else:
                 lock.acquire_write()
+        # the statement span's own time by stage: `select` from the
+        # gate on (the memos and fast-path matches before `plan`, the
+        # glue between the layers' spans), `unwind` (Prepared.run)
+        # from the rows back
+        _trc.stage("select")
         try:
             return self._dispatch_stmt(stmt, session, sql_text)
         finally:
@@ -1900,7 +1917,6 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         stats for every referenced table, and the statement's metric
         deltas. Every section is best-effort — diagnostics must never
         fail the statement that carried them."""
-        from ..utils import tracing as _trc
         from ..utils.sqlstats import fingerprint
         from . import profile as _prof
         bundle: dict = {
@@ -2781,6 +2797,13 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                                 sql_text: str, no_memo: bool,
                                 no_topk: bool, no_compact: bool,
                                 no_dist: bool) -> "Prepared":
+        # the `plan` span's own time by stage (marks, not child spans:
+        # utils/tracing.stage): `build` the planner, `placement` the
+        # verdict, `tables` the device tables and the guards that read
+        # them, `key` session variables, compaction and literal
+        # lifting, `fingerprint` the plan's structural hash and the
+        # key, `lookup` the cache and what is made of its answer
+        _trc.stage("build")
         for td in self.store.tables.values():
             if td.open_ts:
                 self.store.seal(td.schema.name)
@@ -2790,6 +2813,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         node, meta = self._plan(sel, session, no_memo=no_memo,
                                 subquery_slots=subs)
 
+        _trc.stage("placement")
         scan_aliases = _collect_scans(node)
         scan_cols = _collect_scan_columns(node)
         # read-your-own-writes: tables this txn has written get an
@@ -2819,6 +2843,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                    else "stream" if stream is not None else "resident")
         self.tracer.tag(placement=verdict)
         self._m_placement[verdict].inc()
+        _trc.stage("tables")
         read_ts = self._read_ts(session)
         # the join-build uniqueness guard is snapshot-aware: it must
         # judge the rows visible at THIS query's read timestamp — and
@@ -2884,6 +2909,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         SCAN_WIDE_ARGS.inc(sum(d.dtype.itemsize == 8
                                for b in scans.values() for d in b.data))
 
+        _trc.stage("key")
         cap = int(session.vars.get("hash_group_capacity", 1 << 17))
         pallas = session.vars.get("pallas_groupagg", "auto")
         pallas = self._pallas_mode(pallas)
@@ -2937,6 +2963,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
 
             node, n = inline_subquery_args(node, const_of)
             inlined += n
+        _trc.stage("fingerprint")
         if pvals:
             # literals left the plan, so they must leave the key text
             # too; the structural fingerprint below is what rejects a
@@ -2972,13 +2999,12 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                        sortn, plan_fp, no_topk, no_compact, psig)
             else:
                 prefix_key = key
+        _trc.stage("lookup")
         cached = self._exec_cache.get(key)
         self.tracer.tag(plan_cache="hit" if cached else "miss")
-        if self.tracer.recording():
+        if _trc.current_span() is not None:
             self.tracer.tag(**self._plan_shape_tags(node, scans, pallas))
-        self.metrics.counter(
-            "sql.plan.cache.hit" if cached else "sql.plan.cache.miss",
-            "compiled-plan cache lookups, by outcome").inc()
+        self._m_plan_cache[cached is not None].inc()
         if cached is None:
             if verdict == "resident" and not overlay:
                 self.note_placement_model(planned, scan_aliases,

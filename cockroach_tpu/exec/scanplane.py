@@ -1240,6 +1240,10 @@ class ScanPlaneMixin:
         gathered on device."""
         from ..ops.batch import _SMALL_PULL, flag_any, pull_arrays, \
             pull_batch_columns
+        # the span's own time by stage: `flags` up to the pull (the
+        # sentinel programs, the column lists), then what
+        # pull_batch_columns marks behind it (`gather`, `assemble`)
+        _trc.stage("flags")
         sent = [(n, exc) for n, exc in self._SENTINELS if out.has(n)]
         flags_dev = [flag_any(out.col(n)) for n, _ in sent]
         names = list(meta.names)

@@ -18,6 +18,7 @@ from ..ops.batch import (H2D_BYTES, H2D_CALLS, JOIN_BUILD_ROWS,
                          read_ts_words)
 from ..sql import ast
 from ..storage.hlc import Timestamp
+from ..utils import tracing
 from ..utils.settings import SessionVars
 
 EPOCH_DATE = datetime.date(1970, 1, 1)
@@ -252,7 +253,13 @@ class Prepared:
                  params: Optional[tuple] = None) -> ColumnBatch:
         """`params`: subquery_params(read_ts) where the caller has
         read them (run(), outside its `dispatch` span); read here
-        otherwise."""
+        otherwise. The open span's time (`dispatch`, under run()) is
+        marked by stage: `args` (refresh, read timestamp, words,
+        scalars, counters), `call` (the executable's call returning;
+        on a mesh the dispatcher thread runs it and
+        queued_collective_call credits the stage with that thread's
+        CPU)."""
+        tracing.stage("args")
         p = self._refresh()
         if p is not self:
             self._adopt(p)
@@ -285,6 +292,7 @@ class Prepared:
             H2D_CALLS.inc(3 + len(params))
             H2D_BYTES.inc(16 + sum(int(getattr(v, "nbytes", 8))
                                    for v in params))
+            tracing.stage("call")
             out = self.jfn(self.scans, tsv, np.int32(nparts),
                            np.int32(pid), params)
             stats = getattr(self.meta, "join_stats", None)
@@ -404,7 +412,13 @@ class Prepared:
             with tracer.span("dispatch"):
                 out = self.dispatch(read_ts, params=params)
             with tracer.span("materialize"):
-                return self.engine._materialize(out, self.meta)
+                res = self.engine._materialize(out, self.meta)
+            # the statement span's own time from here to its close:
+            # the device batch released, the gate, the compile split
+            # (a mark for the span Engine._dispatch_locked marked, not
+            # for a `plan` this runs beneath)
+            tracing.stage("unwind", after="select")
+            return res
         except CollectiveFault:
             # an injected ICI fault lost this plan's collective
             # dispatch: retry gateway-local, the reference's DistSQL

@@ -434,6 +434,7 @@ def pull_batch_columns(batch: ColumnBatch, names: list,
 
     if n <= _SMALL_PULL and sel_np is None:
         pulled = pull_arrays(datas + valids + [batch.sel] + extra)
+        tracing.stage("assemble")
         k = len(datas) + len(valids)
         return assemble(pulled, live_mask=pulled[k]), pulled[k + 1:]
     if sel_np is None:
@@ -441,6 +442,9 @@ def pull_batch_columns(batch: ColumnBatch, names: list,
         sel_np, extra_np = first[0], first[1:]
     else:
         extra_np = pull_arrays(extra) if extra else []
+    # between the two pulls: the live rows' indices and one eager
+    # gather a column
+    tracing.stage("gather")
     live = np.flatnonzero(sel_np)
     if len(live) * 2 < n:
         if not len(live):
@@ -459,8 +463,10 @@ def pull_batch_columns(batch: ColumnBatch, names: list,
         PROGRAMS.inc(len(datas) + len(valids))  # one eager gather each
         pulled = pull_arrays([jnp.take(a, idx, axis=0)
                               for a in datas + valids])
+        tracing.stage("assemble")
         return assemble(pulled, trim=len(live)), extra_np
     pulled = pull_arrays(datas + valids)
+    tracing.stage("assemble")
     return assemble(pulled, live_mask=np.asarray(sel_np)), extra_np
 
 

@@ -398,17 +398,36 @@ def queued_collective_call(jfn, metrics=None, mesh=None,
         if tracing.current_span() is None:
             return disp.submit(jfn, args, kwargs, on_start).result()
         # recording: a `queue` span from enqueue to the dispatcher's
-        # pick-up, stamped on its thread, recorded here on ours
-        started = []
+        # pick-up, stamped on its thread, recorded here on ours; and
+        # the call itself, which that thread runs while this one
+        # waits: its stamps and its CPU, credited to the open span's
+        # stage (`call`, Prepared.dispatch)
+        started, ran = [], []
+        cpu = tracing.reads_cpu()
 
         def picked_up(wait: float):
             started.append(_time.monotonic_ns())
             on_start(wait)
+
+        def timed(*a, **kw):
+            c0 = _time.thread_time_ns() if cpu else 0
+            t0 = _time.monotonic_ns()
+            try:
+                return jfn(*a, **kw)
+            finally:
+                ran.append((t0, _time.monotonic_ns(),
+                            _time.thread_time_ns() - c0 if cpu else 0,
+                            threading.current_thread().name))
         t_enq = _time.monotonic_ns()
-        out = disp.submit(jfn, args, kwargs, picked_up).result()
-        if started:
-            tracing.record("queue", t_enq, started[0])
-        return out
+        try:
+            return disp.submit(timed, args, kwargs, picked_up).result()
+        finally:
+            if started:
+                tracing.record("queue", t_enq, started[0])
+            if ran:
+                t0, t1, spent, name = ran[0]
+                tracing.stage_cpu(spent, call_thread=name, call_b=t0,
+                                  call_e=t1)
 
     def _call_inner(*args, **kwargs):
         t0 = _time.monotonic()

@@ -911,6 +911,7 @@ class _Conn:
                 if queued_ns is not None:
                     tracing.record("wire.queue", queued_ns,
                                    time.monotonic_ns())
+                tracing.stage("frame")
                 return self._process(typ, body)
         return self._process(typ, body)
 

@@ -11,12 +11,21 @@ counters (SocketTransport.sent, DistSender.retries, ...) and still
 surface through /_status/vars without adding a lock acquisition per
 frame. Registered collectors run before every snapshot/export to
 refresh dynamic families (per-peer breaker gauges).
+
+The process's own cost (`register_process_metrics`): CPU seconds of
+the process and of its Python threads by role, and the collector's
+pauses by generation. Func counters read at snapshot time and a
+`gc.callbacks` stopwatch that runs only when a collection does, so
+nothing of it is on a statement's path.
 """
 
 from __future__ import annotations
 
+import gc
 import math
 import threading
+import time
+import weakref
 from typing import Callable, Optional
 
 
@@ -232,3 +241,151 @@ class MetricRegistry:
                 out.append(f"{pname}_sum {v['sum']}")
                 out.append(f"{pname}_count {v['count']}")
         return "\n".join(out) + "\n"
+
+
+# -- the process's own cost --------------------------------------------------
+
+# thread-name prefix -> group of process.threads.cpu.seconds.<group>
+THREAD_GROUPS = (("pgfront-loop", "reactor"), ("pgfront-worker", "workers"),
+                 ("mesh-dispatch-", "mesh_dispatch"))
+OTHER_THREADS = "other"
+THREAD_GROUP_NAMES = tuple(g for _, g in THREAD_GROUPS) + (OTHER_THREADS,)
+
+
+class _GcPauseHistogram(Histogram):
+    """Observed from a gc callback only: one writer at a time (a
+    collection does not nest), and no lock, because a reader that
+    allocates under the lock (`buckets()`) can start the very
+    collection whose callback would then wait for it."""
+
+    def observe(self, v: float) -> None:
+        self._buckets[log2_bucket_index(v, len(self._buckets))] += 1
+        self._sum += v
+        self._count += 1
+
+
+class _ProcessStats:
+    """One a process: its threads' CPU clocks by group and the
+    collector's pauses. Registries share it (an Engine each has one),
+    since the threads and the collector are the process's."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        # ident -> [weakref to the Thread, its CPU clock id, group,
+        # CPU ns last read]; a thread that exited keeps its last
+        # reading in `_retired` (an ident is reused)
+        self._threads: dict = {}
+        self._retired = dict.fromkeys(THREAD_GROUP_NAMES, 0)
+        self._read_ns = 0
+        self._last: dict = {}
+        self.gc_pause = [
+            _GcPauseHistogram(
+                f"process.gc.pause.seconds.gen{g}",
+                f"seconds the collector stopped the interpreter, a "
+                f"generation-{g} pass each") for g in range(3)]
+        self._gc_t0 = 0
+        gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._gc_t0 = time.perf_counter_ns()
+        elif self._gc_t0:
+            self.gc_pause[min(int(info.get("generation", 0)), 2)].observe(
+                (time.perf_counter_ns() - self._gc_t0) / 1e9)
+            self._gc_t0 = 0
+
+    @staticmethod
+    def _group(name: str) -> str:
+        for prefix, group in THREAD_GROUPS:
+            if name.startswith(prefix):
+                return group
+        return OTHER_THREADS
+
+    def thread_cpu_seconds(self) -> dict:
+        """{group: CPU seconds of its threads, live and exited}. One
+        walk serves the four counters of one snapshot (readings under
+        a millisecond old are handed out again)."""
+        with self._lock:
+            now = time.monotonic_ns()
+            if now - self._read_ns > 1_000_000:
+                self._last = self._walk()
+                self._read_ns = now
+            return self._last
+
+    def _walk(self) -> dict:
+        # a thread's clock id is taken once, while it is alive;
+        # reading a clock whose thread has gone is an OSError and
+        # keeps the last value
+        live = {t.ident: t for t in threading.enumerate()
+                if t.ident is not None}
+        for ident, t in live.items():
+            ent = self._threads.get(ident)
+            if ent is not None and ent[0]() is not t:
+                self._retired[ent[2]] += ent[3]
+                ent = None
+            if ent is None:
+                try:
+                    clk = time.pthread_getcpuclockid(ident)
+                except OSError:
+                    continue
+                ent = self._threads[ident] = [
+                    weakref.ref(t), clk, self._group(t.name), 0]
+            try:
+                ent[3] = time.clock_gettime_ns(ent[1])
+            except OSError:
+                pass
+        for ident in [i for i in self._threads if i not in live]:
+            ent = self._threads.pop(ident)
+            self._retired[ent[2]] += ent[3]
+        out = dict(self._retired)
+        for _, _, group, ns in self._threads.values():
+            out[group] += ns
+        return {g: ns / 1e9 for g, ns in out.items()}
+
+
+_process_stats: Optional[_ProcessStats] = None
+_process_stats_lock = threading.Lock()
+
+
+def _process_readers() -> dict:
+    """{metric name: (reader, help)} of the process's func counters,
+    over the one _ProcessStats of the process (made on first use)."""
+    global _process_stats
+    with _process_stats_lock:
+        if _process_stats is None:
+            _process_stats = _ProcessStats()
+        stats = _process_stats
+    readers = {
+        "process.cpu.seconds": (
+            time.process_time,
+            "CPU seconds of the whole process, every thread, user + "
+            "system"),
+        "process.wall.seconds": (
+            time.monotonic,
+            "the monotonic clock, beside process.cpu.seconds: a delta "
+            "of one over a delta of the other is cores busy")}
+    for group in THREAD_GROUP_NAMES:
+        readers[f"process.threads.cpu.seconds.{group}"] = (
+            lambda g=group: stats.thread_cpu_seconds()[g],
+            "CPU seconds of the Python threads of one role (reactor: "
+            "pgfront-loop; workers: pgfront-worker-*; mesh_dispatch: "
+            "mesh-dispatch-*; other: the rest), exited ones included")
+    return readers
+
+
+def register_process_metrics(registry: MetricRegistry) -> None:
+    """`process.cpu.seconds`, `process.wall.seconds`,
+    `process.threads.cpu.seconds.{reactor,workers,mesh_dispatch,other}`
+    and `process.gc.pause.seconds.gen{0,1,2}` in `registry`."""
+    for name, (fn, help_) in _process_readers().items():
+        registry.func_counter(name, fn, help_)
+    for h in _process_stats.gc_pause:
+        registry._get_or_add(h.name, lambda h=h: h)
+
+
+def process_status() -> dict:
+    """The same readings under their names less `process.`, read now
+    (what /_status/runtime shows)."""
+    out = {name: fn() for name, (fn, _) in _process_readers().items()}
+    out.update({h.name: h.value() for h in _process_stats.gc_pause})
+    return {k[len("process."):]: v for k, v in sorted(out.items())}

@@ -29,6 +29,17 @@ general: while it is on, every statement root (served or library) is
 recorded and kept, bounded, oldest dropped first. Stamps are
 `time.monotonic_ns()`, the clock a profiler capture's host events can
 be paired with, so collected roots line up with device ops.
+
+A span also carries the CPU its thread spent while it was open
+(`cpu_ns`, `time.thread_time_ns()` at open and at close; under the
+collector only when it was started with `cpu=True`): wall less
+CPU is the time the thread was off the processor, which inside a
+named wait (`pull`, `queue`, `gate`, `admission`, `wire.queue`) is
+that wait and anywhere else the interpreter lock or the OS. And a
+layer's own time is cut by `stage(name)` marks, not by child spans: a
+mark owns the span's self time up to the next mark or the close, so
+every reader of a span's interval or self time reads what it read
+before the marks were there.
 """
 
 from __future__ import annotations
@@ -42,7 +53,17 @@ from dataclasses import dataclass, field
 from typing import Optional
 
 # One process-wide active-span stack per thread (see module doc).
-_tls = threading.local()
+class _ThreadState(threading.local):
+    # class-level defaults: a thread that never recorded reads them
+    # as plain attributes (a getattr that misses costs five times one
+    # that hits, and the untraced path makes some thirty a statement)
+    span: Optional["Span"] = None
+    rec_req: bool = True
+    # does the recording open on this thread read the CPU clock
+    cpu: bool = True
+
+
+_tls = _ThreadState()
 _ids = itertools.count(1)
 
 
@@ -55,13 +76,40 @@ class Span:
     children: list["Span"] = field(default_factory=list)
     span_id: int = 0
     trace_id: int = 0
+    # CPU of the opening thread between open and close; None on a span
+    # stamped from elsewhere (a wait that ended on another thread)
+    cpu_ns: Optional[int] = None
+    # stage marks, in order: [name, monotonic_ns, the thread's CPU
+    # since the span opened, CPU another thread spent for the stage]
+    stages: list = field(default_factory=list)
+    # the thread's CPU clock at open: this process's, never on the wire
+    cpu0_ns: int = field(default=0, repr=False, compare=False)
 
     @property
     def duration_ms(self) -> float:
         return (self.end_ns - self.start_ns) / 1e6
 
+    def stage_intervals(self) -> list[tuple]:
+        """[(name, start_ns, end_ns, cpu_ns, other_cpu_ns)] a mark: a
+        stage runs to the next mark or the span's close. `cpu_ns` is
+        None where the span's close took no CPU reading."""
+        out = []
+        ends = [m[1] for m in self.stages[1:]] + [self.end_ns]
+        cpus = [m[2] for m in self.stages[1:]] + [self.cpu_ns]
+        for (name, at, cpu, other), end, cpu_end in zip(
+                self.stages, ends, cpus):
+            out.append((name, at, end, None if cpu_end is None
+                        else cpu_end - cpu, other))
+        return out
+
     def tree_lines(self, indent: int = 0) -> list[str]:
         tag_s = "".join(f" {k}={v}" for k, v in self.tags.items())
+        if self.cpu_ns is not None:
+            tag_s = f" cpu={self.cpu_ns / 1e6:.2f}ms" + tag_s
+        if self.stages:
+            tag_s += " stages[" + " ".join(
+                f"{name}={(end - at) / 1e6:.2f}ms"
+                for name, at, end, _, _ in self.stage_intervals()) + "]"
         out = [f"{'  ' * indent}{self.name}: "
                f"{self.duration_ms:.2f}ms{tag_s}"]
         for c in self.children:
@@ -85,20 +133,29 @@ class Span:
 
 
 def current_span() -> Optional[Span]:
-    return getattr(_tls, "span", None)
+    return _tls.span
 
 
 # -- the process-wide collector ----------------------------------------------
 # None while off. A deque(maxlen) drops the oldest root when full, and
 # its append is atomic, so every thread offers without a lock.
 _collected: Optional[collections.deque] = None
+_collect_cpu = False
 COLLECTOR_MAX_ROOTS = 8192
 
 
-def start_collector(max_roots: int = COLLECTOR_MAX_ROOTS) -> None:
+def start_collector(max_roots: int = COLLECTOR_MAX_ROOTS,
+                    cpu: bool = False) -> None:
     """Record every statement root, on all threads, until
-    stop_collector(); at most `max_roots` are kept, the oldest go."""
-    global _collected
+    stop_collector(); at most `max_roots` are kept, the oldest go.
+    The roots it keeps read the CPU clock only with `cpu`: every
+    other recording is one statement somebody asked about, this one
+    is every statement of the process, and a `thread_time_ns()` is a
+    system call some fifty times a statement (0.25 us each on a plain
+    Linux host, 6 us where the benchmark runs: 0.3 ms a statement,
+    PERF.md PR 38)."""
+    global _collected, _collect_cpu
+    _collect_cpu = bool(cpu)
     _collected = collections.deque(maxlen=max_roots)
 
 
@@ -125,8 +182,7 @@ def recording_requested() -> bool:
     sampling). False when nothing records here, or when the capture
     was opened with record_request=False (SET tracing = on: gateway-
     local recording, remote nodes stay dark)."""
-    return current_span() is not None and \
-        bool(getattr(_tls, "rec_req", True))
+    return _tls.span is not None and bool(_tls.rec_req)
 
 
 def trace_context() -> Optional[dict]:
@@ -138,7 +194,7 @@ def trace_context() -> Optional[dict]:
     if s is None:
         return None
     tc = {"tid": s.trace_id, "sid": s.span_id}
-    if getattr(_tls, "rec_req", True):
+    if _tls.rec_req:
         tc["rec"] = 1
     return tc
 
@@ -151,7 +207,7 @@ def _jsonable(v):
 def span_to_wire(s: Span) -> dict:
     """Encode a finished span subtree as JSON-safe primitives (the
     trace-frame wire format documented in OBSERVABILITY.md)."""
-    return {
+    d = {
         "n": s.name,
         "b": s.start_ns,
         "e": s.end_ns,
@@ -160,9 +216,17 @@ def span_to_wire(s: Span) -> dict:
         "sid": s.span_id,
         "tid": s.trace_id,
     }
+    # beside "b" / "e", and only where there is a reading: a reader
+    # that knows neither key reads the span it read before
+    if s.cpu_ns is not None:
+        d["u"] = s.cpu_ns
+    if s.stages:
+        d["g"] = [list(m) for m in s.stages]
+    return d
 
 
 def span_from_wire(d: dict) -> Span:
+    cpu = d.get("u")
     return Span(
         name=d.get("n", "?"),
         start_ns=int(d.get("b", 0)),
@@ -171,6 +235,9 @@ def span_from_wire(d: dict) -> Span:
         children=[span_from_wire(c) for c in d.get("c", [])],
         span_id=int(d.get("sid", 0)),
         trace_id=int(d.get("tid", 0)),
+        cpu_ns=None if cpu is None else int(cpu),
+        stages=[[str(m[0]), int(m[1]), int(m[2]), int(m[3])]
+                for m in d.get("g", [])],
     )
 
 
@@ -206,20 +273,24 @@ class _OpenSpan:
     recording's remote-recording bit while the span is open (a
     statement with SET tracing = cluster nested under a root that was
     opened for local recording only)."""
-    __slots__ = ("span", "parent", "rec_req", "prev_req")
+    __slots__ = ("span", "parent", "rec_req", "prev_req", "cpu")
 
-    def __init__(self, s: Span, parent: Span, rec_req):
+    def __init__(self, s: Span, parent: Span, rec_req, cpu: bool):
         self.span, self.parent, self.rec_req = s, parent, rec_req
+        self.cpu = cpu
 
     def __enter__(self) -> Span:
         _tls.span = self.span
         if self.rec_req is not None:
-            self.prev_req = getattr(_tls, "rec_req", True)
+            self.prev_req = _tls.rec_req
             _tls.rec_req = bool(self.rec_req)
         return self.span
 
     def __exit__(self, *exc):
-        self.span.end_ns = time.monotonic_ns()
+        s = self.span
+        if self.cpu:
+            s.cpu_ns = time.thread_time_ns() - s.cpu0_ns
+        s.end_ns = time.monotonic_ns()
         _tls.span = self.parent
         if self.rec_req is not None:
             _tls.rec_req = self.prev_req
@@ -232,23 +303,64 @@ def span(name: str, record_request: Optional[bool] = None, **tags):
     parent = current_span()
     if parent is None:
         return NO_SPAN
+    cpu = _tls.cpu
     s = Span(name, time.monotonic_ns(), tags=tags, span_id=next(_ids),
-             trace_id=parent.trace_id)
+             trace_id=parent.trace_id,
+             cpu0_ns=time.thread_time_ns() if cpu else 0)
     parent.children.append(s)
-    return _OpenSpan(s, parent, record_request)
+    return _OpenSpan(s, parent, record_request, cpu)
 
 
-def record(name: str, start_ns: int, end_ns: int, **tags) -> Optional[Span]:
+def record(name: str, start_ns: int, end_ns: int,
+           cpu_ns: Optional[int] = None, **tags) -> Optional[Span]:
     """A finished child span from stamps taken elsewhere (a wait that
-    ended on another thread: the frame queue, the mesh dispatcher).
-    None when nothing is recording."""
+    ended on another thread: the frame queue, the mesh dispatcher);
+    `cpu_ns` where that thread read its CPU clock too (a wait has
+    none). None when nothing is recording."""
     parent = current_span()
     if parent is None:
         return None
     s = Span(name, start_ns, end_ns, tags=tags, span_id=next(_ids),
-             trace_id=parent.trace_id)
+             trace_id=parent.trace_id, cpu_ns=cpu_ns)
     parent.children.append(s)
     return s
+
+
+def stage(name: str, after: Optional[str] = None) -> None:
+    """A mark inside the open span: the stage `name` starts here and
+    runs to the next mark or the span's close, and owns the span's
+    self time in between. A mark and not a child span, so the span's
+    own interval, self time and label stay what every reader of them
+    knows. A return when nothing records. `after`: mark only a span
+    whose open stage is that one: what a callee says that does not
+    own the span it marks (Prepared.run also runs beneath `plan`,
+    for a subquery bound at prepare, and must not cut `build`)."""
+    s = _tls.span
+    if s is None:
+        return
+    if after is not None and not (s.stages and s.stages[-1][0] == after):
+        return
+    s.stages.append([name, time.monotonic_ns(),
+                     time.thread_time_ns() - s.cpu0_ns if _tls.cpu
+                     else 0, 0])
+
+
+def reads_cpu() -> bool:
+    """Does the recording open on this thread read the CPU clock (a
+    caller that stamps another thread's work for it asks first)."""
+    return _tls.cpu and _tls.span is not None
+
+
+def stage_cpu(cpu_ns: int, **tags) -> None:
+    """Credit the open span's current stage with CPU that another
+    thread spent for it (the mesh dispatcher running the call this
+    thread waits for); `tags` land on the span."""
+    s = current_span()
+    if s is None:
+        return
+    if s.stages:
+        s.stages[-1][3] += int(cpu_ns)
+    s.tags.update(tags)
 
 
 def event(name: str, **tags) -> Optional[Span]:
@@ -259,7 +371,7 @@ def event(name: str, **tags) -> Optional[Span]:
         return None
     now = time.monotonic_ns()
     s = Span(name, now, now, tags=dict(tags), span_id=next(_ids),
-             trace_id=parent.trace_id)
+             trace_id=parent.trace_id, cpu_ns=0)
     parent.children.append(s)
     return s
 
@@ -288,9 +400,13 @@ def capture(name: str = "trace", remote_ctx: Optional[dict] = None,
     had the work (the frame's arrival). `collect` marks a statement
     root: the collector, while on, keeps it when it closes."""
     prev = current_span()
-    prev_req = getattr(_tls, "rec_req", True)
+    prev_req, prev_cpu = _tls.rec_req, _tls.cpu
+    # a root the collector will keep reads the CPU clock only if the
+    # collector asked for it (start_collector); any other recording does
+    cpu = _collect_cpu or not (collect and _collected is not None)
     root = Span(name, start_ns or time.monotonic_ns(), tags=dict(tags),
-                span_id=next(_ids))
+                span_id=next(_ids),
+                cpu0_ns=time.thread_time_ns() if cpu else 0)
     if remote_ctx:
         root.trace_id = int(remote_ctx.get("tid", 0))
         psid = int(remote_ctx.get("sid", 0))
@@ -302,12 +418,16 @@ def capture(name: str = "trace", remote_ctx: Optional[dict] = None,
         root.trace_id = next(_ids)
     _tls.span = root
     _tls.rec_req = True if record_request is None else bool(record_request)
+    _tls.cpu = cpu
     try:
         yield root
     finally:
+        if cpu:
+            root.cpu_ns = time.thread_time_ns() - root.cpu0_ns
         root.end_ns = time.monotonic_ns()
         _tls.span = prev
         _tls.rec_req = prev_req
+        _tls.cpu = prev_cpu
         kept = _collected
         if collect and kept is not None:
             kept.append(root)
@@ -324,9 +444,6 @@ class Tracer:
     Tracer shares the same per-thread recording, which is what lets
     fabric/KV/DistSQL spans land inside the engine's capture."""
 
-    def _cur(self) -> Optional[Span]:
-        return current_span()
-
     def span(self, name: str, **tags):
         return span(name, **tags)
 
@@ -336,8 +453,3 @@ class Tracer:
 
     def tag(self, **tags) -> None:
         tag(**tags)
-
-    def recording(self) -> bool:
-        """Is a span open on this thread (would a tag land)? Lets a
-        caller skip computing tags nothing records."""
-        return current_span() is not None

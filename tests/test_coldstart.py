@@ -140,7 +140,11 @@ class TestCompileCachePlumbing:
         assert after["exec.compile.seconds"] \
             > before["exec.compile.seconds"]
 
-    def test_statement_compile_split_recorded(self):
+    def test_statement_compile_split_recorded(
+            self, private_compile_cache):
+        # a cold cache of its own: a persistent cache that any test of
+        # this worker warmed with the same program loads it in 0 s,
+        # and the split under test would have nothing to show
         eng = Engine()
         eng.execute("CREATE TABLE sp (v INT)")
         eng.execute("INSERT INTO sp VALUES (1), (5), (9)")

@@ -70,13 +70,13 @@ def _self_ns(s):
         c.end_ns - c.start_ns for c in s.children)
 
 
-def _served_root(addr, sql):
+def _served_root(addr, sql, cpu=False):
     """Serve `sql` once warm, then once more with the collector on:
     the one root of that second execution."""
     c = _client(addr)
     try:
         c.query(sql)
-        tracing.start_collector()
+        tracing.start_collector(cpu=cpu)
         c.query(sql)
         roots = _stop_after_served(1)
     finally:
@@ -228,7 +228,18 @@ class TestOffIsOff:
                          tracing.trace_context()))
             return inner(stmt, session, sql_text)
 
+        class CountedClock:
+            """tracing's `time`, with the CPU clock counted."""
+            calls = 0
+            monotonic_ns = staticmethod(time.monotonic_ns)
+
+            @classmethod
+            def thread_time_ns(cls):
+                cls.calls += 1
+                return time.thread_time_ns()
+
         monkeypatch.setattr(tracing, "Span", CountedSpan)
+        monkeypatch.setattr(tracing, "time", CountedClock)
         monkeypatch.setattr(node.engine, "_dispatch_stmt", probe)
         c = _client(node.sql_addr)
         try:
@@ -237,6 +248,34 @@ class TestOffIsOff:
             c.close()
         assert seen == [(None, False, None)]
         assert made == []
+        assert CountedClock.calls == 0
+        # and a mark with nothing open is a return: no span to mark
+        assert tracing.stage("build") is None
+        assert tracing.stage_cpu(5) is None
+        assert CountedClock.calls == 0
+        # the collector's roots read it only when asked
+        tracing.start_collector()
+        c = _client(node.sql_addr)
+        try:
+            c.query(Q.format(k=6))
+        finally:
+            c.close()
+        root = [r for r in _stop_after_served(1)
+                if r.tags.get("served")][-1]
+        assert CountedClock.calls == 0 and made
+        assert all(s.cpu_ns is None for s in _walk(root))
+        assert root.stages and not any(m[2] for s in _walk(root)
+                                       for m in s.stages)
+        # the same statement with the clock asked for does read it:
+        # the probe above counts what it claims to
+        tracing.start_collector(cpu=True)
+        c = _client(node.sql_addr)
+        try:
+            c.query(Q.format(k=6))
+        finally:
+            c.close()
+        _stop_after_served(1)
+        assert CountedClock.calls > 0
 
     def test_untraced_rpc_ships_no_recording_request(self):
         """A statement of an untraced session on a socket cluster:
@@ -274,6 +313,227 @@ class TestOffIsOff:
         finally:
             n1.stop()
             n2.stop()
+
+
+def _spin(seconds):
+    end = time.monotonic() + seconds
+    while time.monotonic() < end:
+        pass
+
+
+def _stage_self_ns(s):
+    """[(stage, self ns)] of a span: a stage owns the span's self time
+    from its mark to the next mark or the close; `None` before the
+    first mark."""
+    cuts = [(None, s.start_ns)] + [(m[0], m[1]) for m in s.stages]
+    ends = [at for _, at in cuts[1:]] + [s.end_ns]
+    out = []
+    for (name, a), b in zip(cuts, ends):
+        kids = sum(max(0, min(b, c.end_ns) - max(a, c.start_ns))
+                   for c in s.children)
+        out.append((name, b - a - kids))
+    return out
+
+
+class TestCpuAndStages:
+    """ISSUE 38: a span carries its thread's CPU, a layer's own time
+    is cut by marks, the process counts its threads and collector."""
+
+    @pytest.mark.parametrize("work,lo,hi", [
+        (_spin, 0.8, 1.2), (time.sleep, 0.0, 0.1)],
+        ids=["busy_loop", "sleep"])
+    def test_a_span_records_its_threads_cpu(self, work, lo, hi):
+        for _ in range(12):     # a shared machine can preempt a spin
+            c0 = time.thread_time_ns()
+            with tracing.capture("root") as root:
+                with tracing.span("child") as sp:
+                    work(0.05)
+            spent = time.thread_time_ns() - c0
+            # the span's reading is this thread's clock, whatever the
+            # machine did to the thread meanwhile
+            assert 0 <= spent - sp.cpu_ns < 2_000_000, (spent, sp.cpu_ns)
+            wall = sp.end_ns - sp.start_ns
+            if lo * wall <= sp.cpu_ns <= hi * wall:
+                break
+        if work is _spin and sp.cpu_ns < lo * wall:
+            pytest.skip("the machine gave a spinning thread under 80 % "
+                        "of a core in twelve tries of 50 ms")
+        assert lo * wall <= sp.cpu_ns <= hi * wall, (sp.cpu_ns, wall)
+        assert root.cpu_ns >= sp.cpu_ns
+        assert f"cpu={sp.cpu_ns / 1e6:.2f}ms" in root.tree_lines()[1]
+
+    def test_a_span_stamped_elsewhere_has_no_cpu_unless_given(self):
+        with tracing.capture("root") as root:
+            t = time.monotonic_ns()
+            wait = tracing.record("queue", t - 50, t)
+            call = tracing.record("call", t - 40, t, cpu_ns=17, k=1)
+            tracing.event("mark")
+        assert wait.cpu_ns is None and "cpu=" not in root.tree_lines()[1]
+        assert call.cpu_ns == 17 and call.tags == {"k": 1}
+        wire = tracing.span_to_wire(root)
+        assert "u" not in wire["c"][0] and wire["c"][1]["u"] == 17
+        assert tracing.span_from_wire(wire).children[0].cpu_ns is None
+
+    def test_stages_partition_a_spans_self_time(self):
+        with tracing.capture("root") as root:
+            with tracing.span("plan") as plan:
+                _spin(0.002)                # before any mark
+                tracing.stage("build")
+                _spin(0.004)
+                tracing.stage("tables")
+                with tracing.span("upload"):
+                    time.sleep(0.003)
+                _spin(0.001)
+                tracing.stage("key")
+                _spin(0.002)
+        parts = _stage_self_ns(plan)
+        assert [n for n, _ in parts] == [None, "build", "tables", "key"]
+        assert all(ns > 0 for _, ns in parts)
+        assert sum(ns for _, ns in parts) == _self_ns(plan)   # exactly
+        by = dict(parts)
+        # a stage owns what its span owns there: `tables` less `upload`
+        (_, t_build, _, _), (_, t_tables, _, _), (_, t_key, _, _) = \
+            plan.stages
+        upload = plan.children[0]
+        assert by["build"] == t_tables - t_build >= 4e6
+        assert by["tables"] == (t_key - t_tables) - (
+            upload.end_ns - upload.start_ns) > 0
+        # CPU likewise: marks read the span's own clock, in order
+        cpus = [m[2] for m in plan.stages]
+        assert cpus == sorted(cpus) and cpus[-1] <= plan.cpu_ns
+        iv = plan.stage_intervals()
+        assert [i[0] for i in iv] == ["build", "tables", "key"]
+        assert iv[0][2] == iv[1][1] and iv[-1][2] == plan.end_ns
+        assert sum(i[3] for i in iv) == plan.cpu_ns - cpus[0]
+        assert "stages[build=" in root.tree_lines()[1]
+        # and the wire carries them; a reader of b / e / c alone reads
+        # the span it read before
+        wire = tracing.span_to_wire(root)
+        back = tracing.span_from_wire(json.loads(json.dumps(wire)))
+        p2 = back.find("plan")
+        assert p2.stages == plan.stages and p2.cpu_ns == plan.cpu_ns
+        assert _stage_self_ns(p2) == parts
+        assert (p2.start_ns, p2.end_ns, len(p2.children)) == \
+            (plan.start_ns, plan.end_ns, 1)
+        old = {k: v for k, v in wire["c"][0].items()
+               if k not in ("u", "g")}
+        assert tracing.span_from_wire(old).stages == []
+
+    def test_a_served_statements_layers_are_staged(self, node):
+        root = _served_root(node.sql_addr, Q.format(k=15), cpu=True)
+        assert [m[0] for m in root.stages] == [
+            "frame", "route", "setup", "account"]
+        stmt = root.find(Q.format(k=15))
+        assert [m[0] for m in stmt.stages] == ["select", "unwind"]
+        assert [m[0] for m in stmt.find("plan").stages] == [
+            "build", "placement", "tables", "key", "fingerprint", "lookup"]
+        assert [m[0] for m in stmt.find("dispatch").stages] == [
+            "args", "call"]
+        assert [m[0] for m in stmt.find("materialize").stages] == [
+            "flags", "assemble"]
+        for s in _walk(root):
+            # marks lie inside their span, in order, outside its children
+            at = [m[1] for m in s.stages]
+            assert at == sorted(at)
+            assert all(s.start_ns <= t <= s.end_ns for t in at), s.name
+            assert not any(c.start_ns < t < c.end_ns
+                           for t in at for c in s.children), s.name
+            assert sum(ns for _, ns in _stage_self_ns(s)) == _self_ns(s)
+            if s.name not in ("wire.queue", "queue"):
+                assert s.cpu_ns is not None and \
+                    0 <= s.cpu_ns <= (s.end_ns - s.start_ns) * 1.05 + 50_000
+
+    def test_the_mesh_calls_cpu_is_the_dispatcher_threads(self):
+        """queued_collective_call runs the call on `mesh-dispatch-*`
+        while the statement's thread waits: the stage open there is
+        credited with that thread's CPU, and tagged with its stamps."""
+        from cockroach_tpu.parallel.distagg import queued_collective_call
+
+        def burn(x):     # 50 ms of this thread's CPU, however long
+            t0 = time.thread_time()
+            while time.thread_time() - t0 < 0.05:
+                pass
+            return (x, threading.current_thread().name)
+
+        call = queued_collective_call(burn)
+        assert call(1)[1].startswith("mesh-dispatch-")   # untraced
+        with tracing.capture("root"):
+            with tracing.span("dispatch") as disp:
+                tracing.stage("args")
+                tracing.stage("call")
+                out, name = call(2)
+        assert out == 2 and disp.tags["call_thread"] == name
+        assert [c.name for c in disp.children] == ["queue"]
+        (_, _, _, args_other), (_, _, _, other) = disp.stages
+        assert args_other == 0
+        ran = disp.tags["call_e"] - disp.tags["call_b"]
+        # (two clocks: the CPU's may run a hair ahead of the wall's)
+        assert 50e6 <= other <= ran * 1.02 + 100_000
+        assert ran <= disp.end_ns - disp.start_ns
+        # the statement's own thread slept through it
+        assert disp.cpu_ns < 0.2 * other
+        assert disp.children[0].end_ns <= disp.tags["call_b"]
+
+    def test_thread_counters_rise_for_a_worker_that_spins(self, node):
+        def read():
+            snap = node.engine.metrics.snapshot()
+            return {g: snap[f"process.threads.cpu.seconds.{g}"]
+                    for g in ("reactor", "workers", "mesh_dispatch",
+                              "other")}, snap["process.cpu.seconds"], \
+                snap["process.wall.seconds"]
+        stop = threading.Event()
+
+        def spinner():
+            while not stop.is_set():
+                pass
+
+        ts = [threading.Thread(target=spinner, name="pgfront-worker_77"),
+              threading.Thread(target=stop.wait, name="mesh-dispatch-z")]
+        before, cpu0, wall0 = read()
+        for t in ts:
+            t.start()
+        try:
+            read()               # both seen while alive
+            time.sleep(0.3)
+            mid, _, _ = read()
+        finally:
+            stop.set()
+            for t in ts:
+                t.join(5)
+        assert not any(t.is_alive() for t in ts)
+        time.sleep(0.002)
+        after, cpu1, wall1 = read()
+        assert mid["workers"] - before["workers"] > 0.05
+        assert mid["mesh_dispatch"] - before["mesh_dispatch"] < 0.02
+        # a thread that has exited keeps what it had
+        assert after["workers"] >= mid["workers"]
+        assert cpu1 - cpu0 >= mid["workers"] - before["workers"]
+        assert 0.3 <= wall1 - wall0 < 30
+
+    def test_a_forced_collection_lands_in_its_generation(self, node):
+        import gc
+
+        def read():
+            snap = node.engine.metrics.snapshot()
+            return [snap[f"process.gc.pause.seconds.gen{g}"]
+                    for g in range(3)]
+        before = read()
+        junk = [[i] for i in range(50_000)]
+        gc.collect(2)
+        gc.collect(0)
+        after = read()
+        del junk
+        assert after[2]["count"] >= before[2]["count"] + 1
+        assert after[2]["sum"] > before[2]["sum"]
+        assert after[0]["count"] >= before[0]["count"] + 1
+        host, port = node.http_addr
+        with urllib.request.urlopen(
+                f"http://{host}:{port}/_status/runtime", timeout=10) as r:
+            proc = json.loads(r.read().decode())["process"]
+        assert proc["gc.pause.seconds.gen2"]["count"] >= after[2]["count"]
+        assert proc["cpu.seconds"] > 0 and \
+            {"threads.cpu.seconds." + g for g in (
+                "reactor", "workers", "mesh_dispatch", "other")} <= set(proc)
 
 
 class TestReadersSeeTheNewSpans:

@@ -178,16 +178,6 @@ class TestProfileParityAndOverhead:
             eng.settings.set("sql.stmt_profile.enabled", True)
         assert on.rows == off.rows  # exact, not approx
 
-    def test_coarse_plane_populates_last_profile(self, node):
-        eng = node.engine
-        eng.execute(Q)
-        sink = eng.last_profile
-        assert sink is not None
-        assert sink.total_bytes_moved() >= 0
-        digest = sink.summary()
-        assert set(digest) == {"top_ops", "bytes_moved",
-                               "device_seconds"}
-
     def test_operator_profile_digest(self, node):
         out = node.engine.operator_profile(Q)
         assert out["top_ops"], out
@@ -287,11 +277,9 @@ class TestCloseLifecycle:
         rid = eng.stmtdiag.arm("SELECT count(*) FROM t")["request_id"]
         eng.execute("SELECT count(*) FROM t")
         assert eng.stmtdiag.get(rid) is not None
-        assert eng.last_profile is not None
         eng.close()
         assert eng.stmtdiag.get(rid) is None
         assert eng.stmtdiag.summary() == {"armed": [], "bundles": []}
-        assert eng.last_profile is None
 
 
 def _slice(cols, lo, hi):
