@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import datetime
+import math
 import threading
 from dataclasses import dataclass, field
 from typing import Optional
@@ -56,8 +57,8 @@ from . import coldstart
 from . import movement
 from .compile import (AGG_STRATEGY, COMPACTS, JOIN_KINDS, RANGE_PROOFS,
                       ExecParams, JoinStats, RunContext,
-                      aggregate_strategy, can_stream, compile_plan,
-                      compile_streaming, plan_rows)
+                      _compact_block_rows, aggregate_strategy, can_stream,
+                      compile_plan, compile_streaming, plan_rows)
 from .planparam import (SubqueryValue, inline_subquery_args,
                         param_signature, parameterize, plan_fingerprint,
                         shape_text)
@@ -78,6 +79,33 @@ from .stmtutil import (_StreamFns, _RerunPrepared, _has_prefix_sort,
 
 EPOCH_DATE = datetime.date(1970, 1, 1)
 EPOCH_DT = datetime.datetime(1970, 1, 1)
+
+# -- where a Compact pays (Engine._insert_compaction) ------------------------
+# Measured on one TPU v5e, in nanoseconds a row of the batch; PERF.md
+# section 6 holds the runs. They are facts of the chip and of the
+# kernels, not settings.
+# One Compact over 2^23 rows is 1.3 ms plus 0.5 ms a carried 32-bit
+# word, whatever its capacity (PR 37: 2.69 ms at three int32 columns,
+# 3.29 at four, 4.76 at seven; ops/pallas/compact.py's route and pack)
+COMPACT_NS = 1.3e6 / (1 << 23)
+COMPACT_WORD_NS = 0.5e6 / (1 << 23)
+# a probed key (PR 37, SSB's first, full-width probes over 2^23 keys):
+# 60 ms into `date`'s 2,557 rows, 80-124 ms into customer's 30,000 and
+# the larger builds
+PROBE_SMALL_NS = 7.0
+PROBE_SMALL_ROWS = 1 << 13
+PROBE_NS = 12.0
+# an aggregate that scatters is priced as one more such pass
+SCATTER_NS = PROBE_NS
+# wrap where the saving is at least this many times the cost: the
+# prices above are means over plans that differ by a factor of two
+# (where XLA keeps the table a probe gathers from: PERF.md section 7),
+# and a Compact also costs a Mosaic compile a carried column, once a
+# plan. PR 39 chose it on the chip: PERF.md section 6
+COMPACT_PAYS = 2.0
+# no Compact whose capacity is over half its input: it saves under half
+# of what lies above and its headroom is thinnest there
+COMPACT_MAX_FRAC = 1 / 2
 
 
 def _find_scan_column(node, bname: str):
@@ -558,6 +586,15 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 f"{what}: one tally a traced Compact, static shapes "
                 "(compile.compact_batch, the displacement network of "
                 "ops/pallas/compact.py)")
+        self._m_compact_overflows = self.metrics.counter(
+            "exec.compact.overflows",
+            "statements answered by the uncompacted replan: a block of "
+            "one of the plan's Compacts kept more rows than its capacity "
+            "(the estimate undershot, or the rows are skewed between "
+            "blocks), the __compact_overflow sentinel came back set and "
+            "the statement ran again without Compacts. One a statement "
+            "and an execution: such a statement pays for both programs "
+            "every time it runs")
         self._m_subquery = {
             k: self.metrics.counter(
                 "exec.subquery." + k,
@@ -2548,6 +2585,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 # than dropping to the ~100x-slower decoded-row
                 # ingest (which would also re-compact and overflow
                 # again before its own fallback)
+                self._m_compact_overflows.inc()
                 prep = self._prepare_select(sub, session, sql_text,
                                             no_compact=True)
                 out = prep.dispatch()
@@ -2934,7 +2972,8 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             # under streaming (the sentinel cannot ride page state)
             # and distributed plans (a per-shard pack + psum merges
             # would need sentinel plumbing through collectives)
-            node = self._insert_compaction(node)
+            node = self._insert_compaction(
+                node, {a: b.n for a, b in scans.items()})
         # statement-shape plan cache: lift filter literals out of the
         # plan into runtime arguments so literal-varying statements of
         # one shape share a compiled program (the reference strips
@@ -3939,22 +3978,28 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         return Result(names=[name], rows=rows, types=[INT8])
 
     # -- selection compaction (compile.compact_batch) ------------------------
-    COMPACT_MAX_EST = 1 / 8     # only bother below this selectivity
-
     def _estimate_scan_selectivity(self, scan) -> float | None:
-        """Upper-bound selectivity of a scan's pushed-down filter from
+        """Estimated selectivity of a scan's pushed-down filter from
         stored column ranges (the int_range direct-join machinery
-        reused as a mini histogram: uniform within [min, max]). Only
-        int-family range/equality conjuncts contribute; every other
-        conjunct can only shrink the true selectivity further, so the
-        estimate stays an UPPER bound — safe for sizing capacity."""
+        reused as a mini histogram: uniform within [min, max]) and
+        dictionary sizes, conjuncts taken as independent; None where
+        nothing of the filter is understood. Int-family range,
+        equality and BETWEEN conjuncts contribute; a conjunct that is
+        not understood can only shrink the true share further. Not a
+        bound: skew inside a range, correlated conjuncts and the
+        near-unique guess for an IN list can all undershoot, and a
+        Compact's capacity (_compact_frac) is the estimate plus a
+        headroom that is 1.5x where the estimate is large. What makes
+        that safe is the Compact's overflow sentinel and the
+        uncompacted replan, never the estimate."""
         return self._estimate_pred_selectivity(scan, scan.filter)
 
     def _estimate_pred_selectivity(self, scan, pred) -> float | None:
         """_estimate_scan_selectivity of one predicate over the scan's
         columns: a conjunction, whose OR conjuncts are estimated arm by
         arm."""
-        from ..sql.bound import BBin, BCol, BConst, BDictLookup, BInList
+        from ..sql.bound import (BBetween, BBin, BCol, BConst,
+                                 BDictLookup, BInList)
         if pred is None:
             return None
         cons: dict[str, list] = {}
@@ -4019,6 +4064,16 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                     if r is not None and r[2] > 0:
                         dict_fracs.append(
                             min(1.0, len(e.values) / r[2]))
+                return
+            if isinstance(e, BBetween) and not e.negated:
+                # `lo_discount between 1 and 3`: its two bounds
+                if isinstance(e.expr, BCol) \
+                        and not e.expr.type.uses_dictionary and all(
+                        isinstance(b, BConst) and isinstance(b.value, int)
+                        and not isinstance(b.value, bool)
+                        for b in (e.lo, e.hi)):
+                    cons.setdefault(e.expr.name, []).extend(
+                        [(">=", e.lo.value), ("<=", e.hi.value)])
                 return
             if isinstance(e, BBin) and e.op in ("<", "<=", ">", ">=",
                                                 "="):
@@ -4127,42 +4182,74 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 **kinds}
 
     def _compact_frac(self, est: float) -> float:
-        # 4x headroom over the uniform estimate absorbs moderate
-        # per-block skew; worse skew trips the sentinel and the
-        # engine replans uncompacted
-        return min(0.25, max(est * 4, 1 / 256))
+        """Capacity of a Compact, as a share of its input batch, whose
+        rows an estimated `est` survive: the estimate times a headroom
+        that falls as the estimate grows. 4x up to a sixteenth (skew
+        between blocks at a share of 1/100 is what it was set for),
+        then linear in log2(est) down to 1.5x at a quarter and above:
+        a 32,768-row block that keeps a fifth of uniform rows deviates
+        by 1 % of its mean, so there the headroom only has to cover the
+        estimate itself, and a capacity past a half buys nothing (the
+        caller wraps nothing above COMPACT_MAX_FRAC). Worse skew, or
+        an estimate that undershoots, trips the block's sentinel and
+        the statement is answered by the uncompacted plan."""
+        t = min(1.0, max(0.0, (math.log2(max(est, 1e-9)) + 4) / 2))
+        return max(est * (4 - 2.5 * t), 1 / 256)
 
-    def _insert_compaction(self, node):
-        """Wrap the DEEPEST point of a probe spine under aggregation
-        where the estimated surviving fraction drops to <= 1/8 in a
-        Compact node (compile.compact_batch): everything above — join
-        probe gathers, CASE math, grouped scatter-adds — then runs at
-        a fraction of the batch width.
+    def _insert_compaction(self, node, scan_rows: dict | None = None):
+        """Wrap a probe spine under aggregation in Compact nodes
+        (compile.compact_batch) wherever packing the survivors costs
+        clearly less than it saves: everything above a Compact (join
+        probe gathers, CASE math, grouped scatter-adds) runs over its
+        capacity and not over the batch it was handed.
 
-        Selectivity accumulates up the spine: a scan's pushed filter
-        (Q14's date range) or an INNER join against a filtered build
-        side (SSB's p_category/s_region dimension predicates, folded
-        into the packed join table) both shrink the selected set, so
-        the wrap point may be a Scan or a mid-spine HashJoin. A scan
-        feeding aggregation with NO join and no scatter stays masked:
-        the fused filter+agg pipeline is already optimal (measured:
-        Q6 1.9B -> 33M rows/s when compacted). Wraps above the last
-        join additionally require a scatter-strategy aggregate (hash,
-        or dense beyond the unrolled small-G path) so there is real
-        work left to shrink. Expanding joins (duplicate build keys)
-        bound the wrap point — their output length breaks the est
-        bookkeeping above, but the spine below them still compacts,
-        so the K-way copy runs over the packed width. A spine may be
-        wrapped again further up, where the estimate has fallen far
-        enough to shrink the packed batch eight-fold once more.
-        Project and Window stop the walk (fresh columns would drop the
-        sentinel / order matters). A Compact keeps a block's rows in
-        their order but interleaves filler between blocks, and what
-        sits above one must not read an order out of it. Each Compact
-        is told which 64-bit columns of its batch are stored columns
-        the store proves within int32 (`narrow`): those travel as one
-        word."""
+        The candidates are a Scan with a join above it and a
+        non-expanding HashJoin whose build side's filter thins the
+        batch, with a join or a scattering aggregate above. Selectivity
+        accumulates up the spine: a scan's pushed filter (Q14's date
+        range, SSB Q1.x's discount and quantity) and every inner join
+        against a filtered build side (SSB's dimension predicates,
+        folded into the packed join table) shrink the selected set. At
+        a candidate whose batch holds `rows` rows of which an estimated
+        share survives, the capacity is _compact_frac of that share
+        and the two sides are, in nanoseconds a row of the batch
+        (constants beside COMPACT_PAYS, measured on the v5e):
+
+          cost    COMPACT_NS + COMPACT_WORD_NS a carried 32-bit word:
+                  the batch's columns that anything above reads, a
+                  64-bit one two words unless the store proves it
+                  within int32 (`narrow`, which the Compact is told);
+          saving  (1 - capacity) of the work above: PROBE_NS a probe
+                  (PROBE_SMALL_NS into a build of PROBE_SMALL_ROWS rows
+                  or fewer) and SCATTER_NS once if the aggregate
+                  scatters (hash, or dense beyond the unrolled small-G
+                  path); a Project-rooted spine counts as one scatter.
+
+        It is wrapped where saving >= COMPACT_PAYS * cost, the capacity
+        is at most COMPACT_MAX_FRAC and the packed batch is smaller
+        (compile._compact_block_rows: a batch under two blocks, or
+        ragged, is handed on as it is). A spine is wrapped again by the
+        same comparison wherever a join thins it further (SSB Q3.2
+        keeps 0.04 after the customer join and 0.0016 after the
+        supplier's). A scan feeding aggregation with NO join above
+        stays masked: the fused filter+agg pipeline is already optimal
+        (measured: Q6 1.9B -> 33M rows/s when compacted). Expanding
+        joins (duplicate build keys) bound the wrap point: their
+        output length breaks the bookkeeping above, but the spine below
+        them still compacts, so the K-way copy runs over the packed
+        rows. Project and Window stop the walk (fresh columns would
+        drop the sentinel / order matters). A Compact keeps a block's
+        rows in their order but interleaves filler between blocks, and
+        what sits above one must not read an order out of it.
+        `scan_rows` is {scan alias: padded rows of its batch}; without
+        it the store's row count stands in. No estimate is trusted for
+        correctness: see _estimate_scan_selectivity."""
         from ..sql import plan as P
+        from ..sql.bound import BCol
+        from ..sql.bound import walk as walk_expr
+
+        def table_rows(scan) -> int:
+            return self.store.table(scan.table).row_count
 
         def build_sel(jn) -> float:
             if jn.join_type != "inner":
@@ -4172,86 +4259,127 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 return e if e is not None else 1.0
             return 1.0
 
-        def narrow(n) -> set:
-            """Batch columns of the spine under `n` that are stored
-            columns proven within int32 (narrow32_cols): a scan's
-            columns keep their names through filters, joins (as
-            probe columns or payload) and Compacts; anything else
-            renames or computes, and proves nothing."""
+        def probe_ns(jn) -> float:
+            small = isinstance(jn.right, P.Scan) \
+                and table_rows(jn.right) <= PROBE_SMALL_ROWS
+            return PROBE_SMALL_NS if small else PROBE_NS
+
+        def reads_of(exprs) -> dict:
+            """{column: the 32-bit words its type travels as} over the
+            columns the expressions read."""
+            out = {}
+            for e in exprs:
+                for x in walk_expr(e):
+                    if isinstance(x, BCol):
+                        wide = not x.type.uses_dictionary \
+                            and x.type.np_dtype.itemsize > 4
+                        out[x.name] = 2 if wide else 1
+            return out
+
+        seen: dict[int, tuple] = {}
+
+        def columns(n) -> tuple[set, set]:
+            """(the batch's columns, those of them that are stored
+            columns proven within int32: narrow32_cols) for the spine
+            under `n`. A scan's columns keep their names through
+            filters, joins (as probe columns or payload) and Compacts;
+            anything else renames or computes, and proves nothing."""
+            if isinstance(n, (P.Filter, P.Compact)):
+                return columns(n.child)
+            got = seen.get(id(n))
+            if got is not None:
+                return got
+            got = set(), set()
             if isinstance(n, P.Scan):
                 fits = self.narrow32_cols(
                     n.table, frozenset(n.columns.values()))
-                return {bn for bn, sn in n.columns.items() if sn in fits}
-            if isinstance(n, (P.Filter, P.Compact)):
-                return narrow(n.child)
-            if isinstance(n, P.HashJoin):
-                return narrow(n.left) | narrow(n.right)
-            return set()
+                got = set(n.columns), {bn for bn, sn in n.columns.items()
+                                       if sn in fits}
+            elif isinstance(n, P.HashJoin):
+                have, slim = columns(n.left)
+                got = have | set(n.payload), slim | columns(n.right)[1]
+            seen[id(n)] = got
+            return got
 
-        def wrap(n, est, width):
-            """(Compact over n, its share of the scan's rows) for a
-            batch `width` of the scan's rows wide that `est` of them
-            survive into."""
-            frac = self._compact_frac(est / width)
-            return P.Compact(n, frac=frac,
-                             narrow=frozenset(narrow(n))), width * frac
+        def wrap(n, live, rows, above_ns, reads):
+            """(n, or a Compact over it where that pays; the rows of
+            the batch handed on) for a batch of `rows` rows of which an
+            estimated `live` survive, under `above_ns` of work a row
+            and the columns `reads` names."""
+            block = P.Compact.block
+            frac = self._compact_frac(live / rows)
+            kb = _compact_block_rows(rows, frac, block)
+            if frac > COMPACT_MAX_FRAC or kb == block:
+                return n, rows
+            have, slim = columns(n)
+            words = sum(1 if c in slim else w
+                        for c, w in reads.items() if c in have)
+            cost = COMPACT_NS + COMPACT_WORD_NS * words
+            saving = (1 - kb / block) * above_ns
+            if saving < COMPACT_PAYS * cost:
+                return n, rows
+            return (P.Compact(n, frac=frac, narrow=frozenset(slim)),
+                    rows // block * kb)
 
-        # (node, est, width, joins_below): est the share of the scan's
-        # rows that survive, width the share the batch holds after the
-        # Compacts beneath (1.0 none yet; 0.0 nothing above may wrap)
-        def spine(n, joins_above, agg_scatters):
+        # spine(n, ...) -> (node, live, rows): `rows` those of the
+        # batch `n` hands on after the Compacts beneath (0: nothing
+        # above may wrap), `live` how many of them are estimated to be
+        # selected. `joined`: a join lies above; `above_ns` what the
+        # probes and the aggregate above cost a row of that batch;
+        # `reads` {column: words} what they read
+        def spine(n, joined, above_ns, reads):
             if isinstance(n, P.Filter):
-                c, est, width, jb = spine(n.child, joins_above,
-                                          agg_scatters)
+                c, live, rows = spine(n.child, joined, above_ns,
+                                      {**reads, **reads_of([n.pred])})
                 n.child = c
-                return n, est, width, jb
+                return n, live, rows
             if isinstance(n, P.Scan):
+                rows = (scan_rows or {}).get(n.alias) \
+                    or _next_pow2(max(table_rows(n), 1))
+                if not joined:
+                    return n, rows, rows
                 est = self._estimate_scan_selectivity(n)
-                est = est if est is not None else 1.0
-                if est <= self.COMPACT_MAX_EST and joins_above > 0:
-                    c, width = wrap(n, est, 1.0)
-                    return c, est, width, 0
-                return n, est, 1.0, 0
+                live = rows if est is None else est * rows
+                c, rows = wrap(n, live, rows, above_ns, reads)
+                return c, live, rows
             if isinstance(n, P.HashJoin):
+                below = dict(reads)
+                for k in n.left_keys:
+                    below.setdefault(k, 2)
                 if n.expand != 1:
-                    # output width is expand*input, which breaks the
-                    # est bookkeeping for wraps at or above this node
-                    # — but the probe spine BELOW still benefits: a
+                    # output length is expand*input, which breaks the
+                    # bookkeeping for wraps at or above this node —
+                    # but the probe spine BELOW still benefits: a
                     # selective join under the expansion compacts,
                     # and the K-way copy then multiplies the packed
-                    # width instead of the full batch. Width 0 so
+                    # rows instead of the full batch. Rows 0 so
                     # nothing above tries to compact the expanded
                     # output.
-                    c, _, _, jb = spine(n.left, joins_above + 1,
-                                        agg_scatters)
-                    n.left = c
-                    return n, 1.0, 0.0, jb + 1
-                c, left_est, width, jb = spine(
-                    n.left, joins_above + 1, agg_scatters)
+                    n.left = spine(n.left, True,
+                                   probe_ns(n) + n.expand * above_ns,
+                                   below)[0]
+                    return n, 0, 0
+                c, live, rows = spine(n.left, True,
+                                      above_ns + probe_ns(n), below)
                 n.left = c
-                est = left_est * build_sel(n)
-                # the first Compact of a spine where an eighth or less
-                # survives; another only where it shrinks the packed
-                # batch eight-fold again (at four times the estimate:
-                # SSB Q3.2 keeps 0.04 after the customer join and
-                # 0.0016 after the supplier's, and the hash table
-                # above ran over the 1.3 M rows of the first Compact,
-                # two or three 0.43 s passes by the seed's collisions)
-                bar = (self.COMPACT_MAX_EST if width == 1.0
-                       else self.COMPACT_MAX_EST / 4)
-                if width and est <= bar * width \
-                        and (joins_above > 0 or agg_scatters):
-                    c2, width = wrap(n, est, width)
-                    return c2, est, width, jb + 1
-                return n, est, width, jb + 1
-            return n, 1.0, 1.0, 0
+                thins = build_sel(n)
+                if rows and thins < 1.0:
+                    live *= thins
+                    c, rows = wrap(n, live, rows, above_ns, reads)
+                    return c, live, rows
+                return n, live, rows
+            return n, 0, 0
 
         def walk(n):
             if isinstance(n, P.Aggregate):
                 dense = n.max_groups > 0
                 scatters = bool(n.group_by) and \
                     (not dense or n.max_groups > 64)
-                n.child = spine(n.child, 0, scatters)[0]
+                reads = reads_of([e for _, e in n.group_by]
+                                 + [a.arg for a in n.aggs
+                                    if a.arg is not None])
+                n.child = spine(n.child, False,
+                                SCATTER_NS if scatters else 0.0, reads)[0]
                 return n
             if isinstance(n, P.Project):
                 # a projection-rooted spine (CTE/derived bodies, q9's
@@ -4259,7 +4387,8 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 # temp materialization above the compact are the work
                 # being shrunk; compile bubbles the overflow sentinel
                 # through Project
-                n.child = spine(n.child, 0, True)[0]
+                n.child = spine(n.child, False, SCATTER_NS,
+                                reads_of([e for _, e in n.items]))[0]
                 return n
             if isinstance(n, (P.Sort, P.Limit)):
                 n.child = walk(n.child)

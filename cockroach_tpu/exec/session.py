@@ -443,9 +443,7 @@ class Prepared:
             try:
                 return self.engine._run_partitioned(self, read_ts)
             except CompactOverflow:
-                return self.engine._prepare_select(
-                    self.stmt, self.session, self.sql_text,
-                    no_compact=True).run(read_ts)
+                return self._run_uncompacted(read_ts)
         except TopKInexact:
             # primary-key ties crossed the top-k candidate cut, or a
             # prefix sort met more groups than it holds: replan with
@@ -465,10 +463,15 @@ class Prepared:
                 self._adopt(whole)
             return whole.run(read_ts)
         except CompactOverflow:
-            # the stats-estimated selectivity undershot: replan with
-            # the full-width masked pipeline (always exact)
-            return self.engine._prepare_select(
-                self.stmt, self.session, self.sql_text,
-                no_compact=True).run(read_ts)
+            return self._run_uncompacted(read_ts)
+
+    def _run_uncompacted(self, read_ts):
+        """The stats-estimated selectivity undershot (or the rows are
+        skewed between blocks): replan with the full-width masked
+        pipeline, always exact, and count it (exec.compact.overflows)."""
+        self.engine._m_compact_overflows.inc()
+        return self.engine._prepare_select(
+            self.stmt, self.session, self.sql_text,
+            no_compact=True).run(read_ts)
 
 

@@ -94,17 +94,22 @@ class Compact(PlanNode):
     """Pack selected rows into a smaller batch (block by block, a
     log-step displacement network laid out from the selection mask:
     ops/pallas/compact.py; rows keep their order inside a block, and
-    nothing may depend on it). Inserted by the engine above
-    low-selectivity scans/filters feeding aggregation: every
-    downstream per-row op — join probe gathers above all — then runs
-    at ``frac`` of the batch instead of full width with masked lanes.
-    The TPU analogue of the reference's selection vectors
-    (coldata.Batch sel), which its operators consume implicitly; XLA
-    needs the compaction to be an explicit op. Per-block capacity
-    overflow raises the __compact_overflow sentinel and the engine
-    replans uncompacted."""
+    nothing may depend on it). Inserted by the engine
+    (Engine._insert_compaction) on a probe spine under aggregation
+    wherever the pack costs clearly less than the probes and the
+    scatter above it save: every downstream per-row op — join probe
+    gathers above all — then runs at ``frac`` of the batch instead of
+    full width with masked lanes. The TPU analogue of the reference's
+    selection vectors (coldata.Batch sel), which its operators consume
+    implicitly; XLA needs the compaction to be an explicit op.
+    Per-block capacity overflow raises the __compact_overflow sentinel
+    and the engine replans uncompacted (exec.compact.overflows)."""
     child: PlanNode
-    frac: float = 0.125     # per-block capacity fraction
+    # per-block capacity, a share of the block: the estimated share of
+    # the batch's rows that survive times a headroom, at most a half
+    # (Engine._compact_frac); compile rounds block * frac up to 128
+    # lanes. An estimate, never a bound: the sentinel is what is exact
+    frac: float = 0.125
     block: int = 32768
     # 64-bit columns of the batch whose values the store proves within
     # int32 (Engine.narrow32_cols over the scans beneath): each goes

@@ -127,12 +127,17 @@ def test_float_sums_beside_the_bf16_pass_compile(one_chip):
 # -- a Compact's displacement network ----------------------------------------
 
 @pytest.mark.parametrize("n,frac", [(1 << 23, 0.16), (1 << 23, 0.0508),
-                                    (1343488, 0.04)])
+                                    (1343488, 0.04),
+                                    (1 << 23, 0.3805), (3211264, 0.3211),
+                                    (1 << 23, 0.5)])
 def test_compact_batch_compiles_at_the_benchmarks_shapes(one_chip, n, frac):
     """The body the cells run, through Mosaic: SSB's first Compact at
     SF1 (2^23 rows, 5,248 of a block's 32,768 kept: 41 tile rows, not
     a multiple of 8), Q14's (1,664), and a second Compact over the
-    first one's 1,343,488 rows; a 64-bit column as two words, a bool,
+    first one's 1,343,488 rows; since PR 39 the larger capacities:
+    Q4.1's first Compact (12,544 of a block) and its second over the
+    3,211,264 rows that leaves (10,624), and a capacity of a half
+    (TPC-H Q21's, 16,384); a 64-bit column as two words, a bool,
     a float, a float64 (gathered by packed row numbers: XLA:TPU cannot
     split one), a nullable column, and an inner Compact's flag."""
     from cockroach_tpu.exec.compile import compact_batch, plan_rows
