@@ -35,6 +35,7 @@ from ..ops.pallas import compact as pallas_compact
 from ..sql import plan as P
 from ..sql.bound import BoundAgg
 from ..sql.types import Family
+from . import rollup
 from .expr import ExprContext, compile_expr
 
 
@@ -113,7 +114,10 @@ class JoinStats:
     the rows its probe side and its build side are traced over (static
     shapes: after any Compact beneath). A join takes its slot when it
     is compiled and fills it when it is traced; a retrace overwrites
-    the slot with the same numbers."""
+    the slot with the same numbers. Other operators' rows a dispatch
+    counts the same way by counter name (`site`, `note_site`: a
+    grouping-set Aggregate's exec.agg.rollup.rows, a Window's
+    exec.window.rows), summed in `site_totals`."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -121,6 +125,18 @@ class JoinStats:
         # (joins, probe rows, build rows) of the plan: summed when a
         # join is traced, read (one reference) by every dispatch
         self.totals: tuple = (0, 0, 0)
+        self._sites: dict = {}  # counter name -> [rows a slot]
+        self.site_totals: dict = {}
+
+    def site(self, name: str) -> int:
+        with self._lock:
+            self._sites.setdefault(name, []).append(0)
+            return len(self._sites[name]) - 1
+
+    def note_site(self, name: str, slot: int, rows: int) -> None:
+        with self._lock:
+            self._sites[name][slot] = int(rows)
+            self.site_totals = {k: sum(v) for k, v in self._sites.items()}
 
     def slot(self) -> int:
         with self._lock:
@@ -264,15 +280,10 @@ def _compile_plan(node: P.PlanNode, params: ExecParams,
                 cols[name] = d
                 valid[name] = v
             out = ColumnBatch.from_dict(cols, valid, sel=b.sel)
-            if b.has("__compact_overflow"):
-                # bubble a child Compact's capacity sentinel through
-                # the fresh output batch (projection drops child
-                # columns; the engine checks it at materialize time)
-                out = out.with_column(
-                    "__compact_overflow",
-                    jnp.broadcast_to(jnp.any(b.col("__compact_overflow")),
-                                     (out.n,)))
-            return out
+            # bubble the child's sentinels (a Compact's capacity, a
+            # prefix or grouping sets' slots) through the fresh output
+            # batch: the engine checks them at materialize time
+            return _carry_sentinels(out, b)
         return run_project
     if isinstance(node, P.HashJoin):
         leftf = compile_plan(node.left, params)
@@ -1209,6 +1220,12 @@ def _pallas_large_partials(aggfs, b, ctx, gid, num_groups: int,
     return aggs_out, live, overflow
 
 
+# window functions whose value depends on the order of the rows inside
+# a peer group (the rest read the group as a whole: its start, its end)
+PEER_ORDERED = ("row_number", "ntile", "lag", "lead", "first_value",
+                "last_value")
+
+
 def _compile_window(node: P.Window, params: ExecParams) -> CompiledNode:
     """Window functions: one lexsort + cumulative scans per spec
     (ops/window.py), materialized as __win{i} columns. Not
@@ -1217,6 +1234,10 @@ def _compile_window(node: P.Window, params: ExecParams) -> CompiledNode:
     if params.axis_name:
         raise ExecError("window functions cannot run distributed yet")
     childf = compile_plan(node.child, params)
+    prefix = window_prefix(node, params)
+    stats = params.join_stats
+    rows_slot = stats.site("exec.window.rows") if stats is not None \
+        else None
     specs = []
     for w in node.windows:
         specs.append((
@@ -1228,6 +1249,10 @@ def _compile_window(node: P.Window, params: ExecParams) -> CompiledNode:
 
     def run_window(rc: RunContext) -> ColumnBatch:
         b = childf(rc)
+        if 0 < prefix < b.n:
+            b = _dense_prefix(b, prefix)
+        if rows_slot is not None:
+            stats.note_site("exec.window.rows", rows_slot, b.n)
         ctx = _ctx_of(b, params=rc.params)
         for i, (w, argf, partfs, orderfs) in enumerate(specs):
             parts = [pf(ctx) for pf in partfs]
@@ -1236,7 +1261,8 @@ def _compile_window(node: P.Window, params: ExecParams) -> CompiledNode:
                 od, ov = of(ctx)
                 orders.append((od, ov, desc))
             order, seg_start, peer_start, sel_s = W.order_and_segments(
-                parts, orders, b.sel, params.sort_normalized)
+                parts, orders, b.sel, params.sort_normalized,
+                peers_ordered=w.func in PEER_ORDERED)
             framed = bool(orders)
             if w.func == "row_number":
                 d, v = W.row_number(order, seg_start, sel_s)
@@ -1261,6 +1287,8 @@ def _compile_window(node: P.Window, params: ExecParams) -> CompiledNode:
                 ad, av = argf(ctx) if argf is not None else (None, None)
                 d, v = W.window_agg(w.func, order, seg_start, peer_start,
                                     sel_s, ad, av, framed)
+                if w.func == "avg" and w.arg.type.family == Family.DECIMAL:
+                    d = d / 10.0 ** w.arg.type.scale
             b = b.with_column(f"__win{i}", d, v)
             ctx = _ctx_of(b, params=rc.params)
         return b
@@ -1276,7 +1304,19 @@ def _compile_aggregate(node: P.Aggregate, params: ExecParams) -> CompiledNode:
             # by sum/min/max merges; distagg.analyze refuses these
             # plans, so this is a belt-and-braces guard
             raise ExecError("DISTINCT aggregates cannot run distributed")
-    aggfs = _compile_agg_args(node.aggs)
+    sets = node.grouping_sets
+    if sets is not None:
+        if params.axis_name:
+            raise ExecError("GROUPING SETS cannot run distributed yet")
+        # the finest set computes partial states (AVG: a sum and a
+        # count), the other sets combine them (exec/rollup.py)
+        state, where = rollup.state_aggs(node.aggs)
+        aggfs = _compile_agg_args(state)
+        stats = params.join_stats
+        rows_slot = stats.site("exec.agg.rollup.rows") \
+            if stats is not None else None
+    else:
+        aggfs = _compile_agg_args(node.aggs)
     itemfs = [(name, compile_expr(e)) for name, e in node.items]
     havingf = compile_expr(node.having) if node.having is not None else None
     dense = node.max_groups > 0
@@ -1296,6 +1336,8 @@ def _compile_aggregate(node: P.Aggregate, params: ExecParams) -> CompiledNode:
 
     def run_agg(rc: RunContext) -> ColumnBatch:
         b = childf(rc)
+        if sets is not None and not dense:
+            return _sorted_sets(rc, b)
         # the phases a profile treats apart, under this node's scope:
         # keys (group ids), operands + kernel (the partials; the
         # large-G path names its own two inside ops/pallas), finalize
@@ -1426,6 +1468,16 @@ def _compile_aggregate(node: P.Aggregate, params: ExecParams) -> CompiledNode:
                 garange = jnp.arange(num_groups, dtype=jnp.int32)
                 live = garange < ng
 
+            if sets is not None:
+                rollup.SETS.bump("sets", len(sets))
+                cols, states, live, num_groups, rows = rollup.dense_sets(
+                    sets, dims, los, [n for n, _ in groupfs], aggs_out,
+                    state, live)
+                if rows_slot is not None:
+                    stats.note_site("exec.agg.rollup.rows", rows_slot,
+                                    rows)
+                group_cols = cols
+                aggs_out = rollup.finalize(node.aggs, where, states)
             out = _agg_output(group_cols, aggs_out, live, itemfs, havingf,
                               num_groups, overflow,
                               ht_ovf=(None if (not groupfs or dense)
@@ -1438,6 +1490,48 @@ def _compile_aggregate(node: P.Aggregate, params: ExecParams) -> CompiledNode:
                     jnp.broadcast_to(jnp.any(b.col("__compact_overflow")),
                                      (out.n,)))
         return out
+
+    def _sorted_sets(rc, b):
+        """A grouping-set Aggregate past the dense bound: one sort of
+        the rows by the keys' packed code (exec/rollup.py)."""
+        ctx = _ctx_of(b, params=rc.params)
+        with jax.named_scope("keys"):
+            keys = [gf(ctx) for _, gf in groupfs]
+        states, bound = [], jnp.bool_(True)
+        for a, argf in aggfs:
+            if a.func == "count_rows":
+                states.append((jnp.ones((b.n,), jnp.int64), b.sel))
+                continue
+            d, v = argf(ctx)
+            if a.func == "count":
+                d = jnp.ones((b.n,), jnp.int64)
+            elif a.func in ("sum", "sum_int") and d.dtype != jnp.float64:
+                d = d.astype(jnp.int64)
+                # a group's sum is exact while every running sum of its
+                # rows fits: rows x max |value| under 2^62 proves it
+                top = jnp.max(jnp.abs(jnp.where(
+                    jnp.logical_and(v, b.sel), d, 0))).astype(jnp.float64)
+                bound = jnp.logical_and(
+                    bound, top * b.n < jnp.float64(2 ** 62))
+            states.append((d, v))
+        slots = (node.set_slots if params.topk_sort and node.set_slots
+                 else b.n * len(sets))
+        rollup.SETS.bump("sets", len(sets))
+        AGG_STRATEGY.bump("sorted")
+        with jax.named_scope("kernel"):
+            cols, states, live, slots, rows, short = rollup.sorted_sets(
+                sets, node.sort_dims, [n for n, _ in groupfs], keys,
+                states, state, b.sel, slots)
+        if rows_slot is not None:
+            stats.note_site("exec.agg.rollup.rows", rows_slot, rows)
+        with jax.named_scope("finalize"):
+            out = _agg_output(cols, rollup.finalize(node.aggs, where,
+                                                    states),
+                              live, itemfs, havingf, slots,
+                              jnp.logical_not(bound))
+            out = out.with_column("__topk_inexact",
+                                  jnp.broadcast_to(short, (slots,)))
+        return _carry_sentinels(out, b)
     return run_agg
 
 
@@ -1625,7 +1719,7 @@ def topk_sort_limit_batch(b: ColumnBatch, keys, rank_tables,
     valid = tuple(v[idx] for v in b.valid)
     bm = ColumnBatch(data + (w[idx],),
                      valid + (jnp.ones(m, dtype=bool),),
-                     b.sel[idx], list(b.names) + ["__topk_w"])
+                     b.sel[idx], tuple(b.names) + ("__topk_w",))
     bs = sort_batch(bm, keys, rank_tables, mode)
     # exactness: every row whose rank word could place at or before
     # the k-th selected row must be a candidate
@@ -1635,10 +1729,10 @@ def topk_sort_limit_batch(b: ColumnBatch, keys, rank_tables,
     exact = jnp.logical_or(live <= m,
                            jnp.sum((w <= boundary).astype(jnp.int32))
                            <= m)
-    flag = jnp.broadcast_to(jnp.logical_not(exact), (m,))
-    out = ColumnBatch(bs.data + (flag,),
-                      bs.valid + (jnp.ones(m, dtype=bool),),
-                      bs.sel, list(bs.names) + ["__topk_inexact"])
+    inexact = jnp.logical_not(exact)
+    if b.has("__topk_inexact"):     # a prefix or slots beneath proved short
+        inexact = jnp.logical_or(inexact, jnp.any(b.col("__topk_inexact")))
+    out = bs.with_column("__topk_inexact", jnp.broadcast_to(inexact, (m,)))
     return limit_batch(out, limit, offset)
 
 
@@ -1686,6 +1780,8 @@ def _dense_prefix(b: ColumnBatch, k: int) -> ColumnBatch:
     it does when a top-k cut crosses a tie (TopKInexact -> no_topk),
     and remembers to for that plan."""
     cut = jnp.any(b.sel[k:])
+    if b.has("__topk_inexact"):
+        cut = jnp.logical_or(cut, jnp.any(b.col("__topk_inexact")))
     head = ColumnBatch(tuple(d[:k] for d in b.data),
                        tuple(v[:k] for v in b.valid), b.sel[:k], b.names)
     return head.with_column("__topk_inexact",
@@ -1701,6 +1797,20 @@ def sort_prefix(node: P.Sort, params: ExecParams) -> int:
     if params.topk_sort and params.axis_name is None \
             and isinstance(child, P.Aggregate) and child.group_by \
             and child.max_groups <= 0:
+        return node.prefix
+    return 0
+
+
+def window_prefix(node: P.Window, params: ExecParams) -> int:
+    """Leading rows a Window over a hash-strategy Aggregate orders, 0 =
+    its whole input: the plan's prefix (Engine._size_hash_sorts), as
+    sort_prefix gives a Sort's. The table hands its groups over as a
+    dense prefix of its slots, and a prefix that proves short raises
+    the top-k sentinel (_dense_prefix)."""
+    child = node.child
+    if params.topk_sort and params.axis_name is None \
+            and isinstance(child, P.Aggregate) and child.group_by \
+            and child.max_groups <= 0 and child.grouping_sets is None:
         return node.prefix
     return 0
 
@@ -1873,7 +1983,7 @@ def can_stream(node: P.PlanNode) -> bool:
         n = n.child
     if not isinstance(n, P.Aggregate):
         return False
-    if n.group_by and n.max_groups <= 0:
+    if n.group_by and n.max_groups <= 0 or n.grouping_sets is not None:
         return False
     return not any(a.distinct for a in n.aggs)
 

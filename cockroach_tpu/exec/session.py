@@ -14,7 +14,8 @@ import numpy as np
 
 from ..kv.txn import Txn
 from ..ops.batch import (H2D_BYTES, H2D_CALLS, JOIN_BUILD_ROWS,
-                         JOIN_PROBE_ROWS, JOINS, PROGRAMS, ColumnBatch,
+                         JOIN_PROBE_ROWS, JOINS, PROGRAMS, SITE_ROWS,
+                         ColumnBatch,
                          read_ts_words)
 from ..sql import ast
 from ..storage.hlc import Timestamp
@@ -302,6 +303,9 @@ class Prepared:
                 JOINS.inc(joins)
                 JOIN_PROBE_ROWS.inc(probe_rows)
                 JOIN_BUILD_ROWS.inc(build_rows)
+            if stats is not None:
+                for name, rows in stats.site_totals.items():
+                    SITE_ROWS[name].inc(rows)
             return out
         # paged execution through the prefetch pipeline: a bounded
         # background worker assembles+uploads page i+1 while the

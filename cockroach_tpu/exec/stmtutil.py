@@ -69,9 +69,13 @@ def _root_aggregate(node: P.PlanNode):
 
 
 def _has_prefix_sort(node: P.PlanNode) -> bool:
-    """Does a Sort of the plan order a prefix of its input
-    (P.Sort.prefix, set by Engine._size_hash_sorts)?"""
-    if isinstance(node, P.Sort) and node.prefix:
+    """Does a Sort or a Window of the plan order a prefix of its input
+    (P.Sort.prefix, P.Window.prefix: Engine._size_hash_sorts), or a
+    grouping-set Aggregate pack its sets into slots an estimate sized
+    (P.Aggregate.set_slots: Engine._size_grouping_sets)? Either raises
+    the top-k sentinel where the estimate proves low."""
+    if isinstance(node, (P.Sort, P.Window)) and node.prefix \
+            or isinstance(node, P.Aggregate) and node.set_slots:
         return True
     return any(_has_prefix_sort(c) for c in
                (getattr(node, a, None) for a in ("child", "left", "right"))

@@ -40,6 +40,10 @@ D2H_BYTES = Counter("exec.transfer.d2h.bytes")
 JOINS = Counter("exec.join.joins")
 JOIN_BUILD_ROWS = Counter("exec.join.build_rows")
 JOIN_PROBE_ROWS = Counter("exec.join.probe_rows")
+# what a dispatched statement's grouping-set Aggregates and Windows run
+# over (JoinStats.site_totals, the same trace-time shapes), by name
+SITE_ROWS = {"exec.agg.rollup.rows": Counter("exec.agg.rollup.rows"),
+             "exec.window.rows": Counter("exec.window.rows")}
 # row-length arguments of a 64-bit element type among the scan batches
 # of a prepared statement (Engine._prepare_select): each is an
 # X64SplitHigh/Low pass over every row of every execution on a TPU

@@ -147,11 +147,17 @@ def hash_join(probe: ColumnBatch, build: ColumnBatch,
         with jax.named_scope("build"):
             bidx = jnp.clip(bkeys[0] - base, 0,
                             size - 1).astype(jnp.int32)
-            bslot = jnp.where(bmask, bidx, size - 1)
+            # a masked build row is sent past the table and dropped,
+            # not into one shared slot: TPC-DS Q27's build of 1.92 M
+            # customer_demographics rows, 69 in 70 masked, 68-70 ms ->
+            # 48 on the v5e in a process where the scatter runs fast
+            # (90.5 either way in one where it runs slow; PR 40)
+            bslot = jnp.where(bmask, bidx, size)
             # .min keeps the FIRST (lowest-rowid) duplicate — the
             # same chain head _dup_chain produces
             table = jnp.full((size,), build.n, dtype=jnp.int32) \
-                .at[bslot].min(jnp.arange(build.n, dtype=jnp.int32))
+                .at[bslot].min(jnp.arange(build.n, dtype=jnp.int32),
+                               mode="drop")
         pk0 = pkeys[0]
         in_range = jnp.logical_and(pk0 >= base, pk0 - base < size - 1)
         pidx = jnp.clip(pk0 - base, 0, size - 1).astype(jnp.int32)
@@ -252,9 +258,9 @@ def hash_join(probe: ColumnBatch, build: ColumnBatch,
         # No key-equality re-check needed: direct addressing is
         # collision-free by construction — every live build key maps
         # to its own slot inside [0, size-2] (the engine sized the
-        # table from the all-versions key range), dead rows go to the
-        # sentinel slot size-1, and in_range keeps probes off the
-        # sentinel. Saves one n_probe-wide int64 gather; the fuzzed
+        # table from the all-versions key range), dead rows are
+        # dropped, and in_range keeps probes off the sentinel slot
+        # size-1. Saves one n_probe-wide int64 gather; the fuzzed
         # parity tests vs the hash path pin this reasoning.
         matched = jnp.logical_and(jnp.logical_and(pmask, in_range),
                                   owner < build.n)

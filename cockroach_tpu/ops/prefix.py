@@ -1,0 +1,56 @@
+"""Prefix scans that XLA:TPU compiles in a second.
+
+A one-dimensional `jnp.cumsum` or `lax.cummax` of 2^20 elements takes
+XLA:TPU 15-47 s to compile (int32 / int64 sums, 23 s a running max;
+`lax.associative_scan` 85 s), where the same scan over a
+[n / 1024, 1024] view takes 0.5-1.4 s (compiled here for a described
+v5e, PR 40). So a long array scans as rows of 1,024: each row on its
+own, then the rows' totals, carried into every row. The result is the
+one-dimensional scan's, element for element (integer sums wrap alike;
+a float sum adds in another order). Arrays whose length is no multiple
+of the row scan as they are.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+ROW = 1024
+
+
+def _rows(x):
+    n = x.shape[0]
+    return None if n <= ROW or n % ROW else x.reshape(n // ROW, ROW)
+
+
+def cumsum(x):
+    """Inclusive running sum of a one-dimensional array."""
+    y = _rows(x)
+    if y is None:
+        return jnp.cumsum(x)
+    inner = jnp.cumsum(y, axis=1)
+    last = inner[:, -1]
+    return (inner + (jnp.cumsum(last) - last)[:, None]).reshape(x.shape)
+
+
+def _cumextreme(x, pick, scan):
+    y = _rows(x)
+    if y is None:
+        return scan(x)
+    inner = scan(y, axis=1)
+    carry = scan(inner[:, -1])
+    # what the rows before each row reached (the first row: nothing)
+    before = jnp.concatenate([inner[:1, :1].reshape(1), carry[:-1]])
+    fixed = pick(inner, before[:, None])
+    return jnp.concatenate([inner[:1], fixed[1:]]).reshape(x.shape)
+
+
+def cummax(x):
+    """Inclusive running maximum of a one-dimensional array."""
+    return _cumextreme(x, jnp.maximum, jax.lax.cummax)
+
+
+def cummin(x):
+    """Inclusive running minimum of a one-dimensional array."""
+    return _cumextreme(x, jnp.minimum, jax.lax.cummin)

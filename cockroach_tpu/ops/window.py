@@ -21,11 +21,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from . import sortkey
+from . import prefix, sortkey
 
 
 def order_and_segments(part_keys: list, order_keys: list, sel,
-                       mode: str = "off"):
+                       mode: str = "off", peers_ordered: bool = True):
     """Sort the rows and describe partitions/peer groups.
 
     part_keys: list of (data, valid); order_keys: list of
@@ -33,9 +33,11 @@ def order_and_segments(part_keys: list, order_keys: list, sel,
     their own "partition" (excluded by callers via in_part).
 
     mode (sort_normalized): auto/on pack (partition keys, order keys)
-    into uint64 lanes and run one stable argsort per lane
-    (ops/sortkey.py) instead of the 2K+1-operand lexsort whose XLA
-    compile cost grows per operand.
+    into 32-bit words and run one sort over them (ops/sortkey.py)
+    instead of the 2K+1-operand lexsort whose XLA compile cost grows
+    per operand. peers_ordered=False: the caller reads nothing of the
+    order among a peer group (rank, dense_rank, an aggregate), so the
+    row index is no key of that sort (a key less to compile).
 
     Returns (order, seg_start, peer_start, in_part) — all in the
     sorted domain except `order` which indexes original rows:
@@ -59,7 +61,11 @@ def order_and_segments(part_keys: list, order_keys: list, sel,
         if fields is not None:
             lanes = sortkey.mask_dead(sortkey.pack_lanes(fields, n),
                                       sel)
-            order = sortkey.sort_perm(lanes, kind="window")
+            # one unstable sort with the row index as its last key: the
+            # stable order, at a third of a stable argsort's compile
+            order = sortkey.sort_perm_words(
+                sortkey.words_of(lanes, fields), kind="window",
+                stable=peers_ordered)
         else:
             sortkey.FALLBACKS.bump("window")
     if order is None:
@@ -103,8 +109,8 @@ def order_and_segments(part_keys: list, order_keys: list, sel,
     ob = jnp.logical_not(same_peer).at[0].set(True)  # peer boundary
 
     idx = jnp.arange(n)
-    seg_start = jax.lax.cummax(jnp.where(pb, idx, 0))
-    peer_start = jax.lax.cummax(jnp.where(ob, idx, 0))
+    seg_start = prefix.cummax(jnp.where(pb, idx, 0))
+    peer_start = prefix.cummax(jnp.where(ob, idx, 0))
     return order, seg_start, peer_start, sel_s
 
 
@@ -125,7 +131,7 @@ def _peer_end(peer_start, n):
     is_last = jnp.concatenate([peer_start[1:] != peer_start[:-1],
                                jnp.ones((1,), jnp.bool_)])
     marked = jnp.where(is_last, idx, n - 1)
-    return jax.lax.cummin(marked[::-1])[::-1]
+    return prefix.cummin(marked[::-1])[::-1]
 
 
 def scatter_back(order, vals, valid, n):
@@ -150,7 +156,7 @@ def dense_rank(order, seg_start, peer_start, sel_s):
     n = order.shape[0]
     idx = jnp.arange(n)
     ob = (peer_start == idx)
-    c = jnp.cumsum(ob.astype(jnp.int64))
+    c = prefix.cumsum(ob.astype(jnp.int64))
     dr = c - c[seg_start] + 1
     return scatter_back(order, dr, sel_s, n)
 
@@ -192,7 +198,7 @@ def _seg_end(seg_start, n):
     is_last = jnp.concatenate([seg_start[1:] != seg_start[:-1],
                                jnp.ones((1,), jnp.bool_)])
     marked = jnp.where(is_last, idx, n - 1)
-    return jax.lax.cummin(marked[::-1])[::-1]
+    return prefix.cummin(marked[::-1])[::-1]
 
 
 def first_value(order, seg_start, sel_s, data, valid):
@@ -243,9 +249,9 @@ def window_agg(func: str, order, seg_start, peer_start, sel_s,
         else:
             x = jnp.where(m, ds, 0).astype(
                 jnp.float64 if ds.dtype.kind == "f" else jnp.int64)
-        cum = jnp.cumsum(x)
+        cum = prefix.cumsum(x)
         total = run_to(cum, None)
-        cnt = jnp.cumsum(m.astype(jnp.int64))
+        cnt = prefix.cumsum(m.astype(jnp.int64))
         cntw = cnt[end] - jnp.where(seg_start > 0,
                                     cnt[jnp.maximum(seg_start - 1, 0)], 0)
         if func == "avg":
@@ -265,11 +271,11 @@ def window_agg(func: str, order, seg_start, peer_start, sel_s,
             ident = jnp.asarray(info.max if func == "min" else info.min,
                                 ds.dtype)
         x = jnp.where(m, ds, ident)
-        seg_id = jnp.cumsum((seg_start == idx).astype(jnp.int64))
+        seg_id = prefix.cumsum((seg_start == idx).astype(jnp.int64))
         # per-partition running min/max (segment-reset associative scan)
         run = _segmented(x, seg_id, func)
         out = run[end]  # end = peer end (framed) or partition end
-        cnt = jnp.cumsum(m.astype(jnp.int64))
+        cnt = prefix.cumsum(m.astype(jnp.int64))
         cntw = cnt[end] - jnp.where(seg_start > 0,
                                     cnt[jnp.maximum(seg_start - 1, 0)], 0)
         return scatter_back(order, out,

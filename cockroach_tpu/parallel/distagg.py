@@ -87,6 +87,8 @@ def analyze(node: P.PlanNode) -> DistDecision:
     for a in n.aggs:
         if a.distinct:
             return DistDecision(False, set(), set(), "DISTINCT aggregate")
+    if n.grouping_sets is not None:
+        return DistDecision(False, set(), set(), "grouping sets")
     if not probe_chain(n.child):
         return DistDecision(False, set(), set(), "unsupported probe chain")
     return DistDecision(True, sharded, replicated)

@@ -188,6 +188,9 @@ class Select(Statement):
     joins: list[JoinClause] = field(default_factory=list)
     where: Optional[Expr] = None
     group_by: list[Expr] = field(default_factory=list)
+    # GROUP BY ROLLUP / GROUPING SETS: each set as a tuple of indexes
+    # into group_by (parser.parse_group_by); None for a plain GROUP BY
+    grouping_sets: Optional[list] = None
     having: Optional[Expr] = None
     order_by: list[OrderItem] = field(default_factory=list)
     limit: Optional[int] = None

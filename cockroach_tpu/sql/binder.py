@@ -215,6 +215,11 @@ class Binder:
         # window function instances (bind_with_windows)
         self.windows: list[BoundWindow] = []
         self._collect_windows = False
+        # the GROUP BY keys, bound, of the grouped select being bound
+        # (grouping() names them) and whether it has grouping sets;
+        # None outside a grouped select
+        self.grouping_keys: Optional[list] = None
+        self.grouping_sets = False
         # volatile builtins (nextval/random/gen_random_uuid) fold to
         # ONE constant per bind; in a SELECT with a FROM clause pg
         # evaluates them per ROW, so folding silently corrupts results.
@@ -1191,6 +1196,15 @@ class Binder:
         if rty.family == Family.UNKNOWN:
             raise BindError("untyped CASE")
         if rty.family == Family.STRING:
+            shared = self._case_column_dict(whens, else_)
+            if shared is not None:
+                def typed(v):
+                    return BConst(None, STRING) if isinstance(
+                        v, BConst) else v
+                out = BCase([(c, typed(v)) for c, v in whens],
+                            typed(else_), STRING)
+                out.dictionary = shared
+                return out
             # constant string branches get an ad-hoc output dictionary
             from ..storage.columnstore import Dictionary
             d = Dictionary()
@@ -1220,6 +1234,63 @@ class Binder:
         whens = [(c, self.coerce(v, rty)) for c, v in whens]
         else_ = self.coerce(else_, rty)
         return BCase(whens, else_, rty)
+
+    def _coalesce_strings(self, args):
+        """COALESCE over string columns coded in one dictionary and
+        string constants (`coalesce(i_category, 'ALL')` over a rolled-up
+        key): the column's codes in a dictionary of its values followed
+        by the constants, made once a dictionary length
+        (Dictionary.derived). None where the columns' dictionaries
+        differ or there is none."""
+        shared, consts = None, []
+        for a in args:
+            if isinstance(a, BConst):
+                if a.value is not None:
+                    if not isinstance(a.value, str):
+                        return None
+                    consts.append(a.value)
+                continue
+            d = self._dict_of(a)
+            if d is None or (shared is not None and d is not shared):
+                return None
+            shared = d
+        if shared is None:
+            return None
+
+        def extend(values):
+            from ..storage.columnstore import Dictionary
+            d2 = Dictionary()
+            seen = set(values)
+            d2.seed(list(values) + [c for c in dict.fromkeys(consts)
+                                    if c not in seen])
+            return d2
+        d2 = shared.derived(("coalesce",) + tuple(consts), extend) \
+            if consts else shared
+        out = BCoalesce([BConst(d2.codes[a.value], STRING)
+                         if isinstance(a, BConst) and a.value is not None
+                         else BConst(None, STRING) if isinstance(a, BConst)
+                         else a for a in args], STRING)
+        out.dictionary = d2
+        return out
+
+    def _case_column_dict(self, whens, else_):
+        """The dictionary of a string CASE whose branches are columns
+        coded in one dictionary, or NULL (TPC-DS Q36's `case when
+        grouping(i_class) = 0 then i_category end`): the CASE is then
+        the column's codes, in its dictionary. None where a branch is
+        a string constant (an ad-hoc dictionary of its own) or the
+        columns' dictionaries differ."""
+        shared = None
+        for v in [v for _, v in whens] + [else_]:
+            if isinstance(v, BConst):
+                if v.value is not None:
+                    return None
+                continue
+            d = self._dict_of(v)
+            if d is None or (shared is not None and d is not shared):
+                return None
+            shared = d
+        return shared
 
     def bind_cast(self, x: BExpr, to: SQLType) -> BExpr:
         if x.type.family == to.family and x.type == to:
@@ -1292,12 +1363,18 @@ class Binder:
                         f"setval value must be an integer, got "
                         f"{v.value!r}")
             return BConst(self.sequence_ops(name, seq, arg), INT8)
+        if name == "grouping":
+            return self.bind_grouping(e)
         if name == "coalesce":
             args = [self.bind(a) for a in e.args]
             rty = next((a.type for a in args
                         if a.type.family != Family.UNKNOWN), None)
             if rty is None:
                 raise BindError("untyped COALESCE")
+            if rty.family == Family.STRING:
+                out = self._coalesce_strings(args)
+                if out is not None:
+                    return out
             args = [self.coerce(a, rty) for a in args]
             return BCoalesce(args, rty)
         if name == "abs":
@@ -1315,6 +1392,32 @@ class Binder:
         if out is not None:
             return out
         raise BindError(f"unknown function {name}")
+
+    def bind_grouping(self, e: ast.FuncCall) -> BExpr:
+        """grouping(k1, ..., kn): an integer whose bit i (k1 the most
+        significant) is 1 where k(i) is rolled up in the row's grouping
+        set. A key's bit is the Aggregate's output column
+        `__grouping<j>` (j its place in GROUP BY); 0 in a plain GROUP
+        BY. What tells a rolled-up NULL from a NULL in the data."""
+        keys = self.grouping_keys
+        if keys is None or not self._collect_aggs:
+            raise BindError("grouping() is allowed only in a grouped "
+                            "query's select list, HAVING or ORDER BY")
+        if not e.args or e.star or e.distinct:
+            raise BindError("grouping() takes one or more GROUP BY keys")
+        out = None
+        for a in e.args:
+            b = self.bind(a)
+            j = next((j for j, k in enumerate(keys)
+                      if repr(k) == repr(b)), None)
+            if j is None:
+                raise BindError("arguments to grouping() must be "
+                                "GROUP BY keys")
+            bit = (BCol(f"__grouping{j}", INT8) if self.grouping_sets
+                   else BConst(0, INT8))
+            out = bit if out is None else BBin(
+                "+", BBin("*", out, BConst(2, INT8), INT8), bit, INT8)
+        return out
 
     # statistical aggregates rewritten at bind time into compositions
     # of sum/count partials (the reference computes them the same way

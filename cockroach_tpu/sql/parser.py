@@ -13,6 +13,8 @@ PostgreSQL precedence.
 
 from __future__ import annotations
 
+import itertools
+
 from . import ast
 from .lexer import Tok, Token, lex
 from .types import (BOOL, DATE, FLOAT4, FLOAT8, INT2, INT4, INT8, INTERVAL,
@@ -357,9 +359,7 @@ class Parser:
             sel.where = self.parse_expr()
         if self.accept_kw("group"):
             self.expect_kw("by")
-            sel.group_by.append(self.parse_expr())
-            while self.accept_op(","):
-                sel.group_by.append(self.parse_expr())
+            self.parse_group_by(sel)
         if self.accept_kw("having"):
             sel.having = self.parse_expr()
         if self.accept_kw("order"):
@@ -387,6 +387,76 @@ class Parser:
         if self.accept_kw("offset"):
             sel.offset = int(self.next().text)
         return sel
+
+    def _word(self, ahead: int = 0) -> str | None:
+        t = self.peek(ahead)
+        return t.text.lower() if t.kind == Tok.IDENT else None
+
+    def _paren_exprs(self) -> list:
+        """( [e, ...] ): a grouping set or a ROLLUP's keys."""
+        self.expect_op("(")
+        out: list = []
+        if self.accept_op(")"):
+            return out
+        out.append(self.parse_expr())
+        while self.accept_op(","):
+            out.append(self.parse_expr())
+        self.expect_op(")")
+        return out
+
+    def parse_group_by(self, sel: ast.Select) -> None:
+        """GROUP BY e, ..., where an element may be ROLLUP (e, ...) or
+        GROUPING SETS (set, ...). `sel.group_by` holds every distinct
+        key in the order it first appears; where any element is not a
+        plain key, `sel.grouping_sets` holds the sets, as tuples of
+        indexes into it, the cross product of the elements' sets (pg's
+        reading: ROLLUP (a, b) is the sets (a, b), (a), ())."""
+        elements: list = []
+        plain = True
+        while True:
+            word = self._word()
+            opens = self.peek(1).kind == Tok.OP and self.peek(1).text == "("
+            if word == "cube" and opens:
+                raise ParseError("GROUP BY CUBE is not supported; write "
+                                 "its sets out with GROUPING SETS")
+            if word == "rollup" and opens:
+                self.next()
+                keys = self._paren_exprs()
+                elements.append([keys[:j] for j in range(len(keys), -1, -1)])
+                plain = False
+            elif word == "grouping" and self._word(1) == "sets":
+                self.next()
+                self.next()
+                self.expect_op("(")
+                sets = []
+                while True:
+                    if self.peek().kind == Tok.OP and self.peek().text == "(":
+                        sets.append(self._paren_exprs())
+                    else:
+                        sets.append([self.parse_expr()])
+                    if not self.accept_op(","):
+                        break
+                self.expect_op(")")
+                elements.append(sets)
+                plain = False
+            else:
+                elements.append([[self.parse_expr()]])
+            if not self.accept_op(","):
+                break
+        index: dict = {}
+        for sets in elements:
+            for keys in sets:
+                for k in keys:
+                    if repr(k) not in index:
+                        index[repr(k)] = len(sel.group_by)
+                        sel.group_by.append(k)
+        if plain:
+            return
+        out = []
+        for combo in itertools.product(*elements):
+            idx = sorted({index[repr(k)] for keys in combo for k in keys})
+            out.append(tuple(idx))
+        sel.grouping_sets = out
 
     def parse_table_ref(self) -> ast.TableRef:
         if self.peek().kind == Tok.OP and self.peek().text == "(":

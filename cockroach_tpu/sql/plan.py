@@ -144,6 +144,18 @@ class Aggregate(PlanNode):
     # int64 group sums (ops/agg.py group_sum): a tight bound means 3
     # fast i32 scatters instead of the software-emulated 64-bit one.
     max_group_rows: int = 0
+    # GROUP BY ROLLUP / GROUPING SETS: each set a tuple of indexes into
+    # group_by (None: a plain GROUP BY). The finest set (every key) is
+    # aggregated over the child's rows, each other set from the groups
+    # of a finer one; the output is every set's groups, a rolled-up
+    # key NULL, and `__grouping<j>` 1 where key j is rolled up
+    grouping_sets: Optional[list] = None
+    # [(code-space size, value offset)] a key, where the finest set is
+    # past the dense bound: the keys' packed code its rows sort by
+    sort_dims: list = field(default_factory=list)
+    # slots of the output over all sets, an estimate of their groups
+    # (Engine._size_grouping_sets); 0 = every set's whole domain
+    set_slots: int = 0
 
 
 @dataclass
@@ -153,6 +165,9 @@ class Window(PlanNode):
     ops/window.py)."""
     child: PlanNode
     windows: list = field(default_factory=list)  # BoundWindow
+    # leading rows ordered over a hash-strategy Aggregate, as
+    # Sort.prefix (Engine._size_hash_sorts); 0 = all
+    prefix: int = 0
 
 
 @dataclass
@@ -194,6 +209,19 @@ class OutputMeta:
     # whose results are constants of the plan (Binder.subqueries_run,
     # derived tables' bodies included)
     subqueries: int = 0
+
+
+def grouping_key_order(sets: list, k: int):
+    """The keys of a grouping-set Aggregate, most significant first in
+    its packed sort code (Aggregate.sort_dims): those in more sets
+    first (a plain key beside a ROLLUP is in every set), so that every
+    set is a prefix of the order; None where no order makes every set
+    a prefix (GROUPING SETS ((a), (b)))."""
+    order = sorted(range(k), key=lambda j: (-sum(j in s for s in sets), j))
+    for s in sets:
+        if set(order[:len(s)]) != set(s):
+            return None
+    return order
 
 
 def plan_tree_repr(node: PlanNode, indent: int = 0,
@@ -252,8 +280,10 @@ def plan_tree_repr(node: PlanNode, indent: int = 0,
         return (f"{pad}Project {[n for n, _ in node.items]}{ann()}\n"
                 + child(node.child))
     if isinstance(node, Aggregate):
+        sets = ("" if node.grouping_sets is None
+                else f" sets={node.grouping_sets}")
         return (f"{pad}Aggregate groups={[n for n, _ in node.group_by]} "
-                f"aggs={[a.func for a in node.aggs]}{ann()}\n"
+                f"aggs={[a.func for a in node.aggs]}{sets}{ann()}\n"
                 + child(node.child))
     if isinstance(node, Window):
         return (f"{pad}Window {[w.func for w in node.windows]}{ann()}\n"

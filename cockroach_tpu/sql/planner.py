@@ -23,7 +23,7 @@ from . import ast, plan
 from .binder import Binder, BindError, ColumnBinding, Scope
 from .bound import (BAggRef, BBin, BCol, BConst, BDictRemap, BExpr,
                     referenced_columns, walk)
-from .types import Family, TableSchema
+from .types import INT8, Family, TableSchema
 
 
 class PlanError(Exception):
@@ -834,22 +834,31 @@ class Planner:
                     name = _default_name(g)
                 group_exprs.append((f"g{i}:{name}", bexpr))
 
+        sets = sel.grouping_sets
+        if has_group:
+            binder.grouping_keys = [b for _, b in group_exprs]
+            binder.grouping_sets = sets is not None
         bound_items: list[tuple[str, BExpr]] = []
-        any_agg = False
-        binder._collect_windows = not has_group  # windows over raw rows
+        # ORDER BY expressions of a grouped query that are no output
+        # column: hidden items `__ord<i>` beside the output's
+        hidden: list[tuple[str, BExpr]] = []
+        binder._collect_windows = True
         try:
             for name, expr in items:
                 b = binder.bind_with_aggs(expr)
                 b = _encode_const_string_item(b)
                 bound_items.append((name, b))
-                if any(isinstance(n, BAggRef) for n in walk(b)):
-                    any_agg = True
+            grouped = has_group or bool(binder.aggs)
+            if grouped:
+                names = [n for n, _ in items]
+                for i, ob in enumerate(sel.order_by):
+                    if _orders_by_output(ob, names):
+                        continue
+                    e = _alias_subst(ob.expr, dict(items), scope)
+                    hidden.append((f"__ord{i}", _encode_const_string_item(
+                        binder.bind_with_aggs(e))))
         finally:
             binder._collect_windows = False
-        if binder.windows and (has_group or binder.aggs):
-            raise PlanError(
-                "window functions over grouped queries not supported yet "
-                "(wrap the GROUP BY in a subquery)")
 
         having_b = None
         if sel.having is not None:
@@ -857,13 +866,18 @@ class Planner:
 
         meta = plan.OutputMeta()
 
-        if has_group or binder.aggs:
+        if grouped:
+            if sets is not None and any(a.distinct for a in binder.aggs):
+                raise PlanError("DISTINCT aggregates under ROLLUP or "
+                                "GROUPING SETS are not supported yet")
             # FD reduction: engage only when it unlocks the dense
             # segment-sum strategy the hash path couldn't use — the
-            # hash path handles multi-key groups fine as-is
+            # hash path handles multi-key groups fine as-is (never
+            # under grouping sets: grouping() names keys by place)
             fd_repl = []
-            if len(group_exprs) >= 2 and self._static_group_bound(
-                    group_exprs, scope, tables)[0] == 0:
+            if sets is None and len(group_exprs) >= 2 \
+                    and self._static_group_bound(
+                        group_exprs, scope, tables)[0] == 0:
                 n_aggs = len(binder.aggs)
                 reduced, repl = self._reduce_fd_group_keys(
                     group_exprs, node, tables, binder)
@@ -874,25 +888,61 @@ class Planner:
                     del binder.aggs[n_aggs:]  # undo speculative aggs
             # rewrite grouped output exprs: replace group-expr occurrences
             # with group column refs
-            rewritten = []
-            for name, b in bound_items:
+            def grouped_expr(b):
                 b2 = _replace_group_refs(b, group_exprs)
                 if fd_repl:
                     b2 = _substitute(b2, fd_repl)
-                rewritten.append((name, b2))
+                _check_agg_valid(b2, group_exprs)
+                return b2
+            rewritten = [(name, grouped_expr(b)) for name, b in
+                         bound_items + hidden]
             if having_b is not None:
-                having_b = _replace_group_refs(having_b, group_exprs)
-                if fd_repl:
-                    having_b = _substitute(having_b, fd_repl)
-            for name, b in rewritten:
-                _check_agg_valid(b, group_exprs)
+                having_b = grouped_expr(having_b)
             max_groups, dims, glos = self._static_group_bound(
                 group_exprs, scope, tables)
-            node = plan.Aggregate(node, group_exprs, binder.aggs,
-                                  having_b, rewritten, max_groups, dims,
-                                  group_lo=glos)
-            out_names = [n for n, _ in rewritten]
-            out_types = [b.type for _, b in rewritten]
+            sort_dims = []
+            if sets is not None and max_groups <= 0:
+                sort_dims = self._sort_dims(group_exprs, scope, tables)
+                if plan.grouping_key_order(sets, len(group_exprs)) is None:
+                    raise PlanError(
+                        "GROUPING SETS past the dense group bound must "
+                        "nest (each set a prefix of one order of the "
+                        "keys, as ROLLUP's are)")
+            if not binder.windows:
+                node = plan.Aggregate(node, group_exprs, binder.aggs,
+                                      having_b, rewritten, max_groups,
+                                      dims, group_lo=glos,
+                                      grouping_sets=sets,
+                                      sort_dims=sort_dims)
+            else:
+                # a window over a grouped query: the Aggregate hands on
+                # its keys, its aggregates (`__agg<i>`) and the keys'
+                # grouping bits; the windows see those; a Project
+                # makes the output
+                lifts = [(BAggRef(i, a.type), BCol(f"__agg{i}", a.type))
+                         for i, a in enumerate(binder.aggs)]
+                carried = [(g, BCol(g, ge.type)) for g, ge in group_exprs] \
+                    + [(f"__agg{r.index}", r) for r, _ in lifts]
+                if sets is not None:
+                    carried += [
+                        (f"__grouping{j}", BCol(f"__grouping{j}", INT8))
+                        for j in range(len(group_exprs))]
+                node = plan.Aggregate(node, group_exprs, binder.aggs,
+                                      having_b, carried, max_groups, dims,
+                                      group_lo=glos, grouping_sets=sets,
+                                      sort_dims=sort_dims)
+
+                def lift(e):
+                    return _substitute(grouped_expr(e), lifts)
+                node = plan.Window(node, [
+                    type(w)(w.func, lift(w.arg) if w.arg is not None
+                            else None, [lift(x) for x in w.partition_by],
+                            [(lift(x), d) for x, d in w.order_by],
+                            w.offset, w.type) for w in binder.windows])
+                node = plan.Project(node, [(n, _substitute(b, lifts))
+                                           for n, b in rewritten])
+            out_names = [n for n, _ in bound_items]
+            out_types = [b.type for _, b in rewritten[:len(bound_items)]]
         elif sel.distinct:
             node = plan.Project(node, bound_items)
             group_exprs = [(n, BCol(n, b.type)) for n, b in bound_items]
@@ -915,9 +965,16 @@ class Planner:
         # ---- ORDER BY / LIMIT ----------------------------------------------
         if sel.order_by:
             keys = []
-            grouped = has_group or bool(binder.aggs)
+            hidden_names = {n for n, _ in hidden}
             for i, ob in enumerate(sel.order_by):
-                if isinstance(ob.expr, ast.Literal) and isinstance(ob.expr.value, int):
+                if f"__ord{i}" in hidden_names:
+                    hname = f"__ord{i}"
+                    keys.append((hname, ob.desc, ob.nulls_first))
+                    d = self._find_dict_for_output(
+                        hname, rewritten, group_exprs, scope, node)
+                    if d is not None:
+                        meta.dictionaries[hname] = d
+                elif isinstance(ob.expr, ast.Literal) and isinstance(ob.expr.value, int):
                     keys.append((out_names[ob.expr.value - 1], ob.desc,
                                  ob.nulls_first))
                 elif isinstance(ob.expr, ast.ColumnRef) \
@@ -1137,52 +1194,86 @@ class Planner:
         bound = 1
         dims = []
         los = []
+        span_cap = (self.MAX_INT_GROUP_SPAN_SINGLE if len(group_exprs) == 1
+                    else self.MAX_INT_GROUP_SPAN)
         for _, e in group_exprs:
-            if isinstance(e, BCol) and e.type.uses_dictionary:
-                d = self._dict_by_batch_name(e.name, scope)
-                if d is None:
-                    return 0, [], []
-                dims.append(max(len(d), 1))
-                los.append(0)
-            elif isinstance(e, BCol) and e.type.family == Family.BOOL:
-                dims.append(2)
-                los.append(0)
-            else:
-                if isinstance(e, BCol) and e.type.family == Family.INT \
-                        and self.catalog.int_range_fn is not None \
-                        and "." in e.name:
-                    alias, col = e.name.split(".", 1)
-                    tname = alias_to_table.get(alias)
-                    try:
-                        r = (self.catalog.int_range_fn(tname, col)
-                             if tname else None)
-                    except KeyError:  # renamed/computed: not stored
-                        r = None
-                    if r is None:
-                        return 0, [], []
-                    lo, hi, _n = r
-                else:
-                    # GROUP BY extract(year FROM datecol): the stored
-                    # column's value range bounds the year span
-                    # (TPC-H q7/q8/q9's o_year — 7 years, not a hash
-                    # table)
-                    yr = self._year_extract_range(e, alias_to_table)
-                    if yr is None:
-                        return 0, [], []
-                    lo, hi = yr
-                span = hi - lo + 1
-                span_cap = (self.MAX_INT_GROUP_SPAN_SINGLE
-                            if len(group_exprs) == 1
-                            else self.MAX_INT_GROUP_SPAN)
-                if span > span_cap:
-                    return 0, [], []
-                dims.append(int(span))
-                los.append(int(lo))
+            got = self._key_dim(e, scope, alias_to_table, span_cap)
+            if got is None:
+                return 0, [], []
+            dims.append(got[0])
+            los.append(got[1])
             bound *= dims[-1] + 1
             if bound > ((1 << 21) + 2 if len(group_exprs) == 1
                         else 1 << 16):
                 return 0, [], []
         return bound, dims, los
+
+    def _key_dim(self, e, scope: Scope, alias_to_table: dict,
+                 span_cap: int):
+        """(code-space size, value offset) of one group key: a
+        dictionary's length, 2 for a bool, a proven integer range of
+        at most span_cap values (a stored column's, or a year's
+        extracted from a stored date); None where nothing bounds it."""
+        if isinstance(e, BCol) and e.type.uses_dictionary:
+            d = self._dict_by_batch_name(e.name, scope)
+            return None if d is None else (max(len(d), 1), 0)
+        if isinstance(e, BCol) and e.type.family == Family.BOOL:
+            return 2, 0
+        if isinstance(e, BCol) and e.type.family == Family.INT \
+                and self.catalog.int_range_fn is not None \
+                and "." in e.name:
+            alias, col = e.name.split(".", 1)
+            tname = alias_to_table.get(alias)
+            try:
+                r = (self.catalog.int_range_fn(tname, col)
+                     if tname else None)
+            except KeyError:  # renamed/computed: not stored
+                r = None
+            if r is None:
+                return None
+            lo, hi, _n = r
+        else:
+            # GROUP BY extract(year FROM datecol): the stored
+            # column's value range bounds the year span
+            # (TPC-H q7/q8/q9's o_year — 7 years, not a hash
+            # table)
+            yr = self._year_extract_range(e, alias_to_table)
+            if yr is None:
+                return None
+            lo, hi = yr
+        span = hi - lo + 1
+        if span > span_cap:
+            return None
+        return int(span), int(lo)
+
+    # a grouping-set Aggregate whose finest set is past the dense bound
+    # groups by one sort of a packed code (exec/rollup.py
+    # sorted_sets): every key's code space, its NULL code included,
+    # takes bits of it
+    SORT_CODE_BITS = 62
+    MAX_INT_SORT_SPAN = 1 << 32
+
+    def _sort_dims(self, group_exprs, scope: Scope, tables) -> list:
+        """[(code-space size, value offset)] of a grouping-set
+        Aggregate's keys, for the packed sort code; a PlanError where
+        a key's domain is unknown or the code would not fit."""
+        alias_to_table = dict(tables or [])
+        out = []
+        for _, e in group_exprs:
+            got = self._key_dim(e, scope, alias_to_table,
+                                self.MAX_INT_SORT_SPAN)
+            if got is None:
+                raise PlanError(
+                    "ROLLUP / GROUPING SETS past the dense group bound "
+                    "need every key's domain known (a string, a bool, "
+                    "an integer column with a stored range): "
+                    f"{e!r} has none")
+            out.append(got)
+        if sum(int(dim).bit_length() for dim, _ in out) \
+                > self.SORT_CODE_BITS:
+            raise PlanError("ROLLUP / GROUPING SETS keys' domains do not "
+                            f"fit a {self.SORT_CODE_BITS}-bit code")
+        return out
 
     def _year_extract_range(self, e, alias_to_table):
         """(lo_year, hi_year) when e is extract(year FROM <stored
@@ -1284,7 +1375,7 @@ def _substitute(e: BExpr, pairs) -> BExpr:
     import copy
     e2 = copy.copy(e)
     from .bound import (BBetween, BCase, BCast, BCoalesce, BDictLookup,
-                        BExtract, BInList, BIsNull, BUnary)
+                        BExtract, BFunc, BInList, BIsNull, BUnary)
     if isinstance(e2, BBin):
         e2.left = _substitute(e2.left, pairs)
         e2.right = _substitute(e2.right, pairs)
@@ -1303,16 +1394,57 @@ def _substitute(e: BExpr, pairs) -> BExpr:
                     for c, v in e2.whens]
         if e2.else_ is not None:
             e2.else_ = _substitute(e2.else_, pairs)
-    elif isinstance(e2, BCoalesce):
+    elif isinstance(e2, (BCoalesce, BFunc)):
         e2.args = [_substitute(a, pairs) for a in e2.args]
     return e2
 
 
+def _orders_by_output(ob: ast.OrderItem, names: list) -> bool:
+    """Does an ORDER BY item name an output column (by position or by
+    name)?"""
+    return (isinstance(ob.expr, ast.Literal)
+            and isinstance(ob.expr.value, int)) \
+        or (isinstance(ob.expr, ast.ColumnRef) and ob.expr.name in names)
+
+
+def _alias_subst(e, items: dict, scope: Scope):
+    """An ORDER BY expression of a grouped query with each bare name
+    that is no column of the FROM clause but an output alias replaced
+    by that output's expression (TPC-DS Q36 orders by `case when
+    lochierarchy = 0 then i_category end`)."""
+    import dataclasses
+    if isinstance(e, ast.ColumnRef):
+        if e.table is None and e.name in items:
+            try:
+                scope.resolve(e.name, None)
+            except BindError:
+                return items[e.name]
+        return e
+    if isinstance(e, (ast.Subquery, ast.Exists, ast.InSubquery)) \
+            or not dataclasses.is_dataclass(e):
+        return e
+    changes = {}
+    for f in dataclasses.fields(e):
+        v = getattr(e, f.name)
+        if isinstance(v, ast.Expr):
+            changes[f.name] = _alias_subst(v, items, scope)
+        elif isinstance(v, list):
+            changes[f.name] = [
+                tuple(_alias_subst(x, items, scope) if isinstance(
+                    x, ast.Expr) else x for x in t) if isinstance(t, tuple)
+                else _alias_subst(t, items, scope)
+                if isinstance(t, ast.Expr) else t for t in v]
+    return dataclasses.replace(e, **changes) if changes else e
+
+
 def _check_agg_valid(e: BExpr, group_exprs) -> None:
     """Every column in a grouped output must be a group col or inside an
-    aggregate (the binder already folded aggregates into BAggRef)."""
+    aggregate (the binder already folded aggregates into BAggRef); a
+    key's grouping bit (`__grouping<j>`, grouping()) is the Aggregate's
+    own output."""
     gnames = {n for n, _ in group_exprs}
     for n in walk(e):
-        if isinstance(n, BCol) and n.name not in gnames:
+        if isinstance(n, BCol) and n.name not in gnames \
+                and not n.name.startswith("__grouping"):
             raise PlanError(
                 f"column {n.name!r} must appear in GROUP BY or an aggregate")

@@ -59,12 +59,16 @@ class TestEncodeValue:
         assert bits[0] == 0 and bits[-1] == (1 << 32) - 1
         assert (np.diff(bits.astype(object)) > 0).all()
 
-    def test_float_monotone_bits(self):
-        vals = np.array([-np.inf, -1e300, -1.5, -1e-300, 0.0, 1e-300,
-                         2.5, 1e300, np.inf], np.float64)
+    def test_float_monotone_bits_and_no_float64_image(self):
+        # IEEE-754 monotone bits for a float32; a float64 has no image
+        # (XLA:TPU cannot bitcast it), so its sorts take the lexsort
+        vals = np.array([-np.inf, -3e38, -1.5, -1e-38, 0.0, 1e-38,
+                         2.5, 3e38, np.inf], np.float32)
         bits, w = _img(vals)
-        assert w == 64
+        assert w == 32
         assert (np.diff(bits.astype(object)) > 0).all()
+        assert sk.encode_value(jnp.asarray(vals.astype(np.float64))) \
+            is None
 
     def test_float32_width(self):
         bits, w = _img(np.array([-2.0, 0.5], np.float32))
@@ -138,9 +142,12 @@ def _fuzz_batch(rng, n, kinds):
                                         I64.max - 1]
         elif kind == "int32":
             d = rng.integers(-3, 3, n).astype(np.int32)
-        elif kind == "float64":
+        elif kind in ("float32", "float64"):
+            # a float32 has an image (encode_value); a float64 has
+            # none and sorts on the lexsort path, "on" arm included
             d = np.round(rng.standard_normal(n), 2)  # ties, no -0.0
             d = np.abs(d) * np.where(d < 0, -1.0, 1.0)
+            d = d.astype(np.dtype(kind))
         elif kind == "bool":
             d = rng.random(n) > 0.5
         elif kind == "dict":
@@ -169,7 +176,8 @@ def _live_idx(bs: ColumnBatch):
 @pytest.mark.parametrize("nulls_first", [None, True, False])
 def test_sort_batch_parity_single_key(desc, nulls_first):
     rng = np.random.default_rng(7 + desc + 10 * bool(nulls_first))
-    for kind in ("int64", "int32", "float64", "bool", "dict"):
+    for kind in ("int64", "int32", "float32", "float64", "bool",
+                 "dict"):
         b, ranks = _fuzz_batch(rng, 257, [kind])
         key = ("k0", desc) if nulls_first is None \
             else ("k0", desc, nulls_first)
@@ -183,7 +191,8 @@ def test_sort_batch_parity_multi_key_mixed():
     rng = np.random.default_rng(42)
     for trial in range(6):
         kinds = list(rng.choice(
-            ["int64", "int32", "float64", "bool", "dict"], 3))
+            ["int64", "int32", "float32", "float64", "bool", "dict"],
+            3))
         b, ranks = _fuzz_batch(rng, 193, kinds)
         keys = []
         for i in range(3):
@@ -248,7 +257,7 @@ def test_dup_chain_parity():
 def test_distinct_first_mask_parity():
     rng = np.random.default_rng(17)
     n = 300
-    for dtype in (np.int64, np.float64):
+    for dtype in (np.int64, np.float32, np.float64):
         data = jnp.asarray(rng.integers(-4, 4, n).astype(dtype))
         mask = jnp.asarray(rng.random(n) > 0.3)
         gid = jnp.asarray(rng.integers(0, 6, n).astype(np.int32))
@@ -368,8 +377,8 @@ def _sort_arities(text: str):
 def seng():
     from cockroach_tpu.exec.engine import Engine
     e = Engine()
-    e.execute("CREATE TABLE st (k INT, a INT, f FLOAT, s STRING, "
-              "u STRING)")
+    e.execute("CREATE TABLE st (k INT, a INT, f REAL, s STRING, "
+              "u STRING, g FLOAT)")
     rng = np.random.default_rng(23)
     vals = []
     for i in range(300):
@@ -377,7 +386,8 @@ def seng():
         f = float(np.round(rng.standard_normal(), 2))
         s = "aa" if i < 200 else "bb"
         fv = "NULL" if rng.random() < 0.15 else f"{f}"
-        vals.append(f"({i}, {a}, {fv}, '{s}', 'u{i:04d}')")
+        gv = "NULL" if rng.random() < 0.15 else f"{f * 1e-3 + i * 1e-12}"
+        vals.append(f"({i}, {a}, {fv}, '{s}', 'u{i:04d}', {gv})")
     e.execute(f"INSERT INTO st VALUES {', '.join(vals)}")
     return e
 
@@ -409,6 +419,27 @@ class TestEngineAB:
         # 3 keys -> 2K+1 = 7-operand lexsort in the off arm
         assert max(off) >= 7, \
             f"off arm should restore the variadic lexsort: {off}"
+
+    def test_float64_key_sorts_on_the_lexsort(self, seng):
+        """A FLOAT (float64) key has no packed image (XLA:TPU cannot
+        bitcast a float64): under auto its sort compiles the variadic
+        lexsort and tallies the fallback, and the order is exact."""
+        sql = "SELECT k, g FROM st ORDER BY g DESC, k"
+        s = _sess(seng, "auto")
+        p = seng.prepare(sql, session=s)
+        tsv = read_ts_words(seng._read_ts(s).to_int())
+        before = sk.FALLBACKS.value("sort")
+        arities = _sort_arities(p.jfn.lower(
+            p.scans, tsv, np.int32(1), np.int32(0)).as_text())
+        assert sk.FALLBACKS.value("sort") > before
+        assert arities and max(arities) > 2, arities
+        rows = seng.execute("SELECT k, g FROM st",
+                            session=_sess(seng, "off")).rows
+        # DESC puts NULLs first (pg's default)
+        want = sorted(rows, key=lambda r: (r[1] is not None,
+                                           -(r[1] or 0.0), r[0]))
+        assert seng.execute(sql, session=_sess(seng, "auto")).rows \
+            == want
 
     def test_order_by_parity(self, seng):
         want = seng.execute(ORDER_SQL,

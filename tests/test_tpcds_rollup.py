@@ -1,0 +1,362 @@
+"""TPC-DS's subtotal reports (workload/tpcds.py) through the engine:
+Q27, Q36, Q67 and Q89 against their numpy oracles on seeded data, with
+the kernel allowed (`auto`) and not (`off`); GROUP BY ROLLUP / GROUPING
+SETS and grouping() against answers computed here (a NULL in the data
+against a rolled-up NULL, the grand total of no rows, AVG through the
+combine step, the dense and the sorted layouts); windows over grouped
+queries; and what exec.agg.grouping_sets counts a plan."""
+
+import math
+from fractions import Fraction
+
+import numpy as np
+import pytest
+
+from cockroach_tpu.exec.engine import Engine
+from cockroach_tpu.workload import tpcds
+
+SF = 0.01
+SEED = 11
+
+
+def _seeded():
+    data = tpcds.generate(SF, SEED, null_share=0.02)
+    ss = data["store_sales"][0]
+    rng = np.random.default_rng(5)
+    # plant rows where the narrowest filters look: Q89's six (category,
+    # class) pairs in 1999, Q27's demographic in 2002
+    it, itd, _ = data["item"]
+    cat = np.asarray(itd["i_category"], dtype=object)[it["i_category"]]
+    cls = np.asarray(itd["i_class"], dtype=object)[it["i_class"]]
+    q89 = np.flatnonzero(np.isin(cat, ["Books", "Men", "Sports"])
+                         & np.isin(cls, ["computers", "shirts", "football"]))
+    assert len(q89)
+    rows = slice(0, 600)
+    ss["ss_item_sk"][rows] = it["i_item_sk"][rng.choice(q89, 600)]
+    lo = tpcds.date_sk(tpcds.datetime.date(1999, 1, 1))
+    ss["ss_sold_date_sk"][rows] = rng.integers(lo, lo + 365, 600)
+    cd = data["customer_demographics"][0]
+    want = np.flatnonzero((cd["cd_gender"] == 0) & (cd["cd_marital_status"]
+                                                    == 1)
+                          & (cd["cd_education_status"] == 2))
+    rows = slice(600, 900)
+    ss["ss_cdemo_sk"][rows] = cd["cd_demo_sk"][rng.choice(want, 300)]
+    lo = tpcds.date_sk(tpcds.datetime.date(2002, 1, 1))
+    ss["ss_sold_date_sk"][rows] = rng.integers(lo, lo + 365, 300)
+    return data
+
+
+@pytest.fixture(scope="module")
+def loaded():
+    data = _seeded()
+    eng = Engine()
+    tpcds.load(eng, tables=data)
+    return eng, data
+
+
+def _same(got, want) -> bool:
+    if want is None or got is None:
+        return got is None and want is None
+    if isinstance(want, Fraction):
+        return math.isclose(float(got), float(want), rel_tol=1e-12,
+                            abs_tol=1e-12)
+    return got == want
+
+
+def _check(rows, want):
+    assert len(rows) == len(want)
+    for i, (g, w) in enumerate(zip(rows, want)):
+        assert len(g) == len(w), i
+        for j, (a, b) in enumerate(zip(g, w)):
+            assert _same(a, b), (i, j, g, w)
+
+
+@pytest.mark.parametrize("kernel", ["auto", "off"])
+@pytest.mark.parametrize("name", ["q27", "q36", "q67", "q89"])
+def test_query_equals_its_oracle(loaded, name, kernel):
+    eng, data = loaded
+    eng.execute(f"SET pallas_groupagg = {kernel}")
+    try:
+        rows = eng.execute(tpcds.query(name)).rows
+    finally:
+        eng.execute("SET pallas_groupagg = auto")
+    want = tpcds.ORACLES[name](data, **tpcds.QUALIFICATION[name])
+    assert len(want) >= 20, name
+    _check(rows, want)
+
+
+@pytest.fixture(scope="module")
+def small():
+    eng = Engine()
+    eng.execute("CREATE TABLE t (a STRING, b INT, x INT, y DECIMAL(7,2))")
+    eng.execute("INSERT INTO t VALUES ('p', 1, 10, 1.50), ('p', 2, 20, "
+                "2.25), ('q', 1, 5, NULL), (NULL, 1, 7, 3.00), "
+                "(NULL, NULL, 1, 0.75), ('q', NULL, NULL, 4.00)")
+    eng.execute("CREATE TABLE e (a STRING, x INT)")
+    eng.execute("ANALYZE t")
+    return eng
+
+
+ROWS = [("p", 1, 10, Fraction(150, 100)), ("p", 2, 20, Fraction(225, 100)),
+        ("q", 1, 5, None), (None, 1, 7, Fraction(3)),
+        (None, None, 1, Fraction(3, 4)), ("q", None, None, Fraction(4))]
+
+
+def _expect(sets):
+    """(a, b, grouping(a), grouping(b), sum(x), count(*), avg(y)) a
+    group of every set, computed here row by row."""
+    out = []
+    for s in sets:
+        groups: dict = {}
+        for r in ROWS:
+            key = tuple(r[j] if j in s else None for j in (0, 1))
+            groups.setdefault(key, []).append(r)
+        for key, rs in groups.items():
+            xs = [r[2] for r in rs if r[2] is not None]
+            ys = [r[3] for r in rs if r[3] is not None]
+            out.append(key + (0 if 0 in s else 1, 0 if 1 in s else 1,
+                              sum(xs) if xs else None, len(rs),
+                              sum(ys) / len(ys) if ys else None))
+    return out
+
+
+def _sort_key(r):
+    return tuple((v is None, v if v is not None else 0) for v in r[:4])
+
+
+@pytest.mark.parametrize("group_by,sets", [
+    ("rollup(a, b)", [(0, 1), (0,), ()]),
+    ("grouping sets ((a, b), (b), ())", [(0, 1), (1,), ()]),
+    ("a, rollup(b)", [(0, 1), (0,)]),
+])
+def test_grouping_sets_tell_a_null_from_a_rolled_up_key(small, group_by,
+                                                        sets):
+    got = small.execute(
+        "SELECT a, b, grouping(a), grouping(b), sum(x), count(*), avg(y) "
+        f"FROM t GROUP BY {group_by}").rows
+    want = _expect(sets)
+    _check(sorted(got, key=_sort_key), sorted(want, key=_sort_key))
+    # a NULL of the data keeps grouping() 0; a rolled-up one reads 1
+    assert any(r[0] is None and r[2] == 0 for r in got)
+    if sets[-1] == ():
+        assert any(r[0] is None and r[2] == 1 for r in got)
+
+
+def test_grouping_is_a_bit_a_key(small):
+    got = small.execute(
+        "SELECT grouping(a, b), count(*) FROM t GROUP BY rollup(a, b) "
+        "ORDER BY 1, 2").rows
+    assert {g for g, _ in got} == {0, 1, 3}
+    assert (3, 6) in got
+
+
+def test_grand_total_of_no_rows(small):
+    got = small.execute("SELECT a, grouping(a), count(*), sum(x) FROM e "
+                        "GROUP BY rollup(a)").rows
+    assert got == [(None, 1, 0, None)]
+
+
+def test_avg_through_the_combine_step(small):
+    rolled = small.execute("SELECT avg(y), avg(x) FROM t GROUP BY "
+                           "rollup(a) HAVING grouping(a) = 1").rows
+    direct = small.execute("SELECT avg(y), avg(x) FROM t").rows
+    assert len(rolled) == 1
+    assert all(math.isclose(a, b, rel_tol=1e-12)
+               for a, b in zip(rolled[0], direct[0]))
+
+
+@pytest.mark.parametrize("sql,match", [
+    ("SELECT a, count(*) FROM t GROUP BY cube(a, b)", "CUBE"),
+    ("SELECT a, count(DISTINCT x) FROM t GROUP BY rollup(a)", "DISTINCT"),
+    ("SELECT grouping(x) FROM t GROUP BY rollup(a)", "GROUP BY keys"),
+])
+def test_unsupported_forms_are_refused(small, sql, match):
+    with pytest.raises(Exception, match=match):
+        small.execute(sql)
+
+
+@pytest.fixture(scope="module")
+def wide():
+    """Keys whose dense domain is past the planner's bound, so the
+    grouping sets take the sorted layout."""
+    eng = Engine()
+    eng.execute("CREATE TABLE w (k1 INT, k2 INT, k3 INT, v INT)")
+    rng = np.random.default_rng(3)
+    n = 3000
+    k1 = rng.integers(0, 40, n)
+    k2 = rng.integers(0, 900, n)
+    k3 = rng.integers(0, 50, n)
+    v = rng.integers(-1000, 1000, n)
+    eng.store.insert_columns("w", {"k1": k1, "k2": k2, "k3": k3, "v": v},
+                             eng.clock.now())
+    eng.execute("ANALYZE w")
+    return eng, (k1, k2, k3, v)
+
+
+def test_sorted_layout_equals_each_set_alone(wide):
+    eng, (k1, k2, k3, v) = wide
+    q = ("SELECT k1, k2, k3, grouping(k1, k2, k3), sum(v), count(*), "
+         "min(v), max(v) FROM w GROUP BY rollup(k1, k2, k3)")
+    plan = eng.execute("EXPLAIN " + q).rows
+    assert any("sets=" in str(r) for r in plan)
+    got = eng.execute(q).rows
+    want = []
+    keys = [k1, k2, k3]
+    for m in range(3, -1, -1):
+        groups: dict = {}
+        for i in range(len(v)):
+            groups.setdefault(tuple(int(keys[j][i]) for j in range(m)),
+                              []).append(int(v[i]))
+        for key, vs in groups.items():
+            want.append(key + (None,) * (3 - m)
+                        + ((1 << (3 - m)) - 1, sum(vs), len(vs), min(vs),
+                           max(vs)))
+    _check(sorted(got, key=_sort_key), sorted(want, key=_sort_key))
+
+
+def test_sets_each_plan_counts(loaded):
+    # an engine of its own, whose plans are all traced anew
+    eng = Engine()
+    tpcds.load(eng, tables=loaded[1])
+    for name, n in (("q27", 3), ("q36", 3), ("q67", 9), ("q89", 0)):
+        before = eng.metrics.snapshot()["exec.agg.grouping_sets"]
+        eng.execute(tpcds.query(name))
+        after = eng.metrics.snapshot()["exec.agg.grouping_sets"]
+        assert after - before == n, name
+
+
+class TestWindowsOverGroups:
+    def test_sum_of_sums(self, small):
+        got = small.execute(
+            "SELECT a, sum(x), sum(sum(x)) OVER () FROM t "
+            "WHERE a IS NOT NULL GROUP BY a ORDER BY a").rows
+        assert got == [("p", 30, 35), ("q", 5, 35)]
+
+    def test_rank_by_an_aggregate(self, small):
+        got = small.execute(
+            "SELECT a, rank() OVER (ORDER BY sum(x) DESC) FROM t "
+            "GROUP BY a ORDER BY 2").rows
+        assert got == [("p", 1), (None, 2), ("q", 3)]
+
+    def test_partition_by_grouping(self, small):
+        got = small.execute(
+            "SELECT a, b, rank() OVER (PARTITION BY grouping(b) "
+            "ORDER BY count(*) DESC, a, b) FROM t WHERE a = 'p' "
+            "GROUP BY rollup(a, b) ORDER BY 3, 1, 2").rows
+        # the two (a, b) groups rank 1 and 2 among themselves; the
+        # subtotal and the grand total (grouping(b) = 1) tie at 2 rows
+        assert [r[2] for r in got] == [1, 1, 2, 2]
+
+    def test_window_in_order_by(self, small):
+        got = small.execute(
+            "SELECT a, sum(x) FROM t GROUP BY a "
+            "ORDER BY rank() OVER (ORDER BY sum(x)) DESC").rows
+        assert [a for a, _ in got] == ["p", None, "q"]
+
+    def test_rank_filter_equals_the_full_rank(self, small):
+        full = small.execute(
+            "SELECT a, b, rank() OVER (PARTITION BY a ORDER BY sum(x) "
+            "DESC) FROM t GROUP BY rollup(a, b)").rows
+        cut = small.execute(
+            "SELECT * FROM (SELECT a, b, rank() OVER (PARTITION BY a "
+            "ORDER BY sum(x) DESC) rk FROM t GROUP BY rollup(a, b)) r "
+            "WHERE rk <= 1").rows
+        assert sorted(cut, key=_sort_key) == sorted(
+            [r for r in full if r[2] <= 1], key=_sort_key)
+
+
+@pytest.mark.parametrize("n", [1000, 4096, 1 << 15])
+def test_prefix_scans_equal_the_plain_ones(n):
+    import jax
+    import jax.numpy as jnp
+
+    from cockroach_tpu.ops import prefix
+
+    rng = np.random.default_rng(n)
+    x = jnp.asarray(rng.integers(-(1 << 40), 1 << 40, n))
+    y = jnp.asarray(rng.integers(-1000, 1000, n).astype(np.int32))
+    assert (prefix.cumsum(x) == jnp.cumsum(x)).all()
+    assert (prefix.cummax(y) == jax.lax.cummax(y)).all()
+    assert (prefix.cummin(y) == jax.lax.cummin(y)).all()
+
+
+def test_a_year_denser_than_its_share_of_the_dates_overflows_exactly():
+    """The date join's share is read off the dimension
+    (exec/dimstats.py) for each statement's own constants: of the date
+    keys the fact table's key range reaches, those the year keeps,
+    rounded up to a quarter octave. Years of one rounded share share
+    one program; a year outside the fact rows' range gets its own (the
+    estimate's), and answers nothing. A year whose fact rows are
+    denser than its share of the keys (here 2004: a fifth of the days,
+    three sevenths of the rows) overflows the Compact's blocks: the
+    sentinel trips, the uncompacted plan answers exactly, and
+    exec.compact.overflows counts it."""
+    from cockroach_tpu.sql import parser
+    from cockroach_tpu.sql import plan as P
+
+    block = 32768
+    n = 8 * block
+    eng = Engine()
+    eng.execute("CREATE TABLE f (k INT8 NOT NULL, d INT8 NOT NULL, "
+                "v INT8 NOT NULL)")
+    eng.execute("CREATE TABLE dd (id INT8 PRIMARY KEY, yr INT8 NOT NULL)")
+    eng.execute("CREATE TABLE dm (id INT8 PRIMARY KEY, w INT8 NOT NULL)")
+    ids = np.arange(200 * 365, dtype=np.int64)       # two centuries
+    eng.store.insert_columns("dd", {"id": ids, "yr": 1900 + ids // 365},
+                             eng.clock.now())
+    rng = np.random.default_rng(43)
+    w = rng.integers(0, 9, 100)
+    eng.store.insert_columns("dm", {"id": np.arange(100, dtype=np.int64),
+                                    "w": w.astype(np.int64)},
+                             eng.clock.now())
+    # five years of sales, 2000-2004; 2004 three times as dense
+    yrs = rng.choice(5, n, p=[1 / 7] * 4 + [3 / 7])
+    d = (100 + yrs) * 365 + rng.integers(0, 365, n)
+    k = rng.integers(0, 100, n)
+    v = rng.integers(0, 1000, n)
+    eng.store.insert_columns("f", {"k": k, "d": d, "v": v},
+                             eng.clock.now())
+    for t in ("f", "dd", "dm"):
+        eng.execute(f"ANALYZE {t}")
+    s = eng.session()
+    s.vars.set("distsql", "off")
+
+    def sql(y):
+        return ("select dm.w, sum(v) from f join dd on f.d = dd.id "
+                f"join dm on f.k = dm.id where dd.yr = {y} "
+                "group by dm.w order by dm.w")
+
+    def fracs(y):
+        node, _ = eng._plan(parser.parse(sql(y)), s)
+        eng._check_join_builds(node, eng._read_ts(s), {})
+        node = eng._insert_compaction(node, {"f": n})
+        out = []
+        while node is not None:
+            if isinstance(node, P.Compact):
+                out.append(node.frac)
+            node = getattr(node, "child", None) \
+                or getattr(node, "left", None)
+        return out
+
+    def want(y):
+        m = 1900 + d // 365 == y
+        return [(int(g), int(v[m & (w[k] == g)].sum()))
+                for g in np.unique(w[k[m]])]
+
+    def counters():
+        snap = eng.metrics.snapshot()
+        return snap["exec.compact.overflows"], snap["sql.plan.cache.miss"]
+
+    # a fifth of the keys, 2^-2.25 once rounded, and 1.5x headroom
+    assert fracs(2001) == fracs(2004) == [pytest.approx(0.3810, abs=5e-5)]
+    assert eng.execute(sql(2001), session=s).rows == want(2001)
+    assert counters() == (0, 1)
+    # no sales in 1950: the estimate's capacity, another program
+    assert fracs(1950) == [pytest.approx(0.02, abs=5e-5)]
+    assert eng.execute(sql(1950), session=s).rows == []
+    assert counters() == (0, 2)
+    # the same program for 2004, whose blocks keep 3/7 of their rows
+    assert eng.execute(sql(2004), session=s).rows == want(2004)
+    assert counters()[0] == 1
+    assert eng.execute(sql(2002), session=s).rows == want(2002)
+    assert counters()[0] == 1

@@ -121,11 +121,13 @@ class TestWindowMisc:
                            "FROM emp WHERE sal IS NOT NULL"))
         assert r["d"] == 10
 
-    def test_window_over_grouped_rejected(self, eng):
-        with pytest.raises(Exception,
-                           match="window functions (over grouped|not allowed)"):
-            rows(eng, "SELECT dept, rank() OVER (ORDER BY sum(sal)) "
-                      "FROM emp GROUP BY dept")
+    def test_window_over_grouped_answers(self, eng):
+        # the Window stands above the Aggregate and orders its groups
+        # (eng 500, ops 120: sum skips the NULL)
+        r = rows(eng, "SELECT dept, sum(sal), rank() OVER "
+                      "(ORDER BY sum(sal)) FROM emp GROUP BY dept "
+                      "ORDER BY dept")
+        assert r == [("eng", 500, 2), ("ops", 120, 1)]
 
     def test_window_in_cte(self, eng):
         r = rows(eng, "WITH ranked AS (SELECT name, sal, row_number() "
