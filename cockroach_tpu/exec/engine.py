@@ -586,6 +586,17 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             "grouping sets of the grouping-set Aggregates traced (GROUP "
             "BY ROLLUP / GROUPING SETS; ROLLUP of k keys is k + 1): one "
             "tally a set a trace")
+        for kind, what in (
+                ("network", "sets of the sorted grouping-set Aggregates "
+                 "traced whose groups a displacement network packed: "
+                 "one tally a set a trace"),
+                ("segmented", "states of those Aggregates that kept a "
+                 "segmented reduction (min, max, any, a float sum): one "
+                 "tally a state a trace")):
+            self.metrics.func_counter(
+                "exec.agg.rollup." + kind,
+                lambda kind=kind: _rollup.SETS.value(kind),
+                f"{what} (exec/rollup.py sorted_sets)")
         for kind in ("inner", "left", "semi", "anti"):
             self.metrics.func_counter(
                 "exec.join.kind." + kind,

@@ -17,9 +17,17 @@ child's rows. Two layouts:
           sort once by the keys' packed code; a set that is a prefix of
           the code's key order is a run of equal prefixes in that
           order, so the finest groups are runs of the sorted rows and a
-          coarser set's groups runs of the finest groups. All the sets'
-          groups are packed into one batch of plan.Aggregate.set_slots
-          slots, level after level
+          coarser set's groups runs of the finest groups. After the
+          sort nothing scatters or gathers: an exact sum or count is a
+          running sum over the sorted rows; an order-preserving
+          displacement network (ops/prefix.py compress) packs the
+          finest groups' last rows, with their running sums, to the
+          front, and one network a coarser set packs the finest groups
+          its groups end at; a group's state is then its running sum
+          less that of the group before it in its set. Min, max, any
+          and a float sum keep a segmented reduction, whose run values
+          the networks carry. All the sets' groups are laid into one
+          batch of plan.Aggregate.set_slots slots, level after level
 
 Every set's groups carry a rolled-up key as NULL and `__grouping<j>` 1
 where key j is rolled up (sql/binder.py bind_grouping reads it).
@@ -38,7 +46,8 @@ from ..sql.bound import BoundAgg
 from ..sql.types import FLOAT8, INT8, Family
 
 # what the traced grouping-set Aggregates did (static shapes, one tally
-# a trace): the engine's exec.agg.grouping_sets
+# a trace): the engine's exec.agg.grouping_sets ("sets") and
+# exec.agg.rollup.network / .segmented
 SETS = sortkey._Tally()
 
 _DEAD = np.int64(np.iinfo(np.int64).max)
@@ -206,34 +215,61 @@ def _ident(kind, dtype):
     return jnp.array(info.max if kind == "min" else info.min, dtype)
 
 
-def _runs(kinds, states, start, end):
-    """Each state's value over runs of rows (`start` / `end` mark a
-    run's first and last row), read at the run's last row; and its
-    validity there. An exact integer sum is a difference of running
-    sums, wrapping in int64 as the group's own sum would; a float sum
-    and an extreme are segmented scans."""
+def _exact(kind, dtype) -> bool:
+    """A state whose group value is a difference of running sums: an
+    integer add or a count (a float sum would round otherwise)."""
+    return kind in ("add", "count") and not jnp.issubdtype(dtype,
+                                                           jnp.floating)
+
+
+def _running(kinds, states, start) -> list:
+    """What the networks carry of each state over rows in order (`start`
+    marks a run's first row), read at a run's last row: an exact
+    state's running sum in int64; any other state's whole value over
+    its run (one segmented reduction); and but for a count, the running
+    count of valid rows."""
     n = start.shape[0]
-    idx = jnp.arange(n, dtype=jnp.int32)
-    first = prefix.cummax(jnp.where(start, idx, 0))
-    seg = jnp.maximum(prefix.cumsum(start.astype(jnp.int32)) - 1, 0)
+    seg = None
     out = []
     for kind, (d, v) in zip(kinds, states):
-        cnt = prefix.cumsum(v.astype(jnp.int32))
-        before = jnp.where(first > 0, cnt[jnp.maximum(first - 1, 0)], 0)
-        valid = (cnt - before) > 0
-        x = jnp.where(v, d, _ident(kind, d.dtype))
-        if kind in ("add", "count") and not jnp.issubdtype(
-                d.dtype, jnp.floating):
-            cum = prefix.cumsum(x.astype(jnp.int64))
-            val = cum - jnp.where(first > 0,
-                                  cum[jnp.maximum(first - 1, 0)], 0)
-            val = val.astype(d.dtype)
+        if _exact(kind, d.dtype):
+            x = prefix.cumsum(jnp.where(v, d, 0).astype(jnp.int64))
         else:
-            val = _segment_reduce(kind, x, seg, n)
-        if kind == "count":
-            valid = jnp.ones_like(valid)
-        out.append((val, valid))
+            if seg is None:
+                seg = jnp.maximum(
+                    prefix.cumsum(start.astype(jnp.int32)) - 1, 0)
+            x = _segment_reduce(kind, jnp.where(v, d, _ident(kind, d.dtype)),
+                                seg, n)
+        out.append((x,) if kind == "count"
+                   else (x, prefix.cumsum(v.astype(jnp.int32))))
     return out
+
+
+def _since_previous(x):
+    """x less the element before it (0 before the first)."""
+    return x - jnp.concatenate([jnp.zeros((1,), x.dtype), x[:-1]])
+
+
+def _groups(kinds, dtypes, words) -> list:
+    """Each state's (data, valid) of packed groups, from its running
+    words at the groups' ends: an exact state's sum and a state's count
+    of valid rows are its running words less the previous group's."""
+    out = []
+    for kind, dt, w in zip(kinds, dtypes, words):
+        d = (_since_previous(w[0]).astype(dt) if _exact(kind, dt)
+             else w[0])
+        out.append((d, jnp.ones(d.shape, jnp.bool_) if kind == "count"
+                    else _since_previous(w[1]) > 0))
+    return out
+
+
+def _flat(words) -> list:
+    return [x for w in words for x in w]
+
+
+def _nest(flat, like) -> list:
+    it = iter(flat)
+    return [tuple(next(it) for _ in w) for w in like]
 
 
 def sorted_sets(sets: list, sort_dims: list, key_names: list, keys: list,
@@ -241,7 +277,14 @@ def sorted_sets(sets: list, sort_dims: list, key_names: list, keys: list,
     """The sets of a grouping-set Aggregate past the dense bound, from
     the child's rows: (group columns with the `__grouping<j>` bits,
     the states, live, slots, the rows the coarser sets were traced
-    over, overflow: more groups than `slots`)."""
+    over, overflow: more groups than `slots`).
+
+    After the one sort, no scatter and no gather: each state's running
+    sum (or its runs' values) over the sorted rows, read at the finest
+    groups' last rows, which one network packs to the front; a coarser
+    set's groups end where the prefix of the code they keep changes,
+    and one network a set packs those ends; a group's state is then its
+    running sum less that of the group before it in the same set."""
     k = len(sort_dims)
     order = P.grouping_key_order(sets, k)
     bits = [int(dim).bit_length() for dim, _ in sort_dims]
@@ -252,88 +295,114 @@ def sorted_sets(sets: list, sort_dims: list, key_names: list, keys: list,
         acc += bits[j]
     total_bits = acc
     n = sel.shape[0]
-    code = jnp.zeros((n,), jnp.int64)
-    for j in order:
-        d, v = keys[j]
-        dim, lo = sort_dims[j]
-        c = jnp.where(v, d.astype(jnp.int64) - lo, dim)
-        code = (code << bits[j]) | c
-    code = jnp.where(sel, code, _DEAD)
-    words = [code.astype(jnp.uint32)]
-    if total_bits > 32:
-        words.insert(0, (code >> 32).astype(jnp.uint32))
-    # grouping needs equal codes together, not an order among them
-    perm = sortkey.sort_perm_words(words, stable=False)
-    cs = code[perm]
-    live = cs != _DEAD
-    idx = jnp.arange(n, dtype=jnp.int32)
-    prev = jnp.concatenate([jnp.full((1,), -1, jnp.int64), cs[:-1]])
-    nxt = jnp.concatenate([cs[1:], jnp.full((1,), -1, jnp.int64)])
-    start = jnp.logical_and(live, cs != prev)
-    end = jnp.logical_and(live, cs != nxt)
+    # the phases a profile tells apart (the caller's scope is the
+    # kernel): the code and its sort, the running words, the networks
+    with jax.named_scope("keys"):
+        code = jnp.zeros((n,), jnp.int64)
+        for j in order:
+            d, v = keys[j]
+            dim, lo = sort_dims[j]
+            c = jnp.where(v, d.astype(jnp.int64) - lo, dim)
+            code = (code << bits[j]) | c
+        code = jnp.where(sel, code, _DEAD)
+        words = [code.astype(jnp.uint32)]
+        if total_bits > 32:
+            words.insert(0, (code >> 32).astype(jnp.uint32))
+        # grouping needs equal codes together, not an order among them
+        perm = sortkey.sort_perm_words(words, stable=False)
+        cs = code[perm]
+        live = cs != _DEAD
+        prev = jnp.concatenate([jnp.full((1,), -1, jnp.int64), cs[:-1]])
+        nxt = jnp.concatenate([cs[1:], jnp.full((1,), -1, jnp.int64)])
+        start = jnp.logical_and(live, cs != prev)
+        end = jnp.logical_and(live, cs != nxt)
     kinds = [_kind(a) for a in state_aggs_]
-    at_end = _runs(kinds, [(d[perm], jnp.logical_and(v[perm], live))
-                           for d, v in states], start, end)
-    # the finest groups, packed to the front of c0 slots in code order
+    dtypes = [d.dtype for d, _ in states]
+    SETS.bump("network", len(sets))
+    SETS.bump("segmented", sum(not _exact(kd, dt)
+                               for kd, dt in zip(kinds, dtypes)))
+    with jax.named_scope("operands"):
+        run = _running(kinds, [(d[perm], jnp.logical_and(v[perm], live))
+                               for d, v in states], start)
+    # the finest groups, packed to the front of c0 slots in code order;
+    # past them every word reads 0, so an empty grand total counts 0
     c0 = min(n, slots)
     ng0 = jnp.sum(end.astype(jnp.int32))
-    dest = jnp.where(end, prefix.cumsum(end.astype(jnp.int32)) - 1, c0)
-    pos = jnp.zeros((c0,), jnp.int32).at[dest].set(idx, mode="drop")
     slot = jnp.arange(c0, dtype=jnp.int32)
     live0 = slot < ng0
-    code0 = jnp.where(live0, cs[pos], _DEAD)
-    fine = [(d[pos], jnp.logical_and(v[pos], live0)) for d, v in at_end]
+    packed = prefix.compress(end, [cs] + _flat(run))
+    code0 = jnp.where(live0, packed[0][:c0], _DEAD)
+    run0 = _nest([jnp.where(live0, x[:c0], jnp.zeros((), x.dtype))
+                  for x in packed[1:]], run)
+    fine = _groups(kinds, dtypes, run0)
     overflow = ng0 > c0
     # every set a run of equal prefixes of the finest groups' codes
     prev0 = jnp.concatenate([jnp.full((1,), -1, jnp.int64), code0[:-1]])
     nxt0 = jnp.concatenate([code0[1:], jnp.full((1,), -1, jnp.int64)])
+    last0 = jnp.logical_not(jnp.concatenate(
+        [live0[1:], jnp.zeros((1,), jnp.bool_)]))
     levels, rows = [], 0
     for s in sets:
         if len(s) == k:
-            levels.append((live0, fine))
+            levels.append((jnp.minimum(ng0, c0), code0, fine))
             continue
         cut = total_bits - sum(bits[j] for j in order[:len(s)])
         lc, lp, ln = (code0 >> cut, prev0 >> cut, nxt0 >> cut)
-        lstart = jnp.logical_and(live0, jnp.logical_or(
-            slot == 0, lc != lp))
-        lend = jnp.logical_and(live0, jnp.logical_or(
-            jnp.logical_not(jnp.roll(live0, -1).at[-1].set(False)),
-            lc != ln))
+        lstart = jnp.logical_and(live0, lc != lp)
+        lend = jnp.logical_and(live0, jnp.logical_or(last0, lc != ln))
         if not s:           # the grand total has a row, rows or none
             none = jnp.logical_and(ng0 == 0, slot == 0)
             lstart = jnp.logical_or(lstart, none)
             lend = jnp.logical_or(lend, none)
-        levels.append((lend, _runs(kinds, fine, lstart, lend)))
+        # an exact state's running words at a set's ends are the
+        # finest groups' at theirs; any other state reduces the finest
+        # groups' values over the set's runs
+        lrun = []
+        for kd, dt, w, (fd, fv) in zip(kinds, dtypes, run0, fine):
+            if not _exact(kd, dt):
+                seg = jnp.maximum(
+                    prefix.cumsum(lstart.astype(jnp.int32)) - 1, 0)
+                w = (_segment_reduce(kd, jnp.where(fv, fd, _ident(kd, dt)),
+                                     seg, c0),) + w[1:]
+            lrun.append(w)
+        out = prefix.compress(lend, [code0] + _flat(lrun))
+        levels.append((jnp.sum(lend.astype(jnp.int32)), out[0],
+                       _groups(kinds, dtypes, _nest(out[1:], lrun))))
         rows += c0
-    # the sets' groups, level after level, in one batch
-    out_e = jnp.zeros((slots,), jnp.int32)
-    out_l = jnp.zeros((slots,), jnp.int32)
-    off = jnp.zeros((), jnp.int32)
-    for li, (ends, _) in enumerate(levels):
-        at = off + prefix.cumsum(ends.astype(jnp.int32)) - 1
-        at = jnp.where(ends, at, slots)
-        out_e = out_e.at[at].set(slot, mode="drop")
-        out_l = out_l.at[at].set(li, mode="drop")
-        off = off + jnp.sum(ends.astype(jnp.int32))
+    # the sets' groups, level after level, in one batch: each level's
+    # packed c0 slots laid at its offset, over the previous one's tail
+    width = slots + c0
+    lay = [jnp.zeros((width,), jnp.int64)] + [
+        jnp.zeros((width,), a.dtype) for st in levels[0][2] for a in st]
+    off, starts = jnp.zeros((), jnp.int32), []
+    for count, gcode, st in levels:
+        starts.append(off)
+        lay = [jax.lax.dynamic_update_slice(b, a, (off,))
+               for b, a in zip(lay, [gcode] + _flat(st))]
+        off = off + count
     overflow = jnp.logical_or(overflow, off > slots)
-    out_live = jnp.arange(slots, dtype=jnp.int32) < off
-    gcode = code0[out_e]
-    kept = jnp.array(np.array([[j in s for j in range(k)]
-                                 for s in sets], dtype=bool))[out_l]
+    out_slot = jnp.arange(slots, dtype=jnp.int32)
+    out_live = out_slot < off
+    gcode = lay[0][:slots]
+    # the level of each slot: the last whose offset it has reached (an
+    # empty level shares its offset with the next)
+    lvl = jnp.zeros((slots,), jnp.int32)
+    for o in starts[1:]:
+        lvl = lvl + (out_slot >= o).astype(jnp.int32)
     cols = {}
     for j, name in enumerate(key_names):
         dim, lo = sort_dims[j]
+        kept = jnp.zeros((slots,), jnp.bool_)
+        for li, s in enumerate(sets):
+            if j in s:
+                kept = jnp.logical_or(kept, lvl == li)
         c = (gcode >> shift[j]) & ((1 << bits[j]) - 1)
-        ok = jnp.logical_and(out_live, kept[:, j])
+        ok = jnp.logical_and(out_live, kept)
         cols[name] = ((c.astype(jnp.int32) if lo == 0 and dim < 2 ** 31
                        else c + lo),
                       jnp.logical_and(ok, c != dim))
-        cols[f"__grouping{j}"] = (1 - kept[:, j].astype(jnp.int64),
+        cols[f"__grouping{j}"] = (1 - kept.astype(jnp.int64),
                                   jnp.ones((slots,), jnp.bool_))
-    out_states = []
-    for i in range(len(states)):
-        d = jnp.stack([lv[1][i][0] for lv in levels])
-        v = jnp.stack([lv[1][i][1] for lv in levels])
-        out_states.append((d[out_l, out_e],
-                           jnp.logical_and(v[out_l, out_e], out_live)))
+    out_states = [(d[:slots], jnp.logical_and(v[:slots], out_live))
+                  for d, v in _nest(lay[1:], levels[0][2])]
     return cols, out_states, out_live, slots, rows, overflow

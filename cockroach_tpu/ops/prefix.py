@@ -9,6 +9,16 @@ own, then the rows' totals, carried into every row. The result is the
 one-dimensional scan's, element for element (integer sums wrap alike;
 a float sum adds in another order). Arrays whose length is no multiple
 of the row scan as they are.
+
+`compress` packs the rows a mask keeps to the front, in their order,
+with no scatter and no gather: every kept row moves left by the count
+of dropped rows before it (one running sum), the moves taken a bit of
+that displacement at a time (the `compress` network of Hacker's
+Delight 7-4, `ops/pallas/compact.py` in blocks). Rows p < q kept sit,
+after the moves of the bits below b, at p - (D_p mod 2^b) and
+q - (D_q mod 2^b), where q - p > D_q - D_p >= (D_q mod 2^b) -
+(D_p mod 2^b): never on one position, never out of order. So a step
+is one shifted select an array, of any dtype.
 """
 
 from __future__ import annotations
@@ -54,3 +64,26 @@ def cummax(x):
 def cummin(x):
     """Inclusive running minimum of a one-dimensional array."""
     return _cumextreme(x, jnp.minimum, jax.lax.cummin)
+
+
+def _shift_left(x, s: int):
+    """y[i] = x[i + s], zeros past the end."""
+    return jnp.concatenate([x[s:], jnp.zeros((s,), x.dtype)])
+
+
+def compress(keep, arrays) -> list:
+    """Each of `arrays` (one-dimensional, keep's length) with the rows
+    `keep` marks at its front, in their order: row i of a result
+    is the i-th kept row while i is under keep's count, anything after
+    it. ceil(log2 n) steps of shifted selects."""
+    n = keep.shape[0]
+    # the displacement of a kept row; a dropped row's 0 never moves it
+    d = jnp.where(keep, cumsum(jnp.logical_not(keep).astype(jnp.int32)), 0)
+    out = list(arrays)
+    for b in range(max(n - 1, 0).bit_length()):
+        s = 1 << b
+        coming = _shift_left(d, s)
+        take = (coming & s) != 0
+        d = jnp.where(take, coming, jnp.where((d & s) != 0, 0, d))
+        out = [jnp.where(take, _shift_left(x, s), x) for x in out]
+    return out

@@ -3,8 +3,10 @@ Q27, Q36, Q67 and Q89 against their numpy oracles on seeded data, with
 the kernel allowed (`auto`) and not (`off`); GROUP BY ROLLUP / GROUPING
 SETS and grouping() against answers computed here (a NULL in the data
 against a rolled-up NULL, the grand total of no rows, AVG through the
-combine step, the dense and the sorted layouts); windows over grouped
-queries; and what exec.agg.grouping_sets counts a plan."""
+combine step, the dense and the sorted layouts, NULL keys and values,
+slots an estimate sized too small); the sorted layout's networks;
+windows over grouped queries; and what exec.agg.grouping_sets and
+exec.agg.rollup.network count a plan."""
 
 import math
 from fractions import Fraction
@@ -214,15 +216,156 @@ def test_sorted_layout_equals_each_set_alone(wide):
     _check(sorted(got, key=_sort_key), sorted(want, key=_sort_key))
 
 
+def _nulls_table(n, seed):
+    """A table of `n` rows whose keys and values are NULL now and then,
+    with a float column: keys past the dense bound."""
+    eng = Engine()
+    eng.execute("CREATE TABLE wn (k1 INT, k2 INT, k3 INT, v INT, f FLOAT8)")
+    rng = np.random.default_rng(seed)
+    cols = {"k1": rng.integers(0, 40, n), "k2": rng.integers(0, 900, n),
+            "k3": rng.integers(0, 50, n), "v": rng.integers(-1000, 1000, n),
+            "f": rng.normal(size=n) * 100}
+    valid = {c: rng.random(n) > 0.05 for c in cols}
+    eng.store.insert_columns("wn", cols, eng.clock.now(), valid=valid)
+    eng.execute("ANALYZE wn")
+    rows = [tuple(None if not valid[c][i] else
+                  (float(cols[c][i]) if c == "f" else int(cols[c][i]))
+                  for c in cols) for i in range(n)]
+    return eng, rows
+
+
+NULLS_Q = ("SELECT k1, k2, k3, grouping(k1, k2, k3), sum(v), count(v), "
+           "count(*), avg(v), sum(f), avg(f), min(f), max(v) FROM wn "
+           "GROUP BY {}")
+
+
+def _each_set_alone(rows, sets):
+    """NULLS_Q's rows, a set at a time, computed here row by row."""
+    out = []
+    for s in sets:
+        groups: dict = {}
+        for r in rows:
+            groups.setdefault(tuple(r[j] if j in s else None
+                                    for j in range(3)), []).append(r)
+        for key, rs in groups.items():
+            vs = [r[3] for r in rs if r[3] is not None]
+            fs = [r[4] for r in rs if r[4] is not None]
+            out.append(key + (
+                sum(1 << (2 - j) for j in range(3) if j not in s),
+                sum(vs) if vs else None, len(vs), len(rs),
+                Fraction(sum(vs), len(vs)) if vs else None,
+                math.fsum(fs) if fs else None,
+                math.fsum(fs) / len(fs) if fs else None,
+                min(fs) if fs else None, max(vs) if vs else None))
+    return out
+
+
+def _check_close(got, want):
+    """_check, with the float sums (taken in another order) close."""
+    got = sorted(got, key=_sort_key)
+    want = sorted(want, key=_sort_key)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g[:7] == w[:7] and _same(g[7], w[7]), (g, w)
+        for a, b in zip(g[8:], w[8:]):
+            assert (a is None) == (b is None) and (
+                a is None or math.isclose(a, b, rel_tol=1e-9,
+                                          abs_tol=1e-9)), (g, w)
+
+
+@pytest.mark.parametrize("group_by,sets", [
+    ("rollup(k1, k2, k3)", [(0, 1, 2), (0, 1), (0,), ()]),
+    ("grouping sets ((k1, k2, k3), (k1), ())", [(0, 1, 2), (0,), ()]),
+])
+def test_sorted_layout_with_nulls_equals_each_set_alone(group_by, sets):
+    eng, rows = _nulls_table(3000, 7)
+    before = eng.metrics.snapshot()
+    got = eng.execute(NULLS_Q.format(group_by)).rows
+    after = eng.metrics.snapshot()
+    # the sorted layout: every set through the network; the float sum,
+    # the float average's sum and the two extremes segmented
+    assert after["exec.agg.rollup.network"] - before[
+        "exec.agg.rollup.network"] == len(sets)
+    assert after["exec.agg.rollup.segmented"] - before[
+        "exec.agg.rollup.segmented"] == 4
+    _check_close(got, _each_set_alone(rows, sets))
+    # a NULL of the data keeps grouping() 0; a rolled-up one reads 1
+    assert any(r[0] is None and r[3] == 0 for r in got)
+
+
+def test_sorted_layout_past_its_slots_replans_exactly():
+    """Slots sized from an estimate that proves low (8,192 for some
+    11,000 groups over the sets): the top-k sentinel fires, the plan
+    with every set's whole slots answers, and keeps answering."""
+    eng, rows = _nulls_table(6000, 9)
+    eng._estimate_set_groups = lambda agg: 10.0
+    sets = [(0, 1, 2), (0, 1), (0,), ()]
+    want = _each_set_alone(rows, sets)
+    assert len(want) > 8192
+    q = NULLS_Q.format("rollup(k1, k2, k3)")
+    _check_close(eng.execute(q).rows, want)
+    assert len(eng._whole_sorts) == 1
+    _check_close(eng.execute(q).rows, want)
+
+
 def test_sets_each_plan_counts(loaded):
     # an engine of its own, whose plans are all traced anew
     eng = Engine()
     tpcds.load(eng, tables=loaded[1])
-    for name, n in (("q27", 3), ("q36", 3), ("q67", 9), ("q89", 0)):
-        before = eng.metrics.snapshot()["exec.agg.grouping_sets"]
+    counters = ("exec.agg.grouping_sets", "exec.agg.rollup.network")
+    # Q67's nine sets are runs of one sort, packed by the networks;
+    # Q27's and Q36's are dense levels, Q89 has none
+    for name, n, net in (("q27", 3, 0), ("q36", 3, 0), ("q67", 9, 9),
+                         ("q89", 0, 0)):
+        before = eng.metrics.snapshot()
         eng.execute(tpcds.query(name))
-        after = eng.metrics.snapshot()["exec.agg.grouping_sets"]
-        assert after - before == n, name
+        after = eng.metrics.snapshot()
+        assert [after[c] - before[c] for c in counters] == [n, net], name
+
+
+def test_sorted_sets_scatter_nothing_after_the_sort():
+    """Q67's shape (eight keys, nine sets, 1.6 M rows into 2^20 slots)
+    with exact states only: one sort, no scatter, and no gather but the
+    permutation's of the code and of each state's data and validity."""
+    import jax
+    import jax.numpy as jnp
+
+    from cockroach_tpu.exec import rollup
+    from cockroach_tpu.sql.bound import BoundAgg
+    from cockroach_tpu.sql.types import INT8
+
+    n, k = 1605632, 8
+    dims = [10, 100, 1000, 18000, 5, 4, 12, 12]
+    sets = [tuple(range(m)) for m in range(k, -1, -1)]
+    aggs = [BoundAgg("sum_int", None, INT8), BoundAgg("count", None, INT8)]
+
+    def f(keys, states, sel):
+        return rollup.sorted_sets(sets, [(d, 0) for d in dims],
+                                  [f"k{j}" for j in range(k)], keys, states,
+                                  aggs, sel, 1 << 20)
+
+    def col(dt):
+        return jax.ShapeDtypeStruct((n,), dt)
+
+    with jax.enable_x64(True):
+        jaxpr = jax.make_jaxpr(f)(
+            [(col(jnp.int32), col(jnp.bool_)) for _ in range(k)],
+            [(col(jnp.int64), col(jnp.bool_)) for _ in aggs], col(jnp.bool_))
+    prims = []
+
+    def walk(j):
+        for e in j.eqns:
+            prims.append(e.primitive.name)
+            for p in e.params.values():
+                for q in p if isinstance(p, (list, tuple)) else [p]:
+                    inner = getattr(q, "jaxpr", q)
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+
+    walk(jaxpr.jaxpr)
+    assert prims.count("sort") == 1
+    assert not [p for p in prims if p.startswith("scatter")]
+    assert prims.count("gather") == 1 + 2 * len(aggs)
 
 
 class TestWindowsOverGroups:
@@ -263,6 +406,36 @@ class TestWindowsOverGroups:
             "WHERE rk <= 1").rows
         assert sorted(cut, key=_sort_key) == sorted(
             [r for r in full if r[2] <= 1], key=_sort_key)
+
+
+@pytest.mark.parametrize("dtype", ["int64", "int32", "bool", "float64"])
+@pytest.mark.parametrize("mask", ["random", "all", "none"])
+@pytest.mark.parametrize("n", [1, 1000, 4096, (1 << 15) + 3, 9 << 12])
+def test_compress_equals_a_scatter(n, mask, dtype):
+    """The network compaction (ops/prefix.py compress) against a
+    scatter of the kept rows to their ranks, keeping the first m rows
+    for m under and over the count kept."""
+    import jax
+    import jax.numpy as jnp
+
+    from cockroach_tpu.ops import prefix
+
+    rng = np.random.default_rng(n)
+    keep = {"random": rng.random(n) < 0.3, "all": np.ones(n, bool),
+            "none": np.zeros(n, bool)}[mask]
+    x = (rng.random(n) < 0.5 if dtype == "bool"
+         else rng.normal(size=n) if dtype == "float64"
+         else rng.integers(-(1 << 40), 1 << 40, n)).astype(dtype)
+    with jax.enable_x64(True):
+        got, = jax.jit(prefix.compress)(jnp.asarray(keep), [jnp.asarray(x)])
+        got = np.asarray(got)
+    count = int(keep.sum())
+    for m in sorted({max(count - 7, 0), count // 2, count, count + 5, n}):
+        ref = np.zeros(m, x.dtype)
+        dest = np.where(keep, np.cumsum(keep) - 1, m)
+        ref[dest[dest < m]] = x[dest < m]
+        assert got.dtype == x.dtype
+        assert (got[:min(m, count)] == ref[:min(m, count)]).all(), m
 
 
 @pytest.mark.parametrize("n", [1000, 4096, 1 << 15])
