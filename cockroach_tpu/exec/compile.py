@@ -30,7 +30,7 @@ from ..ops import agg as aggops
 from ..ops import hashtable
 from ..ops import sortkey
 from ..ops.batch import ColumnBatch, mvcc_live
-from ..ops.join import hash_join
+from ..ops.join import hash_join, join_strategy
 from ..ops.pallas import compact as pallas_compact
 from ..sql import plan as P
 from ..sql.bound import BoundAgg
@@ -218,7 +218,10 @@ def compile_plan(node: P.PlanNode, params: ExecParams,
     depth = getattr(_position, "depth", 0)
     if depth == 0:
         _position.next = 0
-    scope = f"{type(node).__name__.lower()}.{_position.next}"
+    # a UNION ALL is a concatenation of columns, a projection's kind
+    kind = ("project" if isinstance(node, P.UnionAll)
+            else type(node).__name__.lower())
+    scope = f"{kind}.{_position.next}"
     _position.next += 1
     _position.depth = depth + 1
     try:
@@ -298,6 +301,7 @@ def _compile_plan(node: P.PlanNode, params: ExecParams,
             if stats is not None:
                 stats.note(slot, lb.n, rb.n)
             JOIN_KINDS.bump(jn.join_type)
+            JOIN_STRATEGY.bump(join_strategy(jn.direct, jn.join_type))
             out = hash_join(lb, rb, jn.left_keys, jn.right_keys,
                             jn.payload, jn.join_type,
                             expand=jn.expand, direct=jn.direct,
@@ -309,6 +313,8 @@ def _compile_plan(node: P.PlanNode, params: ExecParams,
         return run_join
     if isinstance(node, P.Derived):
         return _compile_derived(node, params)
+    if isinstance(node, P.UnionAll):
+        return _compile_union_all(node, params)
     if isinstance(node, P.Compact):
         childf = compile_plan(node.child, params)
         frac, block, narrow = node.frac, node.block, node.narrow
@@ -378,6 +384,33 @@ def _compile_derived(node: P.Derived, params: ExecParams) -> CompiledNode:
     return run_derived
 
 
+def _compile_union_all(node: P.UnionAll,
+                       params: ExecParams) -> CompiledNode:
+    """The right branch's rows after the left's: each named column's
+    data (at the wider of the two widths) and validity concatenated,
+    the selections beside them, a sentinel either branch raises
+    raised over the whole batch."""
+    leftf = compile_plan(node.left, params)
+    rightf = compile_plan(node.right, params)
+    names = list(node.names)
+    branches = 1 if isinstance(node.left, P.UnionAll) else 2
+
+    def run_union(rc: RunContext) -> ColumnBatch:
+        lb, rb = leftf(rc), rightf(rc)
+        UNION_BRANCHES.bump("branches", branches)
+        cols, valid = {}, {}
+        for name in names:
+            a, b = lb.col(name), rb.col(name)
+            dt = jnp.result_type(a.dtype, b.dtype)
+            cols[name] = jnp.concatenate([a.astype(dt), b.astype(dt)])
+            valid[name] = jnp.concatenate([lb.col_valid(name),
+                                           rb.col_valid(name)])
+        out = ColumnBatch.from_dict(cols, valid, sel=jnp.concatenate(
+            [lb.sel, rb.sel]))
+        return _carry_sentinels(_carry_sentinels(out, lb), rb)
+    return run_union
+
+
 def _compile_scan(node: P.Scan, params: ExecParams) -> CompiledNode:
     alias = node.alias
     colmap = dict(node.columns)  # batch name -> stored name
@@ -438,7 +471,9 @@ def plan_rows(node: P.PlanNode, scan_rows: dict):
         return scan_rows.get(node.alias)
     if isinstance(node, P.HashJoin):
         n = plan_rows(node.left, scan_rows)
-        return None if n is None else n * node.expand
+        if n is None or node.join_type == "cross":
+            return None
+        return n * node.expand
     if isinstance(node, P.Compact):
         n = plan_rows(node.child, scan_rows)
         if n is None:
@@ -596,6 +631,17 @@ RANGE_PROOFS = sortkey._Tally()
 # one tally a compiled HashJoin, by its join type (inner, left, semi,
 # anti): the engine's exec.join.kind.*
 JOIN_KINDS = sortkey._Tally()
+
+# one tally a compiled HashJoin, by the strategy its trace took
+# (ops/join.py join_strategy: direct, packed, bounded, sorted, hash,
+# cross):
+# the engine's exec.join.strategy.*
+JOIN_STRATEGY = sortkey._Tally()
+
+# branches of the traced UNION ALLs (a plan.UnionAll of two plans that
+# are not unions counts both): the engine's
+# exec.setop.union_all.branches
+UNION_BRANCHES = sortkey._Tally()
 
 # one tally a compiled Aggregate, by the strategy its trace took
 # (aggregate_strategy): the engine's exec.agg.strategy.*

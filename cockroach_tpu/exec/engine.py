@@ -56,7 +56,8 @@ from ..utils.settings import SessionVars, Settings
 from . import coldstart
 from . import movement
 from . import rollup as _rollup
-from .compile import (AGG_STRATEGY, COMPACTS, JOIN_KINDS, RANGE_PROOFS,
+from .compile import (AGG_STRATEGY, COMPACTS, JOIN_KINDS, JOIN_STRATEGY,
+                      RANGE_PROOFS, UNION_BRANCHES,
                       ExecParams, JoinStats, RunContext,
                       _compact_block_rows, aggregate_strategy, can_stream,
                       compile_plan, compile_streaming, plan_rows)
@@ -72,7 +73,8 @@ from .session import (subquery_const,
 from .stmtutil import (_StreamFns, _RerunPrepared, _has_prefix_sort,
                       _host_sort, _count_aggs,
                       _collect_scan_columns, _collect_scans,
-                      _contains_func, _decode_column,
+                      _contains_func, _decode_column, inline_ctes,
+                      push_joins_into_unions,
                       _decode_scalar, _decode_storage_value,
                       _next_pow2, _propagate_as_of,
                       _render_create, _rewrite_table_names,
@@ -332,7 +334,8 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         self._parse_cache: TenantLRU = TenantLRU(
             self._PARSE_CACHE_MAX,
             on_evict=lambda k: (self._plain_memo.discard(k),
-                                self._temps_memo.discard(k)))
+                                self._temps_memo.discard(k),
+                                self._inplace_memo.pop(k, None)))
         # the executing statement's tenant, published per-thread
         # between admission acquire/release so cache puts deep in the
         # dispatch stack can attribute entries without plumbing
@@ -347,6 +350,11 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         # _exec_with_temps without asking the planner again. Kept in
         # step with the parse cache, as _plain_memo is
         self._temps_memo: set[str] = set()
+        # SELECT texts with CTEs planned in place -> the statement as
+        # the planner takes it (CTEs inlined, joins pushed into unions,
+        # decorrelated): the same on every execution, kept in step with
+        # the parse cache, as _plain_memo is
+        self._inplace_memo: dict = {}
         # per-table secondary-index descriptors, cached off the catalog
         # (invalidated by index DDL; a fresh engine lazily reloads)
         self._index_defs: dict[str, list] = {}
@@ -597,6 +605,37 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 "exec.agg.rollup." + kind,
                 lambda kind=kind: _rollup.SETS.value(kind),
                 f"{what} (exec/rollup.py sorted_sets)")
+        for kind, how in (
+                ("direct", "a direct-address table on one integer key"),
+                ("packed", "a direct-address table on a composite key "
+                 "whose components' spans multiply to at most "
+                 "MAX_PACKED_JOIN_SLOTS"),
+                ("bounded", "a composite key past that: a direct table "
+                 "on its densest component, each slot's candidates "
+                 "bounded by the store's statistics, a fixed number of "
+                 "compares (ops/join.py bounded_table)"),
+                ("sorted", "a composite key with no component dense "
+                 "enough: a sorted build, a branch-free binary search"),
+                ("hash", "the open-addressing table's while loops "
+                 "(ops/hashtable.py)"),
+                ("cross", "no key: a cartesian product over a build "
+                 "side of few rows")):
+            self.metrics.func_counter(
+                "exec.join.strategy." + kind,
+                lambda kind=kind: JOIN_STRATEGY.value(kind),
+                "hash joins traced, by the strategy their trace took "
+                f"(Engine._maybe_direct_join chooses it): {how}")
+        self.metrics.func_counter(
+            "exec.setop.union_all.branches",
+            lambda: UNION_BRANCHES.value("branches"),
+            "branches of the UNION ALLs traced into device programs "
+            "(plan.UnionAll; a set operation the planner cannot place "
+            "runs its branches as statements of their own)")
+        self._m_cte_temps = self.metrics.counter(
+            "exec.cte.temps",
+            "temp tables materialized for a CTE, a derived table or a "
+            "set operation's CTE, counted at every execution: what the "
+            "planner could not place in the statement's program")
         for kind in ("inner", "left", "semi", "anti"):
             self.metrics.func_counter(
                 "exec.join.kind." + kind,
@@ -1191,6 +1230,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             self._parse_cache.clear()
             self._plain_memo.clear()
             self._temps_memo.clear()
+            self._inplace_memo.clear()
             self._lane_shapes.clear()
             self._lane_mirrors.clear()
         if self.cluster is not None:
@@ -2439,12 +2479,22 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             out.joins = [copy.copy(j) for j in out.joins]
             for holder in [out] + out.joins:    # each has a .table
                 ref = holder.table
-                if ref is not None and isinstance(ref.subquery,
-                                                  ast.Select):
+                if ref is not None and ref.subquery is not None:
                     holder.table = ast.TableRef(
                         ref.name, ref.alias,
-                        self._decorrelate(ref.subquery, inline=True))
+                        self._decorrelate_body(ref.subquery))
         return out
+
+    def _decorrelate_body(self, body):
+        """A derived table's body decorrelated in place: a SELECT, or
+        each SELECT branch of a set operation."""
+        import copy
+        if isinstance(body, ast.SetOp):
+            body = copy.copy(body)
+            body.left = self._decorrelate_body(body.left)
+            body.right = self._decorrelate_body(body.right)
+            return body
+        return self._decorrelate(body, inline=True)
 
     def _plans_in_place(self, sel, session: Session) -> bool:
         """Does this statement run as one program on one device, so
@@ -2452,8 +2502,10 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         derived tables be planned in place? Not on a mesh that may
         distribute it (the distributed planner keeps grouped LEFT
         JOINs over temps), not inside a transaction's overlay, not
-        while a composed-CTE capture records temps."""
-        if not isinstance(sel, ast.Select) or sel.ctes:
+        while a composed-CTE capture records temps. A statement's CTEs
+        are planned in place where each is read once in a FROM
+        (stmtutil.inline_ctes); others keep the temps."""
+        if not isinstance(sel, ast.Select):
             return False
         if session.txn is not None or session.effects \
                 or self._cte_capture is not None \
@@ -2461,6 +2513,12 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             return False
         return (self.mesh is None or self.mesh.size <= 1
                 or session.vars.get("distsql", "auto") == "off")
+
+    def _stored_columns(self, name: str):
+        """A stored table's column names, or None."""
+        if name not in self.store.tables:
+            return None
+        return set(self.store.table(name).schema.column_names)
 
     @staticmethod
     def _has_derived(sel: ast.Select) -> bool:
@@ -2512,6 +2570,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 tname = _tname(name)
                 self._materialize_temp_select(tname, sub, session,
                                               cols, f"(cte {sub!r})")
+                self._m_cte_temps.inc()
                 mapping[name] = tname
                 temps.append(tname)
             sel.ctes = []
@@ -2526,6 +2585,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 tname = _tname(ref.alias)
                 self._materialize_temp_select(
                     tname, sub, session, None, f"(derived {sub!r})")
+                self._m_cte_temps.inc()
                 temps.append(tname)
                 newref = ast.TableRef(tname, ref.alias)
                 if kind == "table":
@@ -3238,11 +3298,25 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         return self._prepare_select(stmt, session, sql_text=sql)
 
     def _exec_select(self, sel, session: Session,
-                     sql_text: str) -> Result:
+                     sql_text: str, in_place: bool = True) -> Result:
         if isinstance(sel, ast.SetOp):
             return self._exec_setop(sel, session, sql_text)
-        inline = self._plans_in_place(sel, session)
-        if sql_text not in self._plain_memo:
+        inline = in_place and self._plans_in_place(sel, session)
+        orig = sel
+        rewritten = None
+        if inline and sel.ctes:
+            # each CTE read once is a derived table planned in place
+            rewritten = self._inplace_memo.get(sql_text)
+            inlined = (rewritten if rewritten is not None
+                       or sql_text in self._temps_memo
+                       else inline_ctes(sel))
+            if inlined is None:
+                inline = False
+            else:
+                sel = inlined
+        if rewritten is None and inline and self._has_derived(sel):
+            sel = push_joins_into_unions(sel, self._stored_columns)
+        if rewritten is None and sql_text not in self._plain_memo:
             sel2 = self._decorrelate(self._expand_views(sel),
                                      inline=inline)
             if sel2 is sel and sql_text and \
@@ -3258,6 +3332,8 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 # guard fixes). DDL invalidates with the parse cache.
                 self._plain_memo.add(sql_text)
             sel = sel2
+        if inline and orig.ctes and sql_text:
+            self._inplace_memo[sql_text] = sel
         if inline and not sel.ctes and self._has_derived(sel):
             # derived tables planned in place (plan.Derived): one
             # program, nothing materialized on the host, nothing in it
@@ -3269,11 +3345,20 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             if sql_text not in self._temps_memo:
                 try:
                     prep = self._prepare_select(sel, session, sql_text)
-                except NotInPlace:
+                except PlanError as e:
+                    # a CTE's body the planner cannot place (a table-
+                    # free SELECT, a set-returning function) is what
+                    # the temps served before CTEs were planned here
+                    if not (isinstance(e, NotInPlace) or orig.ctes):
+                        raise
                     if sql_text:
                         self._temps_memo.add(sql_text)
                 else:
                     return prep.run()
+            if orig.ctes:
+                # the statement as written, its CTEs through the temps
+                return self._exec_select(orig, session, sql_text,
+                                         in_place=False)
         if sel.ctes or self._has_derived(sel):
             return self._exec_with_temps(sel, session, sql_text)
         if sel.table is None:
@@ -3318,6 +3403,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                                             f"(cte {sub!r})")
                     tname = f"__cte{self._temp_seq()}_{name}"
                     self._materialize_temp(tname, res, cols)
+                    self._m_cte_temps.inc()
                     mapping[name] = tname
                     temps.append(tname)
                 so.ctes = []
@@ -3888,7 +3974,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 walk(n.left)
                 walk(n.right)
                 return
-            for attr in ("child",):
+            for attr in ("child", "left", "right"):   # a UnionAll's too
                 c = getattr(n, attr, None)
                 if c is not None:
                     walk(c)
@@ -3907,6 +3993,10 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         in-place plan (NotInPlace), and the statement takes temps."""
         from ..sql.bound import BCol
         d = join.right
+        join.expand = 1
+        join.direct = None
+        if join.join_type == "cross":
+            return      # the planner proved the build's rows few
         n = d.child
         while isinstance(n, (P.Project, P.Sort, P.Limit, P.Filter)):
             if isinstance(n, P.Project) and not all(
@@ -3927,8 +4017,6 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 f"derived table {d.alias!r} is joined on columns that "
                 "are not its GROUP BY key: it cannot be a build side "
                 "in place")
-        join.expand = 1
-        join.direct = None
         if len(keys) == 1 and isinstance(keys[0], BCol):
             src = _find_scan_column(n, keys[0].name)
             if src is not None:
@@ -4044,23 +4132,88 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         # composite keys (q9's partsupp (ps_partkey, ps_suppkey)):
         # mixed-radix-pack the components; the span PRODUCT sizes the
         # table, so the cap is higher (an int32 slot table at 2^27 is
-        # 0.5GB of HBM — cheap next to the while-loop hash path's
-        # ~140s/exec) and the sparsity allowance wider
+        # 0.5GB of HBM) and the sparsity allowance wider. The payload-
+        # folding path allocates ~one size-length table per carried
+        # payload column on top of the slot table: budget TOTAL
+        # slot-table cells, not just the key table (2^29 cells ~= 2-4GB
+        # transient HBM worst case; duplicate-keyed builds take the
+        # expand path, which builds only the slot table)
         total = 1
         for _, span in ranges:
             total *= span
-            if total > self.MAX_PACKED_JOIN_SLOTS:
-                return
-        # the payload-folding path allocates ~one size-length table
-        # per carried payload column on top of the slot table: budget
-        # TOTAL slot-table cells, not just the key table (2^29 cells
-        # ~= 2-4GB transient HBM worst case; duplicate-keyed builds
-        # take the expand path, which builds only the slot table)
-        if total * (2 + len(join.payload)) > 1 << 29:
+        los = tuple(lo for lo, _ in ranges)
+        spans = tuple(span for _, span in ranges)
+        if total <= self.MAX_PACKED_JOIN_SLOTS \
+                and total * (2 + len(join.payload)) <= 1 << 29 \
+                and total <= max(2048 * n_all, 4096):
+            join.direct = ("packed", los, spans)
             return
-        if total <= max(2048 * n_all, 4096):
-            join.direct = ("packed", tuple(lo for lo, _ in ranges),
-                           tuple(span for _, span in ranges))
+        join.direct = self._bounded_or_sorted(b.table, stored, los, spans,
+                                              n_all, read_ts)
+
+    # candidates a slot of the bounded form at most, and its table's
+    # cells (slots x candidates) at most
+    MAX_BOUNDED_CANDIDATES = 32
+    MAX_BOUNDED_CELLS = 1 << 26
+
+    @staticmethod
+    def _coarse_range(lo: int, hi: int) -> tuple:
+        """[lo, hi] widened outward to multiples of g, an eighth of the
+        power of two above hi - lo (at least 1): at most half the span
+        again, and the same for every range whose ends lie in the same
+        cells of the grid."""
+        g = 1 << max((hi - lo).bit_length() - 3, 0)
+        return (lo // g) * g, (hi // g + 1) * g - 1
+
+    def _bounded_or_sorted(self, table: str, stored, los, spans,
+                           n_all: int, read_ts):
+        """The form of a composite-key join past the packed table (TPC-DS
+        Q80's sale to its return on (item, ticket): 4.3e9 slots at SF1;
+        TPC-H Q9's partsupp: 2e9), chosen from the store's statistics,
+        never the while-loop hash table:
+
+        - `bounded`: a direct table on the component whose values have
+          the fewest live rows each (a ticket's lines, a part's
+          suppliers), where its span takes a direct table and that
+          count, k, is small: two scatters to build where the table
+          is stored in that component's order, k rounds of one where
+          not; one gather of k candidates and k compares of each other
+          component to probe (ops/join.py bounded_table);
+        - `sorted`: where no component is dense enough, the packed key
+          in 62 bits: a sorted build, a binary search of log2(n) steps;
+        - None (the hash table) only where the key does not pack.
+
+        Each component's range is widened to a coarse grid
+        (`_coarse_range`) before either form is sized: a returns
+        table's lowest and highest ticket move with the lines drawn,
+        and a program that followed them would compile again for every
+        draw of the same table."""
+        coarse = [self._coarse_range(lo, lo + span - 1)
+                  for lo, span in zip(los, spans)]
+        los = tuple(lo for lo, _ in coarse)
+        spans = tuple(hi - lo + 1 for lo, hi in coarse)
+        best = None
+        for i, (s, lo, span) in enumerate(zip(stored, los, spans)):
+            slots = self._direct_slots(lo, lo + span - 1, n_all)
+            if slots is None:
+                continue
+            k = self.store.key_max_multiplicity(table, (s,),
+                                                read_ts.to_int())
+            # a power of four: the largest count moves with the data (a
+            # ticket's 7 returns, its 8 or its 9 over draws of one
+            # table), a program that followed it would compile again per
+            # draw, and a power of two would still split 8 from 9
+            k = 4 ** math.ceil(math.log(k, 4) - 1e-9) if k > 0 else 0
+            if 0 < k <= self.MAX_BOUNDED_CANDIDATES \
+                    and slots[1] * k <= self.MAX_BOUNDED_CELLS \
+                    and (best is None or (k, slots[1]) < best[:2]):
+                best = (k, slots[1], i, slots[0])
+        if best is not None:
+            k, size, i, base = best
+            return ("bounded", i, base, size, k, los, spans)
+        if sum(int(span).bit_length() for span in spans) <= 62:
+            return ("sorted", los, spans)
+        return None
 
     def _dist_decision(self, node, session: Session):
         """Choose distributed (SPMD over the mesh) vs single-device —
@@ -4292,9 +4445,12 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
 
     def _plan_shape_tags(self, node, scans: dict, pallas: str) -> dict:
         """The `plan` span's `joins` (hash joins in the plan), `compacts`
-        (its Compact nodes) and `agg` (compile.aggregate_strategy of
-        its outermost Aggregate, `none` without one), from the plan
+        (its Compact nodes), `agg` (compile.aggregate_strategy of its
+        outermost Aggregate, `none` without one), `union_branches` (the
+        branches of its UNION ALLs) and `join_strategy` (its joins by
+        ops/join.py join_strategy, `direct:9,bounded:1`), from the plan
         and its scans' shapes alone."""
+        from ..ops.join import join_strategy
         from ..sql import plan as P
 
         def nodes(n):
@@ -4306,9 +4462,15 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
 
         joins, compacts, agg = 0, 0, None
         kinds = {"semi": 0, "anti": 0, "left": 0}
-        sets = windows = 0
+        sets = windows = branches = 0
+        forms: dict = {}
         for n in nodes(node):
             joins += isinstance(n, P.HashJoin)
+            if isinstance(n, P.UnionAll):
+                branches += 1 if isinstance(n.left, P.UnionAll) else 2
+            if isinstance(n, P.HashJoin):
+                form = join_strategy(n.direct, n.join_type)
+                forms[form] = forms.get(form, 0) + 1
             compacts += isinstance(n, P.Compact)
             if isinstance(n, P.Aggregate) and n.grouping_sets is not None:
                 sets += len(n.grouping_sets)
@@ -4326,7 +4488,11 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 pallas_groupagg=pallas,
                 pallas_interpret=self._pallas_interpret()))
         return {"joins": joins, "compacts": compacts, "agg": strategy,
-                "grouping_sets": sets, "windows": windows, **kinds}
+                "grouping_sets": sets, "windows": windows,
+                "union_branches": branches,
+                "join_strategy": ",".join(
+                    f"{k}:{v}" for k, v in sorted(forms.items())),
+                **kinds}
 
     def _compact_frac(self, est: float) -> float:
         """Capacity of a Compact, as a share of its input batch, whose
@@ -4552,6 +4718,8 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 # a Window or a derived table orders or renames what
                 # an Aggregate beneath made: that Aggregate's spine
                 # packs as any other (TPC-DS Q36, Q67, Q89)
+                if isinstance(n, P.Derived):
+                    walked.add(id(n))
                 n.child = walk(n.child)
                 return n
             if isinstance(n, P.Project):
@@ -4567,7 +4735,28 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 n.child = walk(n.child)
                 return n
             return n
-        return self._defer_payloads_past_compact(walk(node))
+
+        walked: set = set()
+
+        def nested(n):
+            """The plans a derived table or a UNION ALL's branch holds
+            (a CTE's aggregate beneath a spine's end, TPC-DS Q80's
+            channels) pack as a statement's would: each one's spine is
+            walked once."""
+            for attr in ("child", "left", "right"):
+                c = getattr(n, attr, None)
+                if c is None:
+                    continue
+                if isinstance(n, P.UnionAll) or isinstance(n, P.Derived) \
+                        and id(n) not in walked:
+                    walked.add(id(n))
+                    c = walk(c)
+                    setattr(n, attr, c)
+                nested(c)
+
+        root = walk(node)
+        nested(root)
+        return self._defer_payloads_past_compact(root)
 
     def _defer_payloads_past_compact(self, root):
         """Payload pull-up: for every direct inner join BELOW a

@@ -98,19 +98,28 @@ def _eligible_const(e) -> bool:
 
 
 class _Lifter:
-    def __init__(self):
+    def __init__(self, shared: bool = False):
         self.values: list = []
         self.overflow = False
+        # with `shared`, (type, value) -> its slot: a literal a
+        # statement repeats (a report's date window in each of its CTEs
+        # and UNION ALL branches) is one argument, not one an occurrence
+        self.shared = shared
+        self.slots: dict = {}
 
     def const(self, e: B.BConst):
         dt = e.type.np_dtype
         v = np.asarray(e.value, dtype=dt)
         if v.item() != e.value:  # lossy physical round-trip: keep baked
             return e
+        key = (e.type, v.dtype.str, v.item())
+        if self.shared and key in self.slots:
+            return B.BParam(self.slots[key], e.type)
         if len(self.values) >= _MAX_PARAMS:
             self.overflow = True
             return e
         self.values.append(v)
+        self.slots[key] = len(self.values) - 1
         return B.BParam(len(self.values) - 1, e.type)
 
     def arg(self, e: B.BSubqueryArg):
@@ -168,7 +177,7 @@ class _Lifter:
             if c is n.child and p is n.pred:
                 return n
             return dataclasses.replace(n, child=c, pred=p)
-        if isinstance(n, P.HashJoin):
+        if isinstance(n, (P.HashJoin, P.UnionAll)):
             l, r = self.node(n.left), self.node(n.right)
             if l is n.left and r is n.right:
                 return n
@@ -282,6 +291,14 @@ def parameterize(node, tables: bool = True):
     scalars)."""
     lf = _Lifter()
     out = lf.node(node)
+    if lf.overflow:
+        # past _MAX_PARAMS occurrences, equal literals share one slot.
+        # Only there: sharing makes the plan follow the values (two
+        # literals that happen to be equal would be one argument for one
+        # parameter set and two for the next), which a statement of few
+        # literals need not pay for
+        lf = _Lifter(shared=True)
+        out = lf.node(node)
     if lf.overflow:
         out, lf = node, _Lifter()
     values = list(lf.values)

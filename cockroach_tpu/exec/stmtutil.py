@@ -151,9 +151,11 @@ from ..utils.num import next_pow2 as _next_pow2  # noqa: E402
 
 @dataclass
 class _RerunPrepared:
-    """Prepared handle for statements that cannot pin one compiled
-    program (CTEs materialize fresh temps per run; set ops merge on
-    the host). Each run() re-executes through the engine — but a
+    """Prepared handle for statements with CTEs, derived tables or set
+    operations. Each run() re-executes through the engine: one planned
+    in place finds its program in the plan cache; one that takes the
+    temps (CTEs materialize fresh temps per run; set ops merge on the
+    host) is re-planned — but a
     successful CTE/derived execution CAPTURES its sub + main compiled
     programs, and steady-state re-runs against unchanged base tables
     compose them device-resident (exec/ctecompose.py): no host
@@ -177,7 +179,13 @@ class _RerunPrepared:
                     self._composed = None
             else:
                 self._composed = None
-        capturing = eng._begin_cte_capture(self.stmt, self.session)
+        # a statement whose CTEs and set operations the planner places
+        # is one program in the plan cache: nothing to compose
+        in_place = eng._plans_in_place(self.stmt, self.session) \
+            and self.sql_text not in eng._temps_memo \
+            and (not self.stmt.ctes or inline_ctes(self.stmt) is not None)
+        capturing = not in_place and eng._begin_cte_capture(
+            self.stmt, self.session)
         try:
             res = eng._exec_select(self.stmt, self.session,
                                    self.sql_text)
@@ -312,6 +320,246 @@ def _rewrite_table_names(sel, mapping: dict):
             fix_select(sub)
 
     fix_select(sel)
+    return sel
+
+
+def inline_ctes(stmt):
+    """A copy of a SELECT whose CTEs are each read exactly once, in a
+    FROM, with every such read replaced by the CTE's body as a derived
+    table (`FROM ssr` -> `FROM (<body>) AS ssr`), a later CTE's reads of
+    an earlier one included: what the planner can plan in place
+    (plan.Derived). None where that is not the same statement or not
+    known to be: a CTE read twice or never, a CTE with a column list,
+    a nested WITH (it could shadow a name), an AS OF clause (the temps
+    path carries it into each body)."""
+    import copy
+    import dataclasses
+    if not isinstance(stmt, ast.Select) or stmt.as_of is not None:
+        return None
+    names = [name for name, _, _ in stmt.ctes]
+    if len(set(names)) != len(names) or any(
+            cols for _, cols, _ in stmt.ctes):
+        return None
+    stmt = copy.deepcopy(stmt)
+    ctes, stmt.ctes = stmt.ctes, []
+    reads = dict.fromkeys(names, 0)
+    nested = []
+
+    def walk(o, fn):
+        if isinstance(o, (ast.Select, ast.SetOp)) and o.ctes:
+            nested.append(o)
+        if isinstance(o, ast.TableRef):
+            fn(o)
+        if isinstance(o, (list, tuple)):
+            for x in o:
+                walk(x, fn)
+        elif dataclasses.is_dataclass(o) and not isinstance(o, type):
+            for f in dataclasses.fields(o):
+                walk(getattr(o, f.name), fn)
+
+    def count(ref):
+        if ref.subquery is None and ref.name in reads:
+            reads[ref.name] += 1
+
+    for _, _, body in ctes:
+        walk(body, count)
+    walk(stmt, count)
+    if nested or any(n != 1 for n in reads.values()):
+        return None
+    bodies: dict = {}
+
+    def substitute(ref):
+        if ref.subquery is None and ref.name in bodies:
+            ref.alias = ref.alias or ref.name
+            ref.subquery = bodies[ref.name]
+
+    for name, _, body in ctes:
+        walk(body, substitute)
+        bodies[name] = body
+    walk(stmt, substitute)
+    return stmt
+
+
+def _refs(e) -> list:
+    """Every ColumnRef in an expression (an expression subquery's are
+    its own: one there makes the caller leave the conjunct alone)."""
+    import dataclasses
+    out = []
+
+    def walk(x):
+        if isinstance(x, ast.ColumnRef):
+            out.append(x)
+        elif isinstance(x, (ast.Select, ast.SetOp)):
+            out.append(None)
+        elif isinstance(x, (list, tuple)):
+            for v in x:
+                walk(v)
+        elif dataclasses.is_dataclass(x) and not isinstance(x, type):
+            for f in dataclasses.fields(x):
+                walk(getattr(x, f.name))
+    walk(e)
+    return out
+
+
+def _union_branches(so):
+    """The SELECT branches of a plain UNION ALL, None for any other
+    set operation or a branch that is not a plain projection (a GROUP
+    BY, DISTINCT, LIMIT, window or star would not commute with a
+    join)."""
+    if isinstance(so, ast.SetOp):
+        if so.op != "union" or not so.all or so.ctes or so.order_by \
+                or so.limit is not None or so.offset:
+            return None
+        left, right = _union_branches(so.left), _union_branches(so.right)
+        return None if left is None or right is None else left + right
+    if not isinstance(so, ast.Select) or so.group_by or so.distinct \
+            or so.having is not None or so.limit is not None \
+            or so.offset or so.ctes or so.table is None \
+            or any(it.star for it in so.items):
+        return None
+    return [so]
+
+
+def push_joins_into_unions(sel, columns_of):
+    """A copy of `sel` in which a table joined to a UNION ALL derived
+    table, and to nothing else, is joined inside each branch instead
+    (TPC-DS Q5: `FROM (store_sales' rows UNION ALL store_returns')
+    salesreturns, date_dim, store WHERE date_sk = d_date_sk AND d_date
+    BETWEEN ...` joins date_dim in each branch): inner joins distribute
+    over UNION ALL, so where the table's columns appear in nothing but
+    those conjuncts the statement is the same, and each branch's plan
+    filters its own rows before the union is made. Every SELECT of the
+    statement, derived tables' and branches' included, is rewritten.
+    `columns_of(table)` is a stored table's column names, or None."""
+    import copy
+    import dataclasses
+
+    def unions(s):
+        if isinstance(s, ast.SetOp):
+            return True
+        refs = ([s.table] if s.table is not None else []) \
+            + [j.table for j in s.joins]
+        return any(r.subquery is not None and unions(r.subquery)
+                   for r in refs)
+
+    if not unions(sel):
+        return sel
+    sel = copy.deepcopy(sel)
+
+    def conjuncts(e):
+        if isinstance(e, ast.BinOp) and e.op == "and":
+            return conjuncts(e.left) + conjuncts(e.right)
+        return [] if e is None else [e]
+
+    def conj(es):
+        out = None
+        for e in es:
+            out = e if out is None else ast.BinOp("and", out, e)
+        return out
+
+    def item_name(it):
+        return it.alias or (it.expr.name if isinstance(
+            it.expr, ast.ColumnRef) else None)
+
+    def rewrite(s):
+        if isinstance(s, ast.SetOp):
+            rewrite(s.left)
+            rewrite(s.right)
+            return
+        if not isinstance(s, ast.Select):
+            return
+        refs = ([s.table] if s.table is not None else []) \
+            + [j.table for j in s.joins]
+        for r in refs:
+            if r.subquery is not None:
+                rewrite(r.subquery)
+        unions = [r for r in refs if r.subquery is not None
+                  and _union_branches(r.subquery) is not None]
+        if len(unions) != 1 or any(j.join_type not in ("inner", "cross")
+                                   for j in s.joins):
+            return
+        u = unions[0]
+        ualias = u.alias or u.name
+        branches = _union_branches(u.subquery)
+        names = [item_name(it) for it in branches[0].items]
+        if None in names or len(set(names)) != len(names):
+            return
+        for jc in [j for j in s.joins if j.table.subquery is None]:
+            d = jc.table
+            dcols = columns_of(d.name)
+            dalias = d.alias or d.name
+            if dcols is None:
+                continue
+            others = [columns_of(r.name) if r.subquery is None else None
+                      for r in refs if (r.alias or r.name)
+                      not in (dalias, ualias)]
+
+            def side(ref):
+                """'d', 'u' or None (another table, or unknown)."""
+                if ref is None:
+                    return None
+                if ref.table is not None:
+                    return {dalias: "d", ualias: "u"}.get(ref.table)
+                in_d, in_u = ref.name in dcols, ref.name in names
+                if in_d == in_u:
+                    return None
+                if in_d and any(cols is None or ref.name in cols
+                                for cols in others):
+                    return None
+                return "d" if in_d else "u"
+
+            pool = conjuncts(s.where) + conjuncts(jc.on)
+            mine, rest = [], []
+            for c in pool:
+                sides = {side(r) for r in _refs(c)}
+                (mine if "d" in sides else rest).append(c)
+            if not mine or any(not ({side(r) for r in _refs(c)}
+                                    <= {"d", "u"}) for c in mine) \
+                    or not any(isinstance(c, ast.BinOp) and c.op == "="
+                               and {side(r) for r in _refs(c)}
+                               == {"d", "u"} for c in mine):
+                continue
+            # the table's columns in nothing else of the statement
+            elsewhere = [it.expr for it in s.items] + list(s.group_by) \
+                + [s.having] + [ob.expr for ob in s.order_by] \
+                + [j.on for j in s.joins if j is not jc] + rest
+            if any(r is None or side(r) == "d"
+                   for e in elsewhere for r in _refs(e)):
+                continue
+            # nor a name a branch reads unqualified (it would become
+            # ambiguous there)
+            if any(r is not None and r.table is None and r.name in dcols
+                   for b in branches
+                   for r in _refs([b.items, b.where, b.joins])):
+                continue
+            for i, b in enumerate(branches):
+                exprs = {n: it.expr for n, it in zip(names, b.items)}
+                balias = f"{dalias}${i}"
+
+                def subst(x):
+                    if isinstance(x, ast.ColumnRef):
+                        sd = side(x)
+                        if sd == "u":
+                            return copy.deepcopy(exprs[x.name])
+                        if sd == "d":
+                            return ast.ColumnRef(x.name, balias)
+                        return x
+                    if isinstance(x, (list, tuple)):
+                        return type(x)(subst(v) for v in x)
+                    if isinstance(x, ast.Expr):
+                        x = copy.copy(x)
+                        for f in dataclasses.fields(x):
+                            setattr(x, f.name, subst(getattr(x, f.name)))
+                    return x
+                b.joins.append(ast.JoinClause(
+                    ast.TableRef(d.name, balias), "cross", None))
+                b.where = conj(conjuncts(b.where)
+                               + [subst(c) for c in mine])
+            s.joins = [j for j in s.joins if j is not jc]
+            s.where = conj(rest)
+            refs = [r for r in refs if r is not d]
+
+    rewrite(sel)
     return sel
 
 

@@ -80,6 +80,150 @@ def summarize_build_keys(keys: np.ndarray, key_cap: int):
     return lo, hi, None, bl
 
 
+def _offset(keys, lo: int, span: int):
+    """A key component as its offset from `lo`, int32 where the span
+    allows (what every compare of the bounded and sorted forms reads),
+    and whether it lies inside [lo, lo + span)."""
+    inside = jnp.logical_and(keys >= lo, keys - lo < span)
+    off = keys.astype(jnp.int64) - lo
+    if span < (1 << 31):
+        off = jnp.clip(off, 0, span).astype(jnp.int32)
+    return off, inside
+
+
+def bounded_table(bkeys: tuple, bmask, n: int, direct) -> tuple:
+    """The bounded form's build: a direct table over one component of
+    the key (`comp`, [base, base + size)), `k` candidate build rows a
+    slot, where the store's statistics say no value of that component
+    has more than k live rows (Engine._maybe_direct_join). A slot's
+    candidates stand in ascending row order: placed by their distance
+    from the slot's first row where every one is within k of it, else
+    in k rounds, each a scatter-min of the rows no earlier round placed
+    (the two are the branches of one lax.cond, not a loop). Returns
+    (candidate rows [size, k], n where none; for each other component,
+    its offsets at those rows [size, k])."""
+    _, comp, base, size, k, los, spans = direct
+    slot = jnp.clip(bkeys[comp] - base, 0, size - 1).astype(jnp.int32)
+    slot = jnp.where(bmask, slot, size)               # dropped
+    rows = jnp.arange(n, dtype=jnp.int32)
+    # a table stored in the component's order (a returns table by its
+    # ticket, partsupp by its part) puts a slot's rows within k of its
+    # first: each row's place is its distance from that first, two
+    # scatters in all; any other order takes the k rounds
+    first = jnp.full((size,), n, dtype=jnp.int32).at[slot].min(
+        rows, mode="drop")
+    rank = rows - first[jnp.minimum(slot, size - 1)]
+    clustered = jnp.all(jnp.logical_or(jnp.logical_not(bmask),
+                                       rank < k))
+
+    def by_rank():
+        return jnp.full((size, k), n, dtype=jnp.int32).at[
+            slot, jnp.clip(rank, 0, k - 1)].set(rows, mode="drop")
+
+    def by_rounds():
+        placed = jnp.logical_not(bmask)
+        cands = []
+        for _ in range(k):
+            t = jnp.full((size,), n, dtype=jnp.int32).at[
+                jnp.where(placed, size, slot)].min(rows, mode="drop")
+            cands.append(t)
+            placed = jnp.logical_or(
+                placed, t[jnp.minimum(slot, size - 1)] == rows)
+        return jnp.stack(cands, axis=1)
+
+    cand = jax.lax.cond(clustered, by_rank, by_rounds)
+    at = jnp.minimum(cand, n - 1)
+    others = tuple(_offset(bkeys[c], los[c], spans[c])[0][at]
+                   for c in range(len(bkeys)) if c != comp)
+    return cand, others
+
+
+def bounded_probe(table, pkeys: tuple, pmask, n: int, direct):
+    """(matched, build row) of each probe row under bounded_table: its
+    slot's k candidates gathered as one row, each other component
+    compared at all k, the first candidate equal on every one."""
+    _, comp, base, size, k, los, spans = direct
+    cand, others = table
+    pk = pkeys[comp]
+    ok = jnp.logical_and(pmask, jnp.logical_and(pk >= base,
+                                                pk - base < size))
+    pidx = jnp.clip(pk - base, 0, size - 1).astype(jnp.int32)
+    rows = cand[pidx]                                 # [n_p, k]
+    eq = rows < n
+    j = 0
+    for c in range(len(pkeys)):
+        if c == comp:
+            continue
+        off, inside = _offset(pkeys[c], los[c], spans[c])
+        ok = jnp.logical_and(ok, inside)
+        eq = jnp.logical_and(eq, others[j][pidx] == off[:, None])
+        j += 1
+    first = jnp.argmax(eq, axis=1)
+    matched = jnp.logical_and(ok, jnp.any(eq, axis=1))
+    row = jnp.take_along_axis(rows, first[:, None], axis=1)[:, 0]
+    return matched, jnp.minimum(row, n - 1)
+
+
+def _packed(keys: tuple, los, spans):
+    """The key's components packed into one int64 (mixed radix, the
+    first most significant), and whether every one is in its range."""
+    code = jnp.zeros(keys[0].shape, dtype=jnp.int64)
+    ok = jnp.ones(keys[0].shape, dtype=jnp.bool_)
+    for kc, lo, span in zip(keys, los, spans):
+        inside = jnp.logical_and(kc >= lo, kc - lo < span)
+        code = code * span + jnp.clip(kc.astype(jnp.int64) - lo, 0,
+                                      span - 1)
+        ok = jnp.logical_and(ok, inside)
+    return code, ok
+
+
+def sorted_table(bkeys: tuple, bmask, n: int, direct) -> tuple:
+    """The sorted form's build: the live rows' packed keys in ascending
+    order (a dead row's key past every live one) and the rows they came
+    from, equal keys in ascending row order."""
+    _, los, spans = direct
+    code, _ = _packed(bkeys, los, spans)
+    code = jnp.where(bmask, code, jnp.iinfo(jnp.int64).max)
+    order = jnp.argsort(code, stable=True).astype(jnp.int32)
+    return code[order], order
+
+
+def sorted_probe(table, pkeys: tuple, pmask, n: int, direct):
+    """(matched, build row) of each probe row under sorted_table: a
+    lower-bound binary search of ceil(log2(n + 1)) unrolled steps, each
+    one gather, then one compare."""
+    _, los, spans = direct
+    keys, order = table
+    pk, ok = _packed(pkeys, los, spans)
+    lo = jnp.zeros(pk.shape, dtype=jnp.int32)
+    hi = jnp.full(pk.shape, n, dtype=jnp.int32)
+    for _ in range(max(n, 1).bit_length()):
+        mid = (lo + hi) // 2
+        right = keys[jnp.minimum(mid, n - 1)] < pk
+        right = jnp.logical_and(right, mid < hi)
+        lo = jnp.where(right, mid + 1, lo)
+        hi = jnp.where(right, hi, mid)
+    at = jnp.minimum(lo, n - 1)
+    matched = jnp.logical_and(jnp.logical_and(pmask, ok),
+                              jnp.logical_and(lo < n, keys[at] == pk))
+    return matched, order[at]
+
+
+def join_strategy(direct, join_type: str = "inner") -> str:
+    """The form a join's `direct` setting gives it: `direct` (a
+    direct-address table on one key), `packed` (on a composite key
+    packed into one), `bounded` (bounded_table), `sorted`
+    (sorted_table), `hash` (the while-loop table), or `cross` (a
+    cartesian product, no key)."""
+    if join_type == "cross":
+        return "cross"
+    if direct is None:
+        return "hash"
+    if isinstance(direct[0], str):
+        return direct[0]
+    return "direct"
+
+
 def hash_join(probe: ColumnBatch, build: ColumnBatch,
               probe_keys: list[str], build_keys: list[str],
               build_payload: list[str], join_type: str = "inner",
@@ -102,6 +246,8 @@ def hash_join(probe: ColumnBatch, build: ColumnBatch,
     (`build`: the key->row table and what is folded into it over the
     build domain; `probe`: every probe-width gather; `expand`), under
     the operator scope exec/compile.py opens."""
+    if join_type == "cross":
+        return _cross_join(probe, build, build_payload, suffix)
     bkeys = tuple(build.col(k) for k in build_keys)
     pkeys = tuple(probe.col(k) for k in probe_keys)
     bmask = build.sel
@@ -112,21 +258,30 @@ def hash_join(probe: ColumnBatch, build: ColumnBatch,
     for k in probe_keys:
         pmask = jnp.logical_and(pmask, probe.col_valid(k))
 
-    if direct is not None and direct[0] == "packed":
+    if direct is not None and direct[0] in ("bounded", "sorted"):
+        # a composite key past the packed table's span: a fixed number
+        # of compares (bounded) or of binary-search steps (sorted), no
+        # data-dependent loop; what it finds is what the hash table's
+        # probe finds, the lowest live build row of the key, so the
+        # payload and expansion paths below take it as they are
+        with jax.named_scope("build"):
+            if direct[0] == "bounded":
+                table = bounded_table(bkeys, bmask, build.n, direct)
+            else:
+                table = sorted_table(bkeys, bmask, build.n, direct)
+        with jax.named_scope("probe"):
+            matched, build_row = (bounded_probe if direct[0] == "bounded"
+                                  else sorted_probe)(table, pkeys, pmask,
+                                                     build.n, direct)
+    elif direct is not None and direct[0] == "packed":
         # Composite-key direct addressing (q9's partsupp (partkey,
         # suppkey)): mixed-radix-pack the components into ONE synthetic
         # key, then reuse the single-key direct machinery unchanged.
         # The engine proved every component's value range; the packed
         # span product fits the slot cap.
         _, los, spans = direct
-        bp = jnp.zeros_like(bkeys[0], dtype=jnp.int64)
-        pp = jnp.zeros_like(pkeys[0], dtype=jnp.int64)
-        ok_p = None
-        for kb, kp, lo, span in zip(bkeys, pkeys, los, spans):
-            bp = bp * span + (kb.astype(jnp.int64) - lo)
-            pp = pp * span + (kp.astype(jnp.int64) - lo)
-            comp = jnp.logical_and(kp >= lo, kp - lo < span)
-            ok_p = comp if ok_p is None else jnp.logical_and(ok_p, comp)
+        bp, _ = _packed(bkeys, los, spans)
+        pp, ok_p = _packed(pkeys, los, spans)
         size = 1
         for span in spans:
             size *= int(span)
@@ -138,7 +293,9 @@ def hash_join(probe: ColumnBatch, build: ColumnBatch,
         bkeys, pkeys = (bp,), (pp,)
         direct = (0, size)
 
-    if direct is not None and len(bkeys) == 1:
+    if direct is not None and direct[0] in ("bounded", "sorted"):
+        pass
+    elif direct is not None and len(bkeys) == 1:
         # Direct addressing: TPU scatters/gathers inside the hash
         # table's while_loops are ~100x slower than straight-line ops,
         # and dimension join keys are almost always dense ints (pks,
@@ -295,6 +452,33 @@ def hash_join(probe: ColumnBatch, build: ColumnBatch,
         return _expand_join(probe, build, bkeys, bmask, matched,
                             build_row, build_payload, join_type, suffix,
                             expand, sort_normalized)
+
+
+# rows of a cartesian product's batch at most
+CROSS_MAX_ROWS = 1 << 24
+
+
+def _cross_join(probe: ColumnBatch, build: ColumnBatch,
+                build_payload: list, suffix: str) -> ColumnBatch:
+    """Every probe row beside every build row (a comma join with no
+    equality between its sides): probe row p, build row b at p * n_b +
+    b, live where both are. The planner allows it only over a build
+    side of few rows (planner._few_rows)."""
+    n_p, n_b = probe.n, build.n
+    if n_p * n_b > CROSS_MAX_ROWS:
+        raise ValueError(f"a cartesian product of {n_p} x {n_b} rows "
+                         f"is past {CROSS_MAX_ROWS}")
+    with jax.named_scope("expand"):
+        cols, valid = {}, {}
+        for i, name in enumerate(probe.names):
+            cols[name] = jnp.repeat(probe.data[i], n_b)
+            valid[name] = jnp.repeat(probe.valid[i], n_b)
+        for name in build_payload:
+            cols[name + suffix] = jnp.tile(build.col(name), n_p)
+            valid[name + suffix] = jnp.tile(build.col_valid(name), n_p)
+        sel = jnp.logical_and(jnp.repeat(probe.sel, n_b),
+                              jnp.tile(build.sel, n_p))
+    return ColumnBatch.from_dict(cols, valid, sel=sel)
 
 
 def _dup_chain(bkeys: tuple, bmask, n: int, mode: str = "off"):

@@ -692,7 +692,8 @@ def _bind_string_builtin(binder, name: str, args: list) -> BExpr | None:
         pre = "".join(p for p in parts[:col_i] if p is not None)
         post = "".join(p for p in parts[col_i + 1:] if p is not None)
         return _dict_transform(binder, name, args[col_i],
-                               lambda s: pre + s + post)
+                               lambda s: pre + s + post,
+                               cache_key=("concat", pre, post))
     if name in _STR_TO_STR:
         if not args:
             raise BuiltinError(f"{name} needs arguments")

@@ -61,6 +61,20 @@ class Derived(PlanNode):
 
 
 @dataclass
+class UnionAll(PlanNode):
+    """UNION ALL of two plans whose batches hold the same column names
+    (`names`): the right batch's rows follow the left's, a column's
+    data and validity concatenated and the selections beside them. A
+    derived table's body of three branches is two of these, nested on
+    the left. Each branch is planned on its own (its string columns
+    already translated into one dictionary by the planner's Project
+    above it), so nothing of a branch is decoded to the host."""
+    left: PlanNode
+    right: PlanNode
+    names: list[str] = field(default_factory=list)
+
+
+@dataclass
 class Filter(PlanNode):
     child: PlanNode
     pred: BExpr = None
@@ -209,6 +223,11 @@ class OutputMeta:
     # whose results are constants of the plan (Binder.subqueries_run,
     # derived tables' bodies included)
     subqueries: int = 0
+    # output name -> (lo, hi): an integer output column that is a
+    # stored column (or a group key of one) and its stored range; what
+    # an outer plan that reads it as a derived table's column groups
+    # by densely (a UNION ALL's: the branches' ranges together)
+    int_ranges: dict = field(default_factory=dict)
 
 
 def grouping_key_order(sets: list, k: int):
@@ -272,6 +291,9 @@ def plan_tree_repr(node: PlanNode, indent: int = 0,
         return f"{pad}Derived as {node.alias}{f}{ann()}\n" + child(node.child)
     if isinstance(node, Filter):
         return f"{pad}Filter {node.pred!r}{ann()}\n" + child(node.child)
+    if isinstance(node, UnionAll):
+        return (f"{pad}UnionAll {node.names}{ann()}\n"
+                + child(node.left) + child(node.right))
     if isinstance(node, HashJoin):
         return (f"{pad}HashJoin[{node.join_type}] "
                 f"{node.left_keys}={node.right_keys}{ann()}\n"

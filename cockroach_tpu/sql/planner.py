@@ -103,7 +103,24 @@ def _prefix_aliases(sel: ast.Select, prefix: str) -> ast.Select:
     scopes of their own and are left as they are."""
     import copy
     import dataclasses
-    sel = copy.deepcopy(sel)
+
+    # the nested scopes are left as they are, so they are not copied
+    # (a derived table's planner copies its own body)
+    keep: dict = {}
+
+    def nested(x):
+        if isinstance(x, (ast.Select, ast.SetOp)) and x is not sel:
+            keep[id(x)] = x
+        elif isinstance(x, (list, tuple)):
+            for v in x:
+                nested(v)
+        elif dataclasses.is_dataclass(x) and not isinstance(x, type):
+            for f in dataclasses.fields(x):
+                nested(getattr(x, f.name))
+
+    for f in dataclasses.fields(sel):
+        nested(getattr(sel, f.name))
+    sel = copy.deepcopy(sel, keep)
     refs = ([sel.table] if sel.table is not None else []) \
         + [j.table for j in sel.joins]
     renamed = {}
@@ -165,6 +182,9 @@ class Planner:
         self.use_memo = use_memo
         self.volatile_fold_ok = volatile_fold_ok
         self.last_memo = None  # sql/memo.MemoResult of the last plan
+        # "alias.col" of a derived table's integer column -> its
+        # (lo, hi), from the derived plan's OutputMeta.int_ranges
+        self._derived_ranges: dict = {}
 
     def _keys_unique(self, cand_alias: str, cand_table: str, pool,
                      other_side: set, _key_side, scans) -> bool:
@@ -201,7 +221,10 @@ class Planner:
             stored.append(sname)
         if not stored:
             return False
-        distinct, nonnull = fn(cand_table, tuple(stored))
+        try:
+            distinct, nonnull = fn(cand_table, tuple(stored))
+        except KeyError:        # a derived table: nothing stored
+            return False
         return distinct == nonnull
 
     def _choose_access_paths(self, tables, conjuncts,
@@ -426,6 +449,124 @@ class Planner:
         return memomod.search(aliases, scan_rows, join_info,
                               scan_cost=scan_cost)
 
+    def _sub_planner(self) -> "Planner":
+        """A planner for a sub-select of this statement (a derived
+        table's body, a branch of a UNION ALL), with this one's hooks
+        and settings."""
+        return Planner(self.catalog, subquery_eval=self.subquery_eval,
+                       now_micros=self.now_micros,
+                       sequence_ops=self.sequence_ops,
+                       use_memo=self.use_memo,
+                       volatile_fold_ok=self.volatile_fold_ok,
+                       dict_folds=self.dict_folds, rules=self.rules_on,
+                       trace=self._trace, subquery_arg=self.subquery_arg)
+
+    def _plan_union_all(self, so: ast.SetOp, alias: str):
+        """A derived table whose body is a UNION ALL of SELECTs, planned
+        in place: each branch on its own (its tables' aliases prefixed
+        `alias$<i>$`), a Project above each that names its columns as
+        the first branch does, coerces a numeric column to the type
+        the branches share, and translates a string column's codes
+        into one dictionary over every branch's values (sorted, so
+        that a code's order is its value's); then plan.UnionAll nodes,
+        nested on the left. Anything else (UNION, INTERSECT, EXCEPT,
+        an ORDER BY or LIMIT of the set operation, a branch with
+        CTEs) says NotInPlace."""
+        import numpy as np
+
+        from ..storage.columnstore import Dictionary
+
+        def branches(s):
+            if isinstance(s, ast.SetOp):
+                if s.op != "union" or not s.all or s.ctes \
+                        or s.order_by or s.limit is not None \
+                        or s.offset:
+                    raise NotInPlace(
+                        f"derived table {alias!r} is a set operation "
+                        "other than a plain UNION ALL: it is "
+                        "materialized, not planned in place")
+                return branches(s.left) + branches(s.right)
+            if not isinstance(s, ast.Select) or s.ctes:
+                raise NotInPlace(
+                    f"a branch of derived table {alias!r} has CTEs: "
+                    "it is materialized, not planned in place")
+            return [s]
+
+        planned = [self._sub_planner().plan_select(
+            _prefix_aliases(b, f"{alias}${i}$"))
+            for i, b in enumerate(branches(so))]
+        names = list(planned[0][1].names)
+        width = len(names)
+        for _, m in planned:
+            if len(m.names) != width:
+                raise PlanError(
+                    "each UNION branch must have the same number of "
+                    f"columns ({width} vs {len(m.names)})")
+        from .types import common_numeric_type
+        numeric = (Family.INT, Family.FLOAT, Family.DECIMAL)
+        types = []
+        for j in range(width):
+            ts = [m.types[j] for _, m in planned]
+            t = ts[0]
+            for u in ts[1:]:
+                if u.family == t.family and (
+                        t.family != Family.DECIMAL or u.scale == t.scale):
+                    continue
+                if "unknown" in (u.family.value, t.family.value):
+                    t = u if t.family.value == "unknown" else t
+                elif t.family in numeric and u.family in numeric:
+                    t = common_numeric_type(t, u)
+                else:
+                    raise PlanError(
+                        f"UNION branch column types do not match: {t} "
+                        f"vs {u}")
+            types.append(t)
+        dicts = {}
+        for j, (name, t) in enumerate(zip(names, types)):
+            if not t.uses_dictionary:
+                continue
+            ds = [m.dictionaries.get(m.names[j]) for _, m in planned]
+            if any(d is None for d in ds):
+                raise NotInPlace(
+                    f"a string column of derived table {alias!r} has no "
+                    "dictionary in one branch: it is materialized")
+            d = Dictionary()
+            d.seed(sorted({v for x in ds for v in x.values}))
+            dicts[name] = d
+        coerce = Binder(Scope()).coerce
+        node = None
+        for node_i, m in planned:
+            items = []
+            for j, (name, t) in enumerate(zip(names, types)):
+                e = BCol(m.names[j], m.types[j])
+                if name in dicts:
+                    codes = dicts[name].codes
+                    src = m.dictionaries[m.names[j]]
+                    e = BDictRemap(e, np.array(
+                        [codes[v] for v in src.values] or [0],
+                        dtype=np.int32), t)
+                elif m.types[j] != t:
+                    e = coerce(e, t)
+                items.append((name, e))
+            if isinstance(node_i, plan.Project):
+                # one projection: the branch's own items seen through
+                items = [(name, _substitute(e, [
+                    (BCol(n, b.type), b) for n, b in node_i.items]))
+                    for name, e in items]
+                node_i = node_i.child
+            branch = plan.Project(node_i, items)
+            node = branch if node is None \
+                else plan.UnionAll(node, branch, list(names))
+        meta = plan.OutputMeta(names=names, types=types,
+                               dictionaries=dicts)
+        for j, name in enumerate(names):
+            rs = [m.int_ranges.get(m.names[j]) for _, m in planned]
+            if types[j].family == Family.INT and None not in rs:
+                meta.int_ranges[name] = (min(r[0] for r in rs),
+                                         max(r[1] for r in rs))
+        meta.subqueries = sum(m.subqueries for _, m in planned)
+        return node, meta
+
     def plan_select(self, sel: ast.Select) -> tuple[plan.PlanNode, plan.OutputMeta]:
         if sel.table is None:
             raise PlanError("SELECT without FROM not supported")
@@ -456,21 +597,16 @@ class Planner:
             alias's batch names. Its tables' aliases are prefixed with
             this alias, so that no two scans of one program share one."""
             alias = tref.alias or tref.name
-            if not isinstance(tref.subquery, ast.Select) \
-                    or tref.subquery.ctes:
+            if isinstance(tref.subquery, ast.SetOp):
+                subnode, submeta = self._plan_union_all(tref.subquery,
+                                                        alias)
+            elif tref.subquery.ctes or tref.subquery.table is None:
                 raise NotInPlace(
-                    f"derived table {alias!r} is a set operation or "
-                    "has CTEs: it is materialized, not planned in place")
-            sub = Planner(self.catalog, subquery_eval=self.subquery_eval,
-                          now_micros=self.now_micros,
-                          sequence_ops=self.sequence_ops,
-                          use_memo=self.use_memo,
-                          volatile_fold_ok=self.volatile_fold_ok,
-                          dict_folds=self.dict_folds, rules=self.rules_on,
-                          trace=self._trace,
-                          subquery_arg=self.subquery_arg)
-            subnode, submeta = sub.plan_select(
-                _prefix_aliases(tref.subquery, alias + "$"))
+                    f"derived table {alias!r} has CTEs or no FROM: it "
+                    "is materialized, not planned in place")
+            else:
+                subnode, submeta = self._sub_planner().plan_select(
+                    _prefix_aliases(tref.subquery, alias + "$"))
             derived_subqueries.append(submeta.subqueries)
             cols, colmap = {}, {}
             for name, ty in zip(submeta.names, submeta.types):
@@ -478,6 +614,8 @@ class Planner:
                 cols[name] = ColumnBinding(
                     bname, ty, submeta.dictionaries.get(name))
                 colmap[bname] = name
+                if name in submeta.int_ranges:
+                    self._derived_ranges[bname] = submeta.int_ranges[name]
             scope.add_table(alias, cols, hidden=hidden)
             tname = f"(derived {alias})"
             tables.append((alias, tname))
@@ -728,7 +866,8 @@ class Planner:
             if lk and jt == "cross":
                 # comma-join with equality predicates in WHERE -> hash join
                 jt = "inner"
-            if not lk:
+            if not lk and not (jt == "cross"
+                               and _few_rows(scans[alias])):
                 raise PlanError(
                     f"no equality join condition for {alias} "
                     "(cartesian products unsupported)")
@@ -1021,6 +1160,15 @@ class Planner:
 
         meta.names = out_names
         meta.types = out_types
+        keys = dict(group_exprs)
+        for name, ty, (_, b) in zip(out_names, out_types,
+                                    (rewritten if grouped else
+                                     bound_items)):
+            b = keys.get(b.name, b) if isinstance(b, BCol) else b
+            if ty.family == Family.INT and isinstance(b, BCol):
+                r = self._int_range_of(b.name, dict(tables))
+                if r is not None:
+                    meta.int_ranges[name] = r
         # attach dictionaries for string outputs
         for name, ty in zip(out_names, out_types):
             if ty.uses_dictionary:
@@ -1180,6 +1328,22 @@ class Planner:
             return group_exprs, []
         return kept, repl
 
+    def _int_range_of(self, bname: str, alias_to_table: dict):
+        """(lo, hi) of the batch column `bname`: a stored integer
+        column's range, or a derived table's column's; None where
+        neither is known."""
+        if bname in self._derived_ranges:
+            return self._derived_ranges[bname]
+        if self.catalog.int_range_fn is None or "." not in bname:
+            return None
+        alias, col = bname.split(".", 1)
+        tname = alias_to_table.get(alias)
+        try:
+            r = self.catalog.int_range_fn(tname, col) if tname else None
+        except KeyError:
+            r = None
+        return None if r is None else (int(r[0]), int(r[1]))
+
     def _static_group_bound(self, group_exprs, scope: Scope,
                             tables=None):
         """If every group key is a dict-encoded column, bool, or an int
@@ -1219,7 +1383,9 @@ class Planner:
             return None if d is None else (max(len(d), 1), 0)
         if isinstance(e, BCol) and e.type.family == Family.BOOL:
             return 2, 0
-        if isinstance(e, BCol) and e.type.family == Family.INT \
+        if isinstance(e, BCol) and e.name in self._derived_ranges:
+            lo, hi = self._derived_ranges[e.name]
+        elif isinstance(e, BCol) and e.type.family == Family.INT \
                 and self.catalog.int_range_fn is not None \
                 and "." in e.name:
             alias, col = e.name.split(".", 1)
@@ -1333,6 +1499,25 @@ class Planner:
                     if isinstance(ge, BCol):
                         return self._dict_by_batch_name(ge.name, scope)
         return None
+
+
+def _few_rows(build: plan.PlanNode) -> bool:
+    """May `build` be the right side of a cartesian product? A derived
+    table whose rows are the groups of a GROUP BY over keys of a small
+    static domain (TPC-DS Q77's `cs, cr`, call centres on both sides):
+    the product's rows are static and few. A stored table never is."""
+    if not isinstance(build, plan.Derived):
+        return False
+    n = build.child
+    while isinstance(n, (plan.Project, plan.Sort, plan.Limit,
+                         plan.Filter)):
+        n = n.child
+    return isinstance(n, plan.Aggregate) and (
+        not n.group_by or 0 < n.max_groups <= CROSS_MAX_GROUPS)
+
+
+# groups of a cartesian product's build side at most (_few_rows)
+CROSS_MAX_GROUPS = 1 << 12
 
 
 def _encode_const_string_item(b: BExpr) -> BExpr:

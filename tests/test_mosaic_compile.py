@@ -1,5 +1,6 @@
-"""The large-G kernel and a Compact's two kernels (ops/pallas/compact.py,
-through compile.compact_batch) compiled for the v5e here, without a chip: the
+"""The large-G kernel, a Compact's two kernels (ops/pallas/compact.py,
+through compile.compact_batch) and the bounded form of a composite-key
+join (ops/join.py) compiled for the v5e here, without a chip: the
 TPU's own compiler (XLA:TPU and Mosaic) is installed and compiles for
 a topology that is described, not attached. Interpret mode cannot see
 what these see: a contraction Mosaic refuses, a block that passes the
@@ -165,3 +166,38 @@ def test_compact_batch_compiles_at_the_benchmarks_shapes(one_chip, n, frac):
     assert text.count('custom_call_target="tpu_custom_call"') == 7
     out = plan_rows(P.Compact(P.Scan("t", "t"), frac=frac), {"t": n})
     assert out < n and f"[{out}]" in text
+
+
+# -- a composite-key join's bounded form --------------------------------------
+
+def test_bounded_composite_join_compiles_at_the_benchmarks_shape(one_chip):
+    """TPC-DS Q80's store_returns build (2^19 rows, a direct table over
+    240,034 tickets widened to 2^18, k = 16) probed at store_sales' full
+    2^22 rows: both
+    branches of the build's lax.cond (two scatters, or k rounds of one)
+    and the probe's gather of k candidates a row, through XLA:TPU."""
+    from cockroach_tpu.ops.batch import ColumnBatch
+    from cockroach_tpu.ops.join import hash_join, join_strategy
+
+    n_build, n_probe, size = 1 << 19, 1 << 22, (1 << 18) + 1
+    direct = ("bounded", 1, 0, size, 16, (0, 0), (20_480, 1 << 18))
+    assert join_strategy(direct) == "bounded"
+
+    def spec(n, dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+
+    def fn(psel, pitem, pticket, bsel, bitem, bticket, amt):
+        probe = ColumnBatch.from_dict({"p.i": pitem, "p.t": pticket}, {},
+                                      sel=psel)
+        build = ColumnBatch.from_dict(
+            {"b.i": bitem, "b.t": bticket, "b.amt": amt}, {}, sel=bsel)
+        return hash_join(probe, build, ["p.i", "p.t"], ["b.i", "b.t"],
+                         ["b.amt"], "left", direct=direct)
+
+    with jax.enable_x64(True):
+        compiled = jax.jit(fn).trace(
+            spec(n_probe, jnp.bool_), spec(n_probe, jnp.int64),
+            spec(n_probe, jnp.int64), spec(n_build, jnp.bool_),
+            spec(n_build, jnp.int64), spec(n_build, jnp.int64),
+            spec(n_build, jnp.int64)).lower().compile()
+    assert "conditional" in compiled.as_text()
