@@ -647,6 +647,12 @@ UNION_BRANCHES = sortkey._Tally()
 # (aggregate_strategy): the engine's exec.agg.strategy.*
 AGG_STRATEGY = sortkey._Tally()
 
+# plain GROUP BYs past the dense bound whose keys pack, one tally a
+# trace: `group_by` took the sorted layout, `declined` kept the hash
+# table (a batch under SORTED_GROUP_MIN_ROWS, or an exact sum the
+# plan did not prove inside int64); the engine's exec.agg.sorted.*
+SORTED_GROUP_BYS = sortkey._Tally()
+
 
 def _compile_agg_args(aggs) -> list:
     """(aggregate, its compiled argument or None) for an Aggregate's
@@ -674,6 +680,19 @@ def _sum_cannot_wrap(a: BoundAgg, rows: int) -> bool:
     overflow sentinel is compiled for it."""
     bits = _proven_bits(a)
     return bits > 0 and (rows << bits) < (1 << 62)
+
+
+def _exact_sums_cannot_wrap(aggs, rows: int) -> bool:
+    """Is every exact SUM and AVG among `aggs` (an INT / DECIMAL
+    argument) proven inside int64 over `rows` rows (_sum_cannot_wrap)?
+    The sorted layout keeps such a sum in int64 running sums, and its
+    overflow sentinel trips on the global bound rows x max|value| alone;
+    the hash table runs an f64 shadow where that bound trips, and an
+    AVG without the proof adds floats there, so a plain GROUP BY whose
+    sums are not proven keeps the table."""
+    return all(_sum_cannot_wrap(a, rows) for a in aggs
+               if a.func in ("sum", "sum_int", "avg") and a.arg is not None
+               and a.arg.type.family in (Family.INT, Family.DECIMAL))
 
 
 def _agg_partials(a: BoundAgg, argf, batch, ctx, gid, num_groups,
@@ -948,12 +967,20 @@ def aggregate_strategy(node: P.Aggregate, n: int,
     """How this Aggregate over an n-row batch computes its groups,
     from the plan alone: `scalar` (no GROUP BY: masked reductions),
     `kernel` (a dense group domain on the large-G Pallas kernel),
-    `dense` (a dense domain on XLA's segment sums) or `hash` (a domain
-    the planner could not bound: ops/hashtable.py's while-loop table,
-    segment sums over its slots)."""
+    `dense` (a dense domain on XLA's segment sums), `sorted` (past the
+    dense bound, keys that pack into one code: exec/rollup.py's one
+    sort, for grouping sets always and for a plain GROUP BY on one
+    device over at least SORTED_GROUP_MIN_ROWS rows whose exact sums
+    the plan proves inside int64) or `hash` (a domain the planner could
+    not bound: ops/hashtable.py's while-loop table, segment sums over
+    its slots)."""
     if not node.group_by:
         return "scalar"
     if node.max_groups <= 0:
+        if node.sort_dims and (node.grouping_sets is not None or (
+                params.axis_name is None and n >= SORTED_GROUP_MIN_ROWS
+                and _exact_sums_cannot_wrap(node.aggs, n))):
+            return "sorted"
         return "hash"
     return "kernel" if large_kernel_eligible(node, n, params) else "dense"
 
@@ -1351,18 +1378,24 @@ def _compile_aggregate(node: P.Aggregate, params: ExecParams) -> CompiledNode:
             # plans, so this is a belt-and-braces guard
             raise ExecError("DISTINCT aggregates cannot run distributed")
     sets = node.grouping_sets
+    rows_slot = None
+    if sets is not None or node.sort_dims:
+        # the finest set computes partial states (AVG: a sum and a
+        # count), the other sets combine them (exec/rollup.py); a plain
+        # GROUP BY on the sorted layout is its one set
+        state, where = rollup.state_aggs(node.aggs)
     if sets is not None:
         if params.axis_name:
             raise ExecError("GROUPING SETS cannot run distributed yet")
-        # the finest set computes partial states (AVG: a sum and a
-        # count), the other sets combine them (exec/rollup.py)
-        state, where = rollup.state_aggs(node.aggs)
-        aggfs = _compile_agg_args(state)
+        aggfs = statefs = _compile_agg_args(state)
         stats = params.join_stats
         rows_slot = stats.site("exec.agg.rollup.rows") \
             if stats is not None else None
     else:
         aggfs = _compile_agg_args(node.aggs)
+        if node.sort_dims:
+            statefs = [(a, compile_expr(a.arg) if a.arg is not None
+                        else None) for a in state]
     itemfs = [(name, compile_expr(e)) for name, e in node.items]
     havingf = compile_expr(node.having) if node.having is not None else None
     dense = node.max_groups > 0
@@ -1382,8 +1415,10 @@ def _compile_aggregate(node: P.Aggregate, params: ExecParams) -> CompiledNode:
 
     def run_agg(rc: RunContext) -> ColumnBatch:
         b = childf(rc)
-        if sets is not None and not dense:
+        if aggregate_strategy(node, b.n, params) == "sorted":
             return _sorted_sets(rc, b)
+        if node.sort_dims and sets is None:
+            SORTED_GROUP_BYS.bump("declined")
         # the phases a profile treats apart, under this node's scope:
         # keys (group ids), operands + kernel (the partials; the
         # large-G path names its own two inside ops/pallas), finalize
@@ -1538,13 +1573,15 @@ def _compile_aggregate(node: P.Aggregate, params: ExecParams) -> CompiledNode:
         return out
 
     def _sorted_sets(rc, b):
-        """A grouping-set Aggregate past the dense bound: one sort of
-        the rows by the keys' packed code (exec/rollup.py)."""
+        """An Aggregate past the dense bound whose keys pack: one sort
+        of the rows by the keys' packed code (exec/rollup.py), its
+        grouping sets or, for a plain GROUP BY, the one set of every
+        key."""
         ctx = _ctx_of(b, params=rc.params)
         with jax.named_scope("keys"):
             keys = [gf(ctx) for _, gf in groupfs]
         states, bound = [], jnp.bool_(True)
-        for a, argf in aggfs:
+        for a, argf in statefs:
             if a.func == "count_rows":
                 states.append((jnp.ones((b.n,), jnp.int64), b.sel))
                 continue
@@ -1553,21 +1590,29 @@ def _compile_aggregate(node: P.Aggregate, params: ExecParams) -> CompiledNode:
                 d = jnp.ones((b.n,), jnp.int64)
             elif a.func in ("sum", "sum_int") and d.dtype != jnp.float64:
                 d = d.astype(jnp.int64)
-                # a group's sum is exact while every running sum of its
-                # rows fits: rows x max |value| under 2^62 proves it
-                top = jnp.max(jnp.abs(jnp.where(
-                    jnp.logical_and(v, b.sel), d, 0))).astype(jnp.float64)
-                bound = jnp.logical_and(
-                    bound, top * b.n < jnp.float64(2 ** 62))
+                if not _sum_cannot_wrap(a, b.n):
+                    # a group's sum is exact while every running sum of
+                    # its rows fits: rows x max |value| under 2^62
+                    # proves it where the plan did not (a plain GROUP BY
+                    # takes this layout only with every sum proven)
+                    top = jnp.max(jnp.abs(jnp.where(
+                        jnp.logical_and(v, b.sel), d,
+                        0))).astype(jnp.float64)
+                    bound = jnp.logical_and(
+                        bound, top * b.n < jnp.float64(2 ** 62))
             states.append((d, v))
+        levels = sets if sets is not None else [tuple(range(len(keys)))]
         slots = (node.set_slots if params.topk_sort and node.set_slots
-                 else b.n * len(sets))
-        rollup.SETS.bump("sets", len(sets))
+                 else b.n * len(levels))
+        if sets is not None:
+            rollup.SETS.bump("sets", len(sets))
+        else:
+            SORTED_GROUP_BYS.bump("group_by")
         AGG_STRATEGY.bump("sorted")
         with jax.named_scope("kernel"):
             cols, states, live, slots, rows, short = rollup.sorted_sets(
-                sets, node.sort_dims, [n for n, _ in groupfs], keys,
-                states, state, b.sel, slots)
+                levels, node.sort_dims, [n for n, _ in groupfs], keys,
+                states, state, b.sel, slots, tally=sets is not None)
         if rows_slot is not None:
             stats.note_site("exec.agg.rollup.rows", rows_slot, rows)
         with jax.named_scope("finalize"):
@@ -1807,16 +1852,34 @@ def limit_batch(b: ColumnBatch, limit, offset) -> ColumnBatch:
     return b.with_sel(keep)
 
 
-# Slots of a hash-strategy Aggregate's output that a Sort above it
-# orders where the engine estimates far fewer groups (P.Sort.prefix,
-# Engine._size_hash_sorts): the table hands its groups over as a dense
-# prefix of hash_group_capacity slots (hashtable.group_ids numbers them
-# 0..ng-1), 2^17 by default, and XLA:TPU takes 65-80 s to compile a
-# sort of 2^15 rows or more against 8 s for 2^13 (measured for a
-# described v5e: a stable argsort of u64[n]; SSB's four hash-strategy
+# Slots of a plain GROUP BY's output past the dense bound that a Sort
+# above it orders where the engine estimates far fewer groups
+# (P.Sort.prefix, Engine._size_hash_sorts): the table hands its groups
+# over as a dense prefix of hash_group_capacity slots
+# (hashtable.group_ids numbers them 0..ng-1), 2^17 by default, the
+# sorted layout as one of its set_slots, and XLA:TPU takes 65-80 s to
+# compile a sort of 2^15 rows or more against 8 s for 2^13 (measured
+# for a described v5e: a stable argsort of u64[n]; SSB's four hash-strategy
 # statements were 37 to 75 s of cold compile each, nearly all of it
 # this sort, for a few hundred live groups)
 HASH_SORT_PREFIX = 1 << 13
+
+# Rows of its batch from which a plain GROUP BY past the dense bound
+# whose keys pack (P.Aggregate.sort_dims) groups by the sorted layout
+# (exec/rollup.py sorted_sets, one set) and not by the hash table
+# (aggregate_strategy). The table's while loop passes over every row
+# until its longest probe chain closes; the sort is one pass of fixed
+# depth. group_by_crossover.py times the two on a TPU v5e: 3 / 6 key
+# columns, 3,000 groups, 70 % of the rows live, a sum and a count, ms
+# a call for the table against the sort: 2^15 rows 17 / 24 against
+# 3.3; 2^16 30 / 54 against 5.3; 2^17 41 / 101 against 11.8; 2^18
+# 131 / 370 against 21; 313,600 192 / 370 against 22. The sort's
+# program compiles in 5.5-11.6 s, the table's in 2-6 s. So the sort
+# wins at every size measured; the bound keeps the batches a star
+# join's Compacts hand on under it (SSB Q3.2-Q3.4 and Q4.3 at SF1:
+# 57,728 rows and fewer, where the table costs 17-54 ms a statement)
+# on the table's program, and with it those statements' cold compile.
+SORTED_GROUP_MIN_ROWS = 1 << 17
 
 
 def _dense_prefix(b: ColumnBatch, k: int) -> ColumnBatch:
@@ -1836,9 +1899,9 @@ def _dense_prefix(b: ColumnBatch, k: int) -> ColumnBatch:
 
 def sort_prefix(node: P.Sort, params: ExecParams) -> int:
     """Leading slots this Sort orders, 0 = its whole input: the plan's
-    prefix, on one device, over a hash-strategy Aggregate (its HAVING
-    thins the prefix, no more), unless the engine asked for the whole
-    sort (no_topk)."""
+    prefix, on one device, over a plain GROUP BY past the dense bound
+    (its HAVING thins the prefix, no more), unless the engine asked for
+    the whole sort (no_topk)."""
     child = node.child
     if params.topk_sort and params.axis_name is None \
             and isinstance(child, P.Aggregate) and child.group_by \
@@ -1848,10 +1911,11 @@ def sort_prefix(node: P.Sort, params: ExecParams) -> int:
 
 
 def window_prefix(node: P.Window, params: ExecParams) -> int:
-    """Leading rows a Window over a hash-strategy Aggregate orders, 0 =
-    its whole input: the plan's prefix (Engine._size_hash_sorts), as
-    sort_prefix gives a Sort's. The table hands its groups over as a
-    dense prefix of its slots, and a prefix that proves short raises
+    """Leading rows a Window over a plain GROUP BY past the dense bound
+    orders, 0 = its whole input: the plan's prefix
+    (Engine._size_hash_sorts), as sort_prefix gives a Sort's. The hash
+    table and the sorted layout hand their groups over as a dense
+    prefix of their slots, and a prefix that proves short raises
     the top-k sentinel (_dense_prefix)."""
     child = node.child
     if params.topk_sort and params.axis_name is None \

@@ -57,7 +57,7 @@ from . import coldstart
 from . import movement
 from . import rollup as _rollup
 from .compile import (AGG_STRATEGY, COMPACTS, JOIN_KINDS, JOIN_STRATEGY,
-                      RANGE_PROOFS, UNION_BRANCHES,
+                      RANGE_PROOFS, SORTED_GROUP_BYS, UNION_BRANCHES,
                       ExecParams, JoinStats, RunContext,
                       _compact_block_rows, aggregate_strategy, can_stream,
                       compile_plan, compile_streaming, plan_rows)
@@ -580,9 +580,11 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                  "the while-loop hash table, segment sums over its "
                  "slots"),
                 ("scalar", "no GROUP BY: masked reductions"),
-                ("sorted", "grouping sets past the dense bound: one "
-                 "sort of the rows by the keys' packed code "
-                 "(exec/rollup.py)")):
+                ("sorted", "past the dense bound, keys that pack "
+                 "into one code: one sort of the rows by it "
+                 "(exec/rollup.py), grouping sets always, a plain "
+                 "GROUP BY over a batch of SORTED_GROUP_MIN_ROWS or "
+                 "more whose exact sums are proven inside int64")):
             self.metrics.func_counter(
                 "exec.agg.strategy." + kind,
                 lambda kind=kind: AGG_STRATEGY.value(kind),
@@ -594,6 +596,18 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             "grouping sets of the grouping-set Aggregates traced (GROUP "
             "BY ROLLUP / GROUPING SETS; ROLLUP of k keys is k + 1): one "
             "tally a set a trace")
+        for kind, what in (
+                ("group_by", "plain GROUP BYs past the dense bound that "
+                 "took the sorted layout as one set"),
+                ("declined", "plain GROUP BYs past the dense bound whose "
+                 "keys pack that kept the hash table: a batch under "
+                 "SORTED_GROUP_MIN_ROWS, or an exact sum not proven "
+                 "inside int64")):
+            self.metrics.func_counter(
+                "exec.agg.sorted." + kind,
+                lambda kind=kind: SORTED_GROUP_BYS.value(kind),
+                f"{what} (compile.aggregate_strategy): one tally a "
+                "trace")
         for kind, what in (
                 ("network", "sets of the sorted grouping-set Aggregates "
                  "traced whose groups a displacement network packed: "
@@ -3745,12 +3759,14 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         walk(node)
 
     def _size_hash_sorts(self, node) -> None:
-        """Give a Sort or a Window right above a hash-strategy Aggregate
-        the prefix it orders (P.Sort.prefix, P.Window.prefix,
-        compile.HASH_SORT_PREFIX) where the Aggregate's estimated group
-        count is at most half of it; otherwise, and where nothing is
-        known of a key, it orders all hash_group_capacity slots as a
-        Sort always did. An estimate that proves low raises the top-k
+        """Give a Sort or a Window right above a plain GROUP BY past
+        the dense bound the prefix it orders (P.Sort.prefix,
+        P.Window.prefix, compile.HASH_SORT_PREFIX) where the Aggregate's
+        estimated group count is at most half of it; otherwise, and
+        where nothing is known of a key, it orders all the slots (the
+        hash table's hash_group_capacity, the sorted layout's
+        set_slots) as a Sort always did. Either strategy hands its live
+        groups on first. An estimate that proves low raises the top-k
         sentinel once (_whole_sorts)."""
         from .compile import HASH_SORT_PREFIX
 
@@ -3841,10 +3857,11 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         return None
 
     def _size_grouping_sets(self, node) -> None:
-        """Give a grouping-set Aggregate of the sorted layout the slots
-        its sets' groups are packed into (P.Aggregate.set_slots): a
-        power of two past 5/4 of the groups estimated over all its
-        sets, at least 2^13. A set's groups are estimated from D, the
+        """Give an Aggregate of the sorted layout (grouping sets, or a
+        plain GROUP BY as its one set) the slots its sets' groups are
+        packed into (P.Aggregate.set_slots): a power of two past 5/4
+        of the groups estimated over all its sets, at least 2^13. A
+        set's groups are estimated from D, the
         product over the tables its keys come from of the distinct
         tuples of those keys among the rows the table's filter keeps
         (exec/dimstats.py), and n, the rows into the Aggregate: the
@@ -3878,8 +3895,10 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 return None
             scans.append(sc)
         rows = self._estimate_rows(agg.child)
+        sets = (agg.grouping_sets if agg.grouping_sets is not None
+                else [tuple(range(len(agg.group_by)))])
         total, finer = 0.0, None
-        for s in sorted(agg.grouping_sets, key=len, reverse=True):
+        for s in sorted(sets, key=len, reverse=True):
             by_scan: dict = {}
             for j in s:
                 by_scan.setdefault(id(scans[j]), (scans[j], []))[1].append(

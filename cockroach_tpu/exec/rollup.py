@@ -27,7 +27,10 @@ child's rows. Two layouts:
           less that of the group before it in its set. Min, max, any
           and a float sum keep a segmented reduction, whose run values
           the networks carry. All the sets' groups are laid into one
-          batch of plan.Aggregate.set_slots slots, level after level
+          batch of plan.Aggregate.set_slots slots, level after level.
+          A plain GROUP BY past the dense bound whose keys pack takes
+          this layout too, as its one set, over a batch large enough
+          (exec/compile.py SORTED_GROUP_MIN_ROWS)
 
 Every set's groups carry a rolled-up key as NULL and `__grouping<j>` 1
 where key j is rolled up (sql/binder.py bind_grouping reads it).
@@ -273,11 +276,13 @@ def _nest(flat, like) -> list:
 
 
 def sorted_sets(sets: list, sort_dims: list, key_names: list, keys: list,
-                states: list, state_aggs_: list, sel, slots: int) -> tuple:
-    """The sets of a grouping-set Aggregate past the dense bound, from
-    the child's rows: (group columns with the `__grouping<j>` bits,
-    the states, live, slots, the rows the coarser sets were traced
-    over, overflow: more groups than `slots`).
+                states: list, state_aggs_: list, sel, slots: int,
+                tally: bool = True) -> tuple:
+    """The sets of an Aggregate past the dense bound, from the child's
+    rows: (group columns with the `__grouping<j>` bits, the states,
+    live, slots, the rows the coarser sets were traced over, overflow:
+    more groups than `slots`). A plain GROUP BY is the one set of every
+    key, and `tally` False leaves it out of SETS.
 
     After the one sort, no scatter and no gather: each state's running
     sum (or its runs' values) over the sorted rows, read at the finest
@@ -318,9 +323,10 @@ def sorted_sets(sets: list, sort_dims: list, key_names: list, keys: list,
         end = jnp.logical_and(live, cs != nxt)
     kinds = [_kind(a) for a in state_aggs_]
     dtypes = [d.dtype for d, _ in states]
-    SETS.bump("network", len(sets))
-    SETS.bump("segmented", sum(not _exact(kd, dt)
-                               for kd, dt in zip(kinds, dtypes)))
+    if tally:
+        SETS.bump("network", len(sets))
+        SETS.bump("segmented", sum(not _exact(kd, dt)
+                                   for kd, dt in zip(kinds, dtypes)))
     with jax.named_scope("operands"):
         run = _running(kinds, [(d[perm], jnp.logical_and(v[perm], live))
                                for d, v in states], start)

@@ -1042,11 +1042,25 @@ class Planner:
             sort_dims = []
             if sets is not None and max_groups <= 0:
                 sort_dims = self._sort_dims(group_exprs, scope, tables)
+                if sort_dims is None:
+                    raise PlanError(
+                        "ROLLUP / GROUPING SETS past the dense group "
+                        "bound need every key's domain known (a string, "
+                        "a bool, an integer column with a stored range) "
+                        f"in a {self.SORT_CODE_BITS}-bit code")
                 if plan.grouping_key_order(sets, len(group_exprs)) is None:
                     raise PlanError(
                         "GROUPING SETS past the dense group bound must "
                         "nest (each set a prefix of one order of the "
                         "keys, as ROLLUP's are)")
+            elif max_groups <= 0 \
+                    and not any(a.distinct for a in binder.aggs):
+                # a plain GROUP BY past the dense bound whose keys pack
+                # may group by the sorted layout as one set
+                # (compile.aggregate_strategy); where they do not it
+                # keeps the hash table
+                sort_dims = self._sort_dims(group_exprs, scope,
+                                            tables) or []
             if not binder.windows:
                 node = plan.Aggregate(node, group_exprs, binder.aggs,
                                       having_b, rewritten, max_groups,
@@ -1412,33 +1426,27 @@ class Planner:
             return None
         return int(span), int(lo)
 
-    # a grouping-set Aggregate whose finest set is past the dense bound
-    # groups by one sort of a packed code (exec/rollup.py
-    # sorted_sets): every key's code space, its NULL code included,
-    # takes bits of it
+    # an Aggregate past the dense bound may group by one sort of a
+    # packed code (exec/rollup.py sorted_sets): every key's code space,
+    # its NULL code included, takes bits of it
     SORT_CODE_BITS = 62
     MAX_INT_SORT_SPAN = 1 << 32
 
-    def _sort_dims(self, group_exprs, scope: Scope, tables) -> list:
-        """[(code-space size, value offset)] of a grouping-set
-        Aggregate's keys, for the packed sort code; a PlanError where
-        a key's domain is unknown or the code would not fit."""
+    def _sort_dims(self, group_exprs, scope: Scope, tables):
+        """[(code-space size, value offset)] of an Aggregate's keys, for
+        the packed sort code; None where a key's domain is unknown or
+        the code would not fit."""
         alias_to_table = dict(tables or [])
         out = []
         for _, e in group_exprs:
             got = self._key_dim(e, scope, alias_to_table,
                                 self.MAX_INT_SORT_SPAN)
             if got is None:
-                raise PlanError(
-                    "ROLLUP / GROUPING SETS past the dense group bound "
-                    "need every key's domain known (a string, a bool, "
-                    "an integer column with a stored range): "
-                    f"{e!r} has none")
+                return None
             out.append(got)
         if sum(int(dim).bit_length() for dim, _ in out) \
                 > self.SORT_CODE_BITS:
-            raise PlanError("ROLLUP / GROUPING SETS keys' domains do not "
-                            f"fit a {self.SORT_CODE_BITS}-bit code")
+            return None
         return out
 
     def _year_extract_range(self, e, alias_to_table):

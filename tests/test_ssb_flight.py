@@ -125,17 +125,42 @@ def _aggregate_of(eng, sql):
     return node
 
 
+def _aggregate_rows(eng, sql):
+    """Rows of the batch the plan's Compacts hand its Aggregate when
+    lineorder fills the SF1 bucket."""
+    s = eng.session()
+    s.vars.set("distsql", "off")
+    node, _ = eng._plan(parser.parse(sql), s)
+    eng._check_join_builds(node, eng._read_ts(s), {})
+    node = eng._insert_compaction(node, {"lineorder": SF1_ROWS})
+    while not isinstance(node, P.Aggregate):
+        node = node.child
+    return C.plan_rows(node.child, {"lineorder": SF1_ROWS})
+
+
 @pytest.mark.parametrize("name", NAMES)
-def test_strategy_at_the_papers_cardinalities(loaded, name):
+def test_strategy_at_the_papers_cardinalities(loaded, paper, name):
     eng = loaded[0]
     agg = _aggregate_of(eng, ssb.QUERIES[name])
     want, groups = STRATEGY[name]
     # on the chip, over the 2^23-row bucket SF1 pads to
     chip = C.ExecParams(pallas_groupagg="auto", pallas_interpret=False)
-    assert C.aggregate_strategy(agg, 1 << 23, chip) == want
     if want == "hash":
-        assert agg.max_groups == 0
-    elif want == "kernel":
+        # past the dense bound, keys that pack (cities, a year): the
+        # sorted layout over a batch of SORTED_GROUP_MIN_ROWS or more,
+        # the table over the one the two Compacts hand on at SF1
+        assert agg.max_groups == 0 and agg.sort_dims
+        rows = _aggregate_rows(paper, ssb.QUERIES[name])
+        assert rows < C.SORTED_GROUP_MIN_ROWS <= 1 << 23
+        assert C.aggregate_strategy(agg, rows, chip) == want
+        # over the whole bucket: sorted where the plan proves the sums
+        # inside int64 (Q3's revenue), the table where it cannot (Q4.3's
+        # profit, revenue less supply cost, is signed)
+        whole = "hash" if name == "q4.3" else "sorted"
+        assert C.aggregate_strategy(agg, 1 << 23, chip) == whole
+        return
+    assert C.aggregate_strategy(agg, 1 << 23, chip) == want
+    if want == "kernel":
         assert C.dense_num_groups(agg) == groups <= C.LARGE_G_MAX
         off = C.ExecParams(pallas_groupagg="off")
         assert C.aggregate_strategy(agg, 1 << 23, off) == "dense"
@@ -150,8 +175,12 @@ def test_strategy_is_tallied_where_the_aggregate_is_traced(loaded):
                       (ssb.Q4_1.replace("MFGR#2", "MFGR#3"), "kernel"),
                       (ssb.Q3_2.replace("1997", "1996"), "hash")):
         before = C.AGG_STRATEGY.value(kind)
+        declined = C.SORTED_GROUP_BYS.value("declined")
         eng.execute(sql, session=s)
         assert C.AGG_STRATEGY.value(kind) == before + 1, kind
+        # Q3.2's keys pack, but its batch is under SORTED_GROUP_MIN_ROWS
+        assert C.SORTED_GROUP_BYS.value("declined") \
+            == declined + (kind == "hash"), kind
     s.vars.set("pallas_groupagg", "off")
     before = C.AGG_STRATEGY.value("dense")
     eng.execute(ssb.Q4_1.replace("MFGR#2", "MFGR#4"), session=s)
@@ -160,6 +189,9 @@ def test_strategy_is_tallied_where_the_aggregate_is_traced(loaded):
     for kind in ("kernel", "dense", "hash", "scalar"):
         assert snap[f"exec.agg.strategy.{kind}"] \
             == C.AGG_STRATEGY.value(kind)
+    for kind in ("group_by", "declined"):
+        assert snap[f"exec.agg.sorted.{kind}"] \
+            == C.SORTED_GROUP_BYS.value(kind)
 
 
 # -- exec.join.* ---------------------------------------------------------------

@@ -5,8 +5,11 @@ SETS and grouping() against answers computed here (a NULL in the data
 against a rolled-up NULL, the grand total of no rows, AVG through the
 combine step, the dense and the sorted layouts, NULL keys and values,
 slots an estimate sized too small); the sorted layout's networks;
-windows over grouped queries; and what exec.agg.grouping_sets and
-exec.agg.rollup.network count a plan."""
+windows over grouped queries; what exec.agg.grouping_sets and
+exec.agg.rollup.network count a plan; and a plain GROUP BY past the
+dense bound whose keys pack, on the sorted layout as one set or on the
+hash table by its batch's rows (Q89's among them), and what
+exec.agg.sorted.* count."""
 
 import math
 from fractions import Fraction
@@ -438,7 +441,7 @@ def test_compress_equals_a_scatter(n, mask, dtype):
         assert (got[:min(m, count)] == ref[:min(m, count)]).all(), m
 
 
-@pytest.mark.parametrize("n", [1000, 4096, 1 << 15])
+@pytest.mark.parametrize("n", [1000, 4096, 1 << 15, 5000, (1 << 15) + 3])
 def test_prefix_scans_equal_the_plain_ones(n):
     import jax
     import jax.numpy as jnp
@@ -533,3 +536,267 @@ def test_a_year_denser_than_its_share_of_the_dates_overflows_exactly():
     assert counters()[0] == 1
     assert eng.execute(sql(2002), session=s).rows == want(2002)
     assert counters()[0] == 1
+
+
+# -- a plain GROUP BY on the sorted layout -------------------------------------
+
+# SORTED_GROUP_MIN_ROWS that sends a test's batch to each path
+THRESHOLD = {"sorted": 0, "hash": 1 << 40}
+
+SIX_Q = ("SELECT s1, s2, s3, k1, k2, k3, sum(m), count(m), count(*), "
+         "avg(m), min(v), max(v) FROM six "
+         "GROUP BY s1, s2, s3, k1, k2, k3")
+
+
+def _six_keys(n, seed):
+    """Six keys (three strings, three small integers) whose dense
+    domain is past the planner's bound, drawn from 700 tuples so that a
+    group holds several rows; 2 % NULL keys and values. (the columns,
+    the rows as SQL sees them: a NULL None, money in hundredths)."""
+    rng = np.random.default_rng(seed)
+    words = {"s1": [f"c{i}" for i in range(8)],
+             "s2": [f"cl{i}" for i in range(40)],
+             "s3": [f"b{i}" for i in range(25)]}
+    spans = {"s1": 8, "s2": 40, "s3": 25, "k1": 12, "k2": 30, "k3": 5}
+    pool = {c: rng.integers(0, s, 700) for c, s in spans.items()}
+    pick = rng.integers(0, 700, n)
+    cols = {c: pool[c][pick] for c in spans}
+    cols["k1"] = cols["k1"] + 1         # 1..12, a month
+    cols["m"] = rng.integers(0, 100_000, n)
+    cols["v"] = rng.integers(-1000, 1000, n)
+    valid = {c: rng.random(n) > 0.02 for c in cols}
+    rows = []
+    for i in range(n):
+        rows.append(tuple(
+            None if not valid[c][i] else
+            words[c][cols[c][i]] if c in words else int(cols[c][i])
+            for c in cols))
+    return cols, words, valid, rows
+
+
+def _six_engine(cols, words, valid):
+    eng = Engine()
+    eng.execute("CREATE TABLE six (s1 STRING, s2 STRING, s3 STRING, "
+                "k1 INT, k2 INT, k3 INT, m DECIMAL(9,2), v INT)")
+    for c, values in words.items():
+        eng.store.set_dictionary("six", c, values)
+    eng.store.insert_columns("six", cols, eng.clock.now(), valid=valid)
+    eng.execute("ANALYZE six")
+    return eng
+
+
+def _six_oracle(rows):
+    """SIX_Q's groups, computed here row by row."""
+    groups: dict = {}
+    for r in rows:
+        groups.setdefault(r[:6], []).append(r)
+    out = []
+    for key, rs in groups.items():
+        ms = [r[6] for r in rs if r[6] is not None]
+        vs = [r[7] for r in rs if r[7] is not None]
+        out.append(key + (
+            Fraction(sum(ms), 100) if ms else None, len(ms), len(rs),
+            Fraction(sum(ms), 100 * len(ms)) if ms else None,
+            min(vs) if vs else None, max(vs) if vs else None))
+    return out
+
+
+def _six_key(r):
+    return tuple((v is None, v if v is not None else 0) for v in r[:6])
+
+
+def _deltas(eng, run, names):
+    before = eng.metrics.snapshot()
+    out = run()
+    after = eng.metrics.snapshot()
+    return out, {n: after[n] - before[n] for n in names}
+
+
+SORTED_COUNTERS = ("exec.agg.sorted.group_by", "exec.agg.sorted.declined",
+                   "exec.agg.strategy.sorted", "exec.agg.strategy.hash",
+                   "exec.agg.grouping_sets", "exec.agg.rollup.network")
+
+
+@pytest.mark.parametrize("path", ["sorted", "hash"])
+def test_plain_group_by_past_the_dense_bound_equals_its_oracle(
+        path, monkeypatch):
+    """Six keys past the dense bound that pack into one code: one sort
+    of the rows over a batch of SORTED_GROUP_MIN_ROWS or more, the
+    while-loop table under it; the same groups either way, NULL keys
+    grouped together, exact sums and averages."""
+    from cockroach_tpu.exec import compile as C
+    monkeypatch.setattr(C, "SORTED_GROUP_MIN_ROWS", THRESHOLD[path])
+    cols, words, valid, rows = _six_keys(6000, 17)
+    eng = _six_engine(cols, words, valid)
+    s = eng.session()
+    s.vars.set("distsql", "off")    # on a mesh the table merges shards
+    got, d = _deltas(eng, lambda: eng.execute(SIX_Q, session=s).rows,
+                     SORTED_COUNTERS)
+    want = _six_oracle(rows)
+    assert any(None in r[:6] for r in want)
+    _check(sorted(got, key=_six_key), sorted(want, key=_six_key))
+    # one set, so nothing of it is a grouping set or a network's level
+    assert d == {"exec.agg.sorted.group_by": path == "sorted",
+                 "exec.agg.sorted.declined": path == "hash",
+                 "exec.agg.strategy.sorted": path == "sorted",
+                 "exec.agg.strategy.hash": path == "hash",
+                 "exec.agg.grouping_sets": 0,
+                 "exec.agg.rollup.network": 0}
+
+
+def test_the_rule_from_the_plan_alone():
+    """Keys that pack over a batch of at least SORTED_GROUP_MIN_ROWS:
+    `sorted`; under it, or with an exact sum the plan does not prove
+    inside int64: `hash`; a key with no domain (a float, or an
+    aggregate's result as TPC-H Q13's outer key): `hash`, and no
+    PlanError; distributed: `hash`."""
+    from cockroach_tpu.exec import compile as C
+    from cockroach_tpu.sql import parser
+    from cockroach_tpu.sql import plan as P
+
+    cols, words, valid, _ = _six_keys(500, 3)
+    eng = _six_engine(cols, words, valid)
+    eng.execute("CREATE TABLE fl (f FLOAT8, x INT)")
+    eng.execute("INSERT INTO fl VALUES (1.5, 1), (2.5, 2), (1.5, 3)")
+
+    def aggregate(sql):
+        node, _ = eng._plan(parser.parse(sql), eng.session())
+        while not isinstance(node, P.Aggregate):
+            node = node.child
+        return node
+
+    one = C.ExecParams()
+    t = C.SORTED_GROUP_MIN_ROWS
+    agg = aggregate(SIX_Q)
+    assert agg.max_groups == 0 and agg.grouping_sets is None
+    assert [dim for dim, _ in agg.sort_dims] == [8, 40, 25, 12, 30, 5]
+    assert C.aggregate_strategy(agg, t, one) == "sorted"
+    assert C.aggregate_strategy(agg, t - 1, one) == "hash"
+    assert C.aggregate_strategy(
+        agg, t, C.ExecParams(axis_name="shards")) == "hash"
+    # a sum of a signed column is proven inside int64 by nothing
+    agg = aggregate(SIX_Q.replace("max(v)", "max(v), sum(v)"))
+    assert agg.sort_dims and not C._exact_sums_cannot_wrap(agg.aggs, t)
+    assert C.aggregate_strategy(agg, t, one) == "hash"
+    for sql in ("SELECT f, x, count(*) FROM fl GROUP BY f, x",
+                "SELECT c, count(*) FROM (SELECT x, count(*) AS c FROM fl "
+                "GROUP BY x) d GROUP BY c"):
+        agg = aggregate(sql)
+        assert agg.max_groups == 0 and agg.sort_dims == []
+        assert C.aggregate_strategy(agg, 1 << 40, one) == "hash"
+        assert eng.execute(sql).rows
+
+
+def test_plain_sorted_layout_past_its_slots_replans_exactly(monkeypatch):
+    """Slots sized from an estimate that proves low (8,192 for some
+    11,000 groups): the top-k sentinel fires, the plan with the whole
+    slots answers, and keeps answering."""
+    from cockroach_tpu.exec import compile as C
+    monkeypatch.setattr(C, "SORTED_GROUP_MIN_ROWS", 0)
+    eng, rows = _nulls_table(12000, 9)
+    eng._estimate_set_groups = lambda agg: 10.0
+    # NULLS_Q less its sums and averages of the signed v, which keep
+    # the table (no proof bounds them)
+    want = [r[:3] + (r[5], r[6], r[8], r[10], r[11])
+            for r in _each_set_alone(rows, [(0, 1, 2)])]
+    assert len(want) > 8192
+    q = ("SELECT k1, k2, k3, count(v), count(*), sum(f), min(f), max(v) "
+         "FROM wn GROUP BY k1, k2, k3")
+    s = eng.session()
+    s.vars.set("distsql", "off")
+    # traced with the estimate's slots, then with the whole ones; from
+    # then on the plan that answered is the cached one
+    for traces in (2, 0):
+        got, d = _deltas(eng, lambda: eng.execute(q, session=s).rows,
+                         ("exec.agg.sorted.group_by",))
+        got, want = sorted(got, key=_sort_key), sorted(want, key=_sort_key)
+        assert len(got) == len(want)
+        for g, w in zip(got, want):
+            assert g[:5] == w[:5] and g[6:] == w[6:], (g, w)
+            assert (g[5] is None) == (w[5] is None) and (
+                g[5] is None or math.isclose(g[5], w[5], rel_tol=1e-9,
+                                             abs_tol=1e-9)), (g, w)
+        assert len(eng._whole_sorts) == 1
+        assert d == {"exec.agg.sorted.group_by": traces}
+
+
+@pytest.mark.parametrize("path", ["sorted", "hash"])
+def test_sums_no_proof_bounds_keep_the_table_and_stay_exact(
+        path, monkeypatch):
+    """Sums the plan cannot prove inside int64 (a BIGINT of epoch
+    microseconds, rows x max|value| far past 2^62 though every group's
+    sum fits; a signed column) keep the hash table, whose f64 shadow
+    tells a wrapped sum from one that fits, over a batch of any size:
+    exact answers and no overflow error."""
+    from cockroach_tpu.exec import compile as C
+    monkeypatch.setattr(C, "SORTED_GROUP_MIN_ROWS", THRESHOLD[path])
+    cols, words, valid, rows = _six_keys(6000, 23)
+    rng = np.random.default_rng(29)
+    micros = 1_700_000_000_000_000 + rng.integers(0, 10 ** 12, 6000)
+    eng = _six_engine(cols, words, valid)
+    eng.execute("CREATE TABLE ts (s1 STRING, s2 STRING, s3 STRING, "
+                "k1 INT, k2 INT, k3 INT, t BIGINT, v INT)")
+    for c, values in words.items():
+        eng.store.set_dictionary("ts", c, values)
+    eng.store.insert_columns(
+        "ts", {**{c: cols[c] for c in cols if c not in ("m",)},
+               "t": micros},
+        eng.clock.now(),
+        valid={**{c: valid[c] for c in valid if c != "m"},
+               "t": valid["m"]})
+    eng.execute("ANALYZE ts")
+    s = eng.session()
+    s.vars.set("distsql", "off")
+    q = ("SELECT s1, s2, s3, k1, k2, k3, sum(t), avg(t), sum(v) FROM ts "
+         "GROUP BY s1, s2, s3, k1, k2, k3")
+    got, d = _deltas(eng, lambda: eng.execute(q, session=s).rows,
+                     SORTED_COUNTERS)
+    groups: dict = {}
+    for i, r in enumerate(rows):
+        groups.setdefault(r[:6], []).append(
+            (int(micros[i]) if valid["m"][i] else None, r[7]))
+    want = []
+    for key, rs in groups.items():
+        ts = [t for t, _ in rs if t is not None]
+        vs = [v for _, v in rs if v is not None]
+        want.append(key + (sum(ts) if ts else None,
+                           Fraction(sum(ts), len(ts)) if ts else None,
+                           sum(vs) if vs else None))
+    assert 6000 * int(micros.max()) > 1 << 62
+    assert max(len(rs) for rs in groups.values()) > 1
+    got, want = sorted(got, key=_six_key), sorted(want, key=_six_key)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g[:7] == w[:7] and g[8] == w[8], (g, w)
+        assert (g[7] is None) == (w[7] is None) and (
+            g[7] is None or math.isclose(g[7], w[7], rel_tol=1e-12)), (g, w)
+    assert d == {"exec.agg.sorted.group_by": 0,
+                 "exec.agg.sorted.declined": 1,
+                 "exec.agg.strategy.sorted": 0,
+                 "exec.agg.strategy.hash": 1,
+                 "exec.agg.grouping_sets": 0,
+                 "exec.agg.rollup.network": 0}
+
+
+@pytest.mark.parametrize("path", ["sorted", "hash"])
+def test_q89_on_either_path_equals_its_oracle(loaded, path, monkeypatch):
+    """Q89's six keys (category, class, brand, store name, company,
+    month) past the dense bound: sorted where its batch reaches the
+    threshold (at SF1 313,600 rows, over 2^17), the table under it;
+    its Window orders the prefix of what the Aggregate hands on, live
+    groups first on either path."""
+    from cockroach_tpu.exec import compile as C
+    monkeypatch.setattr(C, "SORTED_GROUP_MIN_ROWS", THRESHOLD[path])
+    eng = Engine()
+    tpcds.load(eng, tables=loaded[1])
+    got, d = _deltas(eng, lambda: eng.execute(tpcds.query("q89")).rows,
+                     SORTED_COUNTERS)
+    want = tpcds.ORACLES["q89"](loaded[1], **tpcds.QUALIFICATION["q89"])
+    assert len(want) >= 20
+    _check(got, want)
+    assert d == {"exec.agg.sorted.group_by": path == "sorted",
+                 "exec.agg.sorted.declined": path == "hash",
+                 "exec.agg.strategy.sorted": path == "sorted",
+                 "exec.agg.strategy.hash": path == "hash",
+                 "exec.agg.grouping_sets": 0,
+                 "exec.agg.rollup.network": 0}
