@@ -742,6 +742,7 @@ class DMLMixin:
         return self._dml(session, fn)
 
     def _evict(self, name: str):
+        self._text_plans_drop("write", table=name)
         for k in [k for k in self._device_tables if k[0] == name]:
             self._evict_device(k)
 

@@ -40,7 +40,8 @@ from ..parallel.distagg import make_distributed_fn, queued_collective_call
 from ..parallel.mesh import SHARD_AXIS
 from ..sql import ast, parser
 from ..sql import plan as P
-from ..sql.binder import Binder, BindError, ColumnBinding, Scope
+from ..sql.binder import (Binder, BindError, ColumnBinding, Scope,
+                          volatile_folds)
 from ..sql.bound import BConst
 from ..sql.planner import CatalogView, NotInPlace, PlanError, Planner
 from ..sql.rowenc import ROWID
@@ -134,6 +135,7 @@ from .fastpath import FastpathMixin  # noqa: E402
 from .maintenance import MaintenanceMixin  # noqa: E402
 from .oltplane import OltpLaneMixin  # noqa: E402
 from .scanplane import ScanPlaneMixin  # noqa: E402
+from .textplan import TextPlanMixin, TextProbe  # noqa: E402
 
 
 class _DistRouter:
@@ -257,7 +259,7 @@ class _DistRouter:
 
 
 class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
-             ConstraintMixin, MaintenanceMixin, DMLMixin):
+             ConstraintMixin, MaintenanceMixin, DMLMixin, TextPlanMixin):
     def __init__(self, store: ColumnStore | None = None,
                  clock: Clock | None = None,
                  settings: Settings | None = None,
@@ -407,6 +409,9 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 "sql.plan.cache.hit" if hit else "sql.plan.cache.miss",
                 "compiled-plan cache lookups, by outcome")
             for hit in (True, False)}
+        # the statement-text cache in front of the planner
+        # (exec/textplan.py)
+        self._text_plan_init()
         # cold-start elimination (exec/coldstart.py): persistent XLA
         # compile cache so a restarted process deserializes instead of
         # recompiling; None when disabled or the backend/dir refuses
@@ -787,9 +792,11 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         self.metrics.func_counter(
             "admission.tenant.plan_evictions",
             lambda: (sum(self._exec_cache.tenant_evictions.values())
-                     + sum(self._parse_cache.tenant_evictions.values())),
-            "plan/parse cache entries a tenant evicted from its OWN "
-            "partition on hitting sql.exec.plan_cache.tenant_budget")
+                     + sum(self._parse_cache.tenant_evictions.values())
+                     + sum(self._text_plans.tenant_evictions.values())),
+            "plan/parse/statement-text cache entries a tenant evicted "
+            "from its OWN partition on hitting "
+            "sql.exec.plan_cache.tenant_budget")
         self._admission_settings()
         self.settings.on_change(
             lambda n, v: self._admission_settings()
@@ -874,6 +881,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 "sql.exec.plan_cache.tenant_budget"))
             self._exec_cache.tenant_budget = budget
             self._parse_cache.tenant_budget = budget
+            self._text_plans.tenant_budget = budget
         except Exception:
             pass
 
@@ -1119,23 +1127,36 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         if res is not None:
             return res
         session = session or self.session()
-        # publish the tenant for the parse-cache put: admission (which
-        # publishes it for exec-cache puts) only runs later, inside
-        # _execute_stmt_inner — too late for the parse insert
-        app = str(session.vars.get("application_name") or "")
-        prev_tenant = getattr(self._tenant_tl, "value", "")
-        self._tenant_tl.value = app or f"s{id(session)}"
+        # a SELECT text planned before: its entry holds the parsed
+        # statement too (exec/textplan.py)
+        probe = self._text_probe(sql, session)
+        entry = None if probe is None else probe[1]
+        if entry is not None:
+            stmt = entry.stmt
+        else:
+            # publish the tenant for the parse-cache put: admission
+            # (which publishes it for exec-cache puts) only runs later,
+            # inside _execute_stmt_inner — too late for the parse insert
+            app = str(session.vars.get("application_name") or "")
+            prev_tenant = getattr(self._tenant_tl, "value", "")
+            self._tenant_tl.value = app or f"s{id(session)}"
+            try:
+                stmt = self._parse_cached(sql)
+            except Exception:
+                # a syntax error inside an explicit txn block aborts it,
+                # same as any other statement failure (pg semantics)
+                if session.txn is not None:
+                    session.txn_aborted = True
+                raise
+            finally:
+                self._tenant_tl.value = prev_tenant
+        prev_probe = getattr(self._text_tl, "probe", None)
+        self._text_tl.probe = (None if probe is None
+                               else TextProbe(probe[0], stmt, entry))
         try:
-            stmt = self._parse_cached(sql)
-        except Exception:
-            # a syntax error inside an explicit txn block aborts it,
-            # same as any other statement failure (pg semantics)
-            if session.txn is not None:
-                session.txn_aborted = True
-            raise
+            return self.execute_stmt(stmt, session, sql_text=sql)
         finally:
-            self._tenant_tl.value = prev_tenant
-        return self.execute_stmt(stmt, session, sql_text=sql)
+            self._text_tl.probe = prev_probe
 
     def execute_stmt(self, stmt: ast.Statement, session: Session,
                      sql_text: str = "") -> Result:
@@ -1153,7 +1174,10 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         # statement over other tables neither stalls the OLTP lane nor
         # forces its deferred publish (round-18 group-commit lane).
         _trc.stage("route")
-        tables = self._stmt_tables(stmt)
+        probe = getattr(self._text_tl, "probe", None)
+        tables = (probe.entry.tables if probe is not None
+                  and probe.entry is not None and probe.stmt is stmt
+                  else self._stmt_tables(stmt))
         with self._lane_sync:
             # atomic with lane commits: after this block, any lane
             # write to a suspended table either already sits in
@@ -1242,6 +1266,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
             # index/FK/changefeed must push writes back onto the full
             # path, exec/oltplane.py)
             self._parse_cache.clear()
+            self._text_plans_clear("ddl")
             self._plain_memo.clear()
             self._temps_memo.clear()
             self._inplace_memo.clear()
@@ -1795,6 +1820,8 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 tag="SHOW STATEMENTS")
         if isinstance(stmt, ast.Analyze):
             self.store.analyze(stmt.table)
+            # stats feed the memo's join order and Compact capacities
+            self._text_plans_clear("analyze")
             self.metrics.counter("sql.stats.analyze",
                                  "ANALYZE statements run").inc()
             return Result(tag="ANALYZE")
@@ -2940,6 +2967,13 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                 sel, session, sql_text, no_memo, no_topk, no_compact,
                 no_dist)
 
+    def _seal_open_tables(self) -> None:
+        """Seal every table with rows still buffered, so a plan reads
+        what its tables hold."""
+        for td in self.store.tables.values():
+            if td.open_ts:
+                self.store.seal(td.schema.name)
+
     def _prepare_select_planned(self, sel, session: Session,
                                 sql_text: str, no_memo: bool,
                                 no_topk: bool, no_compact: bool,
@@ -2951,9 +2985,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
         # lifting, `fingerprint` the plan's structural hash and the
         # key, `lookup` the cache and what is made of its answer
         _trc.stage("build")
-        for td in self.store.tables.values():
-            if td.open_ts:
-                self.store.seal(td.schema.name)
+        self._seal_open_tables()
         # the statement's uncorrelated scalar subqueries, each a
         # prepared statement of its own (_prepare_subquery)
         subs: list = []
@@ -3313,6 +3345,21 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
 
     def _exec_select(self, sel, session: Session,
                      sql_text: str, in_place: bool = True) -> Result:
+        probe = getattr(self._text_tl, "probe", None)
+        keep = None
+        if probe is not None and sel is probe.stmt:
+            # the statement execute() handed down: served from the
+            # statement-text cache, or kept there once prepared. Taken
+            # once: the statements it runs beneath are not its own
+            self._text_tl.probe = None
+            if probe.entry is not None:
+                prep = self._text_plan_serve(probe, session)
+                if prep is not None:
+                    return prep.run()
+                # the entry's AST is shared: plan from a copy of its own
+                sel = self._parse_cached(sql_text)
+            self._m_text["miss"].inc()
+            keep = (probe.key, volatile_folds())
         if isinstance(sel, ast.SetOp):
             return self._exec_setop(sel, session, sql_text)
         inline = in_place and self._plans_in_place(sel, session)
@@ -3368,6 +3415,7 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                     if sql_text:
                         self._temps_memo.add(sql_text)
                 else:
+                    self._text_plan_keep(keep, session, sql_text, prep)
                     return prep.run()
             if orig.ctes:
                 # the statement as written, its CTEs through the temps
@@ -3394,7 +3442,9 @@ class Engine(OltpLaneMixin, FastpathMixin, ScanPlaneMixin, DDLMixin,
                     "SELECTs served by the ordered index-range "
                     "path").inc()
                 return res
-        return self._prepare_select(sel, session, sql_text).run()
+        prep = self._prepare_select(sel, session, sql_text)
+        self._text_plan_keep(keep, session, sql_text, prep)
+        return prep.run()
 
     def _exec_setop(self, so: ast.SetOp, session: Session,
                     sql_text: str) -> Result:

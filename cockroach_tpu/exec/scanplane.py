@@ -959,6 +959,8 @@ class ScanPlaneMixin:
         with self._device_lock:
             self._device_tables.pop(key, None)
             self.movement.release_resident(key)
+        # a statement-text cache entry must not keep the batch alive
+        self._text_plans_drop("evicted", table=key[0])
 
     def drop_device_cache(self) -> None:
         """Evict every resident table upload AND release its memory

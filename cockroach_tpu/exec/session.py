@@ -173,6 +173,9 @@ class Prepared:
 
     # (read timestamp, subquery_params' result) of the last dispatch
     _subquery_memo: Optional[tuple] = None
+    # the statement-text cache's key of the entry this handle came from
+    # or went into (exec/textplan.py): a fallback that re-plans drops it
+    text_key: Optional[tuple] = None
 
     def subquery_params(self, ts: Timestamp) -> tuple:
         """This dispatch's runtime arguments: params with every
@@ -459,6 +462,8 @@ class Prepared:
                 self.stmt, self.session, self.sql_text, no_topk=True)
             if self.prefix_key is not None:
                 self.engine._whole_sorts.add(self.prefix_key)
+                self.engine._text_plans_drop("whole_sort",
+                                             key=self.text_key)
                 self.engine.metrics.counter(
                     "exec.sort.prefix_short",
                     "prefix sorts over a hash Aggregate that met more "
@@ -474,6 +479,7 @@ class Prepared:
         skewed between blocks): replan with the full-width masked
         pipeline, always exact, and count it (exec.compact.overflows)."""
         self.engine._m_compact_overflows.inc()
+        self.engine._text_plans_drop("uncompacted", key=self.text_key)
         return self.engine._prepare_select(
             self.stmt, self.session, self.sql_text,
             no_compact=True).run(read_ts)

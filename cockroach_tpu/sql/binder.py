@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import datetime
 import re
+import threading
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -171,6 +172,18 @@ def _leap(y: int) -> bool:
     return y % 4 == 0 and (y % 100 != 0 or y % 400 == 0)
 
 
+# values binds folded that differ from one execution of their statement
+# to the next, counted per thread (Binder.note_volatile)
+_VOLATILE = threading.local()
+
+
+def volatile_folds() -> int:
+    """How many volatile values this thread's binders have folded: a
+    statement whose plan folded one (its count moved while it was
+    planned) is not served again from its text (exec/textplan.py)."""
+    return getattr(_VOLATILE, "n", 0)
+
+
 # ---------------------------------------------------------------------------
 # binder
 # ---------------------------------------------------------------------------
@@ -226,6 +239,13 @@ class Binder:
         # plan_select sets this False for executed SELECTs; DML WHERE /
         # EXPLAIN contexts keep the (documented) per-statement fold
         self.volatile_fold_ok = volatile_fold_ok
+
+    @staticmethod
+    def note_volatile() -> None:
+        """A value folded here differs between executions of the
+        statement: now() and the other statement timestamps, random(),
+        a sequence's value (volatile_folds)."""
+        _VOLATILE.n = volatile_folds() + 1
 
     # -- main dispatch -------------------------------------------------------
     def bind(self, e: ast.Expr) -> BExpr:
@@ -1362,6 +1382,7 @@ class Binder:
                     raise BindError(
                         f"setval value must be an integer, got "
                         f"{v.value!r}")
+            self.note_volatile()
             return BConst(self.sequence_ops(name, seq, arg), INT8)
         if name == "grouping":
             return self.bind_grouping(e)

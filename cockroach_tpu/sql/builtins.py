@@ -187,9 +187,11 @@ def bind_builtin(binder, name: str, args: list, e) -> BExpr | None:
         # one value per statement, not per row — the device kernels
         # have no RNG key plumbing yet)
         import random as _random
+        binder.note_volatile()
         return BConst(_random.random(), FLOAT8)
     if name == "gen_random_uuid":
         import uuid as _uuid
+        binder.note_volatile()
         return BConst(str(_uuid.uuid4()), STRING)
     if name == "version":
         from .. import __version__
@@ -284,11 +286,13 @@ def bind_builtin(binder, name: str, args: list, e) -> BExpr | None:
         us = binder.now_micros
         if us is None:
             raise BuiltinError(f"{name}() needs a statement timestamp")
+        binder.note_volatile()
         return BConst(int(us), TIMESTAMP)
     if name == "current_date":
         us = binder.now_micros
         if us is None:
             raise BuiltinError("current_date needs a statement timestamp")
+        binder.note_volatile()
         return BConst(int(us // 86_400_000_000), DATE)
     if name == "to_timestamp":
         x = binder.coerce(args[0], FLOAT8)
@@ -419,6 +423,7 @@ def bind_builtin(binder, name: str, args: list, e) -> BExpr | None:
         if us is None:
             raise BuiltinError("timeofday() needs a statement "
                                "timestamp")
+        binder.note_volatile()
         dt = datetime.datetime(1970, 1, 1) + \
             datetime.timedelta(microseconds=int(us))
         return BConst(dt.strftime("%a %b %d %H:%M:%S.%f")
